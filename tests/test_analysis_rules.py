@@ -332,20 +332,20 @@ class TestHostEffects:
         )
         assert ids(fs) == ["ZNC002", "ZNC002"]
 
-    def test_compat_shard_map_body_is_traced(self):
-        """The repo's own compat shim must count as a transform — the
-        shard_map bodies are exactly the per-device code these rules
-        exist to protect."""
+    def test_jax_shard_map_body_is_traced(self):
+        """``jax.shard_map`` must count as a transform — the shard_map
+        bodies are exactly the per-device code these rules exist to
+        protect."""
         fs = run(
             """
             import time
-            from znicz_tpu.core.compat import shard_map
+            import jax
 
             def outer(mesh, spec, x):
                 def local(xs):
                     time.time()
                     return xs
-                return shard_map(
+                return jax.shard_map(
                     local, mesh=mesh, in_specs=(spec,), out_specs=spec
                 )(x)
             """,

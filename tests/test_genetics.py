@@ -147,47 +147,32 @@ class TestConcurrentOptimize:
         assert r2["best_genome"] == r1["best_genome"]
 
 
-class TestSharedAcceleratorWarning:
-    def test_warns_when_workers_exceed_chips(self, monkeypatch):
-        import warnings
+class TestAcceleratorWorkerPolicy:
+    """One process per chip (core/backend.py): a pool that cannot get
+    the accelerator is refused BEFORE any child starts."""
 
-        import jax
+    def test_two_accelerator_workers_are_refused(self):
+        from znicz_tpu.core import backend
 
-        from znicz_tpu.core import subproc
+        with pytest.raises(backend.AcceleratorWorkersError, match="2 worker"):
+            backend.check_workers(2, "tpu")
+        # the CPU is the documented recipe for concurrent evaluations,
+        # and what the suite's own JAX_PLATFORMS=cpu pools run on
+        backend.check_workers(4, "cpu")
+        backend.check_workers(4, None)
+        backend.check_workers(0, "tpu")  # in-process search: no pool
 
-        jax.devices()  # the parent-side check only fires on an
-        # already-initialized backend (it must never initialize one)
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(jax, "device_count", lambda: 1)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            subproc.warn_if_shared_accelerator(4, None)
-        assert any("contend" in str(x.message) for x in w)
-        # device='cpu' is the documented recipe: no warning
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            subproc.warn_if_shared_accelerator(4, "cpu")
-            subproc.warn_if_shared_accelerator(1, None)
-        assert not w
-
-    def test_worker_side_check_fires_from_payload_tag(
-        self, monkeypatch, capsys
+    def test_parent_holding_the_chip_refuses_even_one_worker(
+        self, monkeypatch
     ):
-        # the in-worker twin covers the CLI path where the parent never
-        # initializes a backend (only one payload carries the tag)
-        import jax
+        from znicz_tpu.core import backend
 
-        from znicz_tpu.core import subproc
-
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(jax, "device_count", lambda: 1)
-        subproc._worker_warn_shared_chip({"warn_n_workers": 4})
-        assert "contend" in capsys.readouterr().err
-        subproc._worker_warn_shared_chip({})  # untagged: silent
-        subproc._worker_warn_shared_chip(
-            {"warn_n_workers": 4, "device": "cpu"}
-        )
-        assert capsys.readouterr().err == ""
+        backend.check_workers(1, "tpu")  # parent off the chip: fine
+        monkeypatch.setattr(backend, "holds_accelerator", lambda: True)
+        with pytest.raises(
+            backend.AcceleratorWorkersError, match="already holds"
+        ):
+            backend.check_workers(1, "tpu")
 
 
 class TestOptimizeCLI:

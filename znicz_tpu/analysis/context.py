@@ -65,7 +65,6 @@ _MODULE_PATHS = {
     "jax.experimental",
     "jax.experimental.shard_map",
     "jax.experimental.pjit",
-    "znicz_tpu.core.compat",  # this repo's shard_map/pcast shims
 }
 
 

@@ -153,20 +153,22 @@ def train_from_module(
     members share the parent's workflow (model/loader) but carry their own
     trained params — ``predict``/``evaluate`` work as usual.
 
-    On a single shared accelerator pass ``device="cpu"`` — workers would
-    contend for the one chip.
+    More than one worker needs ``device="cpu"``: a jax process holds the
+    chips it opens, so an accelerator pool wider than one — or one whose
+    parent already computes on the chip — is refused before any child
+    starts (``core.backend.check_workers``).
     """
     import pickle
     import tempfile
 
+    from znicz_tpu.core import backend
     from znicz_tpu.core.subproc import (
         _run_workflow_module,
         run_pool,
         train_member,
-        warn_if_shared_accelerator,
     )
 
-    parent_warned = warn_if_shared_accelerator(n_workers, device)
+    backend.check_workers(max(n_workers, 1), device)
     seeds = [base_seed + 1000 * i for i in range(n_models)]
     with tempfile.TemporaryDirectory(prefix="znicz_ens_") as tmp:
         payloads = [
@@ -180,10 +182,6 @@ def train_from_module(
             }
             for i, seed in enumerate(seeds)
         ]
-        if payloads and n_workers > 1 and not parent_warned:
-            # first worker checks contention from ITS backend (the parent
-            # may never initialize one)
-            payloads[0]["warn_n_workers"] = n_workers
         results = run_pool(train_member, payloads, n_workers)
         member_params = []
         for r in results:
@@ -191,14 +189,10 @@ def train_from_module(
                 member_params.append(pickle.load(f))
     # build the aggregation scaffold in-process (dry run: model + loader,
     # no training) and graft each member's trained params onto views of it.
-    # Honor the caller's device choice only while it can still take effect:
-    # a jax_platforms update on an already-initialized parent backend is at
-    # best a no-op (the spawned workers above always honored it)
-    try:
-        from jax._src.xla_bridge import backends_are_initialized
-    except ImportError:  # private API: assume initialized if it moves
-        def backends_are_initialized():
-            return True
+    # The caller's device choice is honored only while it can still take
+    # effect: a parent whose backend is already up keeps it (the spawned
+    # workers above always honored it)
+    from jax._src.xla_bridge import backends_are_initialized
 
     scaffold_device = device if not backends_are_initialized() else None
     launcher, _ = _run_workflow_module(

@@ -217,8 +217,10 @@ def optimize_workflow(
     SURVEY.md 2.5) — every evaluation gets a fresh interpreter seeded from
     ``--random-seed``, so results are deterministic given seeds and
     IDENTICAL for any worker count.  0 (default) keeps the legacy
-    in-process sequential path.  On a single shared accelerator run the
-    search with ``--device cpu`` — workers would contend for the one chip.
+    in-process sequential path.  More than one worker needs
+    ``--device cpu``: a jax process holds the chips it opens, so an
+    accelerator pool wider than one is refused before any child starts
+    (``core.backend.check_workers``).
     """
     if tunables is None:
         tunables = find_tunables(root)
@@ -242,17 +244,11 @@ def optimize_workflow(
 
     evaluate_batch = None
     if n_workers >= 1:
-        from znicz_tpu.core.subproc import (
-            eval_genome,
-            run_pool,
-            warn_if_shared_accelerator,
-        )
+        from znicz_tpu.core import backend
+        from znicz_tpu.core.subproc import eval_genome, run_pool
 
         args = launcher.args
-        # one contention warning per SEARCH: parent-side if its backend is
-        # already up, else the first worker of the first generation
-        parent_warned = warn_if_shared_accelerator(n_workers, args.device)
-        pending_worker_warn = not parent_warned and n_workers > 1
+        backend.check_workers(n_workers, args.device)
 
         def evaluate_batch(genomes):
             payloads = [
@@ -266,12 +262,6 @@ def optimize_workflow(
                 }
                 for genome in genomes
             ]
-            nonlocal pending_worker_warn
-            if payloads and pending_worker_warn:
-                # first worker of the first generation checks contention
-                # from ITS backend (the parent may never initialize one)
-                pending_worker_warn = False
-                payloads[0]["warn_n_workers"] = n_workers
             return run_pool(eval_genome, payloads, n_workers)
 
         evaluate = None  # all evaluations go through the worker pool

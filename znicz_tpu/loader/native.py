@@ -16,6 +16,7 @@ numpy — it exists so callers have one code path.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -45,20 +46,25 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         cache = os.environ.get(
             "ZNICZ_NATIVE_CACHE", os.path.join(_REPO_ROOT, ".native_cache")
         )
-        so_path = os.path.join(cache, "libbatch_assembler.so")
+        # keyed by the source's CONTENT: a library left in the cache by
+        # another checkout (or copied along with the tree) can only ever
+        # be loaded for the source it was built from
+        with open(_SOURCE, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so_path = os.path.join(cache, f"libbatch_assembler-{digest}.so")
         try:
-            if not os.path.exists(so_path) or os.path.getmtime(
-                so_path
-            ) < os.path.getmtime(_SOURCE):
+            if not os.path.exists(so_path):
                 os.makedirs(cache, exist_ok=True)
+                tmp = f"{so_path}.{os.getpid()}.tmp"
                 subprocess.run(
                     [
                         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                        "-o", so_path, _SOURCE, "-pthread",
+                        "-o", tmp, _SOURCE, "-pthread",
                     ],
                     check=True,
                     capture_output=True,
                 )
+                os.replace(tmp, so_path)  # atomic: no half-written loads
             lib = ctypes.CDLL(so_path)
         except (OSError, subprocess.SubprocessError) as exc:
             # falling back to the numpy path is fine for correctness but

@@ -29,7 +29,6 @@ from typing import Dict
 
 import numpy as np
 
-from znicz_tpu.core.compat import shard_map
 from znicz_tpu.loader.base import TRAIN, pool_offsets
 
 
@@ -196,7 +195,7 @@ class PoolShardedMixin:
         spec = P(DATA_AXIS)
 
         def pre(payload, ctx):
-            return shard_map(
+            return jax.shard_map(
                 per_shard_pre,
                 mesh=mesh,
                 in_specs=(spec, spec),

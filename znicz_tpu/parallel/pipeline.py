@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from znicz_tpu.core.compat import pcast, shard_map
 from znicz_tpu.parallel.mesh import PIPE_AXIS  # noqa: F401  (canonical axis)
 
 
@@ -89,7 +88,7 @@ def _local_pipeline(
     # fresh constants are unvarying: pcast buf to varying over EVERY manual
     # axis (pipe, and data when composing with DP) before it mixes with
     # device-dependent values; zeros_like(x) inherits varying from x
-    buf0 = pcast(
+    buf0 = jax.lax.pcast(
         jnp.zeros(x.shape[1:], x.dtype),
         vary_axes or axis_name,
         to="varying",
@@ -218,7 +217,7 @@ def pipeline_apply(
     # row dim additionally shards over data (independent pipeline per
     # data replica)
     store_spec = P(axis, data_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _local_pipeline,
             apply_one=apply_one,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from znicz_tpu.core.compat import pcast, shard_map
 
 SEQ_AXIS = "data"  # default: ring over the data axis of parallel.make_mesh
 
@@ -73,7 +72,7 @@ def _local_ring(q, k, v, *, axis_name, causal, scale):
     # mark the fresh accumulators as device-varying so the fori_loop carry
     # types match after the body mixes them with sharded q/k/v
     def varying(x):
-        return pcast(x, axis_name, to="varying")
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     o = varying(jnp.zeros((b, h, t_local, d), jnp.float32))
     m = varying(jnp.full((b, h, t_local), -jnp.inf, jnp.float32))
@@ -144,7 +143,7 @@ def _local_ring_flash(q, k, v, *, axis_name, causal, scale):
     my_idx = jax.lax.axis_index(axis_name)
 
     def varying(x):
-        return pcast(x, axis_name, to="varying")
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     o = varying(jnp.zeros((b, t_local, h, d), jnp.float32))
     lse = varying(jnp.full((b, t_local, h), -jnp.inf, jnp.float32))
@@ -182,7 +181,7 @@ def ring_attention(
         raise ValueError(f"inner={inner!r}: want 'dense' or 'flash'")
     local = _local_ring_flash if inner == "flash" else _local_ring
     spec = P(None, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(local, axis_name=axis, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
