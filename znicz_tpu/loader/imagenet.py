@@ -23,10 +23,12 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from znicz_tpu import observability
 from znicz_tpu.core import prng
 from znicz_tpu.loader.base import (
     SPLITS,
@@ -38,6 +40,7 @@ from znicz_tpu.loader.base import (
 )
 from znicz_tpu.loader.pool_sharded import PoolShardedMixin
 from znicz_tpu.loader.image import IMAGE_EXTENSIONS, _read_image
+from znicz_tpu.observability import pipeline as _pipeline
 
 MEAN_FILE = "mean_rgb.json"
 CLASSES_FILE = "classes.json"
@@ -265,8 +268,23 @@ class ImageNetLoader(PoolShardedMixin, Loader):
             flip = np.zeros(b, np.uint8)
         return oy, ox, flip
 
+    @staticmethod
+    def _timed(stage: str, fn, *args):
+        """``fn(*args)`` under the span ``loader/<stage>`` and the stage
+        label of the same name: a named part of the producer's fetch."""
+        with observability.span(f"loader/{stage}"):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                _pipeline.stage_seconds().labels(stage=stage).observe(
+                    time.perf_counter() - t0
+                )
+
     def fill(self, indices: np.ndarray, split: str) -> Minibatch:
-        oy, ox, flip = self._crop_params(indices, split)
+        oy, ox, flip = self._timed(
+            _pipeline.STAGE_CROP_PARAMS, self._crop_params, indices, split
+        )
         if self._device_resident:
             # [B, 4] int32 payload: pool row + crop offsets + flip bit —
             # the whole host->device transfer for this minibatch
@@ -285,8 +303,12 @@ class ImageNetLoader(PoolShardedMixin, Loader):
             from znicz_tpu.loader import native
 
             cs = self.crop_size
-            data = native.crop_gather_u8(
-                self.images[split], indices, oy, ox, flip, cs, cs
+            # one native call, one span, whatever the batch: handing the
+            # assembler row chunks for shorter spans cost 8-14 % of the
+            # crop's wall (a thread spawn and a join barrier per chunk)
+            data = self._timed(
+                _pipeline.STAGE_CROP, native.crop_gather_u8,
+                self.images[split], indices, oy, ox, flip, cs, cs,
             )
         return Minibatch(
             data=data,

@@ -16,8 +16,17 @@ placement) and **enqueue** (blocked handing the batch over) observe into
 "producer slow" (long fetch/transform) and "producer starved" (long
 enqueue — the consumer is the bottleneck and the queue stayed full,
 counted by ``znicz_prefetch_queue_full_total``) are distinguishable in
-one capture.  The ``loader.fetch`` fault point fires inside the timed
-fetch, making a slow producer a deterministic CI fixture.
+one capture.  The stages TILE the loop: one
+:class:`~znicz_tpu.observability.pipeline.StageClock` makes each stage's
+end the next one's start, and the wall of each whole iteration goes to
+``znicz_pipeline_producer_seconds``, so time that falls between stages
+is seen.  The ``loader.fetch`` fault point fires inside the timed fetch,
+making a slow producer a deterministic CI fixture.
+
+The consumer's wait (``znicz_prefetch_wait_seconds``) is labelled by
+where in the epoch it fell: ``at="first"`` (the first batch — the
+producer thread starts with the epoch, so this fetch overlaps no step),
+``"steady"``, and ``"end"`` (the wait for the end-of-epoch sentinel).
 """
 
 from __future__ import annotations
@@ -60,7 +69,9 @@ def prefetch(
     thread — host decode/augment work, or the workflow's device-placement
     closure — timed as the ``transform_stage`` pipeline stage (pass
     ``transform_stage=None`` when the callable owns its own
-    instrumentation, e.g. an :class:`~znicz_tpu.observability.H2DProbe`).
+    instrumentation, e.g. an :class:`~znicz_tpu.observability.H2DProbe`,
+    which joins the producer's stage clock; what such a callable leaves
+    unobserved belongs to no stage and shows as unattributed).
 
     Exceptions in the producer (fetch or transform) re-raise at the
     consumer's next pull.  If the consumer abandons the iterator
@@ -74,9 +85,8 @@ def prefetch(
     # per-stage producer telemetry: each span is on the LOADER's own
     # thread track in Perfetto, so producer stalls line up against the
     # consumer's znicz_prefetch_wait_seconds histogram and the
-    # train/serve spans they starve.  No-op span cost when the tracer
-    # is idle; one histogram observe per stage per item otherwise.
-    stage_hist = _pipeline.stage_seconds()
+    # train/serve spans they starve.  One TraceAnnotation and one
+    # histogram observe per stage per item.
     queue_full = observability.counter(
         _pipeline.QUEUE_FULL_METRIC,
         "items whose producer-side enqueue found the prefetch queue "
@@ -88,50 +98,54 @@ def prefetch(
         tracer = observability.get_tracer()
         try:
             it = iter(iterable)
-            while True:
-                t0 = time.perf_counter()
-                with tracer.span("loader/fetch"):
-                    # the fault fires INSIDE the timed window, so an
-                    # injected delay reads as a slow producer to the
-                    # attribution (the input-bound CI fixture)
-                    faults.fire("loader.fetch")
-                    item = next(it, _SENTINEL)
-                stage_hist.labels(stage=_pipeline.STAGE_FETCH).observe(
-                    time.perf_counter() - t0
-                )
-                if item is _SENTINEL:
-                    break
-                if transform is not None:
-                    if transform_stage is None:
-                        item = transform(item)
-                    else:
-                        t0 = time.perf_counter()
-                        with tracer.span(f"loader/{transform_stage}"):
+            # one iteration is fetch -> transform or h2d -> enqueue; the
+            # clock makes each stage end where the next starts
+            with _pipeline.StageClock() as clock:
+                while True:
+                    with tracer.span("loader/fetch"):
+                        # the fault fires INSIDE the timed window, so an
+                        # injected delay reads as a slow producer to the
+                        # attribution (the input-bound CI fixture)
+                        faults.fire("loader.fetch")
+                        item = next(it, _SENTINEL)
+                    clock.lap(_pipeline.STAGE_FETCH)
+                    if item is _SENTINEL:
+                        clock.close_iteration()
+                        break
+                    if transform is not None:
+                        if transform_stage is None:
+                            # the callable laps its own stage on this
+                            # clock (an H2DProbe does, and its hand-over
+                            # tail counts as enqueue); one that observes
+                            # nothing spent time that is in no stage,
+                            # and that must not leak into enqueue
+                            mark = clock.mark
                             item = transform(item)
-                        stage_hist.labels(stage=transform_stage).observe(
-                            time.perf_counter() - t0
-                        )
-                # bounded put that gives up when the consumer went away
-                t0 = time.perf_counter()
-                try:
-                    # non-blocking first attempt: ANY fullness counts as
-                    # a depth-exhaustion stall, even one shorter than
-                    # the polling timeout below
-                    q.put_nowait(item)
-                except queue.Full:  # znicz-check: disable=ZNC008
-                    queue_full.inc()
-                    while not stop.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            break
-                        # polling control flow, not a swallowed failure
-                        except queue.Full:  # znicz-check: disable=ZNC008
-                            continue
-                stage_hist.labels(stage=_pipeline.STAGE_ENQUEUE).observe(
-                    time.perf_counter() - t0
-                )
-                if stop.is_set():
-                    return
+                            if clock.mark == mark:
+                                clock.skip()
+                        else:
+                            with tracer.span(f"loader/{transform_stage}"):
+                                item = transform(item)
+                            clock.lap(transform_stage)
+                    # bounded put that gives up when the consumer went away
+                    try:
+                        # non-blocking first attempt: ANY fullness counts
+                        # as a depth-exhaustion stall, even one shorter
+                        # than the polling timeout below
+                        q.put_nowait(item)
+                    except queue.Full:  # znicz-check: disable=ZNC008
+                        queue_full.inc()
+                        while not stop.is_set():
+                            try:
+                                q.put(item, timeout=0.1)
+                                break
+                            # polling control flow, not a swallowed failure
+                            except queue.Full:  # znicz-check: disable=ZNC008
+                                continue
+                    clock.lap(_pipeline.STAGE_ENQUEUE)
+                    clock.close_iteration()
+                    if stop.is_set():
+                        return
         except BaseException as e:  # noqa: BLE001 — must cross threads
             error.append(e)
         finally:
@@ -149,10 +163,8 @@ def prefetch(
     # how long the training loop blocked waiting on the loader: the
     # "is the input pipeline the bottleneck" histogram — near-zero waits
     # mean the device is the limit; long waits mean the loader is
-    wait = observability.histogram(
-        "znicz_prefetch_wait_seconds",
-        "seconds the consumer blocked waiting for the next minibatch",
-    )
+    wait = _pipeline.wait_seconds()
+    at = _pipeline.WAIT_FIRST
     t = threading.Thread(target=worker, daemon=True)
     t.start()
     try:
@@ -173,11 +185,14 @@ def prefetch(
                             "prefetch producer thread died without "
                             "delivering a sentinel or an error"
                         )
-            wait.observe(time.perf_counter() - t0)
+            waited = time.perf_counter() - t0
             if item is _SENTINEL:
+                wait.labels(at=_pipeline.WAIT_END).observe(waited)
                 if error:
                     raise error[0]
                 return
+            wait.labels(at=at).observe(waited)
+            at = _pipeline.WAIT_STEADY
             yield item
     finally:
         # runs on normal exhaustion AND on generator close/abandonment

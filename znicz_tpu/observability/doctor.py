@@ -7,6 +7,15 @@ anomaly state, e.g. ::
     $ tools/znicz-doctor run/metrics.prom
     input-bound: 0.83 of step wall in prefetch-wait (compute 0.12,
       h2d 0.03, other 0.02); H2D ~12.0 MB/s; confidence high, 64 steps
+    producer loop 5.310 s:
+      fetch           5.102 s  96.1%
+        crop_params   0.031 s
+        crop          4.987 s
+      h2d             0.120 s   2.3%
+      enqueue         0.088 s   1.7%
+      unattributed    0.000 s   0.0%
+      h2d_landed      1.904 s  (beside the loop: device_put call to ready)
+    waits: first 0.331 s, steady 4.650 s, end 0.000 s
     anomalies: none
     suggest: raise prefetch depth, shard loaders across processes, ...
 
@@ -90,6 +99,48 @@ def _render_recovery(rec: dict) -> List[str]:
     return lines
 
 
+def _render_producer(att: dict) -> List[str]:
+    """The producer's stage table and the consumer's waits by position:
+    silent for a capture whose producer never ran."""
+    total = att.get("producer_seconds") or 0.0
+    stages = att.get("stages") or {}
+    lines: List[str] = []
+    if total > 0:
+        lines.append(f"producer loop {total:.3f} s:")
+        for stage, parts in (
+            ("fetch", ("crop_params", "crop")),
+            ("host_transform", ()), ("h2d", ()), ("enqueue", ()),
+        ):
+            seconds = stages.get(stage, 0.0)
+            if not seconds:
+                continue  # a stage this producer does not have
+            lines.append(
+                f"  {stage:<15} {seconds:7.3f} s {100 * seconds / total:5.1f}%"
+            )
+            lines.extend(
+                f"    {part:<13} {stages[part]:7.3f} s"
+                for part in parts if stages.get(part)
+            )
+        hole = att.get("producer_unattributed_frac", 0.0)
+        lines.append(
+            f"  {'unattributed':<15} {hole * total:7.3f} s {100 * hole:5.1f}%"
+        )
+        if stages.get("h2d_landed"):
+            lines.append(
+                f"  {'h2d_landed':<15} {stages['h2d_landed']:7.3f} s  "
+                "(beside the loop: device_put call to ready)"
+            )
+    waits = att.get("waits") or {}
+    if any(waits.values()):
+        lines.append(
+            "waits: " + ", ".join(
+                f"{at} {waits.get(at, 0.0):.3f} s"
+                for at in ("first", "steady", "end")
+            )
+        )
+    return lines
+
+
 def _render(att: dict, anomalies: dict, recovery: dict) -> str:
     lines: List[str] = []
     if att["verdict"] == "no-data":
@@ -115,6 +166,7 @@ def _render(att: dict, anomalies: dict, recovery: dict) -> str:
                 "time(s): the producer outran the consumer — the "
                 "input pipeline is keeping up"
             )
+    lines.extend(_render_producer(att))
     if anomalies["active"]:
         counts = ", ".join(
             f"{k}={v}" for k, v in anomalies["counts"].items()

@@ -14,7 +14,16 @@ instrumentation:
   (decode/augment callables run in the producer thread) and ``enqueue``
   (blocked handing the batch over — depth exhaustion); the workflow's
   device-placement closure observes ``h2d`` through :class:`H2DProbe`
-  (bytes moved + wall -> the live ``znicz_h2d_bytes_per_second`` gauge).
+  (the ``device_put`` calls).  The four TILE the producer's loop: one
+  :class:`StageClock` per producer thread makes each stage's end the
+  next one's start, and ``znicz_pipeline_producer_seconds`` holds the
+  wall of each whole iteration, so what no stage covers shows as
+  ``producer_unattributed_frac`` instead of vanishing.  Three more
+  labels stand beside the tile and are never added to it: ``crop_params``
+  and ``crop`` (parts of ``fetch``, observed by ``ImageNetLoader.fill``)
+  and ``h2d_landed`` (``device_put`` call to the arrays being ready on
+  the device, taken by the probe's watcher thread; bytes over these
+  seconds is the ``znicz_h2d_bytes_per_second`` gauge).
 * **:class:`PipelineAttribution`** — decomposes the per-step wall clock
   (``znicz_train_step_wall_seconds``) into fractions (compute /
   prefetch-wait / h2d / other) that sum to ~1.0, names the bottleneck
@@ -29,9 +38,11 @@ residual — untimed host work: python loop, stacking).  H2D is then
 carved out of whichever slice it actually ran in: with the prefetch
 thread on, the producer's ``h2d`` share of its busy time prorates the
 wait slice (while the consumer waits, the producer is in one of its
-stages); with prefetching off the probe ran inline on the consumer, so
-its seconds come out of the residual.  Either way the four fractions
-are disjoint and sum to 1 (measurement jitter is renormalized away).
+stages, and the share is taken of the producer's own total less its
+time blocked in ``enqueue``); with prefetching off the probe ran inline
+on the consumer, so its seconds come out of the residual.  Either way
+the four fractions are disjoint and sum to 1 (measurement jitter is
+renormalized away).
 
 Pure stdlib — importing this module must never pull in jax (the doctor
 CLI runs on hosts with no accelerator stack).
@@ -40,7 +51,9 @@ CLI runs on hosts with no accelerator stack).
 from __future__ import annotations
 
 import contextlib
+import logging
 import math
+import queue
 import threading
 import time
 from collections import deque
@@ -51,7 +64,10 @@ from znicz_tpu.observability.registry import (
     get_registry,
     parse_prometheus_text,
 )
+from znicz_tpu.observability.tracing import get_tracer
 from znicz_tpu.utils import faults
+
+logger = logging.getLogger(__name__)
 
 # the producer/consumer stage taxonomy (docs/OBSERVABILITY.md
 # "Training observability")
@@ -59,11 +75,25 @@ STAGE_FETCH = "fetch"
 STAGE_TRANSFORM = "host_transform"
 STAGE_H2D = "h2d"
 STAGE_ENQUEUE = "enqueue"
+# the stages that tile one producer iteration, in loop order
+TILING_STAGES = (STAGE_FETCH, STAGE_TRANSFORM, STAGE_H2D, STAGE_ENQUEUE)
+# parts of fetch (ImageNetLoader.fill) and the copy's landing time
+# (H2DProbe's watcher): beside the tile, never added to it
+STAGE_CROP_PARAMS = "crop_params"
+STAGE_CROP = "crop"
+STAGE_H2D_LANDED = "h2d_landed"
+
+# where in the epoch a consumer's wait fell (znicz_prefetch_wait_seconds
+# {at}): the first batch overlaps no step, the sentinel none either
+WAIT_FIRST = "first"
+WAIT_STEADY = "steady"
+WAIT_END = "end"
 
 STEP_WALL_METRIC = "znicz_train_step_wall_seconds"
 WAIT_METRIC = "znicz_prefetch_wait_seconds"
 PHASE_METRIC = "znicz_train_phase_seconds"
 STAGE_METRIC = "znicz_pipeline_stage_seconds"
+PRODUCER_METRIC = "znicz_pipeline_producer_seconds"
 H2D_BPS_METRIC = "znicz_h2d_bytes_per_second"
 H2D_BYTES_METRIC = "znicz_h2d_bytes_total"
 QUEUE_FULL_METRIC = "znicz_prefetch_queue_full_total"
@@ -93,6 +123,7 @@ WINDOW_METRICS = (
     WAIT_METRIC,
     PHASE_METRIC,
     STAGE_METRIC,
+    PRODUCER_METRIC,
     H2D_BYTES_METRIC,
     QUEUE_FULL_METRIC,
 )
@@ -103,9 +134,33 @@ def stage_seconds(registry: Optional[MetricsRegistry] = None):
     reg = registry if registry is not None else get_registry()
     return reg.histogram(
         STAGE_METRIC,
-        "input-pipeline per-stage wall seconds "
-        "(fetch / host_transform / h2d / enqueue)",
+        "input-pipeline per-stage wall seconds (fetch / host_transform / "
+        "h2d / enqueue tile the producer's loop; crop_params and crop are "
+        "parts of fetch; h2d_landed is device_put call to arrays ready)",
         ("stage",),
+    )
+
+
+def producer_seconds(registry: Optional[MetricsRegistry] = None):
+    """Wall of each whole producer iteration (get-or-create): the total
+    the tiling stages are held to."""
+    reg = registry if registry is not None else get_registry()
+    return reg.histogram(
+        PRODUCER_METRIC,
+        "wall seconds of one whole prefetch-producer iteration "
+        "(fetch -> transform or h2d -> enqueue)",
+    )
+
+
+def wait_seconds(registry: Optional[MetricsRegistry] = None):
+    """Consumer-side wait for the next minibatch, labelled by where in
+    the epoch it fell (get-or-create)."""
+    reg = registry if registry is not None else get_registry()
+    return reg.histogram(
+        WAIT_METRIC,
+        "seconds the consumer blocked waiting for the next minibatch "
+        "(at = first batch of an epoch / steady / end-of-epoch sentinel)",
+        ("at",),
     )
 
 
@@ -131,21 +186,87 @@ def reset_window(registry: Optional[MetricsRegistry] = None) -> None:
             m.reset()
 
 
+_clock_local = threading.local()
+
+
+class StageClock:
+    """Contiguous stage timing on one producer thread.
+
+    ``lap(stage)`` observes the time since the previous lap (or skip)
+    into the stage histogram and starts the next stage at that same
+    clock read, so the stages of one iteration leave no hole between
+    them; ``skip()`` moves the mark without observing — that time
+    belongs to no stage and shows as unattributed.  ``close_iteration``
+    observes mark-to-mark into ``znicz_pipeline_producer_seconds``: the
+    sum of the laps equals the sum of the iterations exactly unless
+    something skipped.  While active (a ``with`` block) the clock is
+    this thread's current one, which is how :class:`H2DProbe` joins the
+    tile from inside a transform callable."""
+
+    def __init__(self):
+        self._stages = stage_seconds()
+        self._iterations = producer_seconds()
+        self.mark = self._iteration_start = 0.0
+
+    def __enter__(self) -> "StageClock":
+        _clock_local.clock = self
+        self.mark = self._iteration_start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _clock_local.clock = None
+
+    def lap(self, stage: str, family=None) -> float:
+        """Close ``stage`` at this clock read (``family``: the stage
+        histogram of an instrument that keeps its own registry)."""
+        now = time.perf_counter()
+        seconds, self.mark = now - self.mark, now
+        (family or self._stages).labels(stage=stage).observe(seconds)
+        return seconds
+
+    def skip(self) -> None:
+        self.mark = time.perf_counter()
+
+    def close_iteration(self) -> None:
+        self._iterations.observe(self.mark - self._iteration_start)
+        self._iteration_start = self.mark
+
+
+class _Transfer:
+    """What ``H2DProbe.measure`` yields: ``watch(*arrays)`` names the
+    device arrays whose landing the probe's watcher should time."""
+
+    __slots__ = ("arrays",)
+
+    def __init__(self):
+        self.arrays: tuple = ()
+
+    def watch(self, *arrays) -> None:
+        self.arrays = tuple(
+            a for a in arrays if hasattr(a, "block_until_ready")
+        )
+
+
 class H2DProbe:
-    """Host->device transfer probe: bytes moved + wall time.
+    """Host->device transfer probe: bytes moved, call time, landing time.
 
-    ``with probe.measure(nbytes):`` around the device placement calls
-    observes the ``h2d`` stage histogram, counts
-    ``znicz_h2d_bytes_total`` and keeps the live
-    ``znicz_h2d_bytes_per_second`` gauge fresh from a rolling window of
-    recent transfers.  The wall measured is the *initiation* wall — on
-    an async transport this under-reports link occupancy and
-    over-reports bandwidth, so the gauge is a best-effort live signal,
-    while the byte counter and stage histogram stay exact.
+    ``with probe.measure(nbytes) as put:`` around the device placement
+    calls observes the ``h2d`` stage (the *call* wall: ``device_put``
+    returns before the copy lands) and counts ``znicz_h2d_bytes_total``.
+    ``put.watch(x, y, ...)`` hands the placed arrays to a watcher thread
+    that waits for them (``block_until_ready``) and observes
+    ``h2d_landed``: from the start of the measured region to the arrays
+    being ready on the device.  The caller never waits for the watcher,
+    so neither the batch's hand-off nor the producer's next fetch is
+    delayed.  The live ``znicz_h2d_bytes_per_second`` gauge divides a
+    rolling window of bytes by landed seconds (by call seconds where
+    nothing was watched).
 
-    The ``loader.h2d`` fault point fires inside the measured region, so
-    an injected delay reads as a slow link to the attribution — the
-    CI fixture for the h2d-bound verdict.
+    Under a :class:`StageClock` (the prefetch producer) the measured
+    region starts where the previous stage ended.  The ``loader.h2d``
+    fault point fires inside the measured region, so an injected delay
+    reads as a slow link to the attribution — the CI fixture for the
+    h2d-bound verdict.
     """
 
     def __init__(
@@ -163,31 +284,104 @@ class H2DProbe:
         self._bps = reg.gauge(
             H2D_BPS_METRIC,
             "live host->device transfer rate over the last ~window of "
-            "training batches",
+            "training batches (bytes over landed seconds)",
         )
         self._recent: deque = deque(maxlen=max(int(window), 1))
         self._lock = threading.Lock()
+        self._landing: "queue.Queue" = queue.Queue()
+        self._watcher: Optional[threading.Thread] = None
 
     @contextlib.contextmanager
-    def measure(self, nbytes: int) -> Iterator[None]:
-        faults.fire("loader.h2d")
-        t0 = time.perf_counter()
+    def measure(self, nbytes: int) -> Iterator[_Transfer]:
+        clock = getattr(_clock_local, "clock", None)
+        t0 = clock.mark if clock is not None else time.perf_counter()
+        transfer = _Transfer()
         try:
-            yield
+            with get_tracer().span("loader/h2d"):
+                faults.fire("loader.h2d")
+                yield transfer
         finally:
-            dt = time.perf_counter() - t0
-            self.observe(nbytes, dt)
+            # bookkeeping first, the clock read late: under a stage
+            # clock whatever follows the lap falls to no stage
+            if nbytes > 0:
+                self._bytes.inc(float(nbytes))
+            if transfer.arrays:
+                self._ensure_watcher()
+            if clock is not None:
+                seconds = clock.lap(STAGE_H2D, self._hist)
+            else:
+                seconds = time.perf_counter() - t0
+                self._hist.labels(stage=STAGE_H2D).observe(seconds)
+            if transfer.arrays:
+                # handed over after the lap, so the watcher's clock read
+                # follows it: a batch's landing time is never under its
+                # call time
+                self._landing.put((t0, nbytes, transfer.arrays))
+            else:
+                self._rate(nbytes, seconds)
 
     def observe(self, nbytes: int, seconds: float) -> None:
+        """One transfer timed by the caller (call time only)."""
         self._hist.labels(stage=STAGE_H2D).observe(seconds)
         if nbytes > 0:
             self._bytes.inc(float(nbytes))
+        self._rate(nbytes, seconds)
+
+    def _rate(self, nbytes: int, seconds: float) -> None:
         with self._lock:
             self._recent.append((float(nbytes), float(seconds)))
             total_b = sum(b for b, _ in self._recent)
             total_s = sum(s for _, s in self._recent)
         if total_s > 0:
             self._bps.set(total_b / total_s)
+
+    # -- the landing watcher -----------------------------------------------
+
+    def _ensure_watcher(self) -> None:
+        if self._watcher is not None and self._watcher.is_alive():
+            return
+        with self._lock:
+            if self._watcher is None or not self._watcher.is_alive():
+                # outside its try the loop only blocks in Queue.get(),
+                # which does not raise; a dead watcher is restarted here
+                self._watcher = threading.Thread(  # znicz-check: disable=ZNC013
+                    target=self._watch_landings,
+                    name="znicz-h2d-landing",
+                    daemon=True,
+                )
+                self._watcher.start()
+
+    def _watch_landings(self) -> None:
+        while True:
+            t0, nbytes, arrays = self._landing.get()
+            try:
+                with get_tracer().span("loader/h2d_landed"):
+                    for a in arrays:
+                        # znicz-check: disable=ZNC007 -- waiting for the
+                        # copy is this thread's whole job; no dispatch
+                        # runs here
+                        a.block_until_ready()  # znicz-check: disable=ZNC007
+                seconds = time.perf_counter() - t0
+                self._hist.labels(stage=STAGE_H2D_LANDED).observe(seconds)
+                self._rate(nbytes, seconds)
+            except Exception:
+                # a deleted or donated buffer: the batch itself is the
+                # consumer's business, the instrument only loses a sample
+                logger.debug("h2d landing watch lost a batch", exc_info=True)
+            finally:
+                del arrays
+                self._landing.task_done()
+
+    def drain(self, timeout: float = 10.0) -> bool:
+        """Wait until the watcher has seen every batch handed to it so
+        far; False if it has not within ``timeout`` seconds (tests and
+        end-of-run readers; the hot path never calls it)."""
+        deadline = time.perf_counter() + timeout
+        while self._landing.unfinished_tasks:
+            if time.perf_counter() > deadline:
+                return False
+            time.sleep(0.001)
+        return True
 
 
 # -- attribution ------------------------------------------------------------
@@ -211,6 +405,9 @@ _SUGGESTIONS = {
         "sync) — record a tracer window to see where"
     ),
 }
+
+# above this share of the producer's loop in no stage, a verdict is "low"
+UNATTRIBUTED_LOW_CONFIDENCE = 0.1
 
 _VERDICTS = {
     "input": "input-bound",
@@ -329,15 +526,26 @@ class PipelineAttribution:
         steps = self._sum(f"{STEP_WALL_METRIC}_count")
         stages = {
             s: self._sum(f"{STAGE_METRIC}_sum", stage=s)
-            for s in (
-                STAGE_FETCH, STAGE_TRANSFORM, STAGE_H2D, STAGE_ENQUEUE
-            )
+            for s in TILING_STAGES
+            + (STAGE_CROP_PARAMS, STAGE_CROP, STAGE_H2D_LANDED)
         }
+        tiled = sum(stages[s] for s in TILING_STAGES)
+        # captures from before the producer total existed (or synthetic
+        # ones without it) fall back to the stages' own sum: no hole
+        # can be seen there, and none is claimed
+        producer = self._sum(f"{PRODUCER_METRIC}_sum") or tiled
+        unattributed = max(1.0 - tiled / producer, 0.0) if producer else 0.0
         out: dict = {
             "type": "pipeline",
             "steps": int(steps),
             "wall_seconds": round(wall, 6),
             "stages": {k: round(v, 6) for k, v in stages.items()},
+            "producer_seconds": round(producer, 6),
+            "producer_unattributed_frac": round(unattributed, 4),
+            "waits": {
+                at: round(self._sum(f"{WAIT_METRIC}_sum", at=at), 6)
+                for at in (WAIT_FIRST, WAIT_STEADY, WAIT_END)
+            },
             "queue_full_stalls": int(self._sum(QUEUE_FULL_METRIC)),
             "h2d_bytes_per_second": self._bandwidth(stages),
         }
@@ -367,11 +575,11 @@ class PipelineAttribution:
         h2d_raw = stages[STAGE_H2D]
         if wait_count > 0:
             # prefetch thread on: while the consumer waits, the producer
-            # is in one of its stages — prorate the wait slice by the
-            # producer's h2d share of busy (non-enqueue) time
-            busy = (
-                stages[STAGE_FETCH] + stages[STAGE_TRANSFORM] + h2d_raw
-            )
+            # is somewhere in its loop — prorate the wait slice by the
+            # h2d share of the producer's own total less its time
+            # blocked in enqueue (a hole between stages stays in the
+            # denominator, so it cannot inflate either share)
+            busy = producer - stages[STAGE_ENQUEUE]
             h2d_frac = (
                 (wait / wall) * (h2d_raw / busy) if busy > 0 else 0.0
             )
@@ -415,6 +623,10 @@ class PipelineAttribution:
             confidence = "medium"
         else:
             confidence = "low"
+        if unattributed > UNATTRIBUTED_LOW_CONFIDENCE:
+            # the producer spent time no stage covers: the shares above
+            # divide a total they do not explain
+            confidence = "low"
         out.update(
             {
                 "fractions": fractions,
@@ -434,14 +646,16 @@ class PipelineAttribution:
         return out
 
     def _bandwidth(self, stages: Dict[str, float]) -> Optional[float]:
-        """Window-consistent first: bytes / h2d-stage seconds — both
-        zeroed together by :func:`reset_window`, so the headline never
-        blends the compile epoch back in.  The live gauge (a rolling
-        probe window reset_window cannot reach) is only the fallback
-        for captures without the counter."""
+        """Window-consistent first: bytes / landed seconds (call
+        seconds in a capture that has no landing time) — all zeroed
+        together by :func:`reset_window`, so the headline never blends
+        the compile epoch back in.  The live gauge (a rolling probe
+        window reset_window cannot reach) is only the fallback for
+        captures without the counter."""
         total = self._sum(H2D_BYTES_METRIC)
-        if total > 0 and stages.get(STAGE_H2D, 0.0) > 0:
-            return round(total / stages[STAGE_H2D], 1)
+        seconds = stages.get(STAGE_H2D_LANDED) or stages.get(STAGE_H2D, 0.0)
+        if total > 0 and seconds > 0:
+            return round(total / seconds, 1)
         live = self._gauge_max(H2D_BPS_METRIC)
         if live:
             return round(live, 1)

@@ -3,17 +3,19 @@
 Complements :func:`znicz_tpu.utils.profiling.trace` (the jax profiler's
 device capture): this tracer records the HOST side — admit/decode
 chunks, training phases, loader waits — as Chrome trace events that
-Perfetto (https://ui.perfetto.dev) renders on a timeline.  When jax is
-importable, every span also enters ``jax.profiler.TraceAnnotation``, so
-a simultaneous device capture shows the same span names on the device
-tracks and host spans line up with the XLA executions they dispatched.
+Perfetto (https://ui.perfetto.dev) renders on a timeline.  Once jax has
+been imported, every span also enters ``jax.profiler.TraceAnnotation``,
+whether this tracer records or not: the annotation is inert outside a
+profiler session, and inside one (``/debug/profile``,
+``utils.profiling.trace``, an operator's own ``jax.profiler``) it puts
+the span on the device trace's clock, on whichever thread entered it.
 
 Events are complete spans (``"ph": "X"``) with microsecond ``ts``/
 ``dur`` relative to :meth:`Tracer.start`, one JSON object per line when
 streaming to a file (Perfetto's JSON importer accepts concatenated
 objects; the array wrapper is optional in the trace-event format).
-Spans are no-ops while the tracer is not recording, so instrumentation
-stays in place permanently at ~zero cost.
+While the tracer is not recording a span emits no event and costs the
+annotation alone, so instrumentation stays in place permanently.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import Counter
@@ -266,10 +269,13 @@ class Tracer:
                     self._file = None
 
     def _annotation_cls(self):
-        """``jax.profiler.TraceAnnotation`` when jax is importable, else
-        None — resolved once, lazily, so this module stays jax-free for
-        hosts with no accelerator stack."""
+        """``jax.profiler.TraceAnnotation`` once jax has been imported,
+        else None — this module never imports jax first, so it stays
+        jax-free for hosts with no accelerator stack (and a process
+        that has not touched jax has no profiler session to feed)."""
         if self._annotation is _UNSET:
+            if "jax" not in sys.modules:
+                return None  # not resolved yet: ask again next span
             try:
                 from jax.profiler import TraceAnnotation
 
@@ -286,18 +292,24 @@ class Tracer:
     def span(self, name: str, **args) -> Iterator[None]:
         """One nested host span; ``args`` land in the event's ``args``.
 
-        Inside a recording window the span also enters
-        ``jax.profiler.TraceAnnotation(name)`` so device traces captured
-        concurrently (``profiling.trace``) carry the same names."""
+        Recording or not, the span enters
+        ``jax.profiler.TraceAnnotation(name)`` so a profiler session
+        opened by anyone (``profiling.trace``, ``/debug/profile``) carries
+        the program's span names; the Chrome trace event is emitted
+        only inside a recording window."""
+        ann = self._annotation_cls()
         if not self._recording:
-            yield
+            if ann is None:
+                yield
+            else:
+                with ann(name):
+                    yield
             return
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         parent = stack[-1] if stack else None
         stack.append(name)
-        ann = self._annotation_cls()
         ctx = ann(name) if ann is not None else contextlib.nullcontext()
         t0 = time.perf_counter()
         try:

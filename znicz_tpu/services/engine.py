@@ -684,6 +684,12 @@ class DecodeEngine:
             "znicz_serve_ttft_seconds",
             "submit -> first sampled token per request",
         )
+        self._m_queue_wait = observability.histogram(
+            "znicz_serve_engine_queue_wait_seconds",
+            "seconds a request waited in the engine's own queue before "
+            "a slot took it (once per admission; a preempted request "
+            "is observed again when it is re-admitted)",
+        )
         # per-tick occupancy: what fraction of each engine tick's wall
         # went to admission/prefill vs the decode chunk vs a spec-verify
         # chunk — the measured input the spec-aware-SLO-tuning and
@@ -789,6 +795,8 @@ class DecodeEngine:
         fleet trace collector groups the merged timeline by that tag
         (pid=instance in Perfetto)."""
         args: Dict = {}
+        if not observability.get_tracer().recording:
+            return args  # nobody records the span: build nothing for it
         if trace_id:
             args["trace"] = trace_id
         if self.trace_instance:
@@ -800,6 +808,8 @@ class DecodeEngine:
         trace id (comma-joined) so ONE Perfetto trace-id filter also
         surfaces the decode chunks a request was resident in."""
         args: Dict = {}
+        if not observability.get_tracer().recording:
+            return args  # nobody records the span: join nothing for it
         traces = ",".join(
             r.trace_id for r in residents if r.trace_id
         )
@@ -950,8 +960,14 @@ class DecodeEngine:
         self._m_queue_depth.set(len(self._queue))
         self._m_active.set(self.active)
 
+    def _leave_queue(self, req: Request) -> None:
+        """A slot takes ``req``: close out this spell of queueing."""
+        waited = req.watch.elapsed() - req.last_queued_at
+        req.timings.queue_s += waited
+        self._m_queue_wait.observe(waited)
+
     def _admit_into(self, slot: int, req: Request) -> None:
-        req.timings.queue_s += req.watch.elapsed() - req.last_queued_at
+        self._leave_queue(req)
         t0 = time.perf_counter()
         with self.timer.phase(
             "admit", request=req.id, bucket=req.bucket,
@@ -1451,6 +1467,24 @@ class PagedDecodeEngine(DecodeEngine):
             "accepted draft tokens per row per verify step",
             buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0),
         )
+        # what the decode and verify programs gather, which is what
+        # their device time scales with: every slot's window of K/V
+        # positions per step, active or not
+        self._m_decode_steps = observability.counter(
+            "znicz_serve_decode_steps_total",
+            "token steps run by the paged decode and verify programs "
+            "(a verify chunk is one step)",
+        )
+        self._m_decode_gathered = observability.counter(
+            "znicz_serve_decode_gathered_tokens_total",
+            "K/V positions gathered by the paged decode and verify "
+            "programs: steps x slots x window blocks x block size",
+        )
+        self._m_decode_chunks = observability.counter(
+            "znicz_serve_decode_chunks_total",
+            "paged decode and verify chunks by gather window (blocks)",
+            ("window",),
+        )
         self._update_pool_gauges()
 
     # -- capacity & the block allocator -----------------------------------
@@ -1794,7 +1828,7 @@ class PagedDecodeEngine(DecodeEngine):
         final chunk to the block boundary — the prefix-cache alignment
         contract (see :func:`~znicz_tpu.workflow.generate
         .paged_prefill_chunk`)."""
-        req.timings.queue_s += req.watch.elapsed() - req.last_queued_at
+        self._leave_queue(req)
         size = req.prompt.size
         tokens = np.full((1, req.bucket), self.pad_id, np.int32)
         tokens[0, :size] = req.prompt
@@ -2029,6 +2063,13 @@ class PagedDecodeEngine(DecodeEngine):
             window *= 2
         return min(window, self.blocks_per_row)
 
+    def _count_gathered(self, steps: int, window: int) -> None:
+        self._m_decode_steps.inc(steps)
+        self._m_decode_gathered.inc(
+            steps * self.batch_size * window * self.block_size
+        )
+        self._m_decode_chunks.labels(window=window).inc()
+
     # -- speculative decoding: draft -> verify -> accept -> rollback ------
 
     def _draft_pending(self) -> Dict[int, np.ndarray]:
@@ -2129,6 +2170,7 @@ class PagedDecodeEngine(DecodeEngine):
             n_acc = np.asarray(n_acc)
         dt = time.perf_counter() - t0
         self._n_verify_steps += 1
+        self._count_gathered(1, window)
         for r in residents:
             r.timings.decode_s += dt
         for slot, st in enumerate(self._slots):
@@ -2249,6 +2291,7 @@ class PagedDecodeEngine(DecodeEngine):
             self._done = np.array(done)
             self._remaining = np.array(remaining)
         dt = time.perf_counter() - t0
+        self._count_gathered(steps, window)
         for r in residents:
             r.timings.decode_s += dt
         for slot, st in enumerate(self._slots):

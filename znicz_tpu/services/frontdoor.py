@@ -389,6 +389,11 @@ class ServingFrontDoor:
             "znicz_serve_frontdoor_inflight",
             "requests handed to the engine and not yet completed",
         )
+        self._m_queue_wait = observability.histogram(
+            "znicz_serve_frontdoor_queue_wait_seconds",
+            "front-door submit -> hand-off to the engine, once per "
+            "request handed over",
+        )
         # CLIENT-clock histograms: submit -> first streamed token /
         # completion, front-door queueing and tick cadence included —
         # what the SLO targets judge (the engine's own ttft/latency
@@ -805,6 +810,7 @@ class ServingFrontDoor:
                     break
                 fr = self._pending.popleft()
             fr.pending_wait_s = fr.watch.elapsed()
+            self._m_queue_wait.observe(fr.pending_wait_s)
             try:
                 rid = eng.submit(
                     fr.prompt, fr.max_new_tokens, trace_id=fr.trace_id

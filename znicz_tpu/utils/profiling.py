@@ -24,7 +24,10 @@ def trace(log_dir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
     """
     import jax
 
-    jax.profiler.start_trace(log_dir, host_tracer_level=host_tracer_level)
+    # jax takes the tracer levels through ProfileOptions, not as keywords
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

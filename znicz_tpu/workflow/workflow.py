@@ -981,10 +981,13 @@ class Workflow(Logger):
                 + getattr(y_host, "nbytes", 0)
                 + getattr(mb.mask, "nbytes", 0)
             )
-            with self._h2d_probe.measure(nbytes):
+            with self._h2d_probe.measure(nbytes) as transfer:
                 x = put(mb.data)
                 y = x if y_host is None else put(y_host)
                 mask = put(mb.mask)
+                # the landing time is taken beside the loop: nobody here
+                # waits for the copy
+                transfer.watch(x, y, mask)
             return split, x, y, mask
 
         epoch_iter = self.loader.epoch()
