@@ -45,6 +45,19 @@ class TestPagedAttention:
     numerics must equal a dense masked softmax over the same keys,
     whatever (shuffled) block assignment the table holds."""
 
+    @pytest.fixture(params=["heads_split", "merged"])
+    def stored(self, request):
+        """Puts a pool the tests build as ``[n_blocks, bs, H, D]`` on the
+        device in the layout under test: ``merged`` is the
+        ``[n_blocks, bs, H*D]`` that ``init_paged_kv`` builds and the
+        engine writes; ``heads_split`` is the rank the direct callers
+        below always passed."""
+        if request.param == "merged":
+            return lambda pool: jnp.asarray(
+                pool.reshape(*pool.shape[:2], -1)
+            )
+        return jnp.asarray
+
     def _paged_setup(self, b=2, t=32, h=2, d=8, bs=8, seed=0):
         rng = np.random.default_rng(seed)
         m = t // bs
@@ -86,7 +99,7 @@ class TestPagedAttention:
                 out[row, qi] = np.einsum("hk,khd->hd", w, v[row])
         return out
 
-    def test_decode_step_matches_dense_masked_softmax(self):
+    def test_decode_step_matches_dense_masked_softmax(self, stored):
         bs = 8
         k, v, k_pool, v_pool, table = self._paged_setup(bs=bs)
         rng = np.random.default_rng(1)
@@ -94,7 +107,7 @@ class TestPagedAttention:
         pos = np.asarray([[13], [29]], np.int32)
         start = np.asarray([3, 0], np.int32)
         out = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
             start=jnp.asarray(start),
         )
@@ -103,7 +116,7 @@ class TestPagedAttention:
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
         )
 
-    def test_prefill_chunk_queries_match(self):
+    def test_prefill_chunk_queries_match(self, stored):
         # a whole chunk of queries at consecutive positions (the
         # chunked-prefill shape), pad-region queries included: their
         # window collapses to the self position and stays finite
@@ -114,7 +127,7 @@ class TestPagedAttention:
         q_pos = np.broadcast_to(np.arange(bs), (2, bs)).astype(np.int32)
         start = np.asarray([5, 0], np.int32)  # row 0: pad queries 0..4
         out = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(q_pos), block_size=bs,
             start=jnp.asarray(start),
         )
@@ -124,7 +137,7 @@ class TestPagedAttention:
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
         )
 
-    def test_stale_blocks_cannot_leak(self):
+    def test_stale_blocks_cannot_leak(self, stored):
         # poison every pool block the tables do NOT cover a row's valid
         # window with: garbage past pos / outside the table must not
         # change the output (masking is by index, never by value)
@@ -135,7 +148,7 @@ class TestPagedAttention:
         pos = np.asarray([[10], [3]], np.int32)
         start = np.asarray([2, 0], np.int32)
         clean = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
             start=jnp.asarray(start),
         )
@@ -151,7 +164,7 @@ class TestPagedAttention:
         kp[0] = 1e9  # the null block
         vp[0] = 1e9
         poisoned = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), stored(kp), stored(vp),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
             start=jnp.asarray(start),
         )
@@ -159,7 +172,7 @@ class TestPagedAttention:
             np.asarray(clean), np.asarray(poisoned), rtol=1e-6
         )
 
-    def test_many_tables_one_block_aliasing(self):
+    def test_many_tables_one_block_aliasing(self, stored):
         # prefix sharing maps ONE physical block into MANY tables: each
         # row's output must equal the dense reference over the content
         # its own table resolves to — the gather must not care that a
@@ -196,7 +209,7 @@ class TestPagedAttention:
         pos = np.asarray([[5], [13], [21]], np.int32)
         start = np.zeros((b,), np.int32)
         out = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
             start=jnp.asarray(start),
         )
@@ -205,7 +218,7 @@ class TestPagedAttention:
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
         )
 
-    def test_aliased_block_validity_is_per_row(self):
+    def test_aliased_block_validity_is_per_row(self, stored):
         # poison-grade check for aliasing: positions of the SHARED
         # block past a shallow row's pos are real live content for a
         # deeper row.  Perturbing them must leave the shallow row's
@@ -228,7 +241,7 @@ class TestPagedAttention:
         def run(kp, vp):
             return np.asarray(
                 attention.paged_attention(
-                    jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+                    jnp.asarray(q), stored(kp), stored(vp),
                     jnp.asarray(table), jnp.asarray(pos), block_size=bs,
                 )
             )
@@ -259,6 +272,36 @@ class TestPagedAttention:
         )
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
         assert pp.PALLAS_PAGED_IMPLEMENTED is False
+
+    def test_engine_pools_store_heads_merged(self):
+        # the stored layout is [n_blocks, block_size, H*hd] (why:
+        # tests/test_paged_layout_aot.py); the bytes a block and the
+        # pool are reported to take are those of the K/V they hold
+        from znicz_tpu.services.engine import PagedDecodeEngine
+        from znicz_tpu.workflow.generate import init_paged_kv
+        from znicz_tpu.workflow.transformer import init_lm_params
+
+        layers, heads, head_dim, bs = 2, 4, 8, 16
+        params = init_lm_params(50, heads * head_dim, layers, heads, 64)
+        pools = init_paged_kv(params, 9, bs)
+        assert len(pools) == layers
+        for pool in pools:
+            assert pool["k"].shape == pool["v"].shape == (
+                9, bs, heads * head_dim
+            )
+            assert pool["k"].dtype == params[1]["wq"].dtype
+        eng = PagedDecodeEngine(
+            params, n_heads=heads, eos_id=0, batch_size=2, max_seq=64,
+            block_size=bs,
+        )
+        assert [p["k"].shape for p in eng._pools] == [
+            (2 * 4 + 1, bs, heads * head_dim)
+        ] * layers
+        per_block = layers * 2 * bs * heads * head_dim * 4
+        assert eng.block_bytes == per_block
+        stats = eng.stats()
+        assert stats["block_bytes"] == per_block
+        assert stats["pool_bytes"] == 2 * 4 * per_block
 
 
 class TestRingAttention:

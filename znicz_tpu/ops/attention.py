@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,7 +49,7 @@ def dot_product_attention(
 
 def paged_attention(
     q: jnp.ndarray,  # [B, Tq, H, D]
-    k_pool: jnp.ndarray,  # [N_blocks, block_size, H, D]
+    k_pool: jnp.ndarray,  # [N_blocks, block_size, H*D]
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32 pool block ids
     q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
@@ -60,7 +61,7 @@ def paged_attention(
     """Block-table attention over paged KV pools; returns [B, Tq, H, D].
 
     The serving KV layout (vLLM/PagedAttention lineage): K/V live in a
-    shared ``[n_blocks, block_size, H, D]`` pool and each row owns an
+    shared ``[n_blocks, block_size, H*D]`` pool and each row owns an
     ordered block table — table entry ``j`` covers absolute positions
     ``j*block_size .. (j+1)*block_size-1`` of that row.  The row's
     window is GATHERED from the pool (``k_pool[block_table]``), so the
@@ -74,6 +75,31 @@ def paged_attention(
     even while a deeper row genuinely attends them (aliasing tests in
     tests/test_attention.py).
 
+    Heads are MERGED in storage and the gathered window is read as it
+    is stored; they are split on the QUERY side, after the gather.  A
+    minor ``[block_size, H*D]`` fills the TPU's (8, 128) tiles, where
+    ``[H, D]`` fits none: with heads split in the pool the compiler
+    re-tiled every pool and every window in every step, and with heads
+    split in the gathered window it still re-tiled the window
+    (tests/test_paged_layout_aot.py; PERF.md section 6, PR 25).  So
+    row ``(t, h)`` of ``q_heads`` holds ``q[b, t, h]`` in head ``h``'s
+    columns of the merged width and zeros elsewhere, and ONE product
+    against the merged window gives every head's scores; the weighted
+    sum runs over the merged width too, and row ``(t, h)`` keeps head
+    ``h``'s columns of it.  Both products run at ``Precision.HIGHEST``,
+    in f32 like the window: a decode step's always were (at ``Tq`` = 1
+    the compiler made the per-head products f32 multiply-reduce loops),
+    the zeros then add exactly, and at one bf16 pass the compiler would
+    also round the window to bf16 as the gather writes it.  The zeros
+    cost H times the products' FLOPs.  On the v5e (PERF.md section 6,
+    PR 25; 12 x 64, 16 x 128 and 32 x 128 heads) that is 1.7 to 2.9
+    times faster in a decode step than the per-head einsum over a window
+    with the heads split, level with it while ``Tq * H`` is at most 512
+    (a verify call of 4 or a 32-token prefill chunk, to 16 heads), and
+    slower beyond: 1.3 times in a verify call of 4 at 32 heads, 3 times
+    in a 32-token prefill chunk at 32 heads.  A pool that keeps the
+    heads split (``[.., H, D]``) reads the same.
+
     Validity is by ABSOLUTE key index, exactly like the dense cache
     path (:mod:`znicz_tpu.workflow.generate`): key position must be
     ``<= q_pos`` and (under left-padding) ``>= start``, so unallocated
@@ -86,16 +112,21 @@ def paged_attention(
     """
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
-    b, tq = q.shape[:2]
-    m = block_table.shape[1]
-    # [B, M, bs, H, D] -> [B, M*bs, H, D]: the row-ordered KV window
-    k = k_pool[block_table].reshape(b, m * block_size, *k_pool.shape[2:])
-    v = v_pool[block_table].reshape(b, m * block_size, *v_pool.shape[2:])
+    b, tq, h, d = q.shape
+    n_keys = block_table.shape[1] * block_size
+    # [B, M, bs, H*D] -> [B, M*bs, H*D]: the row-ordered KV window
+    k = k_pool[block_table].reshape(b, n_keys, h * d)
+    v = v_pool[block_table].reshape(b, n_keys, h * d)
+    # [B, Tq, H, H', D] -> [B, Tq*H, H'*D], zero where H' != H
+    q_heads = (
+        q[:, :, :, None, :] * jnp.eye(h, dtype=q.dtype)[:, :, None]
+    ).reshape(b, tq * h, h * d)
     s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    k_idx = jnp.arange(m * block_size)[None, None, None, :]
-    qp = q_pos[:, None, :, None]
+        "bre,bke->brk", q_heads, k, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).reshape(b, tq, h, n_keys) * scale
+    k_idx = jnp.arange(n_keys)[None, None, None, :]
+    qp = q_pos[:, :, None, None]
     valid = k_idx <= qp
     if start is not None:
         st = start[:, None, None, None]
@@ -104,9 +135,12 @@ def paged_attention(
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum(
-        "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+        "brk,bke->bre", p.astype(v.dtype).reshape(b, tq * h, n_keys), v,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
+    # row (t, h) keeps head h's own columns
+    out = jnp.einsum("bthhd->bthd", out.reshape(b, tq, h, h, d))
     return out.astype(q.dtype)
 
 

@@ -1,11 +1,15 @@
 """Paged (block-table) decode attention — Pallas TPU kernel landing site.
 
 The jnp reference (:func:`znicz_tpu.ops.attention.paged_attention`)
-gathers each row's block table into a contiguous ``[B, M*bs, H, D]``
+gathers each row's block table into a contiguous ``[B, M*bs, H*D]``
 window in HBM before the score matmul — correct, and cheap at the
 decode shapes the engine runs today (Tq == 1 or one prefill chunk), but
-it materializes a full window copy per layer per step.  The TPU kernel
-replaces the gather with table-indexed DMA:
+it materializes a full window copy per layer per step.  The pool is
+``[n_blocks, block_size, H*D]``: heads are merged in storage, because a
+minor ``[block_size, H*D]`` fills the TPU's (8, 128) tiles where
+``[H, D]`` fits none (tests/test_paged_layout_aot.py), and split on the
+query side after the gather; a kernel here reads the same pool.  The TPU
+kernel replaces the gather with table-indexed DMA:
 
 * **Grid** — ``(B*H, kv_block)``; the per-row block table rides in as a
   scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so the
@@ -45,7 +49,7 @@ PALLAS_PAGED_IMPLEMENTED = False
 
 def paged_attention(
     q: jnp.ndarray,  # [B, Tq, H, D]
-    k_pool: jnp.ndarray,  # [N_blocks, block_size, H, D]
+    k_pool: jnp.ndarray,  # [N_blocks, block_size, H*D]
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32
     q_pos: jnp.ndarray,  # [B, Tq] int32 absolute positions

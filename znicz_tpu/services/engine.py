@@ -19,14 +19,16 @@ stream:
 
 **Paged backend** (:class:`PagedDecodeEngine`, PAPERS.md vLLM/Sarathi/
 RadixAttention lineage): instead of a dense ``[B, T_max]`` reservation
-per slot, K/V live in a shared block pool (``[n_blocks, block_size, H,
-hd]`` per layer) and each slot owns a block table over REFCOUNTED
-blocks.  Admission maps the longest prefix of the prompt already in the
-content-hash PREFIX CACHE (chained block hashes — an implicit radix
-structure; retiring and preempted requests publish their completed full
-blocks) and chunk-prefills only the uncached tail; shared blocks are
-read-only behind a copy-on-write guard.  Blocks are otherwise allocated
-lazily as decode advances, prompts prefill in block-sized CHUNKS
+per slot, K/V live in a shared block pool (``[n_blocks, block_size,
+H*hd]`` per layer: heads merged, so the minor dimensions fill the TPU's
+(8, 128) tiles and no program re-tiles a pool —
+tests/test_paged_layout_aot.py) and each slot owns a block table over
+REFCOUNTED blocks.  Admission maps the longest prefix of the prompt
+already in the content-hash PREFIX CACHE (chained block hashes — an
+implicit radix structure; retiring and preempted requests publish their
+completed full blocks) and chunk-prefills only the uncached tail; shared
+blocks are read-only behind a copy-on-write guard.  Blocks are otherwise
+allocated lazily as decode advances, prompts prefill in block-sized CHUNKS
 interleaved with decode chunks (a long prompt never stalls the batch),
 and when the free list runs dry allocation first EVICTS cache-only
 blocks (LRU) and only then PREEMPTS the youngest request — publishes +
@@ -1215,7 +1217,7 @@ class PagedDecodeEngine(DecodeEngine):
 
     Same queue surface as :class:`DecodeEngine` (``submit``/``run``/
     ``stats``), different memory model: K/V live in a shared
-    ``[n_blocks, block_size, H, hd]`` pool per layer; each slot owns an
+    ``[n_blocks, block_size, H*hd]`` pool per layer; each slot owns an
     ordered block table and every pool block carries a REFCOUNT — the
     same physical block can appear in many tables at once.  Four
     properties follow:
@@ -1370,8 +1372,7 @@ class PagedDecodeEngine(DecodeEngine):
             self.n_blocks = int(self._n_blocks_arg)
         self.blocks_per_row = m
         self._pools = init_paged_kv(
-            self.params, self.n_blocks, self.block_size,
-            n_heads=self.n_heads,
+            self.params, self.n_blocks, self.block_size
         )
         # LIFO free list: a just-freed (still cache/HBM-warm) block is
         # the next one handed out; block 0 stays reserved as null

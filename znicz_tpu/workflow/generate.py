@@ -171,20 +171,25 @@ def decode_step(
 
 # ---------------------------------------------------------------------------
 # Paged KV cache (vLLM/PagedAttention lineage, docs/SERVING.md): K/V live
-# in a shared [n_blocks, block_size, H, hd] pool per layer and each row
+# in a shared [n_blocks, block_size, H*hd] pool per layer and each row
 # owns an ordered block table — block-granular allocation instead of a
 # dense [B, T_max] reservation per slot, so memory scales with the tokens
 # actually decoded and the pool's free blocks ARE the concurrency budget.
+# Heads are MERGED in storage and split on the query side, after the
+# gather: a minor [block_size, H*hd] fills the TPU's (8, 128) tiles where
+# [H, hd] fits none and made every program re-tile whole pools and
+# windows (tests/test_paged_layout_aot.py).
 
 NULL_BLOCK = 0  # reserved pool block: write target for idle/done rows
 
 
-def init_paged_kv(params, n_blocks: int, block_size: int, *, n_heads: int):
-    """Zeroed ``[n_blocks, block_size, H, hd]`` K/V pools, one pair per
-    block of the tower.  Pool block ``NULL_BLOCK`` (index 0) is reserved
-    as the null write target — allocators must hand out ``1..n_blocks-1``
-    — so rows with nothing to say (done, idle slot) can always write
-    somewhere harmless instead of branching."""
+def init_paged_kv(params, n_blocks: int, block_size: int):
+    """Zeroed ``[n_blocks, block_size, H*hd]`` K/V pools (heads merged,
+    see above), one pair per block of the tower.  Pool block
+    ``NULL_BLOCK`` (index 0) is reserved as the null write target —
+    allocators must hand out ``1..n_blocks-1`` — so rows with nothing to
+    say (done, idle slot) can always write somewhere harmless instead of
+    branching."""
     if n_blocks < 2 or block_size < 1:
         raise ValueError(
             f"want n_blocks >= 2 (one is the reserved null block) and "
@@ -192,9 +197,7 @@ def init_paged_kv(params, n_blocks: int, block_size: int, *, n_heads: int):
         )
     pools = []
     for block in params[1:-1]:
-        inner = block["wq"].shape[1]
-        head_dim = inner // n_heads
-        shape = (n_blocks, block_size, n_heads, head_dim)
+        shape = (n_blocks, block_size, block["wq"].shape[1])
         dtype = block["wq"].dtype
         pools.append(
             {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -207,24 +210,25 @@ def _paged_block_step(
     start=None, moe_top_k=1, moe_dispatch="dense",
 ):
     """One pre-LN block over ``x`` [B, Tq, D] with paged KV: ``write``
-    scatters this layer's new K/V into the pool (the caller resolves
-    block ids once — the same indices serve every layer) and attention
-    gathers through the block table (:func:`ops.attention.paged_attention`
-    — same masked stable-softmax numerics as the dense
-    :func:`_block_step`, asserted by the paged goldens)."""
+    scatters this layer's new K/V, heads merged as the pool stores them,
+    into the pool (the caller resolves block ids once — the same indices
+    serve every layer) and attention gathers through the block table
+    (:func:`ops.attention.paged_attention` — same masked stable-softmax
+    numerics as the dense :func:`_block_step`, asserted by the paged
+    goldens)."""
     b, tq, _ = x.shape
     h = layer_norm(x, block["ln1_scale"], block["ln1_bias"])
 
     def proj(w):
-        y = jnp.dot(h, w, preferred_element_type=jnp.float32).astype(h.dtype)
-        return y.reshape(b, tq, n_heads, -1)
+        return jnp.dot(h, w, preferred_element_type=jnp.float32).astype(
+            h.dtype
+        )
 
-    q, k_new, v_new = proj(block["wq"]), proj(block["wk"]), proj(block["wv"])
-    k_pool = write(pool["k"], k_new)
-    v_pool = write(pool["v"], v_new)
+    k_pool = write(pool["k"], proj(block["wk"]))
+    v_pool = write(pool["v"], proj(block["wv"]))
     o = paged_attention(
-        q, k_pool, v_pool, tables, q_pos, block_size=block_size,
-        start=start,
+        proj(block["wq"]).reshape(b, tq, n_heads, -1), k_pool, v_pool,
+        tables, q_pos, block_size=block_size, start=start,
     )
     o = o.reshape(b, tq, -1)
     x = x + jnp.dot(
