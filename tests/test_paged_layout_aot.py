@@ -199,3 +199,133 @@ def test_no_program_retiles_a_pool(chip, program):
         f"where pools + the rest are {pool_bytes / GB:.3f} + "
         f"{other_bytes / GB:.3f} GB"
     )
+
+
+# -- the latent pool of a LatentMoEModel at axk1-ep16's geometry -----------
+#
+# 6 layers (one dense) at hidden 7168, 64 heads, latent 512 + rope 64, 12
+# of 192 experts of width 2048 held, vocabulary slice 20480, bfloat16; 128
+# slots, block 128, 4096 blocks, the widest decode rung (96 blocks).  What
+# the compiler said of a 576-wide row (my AOT compiles, PR 26): it keeps
+# such a pool tokens-minor ({1,2,0}), every program then copies all six
+# pools into the layout it gathers from, and the decode program needs
+# 17.0 GiB of the chip's 15.75.  A 640-wide row (latent, rope, zeros) is
+# stored as computed on: no copy of a pool, 2.74 GB of temporaries beside
+# 12.36 GB of arguments.
+
+AX = dict(d=7168, heads=64, d_q=1536, d_c=512, d_n=128, d_r=64, d_v=128,
+          f_dense=18432, f_exp=2048, held=12, published=192, vocab=20480,
+          layers=6, slots=128, block=128, n_blocks=4096, window=96)
+
+
+def _axk1_model():
+    from znicz_tpu.workflow.latent_lm import LatentMoEModel
+
+    return LatentMoEModel(
+        n_heads=AX["heads"], kv_lora_rank=AX["d_c"],
+        qk_nope_head_dim=AX["d_n"], qk_rope_head_dim=AX["d_r"], top_k=8,
+        routed_scaling_factor=2.5, first_expert=36, max_positions=131072,
+        rope_factor=32.0,
+    )
+
+
+def _axk1_params(spec):
+    a = AX
+    bf, f32 = jnp.bfloat16, jnp.float32
+    d, h = a["d"], a["heads"]
+
+    def block(dense):
+        leaves = {
+            "attn_norm": ((d,), f32), "wq_a": ((d, a["d_q"]), bf),
+            "q_norm": ((a["d_q"],), f32),
+            "wq_b_nope": ((a["d_q"], h * a["d_n"]), bf),
+            "wq_b_rope": ((a["d_q"], h * a["d_r"]), bf),
+            "wkv_a": ((d, a["d_c"] + a["d_r"]), bf),
+            "kv_norm": ((a["d_c"],), f32),
+            "wk_b": ((a["d_c"], h * a["d_n"]), bf),
+            "wv_b": ((a["d_c"], h * a["d_v"]), bf),
+            "wo": ((h * a["d_v"], d), bf), "ffn_norm": ((d,), f32),
+        }
+        if dense:
+            leaves.update(
+                w_gate=((d, a["f_dense"]), bf), w_up=((d, a["f_dense"]), bf),
+                w_down=((a["f_dense"], d), bf),
+            )
+        else:
+            f, g = a["f_exp"], a["held"]
+            leaves.update(
+                router=((d, a["published"]), bf),
+                experts_gate=((g, d, f), bf), experts_up=((g, d, f), bf),
+                experts_down=((g, f, d), bf), shared_gate=((d, f), bf),
+                shared_up=((d, f), bf), shared_down=((f, d), bf),
+            )
+        return {k: spec(*v) for k, v in leaves.items()}
+
+    return (
+        [{"embed": spec((a["vocab"], d), bf)}]
+        + [block(i == 0) for i in range(a["layers"])]
+        + [{"final_norm": spec((d,), f32), "head": spec((d, a["vocab"]), bf)}]
+    )
+
+
+AXK1_TEMP_LIMIT_GB = {"decode_chunk": 3.2, "prefill": 0.6, "cow_copy": 0.01}
+
+
+@pytest.mark.parametrize("program", list(AXK1_TEMP_LIMIT_GB))
+def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypatch):
+    from znicz_tpu.core import backend
+
+    # the grouped expert product picks its TPU kernel by the backend it
+    # finds; this process computes on the CPU and compiles for the chip
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    a, model = AX, _axk1_model()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = _axk1_params(spec)
+    pools = jax.tree.map(
+        lambda p: spec(p.shape, p.dtype),
+        jax.eval_shape(
+            lambda: model.init_pools(params, a["n_blocks"], a["block"])
+        ),
+    )
+    assert pools[0]["kv"].shape == (a["n_blocks"], a["block"], 640)
+    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
+    tower = dict(
+        n_heads=a["heads"], block_size=a["block"], moe_top_k=1,
+        moe_dispatch="dense", model=model,
+    )
+    with jax.default_matmul_precision("default"):
+        if program == "decode_chunk":
+            lowered = engine._paged_decode_chunk.lower(
+                params, pools, spec((a["slots"], a["window"]), i32), rows_i32,
+                rows_i32, rows_i32, spec((a["slots"],), jnp.bool_), rows_i32,
+                scalar_f32, scalar_f32, key, chunk=CHUNK, t_max=12288,
+                eos_id=0, **SAMPLING, **tower,
+            )
+        elif program == "prefill":
+            lowered = engine._paged_prefill_prog.lower(
+                params, pools, spec((a["window"],), i32),
+                spec((1, a["block"]), i32), scalar_i32, spec((1,), i32),
+                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+            )
+        else:
+            lowered = engine._cow_copy_prog.lower(pools, scalar_i32, scalar_i32)
+        compiled = lowered.compile()  # raises where the chip would refuse it
+    text = compiled.as_text()
+    pool_elements = a["n_blocks"] * a["block"] * 640
+    moved = [m for m in _relayouts(text, pool_elements) if m.startswith("copy")]
+    assert not moved, f"{program} copies a whole latent pool: {sorted(set(moved))}"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < AXK1_TEMP_LIMIT_GB[program] * GB, (
+        f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
+    )
+    if program != "cow_copy":
+        # weights 8.33 GB + pools 4.03 GB + temporaries fit 15.75 GiB, and
+        # the three grouped products of each routed layer are the kernel
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+        assert text.count("tpu_custom_call") >= 3 * (a["layers"] - 1)

@@ -144,6 +144,107 @@ def paged_attention(
     return out.astype(q.dtype)
 
 
+def paged_latent_attention(
+    q_nope: jnp.ndarray,  # [B, Tq, H, d_nope]
+    q_rope: jnp.ndarray,  # [B, Tq, H, d_rope], rotated
+    pool: jnp.ndarray,  # [N_blocks, block_size, >= d_latent + d_rope]
+    block_table: jnp.ndarray,  # [B, M] int32 pool block ids
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    wk_b: jnp.ndarray,  # [d_latent, H * d_nope]
+    wv_b: jnp.ndarray,  # [d_latent, H * d_v]
+    *,
+    block_size: int,
+    scale: float,
+    absorbed: bool,
+) -> jnp.ndarray:
+    """Multi-head LATENT attention over a paged pool of latent rows;
+    returns [B, Tq, H * d_v] in float32.
+
+    A cached token is ONE row ``[c (d_latent), rot(k_r) (d_rope), 0 ...]``
+    (zeros up to the pool's width, which the caller rounds up to whole
+    128-lane tiles: a 576-wide minor dimension fills none, and the TPU's
+    compiler then stores the pool tokens-minor and every program copies
+    every pool into the layout it computes in) shared by every head (DeepSeek-V2 lineage): head ``h``'s key is ``[c wk_b[:,
+    h], rot(k_r)]`` and its value ``c wv_b[:, h]``.  The row's window is
+    gathered through the block table exactly like
+    :func:`paged_attention`'s (same aliasing, same validity by ABSOLUTE
+    key index ``<= q_pos``, same f32 stable softmax), and the two forms
+    give the same numbers:
+
+    * ``absorbed=False`` MATERIALISES K and V of the whole window (``2 *
+      window * d_latent * H * (d_nope + d_v)`` FLOPs, whatever ``Tq``) and
+      attends per head: right for a prefill chunk, whose ``Tq * H``
+      queries amortise it.
+    * ``absorbed=True`` folds ``wk_b`` into the query (``q_abs = q_nope
+      wk_b[h]^T``, ``d_latent`` wide) and ``wv_b`` into the output, so
+      scores and the weighted sum run against the latent rows as stored:
+      a decode step reads ``d_latent + d_rope`` values a cached token and
+      never forms K or V.  The weighted sum runs over the whole row and
+      drops the tail of the small result, so the window is never sliced
+      (a slice of it is a copy of it).
+
+    Products take the pool's dtype (bfloat16 in serving) and accumulate
+    in float32.
+    """
+    with jax.named_scope("mla_absorbed" if absorbed else "mla_materialised"):
+        return _paged_latent_attention(
+            q_nope, q_rope, pool, block_table, q_pos, wk_b, wv_b,
+            block_size=block_size, scale=scale, absorbed=absorbed,
+        )
+
+
+def _paged_latent_attention(
+    q_nope, q_rope, pool, block_table, q_pos, wk_b, wv_b, *, block_size,
+    scale, absorbed,
+):
+    b, tq, h, d_nope = q_nope.shape
+    d_latent, d_rope = wk_b.shape[0], q_rope.shape[-1]
+    n_keys = block_table.shape[1] * block_size
+    dtype, width = pool.dtype, pool.shape[-1]
+    window = pool[block_table].reshape(b, n_keys, width)
+    f32 = dict(preferred_element_type=jnp.float32)
+    if absorbed:
+        q_abs = jnp.einsum(
+            "bthn,chn->bthc", q_nope.astype(dtype),
+            wk_b.reshape(d_latent, h, d_nope), **f32,
+        )
+        q_row = jnp.concatenate(
+            [
+                q_abs.astype(dtype), q_rope.astype(dtype),
+                jnp.zeros((b, tq, h, width - d_latent - d_rope), dtype),
+            ],
+            axis=-1,
+        ).reshape(b, tq * h, width)
+        s = jnp.einsum("bre,bke->brk", q_row, window, **f32)
+        s = s.reshape(b, tq, h, n_keys)
+    else:
+        latent = window[..., :d_latent]
+        k_rope = window[..., d_latent:d_latent + d_rope]
+        k_nope = jnp.dot(latent, wk_b, **f32).astype(dtype)
+        v = jnp.dot(latent, wv_b, **f32).astype(dtype)
+        s = jnp.einsum(
+            "bthn,bkhn->bthk", q_nope.astype(dtype),
+            k_nope.reshape(b, n_keys, h, d_nope), **f32,
+        ) + jnp.einsum("bthr,bkr->bthk", q_rope.astype(dtype), k_rope, **f32)
+    valid = jnp.arange(n_keys)[None, None, None, :] <= q_pos[:, :, None, None]
+    s = jnp.where(valid, s * scale, -jnp.inf)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(dtype)
+    if absorbed:
+        o_latent = jnp.einsum(
+            "brk,bke->bre", p.reshape(b, tq * h, n_keys), window, **f32
+        )[..., :d_latent].reshape(b, tq, h, d_latent)
+        o = jnp.einsum(
+            "bthc,chv->bthv", o_latent.astype(dtype),
+            wv_b.reshape(d_latent, h, -1), **f32,
+        )
+    else:
+        o = jnp.einsum(
+            "bthk,bkhv->bthv", p, v.reshape(b, n_keys, h, -1), **f32
+        )
+    return o.reshape(b, tq, -1)
+
+
 def init_mha_params(
     d_model: int,
     n_heads: int,

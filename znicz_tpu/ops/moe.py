@@ -21,17 +21,28 @@ routing working set is O(B*k*F + E*C*F) — both independent of E (no
 the residual layer wrapper passes them through unchanged — standard
 token-drop accounting).  Slot priority is (choice rank, token index), so
 results are deterministic.
+
+``dispatch`` by :func:`held_experts_apply` is the DROPLESS mode of a model
+with many small experts of which this chip holds a few (one share of an
+expert-parallel layer): the router scores every published expert
+(:func:`route_sigmoid_topk`), the (token, choice) pairs that chose an
+expert held here are sorted by expert, and ONE grouped matrix product per
+projection runs over exactly those rows (:func:`grouped_matmul`).  No
+token is dropped whatever the imbalance, no expert computes a token that
+did not choose it, and an expert nobody chose is not read.  What the
+absent experts would have added is left out: that belongs to the other
+chips' shares, and nothing here stands in for them or for the exchange.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from znicz_tpu.core import prng
+from znicz_tpu.core import backend, prng
 from znicz_tpu.ops.filling import fill
 
 
@@ -246,3 +257,105 @@ def expert_sharding(mesh, axis: str = "model"):
         return {name: put(name, leaf) for name, leaf in params.items()}
 
     return place
+
+
+# -- many small experts, a few of them held here ---------------------------
+
+# (rows, contraction, columns) tile of the grouped product on the TPU: a
+# decode step or a prefill chunk hands each expert a handful of rows, so
+# the product streams weights and wants the widest weight tile that fits
+GMM_TILING = (128, 1024, 1024)
+
+
+def route_sigmoid_topk(
+    h: jnp.ndarray,  # [T, D]
+    router: jnp.ndarray,  # [D, E]
+    *,
+    top_k: int,
+    scale: float = 1.0,
+    normalize: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid-scored routing over ALL ``E`` experts: ``(chosen [T, k]
+    int32, weights [T, k] float32)`` with ``w = scale * s / sum(s)`` over
+    the chosen (DeepSeek-V3 lineage, without its group limit or score
+    bias).  Scores are float32."""
+    s = jax.nn.sigmoid(
+        jnp.dot(h, router, preferred_element_type=jnp.float32)
+    )
+    top, idx = jax.lax.top_k(s, top_k)
+    if normalize:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), top * scale
+
+
+def grouped_matmul(
+    lhs: jnp.ndarray,  # [M, K], rows sorted by group
+    rhs: jnp.ndarray,  # [G, K, N]
+    group_sizes: jnp.ndarray,  # [G] int32, sum <= M
+) -> jnp.ndarray:
+    """``lhs[rows of group g] @ rhs[g]`` for every group, float32; rows
+    past ``sum(group_sizes)`` belong to no group and hold NOTHING a caller
+    may read (the TPU kernel never writes them).  On the TPU this is the
+    megablox grouped-product kernel (a Pallas ``tpu_custom_call`` that
+    visits only the (row tile, group) pairs that hold rows, so an empty
+    group's weights are never fetched); elsewhere ``jax.lax.ragged_dot``."""
+    if backend.on_tpu():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        m, k = lhs.shape
+        n = rhs.shape[2]
+        tiling = tuple(
+            min(t, d) for t, d in zip(GMM_TILING, (m, k, n))
+        )
+        return gmm(
+            lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
+            tiling=tiling,
+        )
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes, preferred_element_type=jnp.float32
+    )
+
+
+def held_experts_apply(
+    h: jnp.ndarray,  # [T, D] normalised rows
+    chosen: jnp.ndarray,  # [T, k] expert ids over all E
+    weight: jnp.ndarray,  # [T, k] float32
+    gate: jnp.ndarray,  # [G, D, F]
+    up: jnp.ndarray,  # [G, D, F]
+    down: jnp.ndarray,  # [G, F, D]
+    *,
+    first_expert: int,
+    row_mask: Optional[jnp.ndarray] = None,  # [T] bool
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of ``sum_e w_e down_e(silu(gate_e h) * up_e h)`` that the
+    experts ``[first_expert, first_expert + G)`` give: ``(y [T, D]
+    float32, pairs [G] int32)`` where ``pairs[g]`` counts the (token,
+    choice) pairs expert ``g`` computed.  Rows with ``row_mask`` False
+    (idle slots, padding) are routed nowhere.
+
+    Dropless sort dispatch: the ``T * k`` pairs are stably sorted by held
+    expert (pairs of experts held elsewhere sort last, into no group), the
+    tokens' rows are gathered in that order, three grouped products run
+    over the groups, and the results are gathered back to ``[T, k]`` and
+    summed under their weights.  Shapes are static at ``T * k`` rows, the
+    work is not: the grouped product touches only the rows in a group."""
+    t, k = chosen.shape
+    g = gate.shape[0]
+    local = chosen - first_expert
+    held = (local >= 0) & (local < g)
+    if row_mask is not None:
+        held = held & row_mask[:, None]
+    local = jnp.where(held, local, g).reshape(-1)
+    order = jnp.argsort(local, stable=True)
+    pairs = jnp.sum(
+        local[:, None] == jnp.arange(g)[None, :], axis=0, dtype=jnp.int32
+    )
+    rows = h[order // k]  # [T * k, D], sorted by expert
+    with jax.named_scope("moe_experts"):
+        act = jax.nn.silu(
+            grouped_matmul(rows, gate, pairs)
+        ) * grouped_matmul(rows, up, pairs)
+        out = grouped_matmul(act.astype(h.dtype), down, pairs)
+    back = jnp.argsort(order).reshape(t, k)
+    y = jnp.where(held[..., None], out[back] * weight[..., None], 0.0)
+    return jnp.sum(y, axis=1), pairs

@@ -83,3 +83,13 @@ def layer_norm(
     var = jnp.var(x, axis=-1, keepdims=True)
     y = (x - mean) * jax.lax.rsqrt(var + eps)
     return y * scale + bias
+
+
+def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, *, eps: float = 1e-6):
+    """Root-mean-square normalization over the trailing axis, computed
+    and returned in float32 whatever ``x`` is stored in (no mean is
+    removed and there is no bias)."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps
+    ) * scale

@@ -357,14 +357,14 @@ def _decode_chunk(
     jax.jit,
     static_argnames=(
         "block_size", "n_heads", "greedy", "top_k", "nucleus",
-        "moe_top_k", "moe_dispatch",
+        "moe_top_k", "moe_dispatch", "model",
     ),
     donate_argnums=(1,),
 )
 def _paged_prefill_prog(
     params, pools, table, tokens, offset, start, last, temperature,
     top_p, key, *, block_size, n_heads, greedy, top_k, nucleus,
-    moe_top_k, moe_dispatch,
+    moe_top_k, moe_dispatch, model=None,
 ):
     """One aligned prompt chunk into the row's blocks + first-token
     sample.  ONE compiled shape covers every prompt length and every
@@ -374,17 +374,30 @@ def _paged_prefill_prog(
     is the in-chunk index of the prompt's final real token (the tail of
     the final chunk is RIGHT-pad — prefix-cache alignment); the sample
     only matters on the final chunk; computing it unconditionally keeps
-    the program single and costs one argmax/categorical per chunk."""
-    pools, logits = paged_prefill_chunk(
-        params, pools, table, tokens, offset, n_heads=n_heads,
-        block_size=block_size, start=start, last=last,
-        moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
-    )
+    the program single and costs one argmax/categorical per chunk.
+
+    With a ``model`` (a tower of another kind, e.g.
+    :class:`~znicz_tpu.workflow.latent_lm.LatentMoEModel`) the chunk runs
+    through ITS tower and the call returns a third value, the chunk's
+    expert-load sums."""
+    if model is None:
+        pools, logits = paged_prefill_chunk(
+            params, pools, table, tokens, offset, n_heads=n_heads,
+            block_size=block_size, start=start, last=last,
+            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+        )
+    else:
+        pools, logits, load = model.prefill_chunk(
+            params, pools, table, tokens, offset, block_size=block_size,
+            last=last,
+        )
     first = _sample_tok(
         logits, key, temperature, top_p, greedy=greedy, top_k=top_k,
         nucleus=nucleus,
     )
-    return pools, first[0]
+    if model is None:
+        return pools, first[0]
+    return pools, first[0], load
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -399,14 +412,14 @@ def _cow_copy_prog(pools, src, dst):
     jax.jit,
     static_argnames=(
         "chunk", "block_size", "t_max", "n_heads", "eos_id", "greedy",
-        "top_k", "nucleus", "moe_top_k", "moe_dispatch",
+        "top_k", "nucleus", "moe_top_k", "moe_dispatch", "model",
     ),
     donate_argnums=(1,),
 )
 def _paged_decode_chunk(
     params, pools, tables, tok, pos, start, done, remaining,
     temperature, top_p, rng, *, chunk, block_size, t_max, n_heads,
-    eos_id, greedy, top_k, nucleus, moe_top_k, moe_dispatch,
+    eos_id, greedy, top_k, nucleus, moe_top_k, moe_dispatch, model=None,
 ):
     """Up to ``chunk`` paged decode steps for the whole batch in ONE
     compiled program (the paged twin of :func:`_decode_chunk`).
@@ -418,7 +431,13 @@ def _paged_decode_chunk(
     and their positions FREEZE (a clamped position could walk into a
     table entry the allocator already handed to another row — the
     dense chunk's clamp-and-ignore trick is not safe against a shared
-    pool)."""
+    pool).
+
+    With a ``model`` each step runs through ITS tower (same loop, same
+    sampling, same freezing of done rows) and the call returns an eighth
+    value: the chunk's expert-load sums, added up step by step on the
+    device so that they cost the host nothing but their fetch with the
+    chunk's tokens."""
     b = tok.shape[0]
     # clamp against the FULL positional capacity, never the (possibly
     # narrower) gathered window: the final loop iteration pushes a live
@@ -431,16 +450,25 @@ def _paged_decode_chunk(
     out = jnp.full((b, chunk), fill, jnp.int32)
 
     def cond(carry):
-        i, _, _, _, done, _, _ = carry
+        i, _, _, _, done, _, _, _ = carry
         return (i < chunk) & ~jnp.all(done)
 
-    def body(carry):
-        i, pools, tok, pos, done, remaining, out = carry
-        pools, logits = paged_decode_step(
-            params, pools, tables, tok, pos, n_heads=n_heads,
-            block_size=block_size, start=start, write_mask=~done,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+    def step(pools, tok, pos, done):
+        if model is None:
+            return paged_decode_step(
+                params, pools, tables, tok, pos, n_heads=n_heads,
+                block_size=block_size, start=start, write_mask=~done,
+                moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            ) + (None,)
+        return model.decode_step(
+            params, pools, tables, tok, pos, block_size=block_size,
+            write_mask=~done,
         )
+
+    def body(carry):
+        i, pools, tok, pos, done, remaining, out, load = carry
+        pools, logits, step_load = step(pools, tok, pos, done)
+        load = jax.tree_util.tree_map(jnp.add, load, step_load)
         nxt = _sample_tok(
             logits, jax.random.fold_in(rng, i), temperature, top_p,
             greedy=greedy, top_k=top_k, nucleus=nucleus,
@@ -450,13 +478,20 @@ def _paged_decode_chunk(
         done = done | (nxt == eos_id) | (remaining <= 0)
         out = jax.lax.dynamic_update_slice(out, nxt[:, None], (0, i))
         pos = jnp.where(done, pos, jnp.minimum(pos + 1, t_cap))
-        return (i + 1, pools, nxt, pos, done, remaining, out)
+        return (i + 1, pools, nxt, pos, done, remaining, out, load)
 
-    i, pools, tok, pos, done, remaining, out = jax.lax.while_loop(
-        cond, body,
-        (jnp.int32(0), pools, tok, pos, done, remaining, out),
+    # the load sums' shapes, without running a step: zeros to add onto
+    load = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(step, pools, tok, pos, done)[2],
     )
-    return pools, tok, pos, done, remaining, out, i
+    i, pools, tok, pos, done, remaining, out, load = jax.lax.while_loop(
+        cond, body,
+        (jnp.int32(0), pools, tok, pos, done, remaining, out, load),
+    )
+    if model is None:
+        return pools, tok, pos, done, remaining, out, i
+    return pools, tok, pos, done, remaining, out, i, load
 
 
 @partial(
@@ -584,6 +619,7 @@ class DecodeEngine:
         spec_k: int = 0,
         drafter=None,
         spec_buckets: Optional[Sequence[int]] = None,
+        model=None,
     ):
         if batch_size < 1 or admit_every < 1:
             raise ValueError(
@@ -609,7 +645,21 @@ class DecodeEngine:
         if not hasattr(self, "spec_k"):
             self.spec_k = 0  # the stats() spec sub-dict reads this
             # (the paged subclass sets its own before delegating here)
-        max_pos = params[0]["pos"].shape[0]
+        # the tower's KIND: None is the classic block of
+        # workflow/transformer.py (learned positions, a k/v pair a
+        # layer); anything else brings its own paged tower
+        # (``init_pools`` / ``prefill_chunk`` / ``decode_step``, e.g.
+        # workflow/latent_lm.LatentMoEModel) and its own context limit
+        self.model = model
+        if model is not None and self.kv_backend != "paged":
+            raise ValueError(
+                f"a {type(model).__name__} tower is served by the paged "
+                "backend only (PagedDecodeEngine)"
+            )
+        max_pos = (
+            params[0]["pos"].shape[0] if model is None
+            else int(model.max_positions)
+        )
         self.t_max = int(max_seq or max_pos)
         if self.t_max > max_pos:
             raise ValueError(
@@ -1301,6 +1351,7 @@ class PagedDecodeEngine(DecodeEngine):
         spec_k: int = 0,
         drafter=None,
         spec_buckets: Sequence[int] = DEFAULT_SPEC_BUCKETS,
+        model=None,
     ):
         if block_size < 1:
             raise ValueError(f"want block_size >= 1; got {block_size}")
@@ -1319,6 +1370,12 @@ class PagedDecodeEngine(DecodeEngine):
         # draft-model drafter plugs into the same hook.
         if spec_k < 0:
             raise ValueError(f"want spec_k >= 0; got {spec_k}")
+        if spec_k and model is not None:
+            raise SpeculationUnsupportedError(
+                f"a {type(model).__name__} tower has no verify program "
+                "yet: speculative decoding is served for the classic "
+                "tower only"
+            )
         self.spec_k = int(spec_k)
         self.spec_buckets = tuple(int(w) for w in spec_buckets)
         if (
@@ -1358,7 +1415,7 @@ class PagedDecodeEngine(DecodeEngine):
             batch_size=batch_size, max_seq=max_seq,
             admit_every=admit_every, pad_id=pad_id,
             temperature=temperature, top_k=top_k, top_p=top_p, rng=rng,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch, model=model,
         )
 
     def _init_kv_state(self) -> None:
@@ -1371,9 +1428,9 @@ class PagedDecodeEngine(DecodeEngine):
         else:
             self.n_blocks = int(self._n_blocks_arg)
         self.blocks_per_row = m
-        self._pools = init_paged_kv(
-            self.params, self.n_blocks, self.block_size
-        )
+        self._pools = (
+            init_paged_kv if self.model is None else self.model.init_pools
+        )(self.params, self.n_blocks, self.block_size)
         # LIFO free list: a just-freed (still cache/HBM-warm) block is
         # the next one handed out; block 0 stays reserved as null
         self._free: List[int] = list(range(1, self.n_blocks))
@@ -1402,13 +1459,13 @@ class PagedDecodeEngine(DecodeEngine):
         # already produced once — re-observing would double-count)
         self._admitted_ids: set = set()
         self._n_preempted = 0
-        # per-block K/V footprint across the whole tower — the byte
-        # twin of the block gauges, so pool pressure is readable in
-        # the same unit device memory is
+        # per-block footprint across the whole tower (a k/v pair a
+        # layer, or one array of latent rows) — the byte twin of the
+        # block gauges, so pool pressure is readable in the same unit
+        # device memory is
         self.block_bytes = sum(
-            2 * int(np.prod(p["k"].shape[1:]))
-            * np.dtype(p["k"].dtype).itemsize
-            for p in self._pools
+            int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
+            for leaf in jax.tree_util.tree_leaves(self._pools)
         )
         self._m_pool = observability.gauge(
             "znicz_serve_kv_pool_blocks",
@@ -1486,7 +1543,65 @@ class PagedDecodeEngine(DecodeEngine):
             "paged decode and verify chunks by gather window (blocks)",
             ("window",),
         )
+        # routed experts held here (a tower that reports its expert
+        # load; silent for every other): the sums come back with each
+        # call's outputs, so reading them adds no sync
+        self._load_backlog: List[dict] = []
+        self._routed_layers = (
+            self.model.routed_layers(self.params) if self.model else 0
+        )
+        self._m_moe_pairs = observability.counter(
+            "znicz_serve_moe_pairs_total",
+            "(token, choice) pairs computed by the experts held here, "
+            "by held expert and by the phase that routed them",
+            ("phase", "expert"),
+        )
+        self._m_moe_busiest = observability.counter(
+            "znicz_serve_moe_busiest_pairs_total",
+            "pairs of the busiest held expert, summed over routed "
+            "layers and steps (over znicz_serve_moe_layer_steps_total: "
+            "the mean busiest load)",
+            ("phase",),
+        )
+        self._m_moe_idle = observability.counter(
+            "znicz_serve_moe_idle_experts_total",
+            "held experts that received no pair, summed over routed "
+            "layers and steps",
+            ("phase",),
+        )
+        self._m_moe_layer_steps = observability.counter(
+            "znicz_serve_moe_layer_steps_total",
+            "routed layers x token steps (decode) or chunks (prefill) "
+            "whose expert load was counted",
+            ("phase",),
+        )
         self._update_pool_gauges()
+
+    def _count_expert_load(self, phase: str, loads, calls: int) -> None:
+        """Fold the expert-load sums of some calls (finished by now:
+        ONE fetch for all of them) into the registry; each covers
+        ``calls`` token steps or chunks."""
+        loads = [load for load in loads if load is not None]
+        if not loads or not calls:
+            return
+        fetched = jax.device_get(loads)
+        for load in fetched:
+            for expert, n in enumerate(load["pairs"]):
+                self._m_moe_pairs.labels(phase=phase, expert=expert).inc(
+                    int(n)
+                )
+            self._m_moe_busiest.labels(phase=phase).inc(int(load["busiest"]))
+            self._m_moe_idle.labels(phase=phase).inc(int(load["idle"]))
+            self._m_moe_layer_steps.labels(phase=phase).inc(
+                calls * self._routed_layers
+            )
+
+    def _drain_load_backlog(self) -> None:
+        """Prefill chunks hand their load sums over unread (reading
+        would wait for the chunk); they are folded in at the next point
+        where the host waits for the device anyway."""
+        backlog, self._load_backlog = self._load_backlog, []
+        self._count_expert_load("prefill", backlog, 1)
 
     # -- capacity & the block allocator -----------------------------------
 
@@ -1926,7 +2041,7 @@ class PagedDecodeEngine(DecodeEngine):
             **self._trace_args(req.trace_id),
         ):
             key = jax.random.fold_in(self._rng, st["seq"])
-            self._pools, first = self._timed_program(
+            self._pools, first, *load = self._timed_program(
                 ("prefill", self.block_size, self._structure),
                 _paged_prefill_prog,
                 self.params, self._pools,
@@ -1947,11 +2062,13 @@ class PagedDecodeEngine(DecodeEngine):
                 block_size=self.block_size, n_heads=self.n_heads,
                 greedy=greedy, top_k=top_k, nucleus=nucleus,
                 moe_top_k=self.moe_top_k,
-                moe_dispatch=self.moe_dispatch,
+                moe_dispatch=self.moe_dispatch, model=self.model,
             )
+            self._load_backlog.extend(load)
             st["chunks_done"] = c + 1
             if last:
                 first = int(first)  # host sync only at admission
+                self._drain_load_backlog()
         req.timings.prefill_s += time.perf_counter() - t0
         self._m_prefill_chunks.inc()
         if not last:
@@ -2266,7 +2383,7 @@ class PagedDecodeEngine(DecodeEngine):
             rng = jax.random.fold_in(self._rng, 1 << 20 | self._chunk_idx)
             self._chunk_idx += 1
             greedy, top_k, nucleus = self._structure
-            (pools, tok, pos, done, remaining, out, steps) = (
+            (pools, tok, pos, done, remaining, out, steps, *load) = (
                 self._timed_program(
                     ("paged_chunk", self.admit_every, self.batch_size,
                      window, self._structure),
@@ -2281,12 +2398,14 @@ class PagedDecodeEngine(DecodeEngine):
                     n_heads=self.n_heads, eos_id=self.eos_id,
                     greedy=greedy, top_k=top_k, nucleus=nucleus,
                     moe_top_k=self.moe_top_k,
-                    moe_dispatch=self.moe_dispatch,
+                    moe_dispatch=self.moe_dispatch, model=self.model,
                 )
             )
             self._pools = pools
             out = np.asarray(out)
             steps = int(steps)
+            self._drain_load_backlog()
+            self._count_expert_load("decode", load, steps)
             self._tok = np.array(tok)
             self._pos = np.array(pos)
             self._done = np.array(done)

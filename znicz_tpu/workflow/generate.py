@@ -299,22 +299,18 @@ def paged_prefill_chunk(
 
 
 def copy_paged_block(pools, src, dst):
-    """Copy pool block ``src`` into ``dst`` across every layer's K/V
-    pool — the copy-on-write split for paged prefix sharing: when a row
+    """Copy pool block ``src`` into ``dst`` across every layer's pool
+    (a ``k``/``v`` pair, or one array of latent rows: every array of a
+    pool is ``[n_blocks, block_size, ...]``) — the copy-on-write split for paged prefix sharing: when a row
     must write into a block other tables (or the prefix cache) still
     reference, the engine allocates a fresh block, copies the shared
     content here, and retargets only its own table entry.  ``src`` and
     ``dst`` are traced operands, so one compiled program serves every
     split."""
-    new_pools = []
-    for pool in pools:
-        new_pools.append(
-            {
-                "k": pool["k"].at[dst].set(pool["k"][src]),
-                "v": pool["v"].at[dst].set(pool["v"][src]),
-            }
-        )
-    return new_pools
+    return [
+        {name: a.at[dst].set(a[src]) for name, a in pool.items()}
+        for pool in pools
+    ]
 
 
 def paged_decode_step(
