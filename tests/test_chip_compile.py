@@ -6,7 +6,8 @@ refuse on the chip — a slice off the tiling, too much VMEM, an op with no
 lowering — fails here, in tier-1, at no chip time.  Shapes are the ones
 ``chip_smoke.py`` and the model zoo run: flash attention at the mid LM's
 ``[8, 2048, 8, 64|128]``, LRN at AlexNet's two normalised activations, the
-SOM step and RBM CD-1 at the MNIST zoo sizes.  Nothing executes: a compile
+SOM step and RBM CD-1 at the MNIST zoo sizes, the latent decode attention
+at ``axk1-ep16``'s pool and three rungs of the engine's decode window.  Nothing executes: a compile
 that passes is not a chip run.
 
 Interpret mode is steered off IN THE TEST (``backend.pallas_interpret``):
@@ -28,6 +29,7 @@ from znicz_tpu.core import backend
 from znicz_tpu.ops import kohonen as kh, normalization
 from znicz_tpu.ops.pallas import kohonen as pallas_kh, rbm as pallas_rbm
 from znicz_tpu.ops.pallas.attention import flash_attention
+from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
 
 
 @pytest.fixture(scope="module")
@@ -133,3 +135,22 @@ def test_rbm_cd1_compiles_with_the_hardware_prng(chip, batch):
     # interpret mode's host-made uniforms must be gone: the chain samples
     # with the chip's PRNG, so no threefry program rides along
     assert "threefry" not in text
+
+
+@pytest.mark.parametrize("window", [1, 4, 96])
+def test_latent_decode_attention_compiles(chip, window):
+    # axk1-ep16 as served: 128 slots, 64 heads, 4,096 blocks of 128 rows of
+    # 640 bfloat16; the engine's decode window doubles from 1 block to 96
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q_row, pool, tables, lengths):
+        return latent_decode_attention(
+            q_row, pool, tables, lengths, scale=0.1147, d_out=512
+        )
+
+    _compile(
+        fn, spec((128, 64, 640), jnp.bfloat16),
+        spec((4096, 128, 640), jnp.bfloat16), spec((128, window), jnp.int32),
+        spec((128,), jnp.int32),
+    )

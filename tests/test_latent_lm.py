@@ -71,11 +71,11 @@ def _reference_view(params):
 
 
 class Toy:
-    def __init__(self, first_expert=4, held=4, seed=1):
+    def __init__(self, first_expert=4, held=4, seed=1, max_positions=256):
         self.ref = _load_reference()
         self.cfg = _config(first_expert, held)
         self.model = latent_lm.LatentMoEModel.from_config(
-            self.cfg, first_expert=first_expert, max_positions=256
+            self.cfg, first_expert=first_expert, max_positions=max_positions
         )
         self.params = latent_lm.init_params(
             self.model, held_experts=held, seed=seed, **SIZES
@@ -463,6 +463,62 @@ def test_expert_load_comes_back_with_the_chunks_and_skips_idle_rows(toy):
     assert 0 < hit <= d["pairs", "decode"]
     # the prompt's 19 tokens (right-padding excluded) choose 4 of 16 each
     assert 0 < d["pairs", "prefill"] <= 19 * 3 * 4
+
+
+@pytest.mark.parametrize("tower", ["latent_in_place", "latent_gathered", "kv"])
+def test_the_gathered_counter_counts_what_the_attention_form_read(
+    tower, monkeypatch
+):
+    """``znicz_serve_decode_gathered_tokens_total`` over a served stream:
+    the decoding rows' lengths in whole blocks, step by step, where the
+    latent pool is read in place; every slot's window where a window is
+    gathered (the latent tower off the TPU, the K/V tower anywhere)."""
+    from znicz_tpu.ops import attention
+
+    if tower == "kv":
+        from znicz_tpu.workflow.transformer import init_lm_params
+
+        eng = PagedDecodeEngine(
+            init_lm_params(256, 32, 2, 4, max_seq=64), n_heads=4, eos_id=0,
+            block_size=BS, batch_size=4, max_seq=64, admit_every=4,
+        )
+    else:
+        if tower == "latent_in_place":
+            # this process computes on the CPU: the op is told that a
+            # decode step reads in place (the kernel runs interpreted)
+            monkeypatch.setattr(
+                attention, "_reads_pool_in_place", lambda tq: tq == 1
+            )
+        # a model of its own, so that the decode chunk is traced here and
+        # not found among the programs other tests compiled
+        eng = Toy(max_positions=250 + (tower == "latent_in_place")).engine()
+    chunks = []
+    count = eng._count_gathered
+    monkeypatch.setattr(
+        eng, "_count_gathered",
+        lambda steps, window, *read: (
+            chunks.append((steps, window)), count(steps, window, *read)
+        ),
+    )
+    rng = np.random.default_rng(14)
+    before = _counter("znicz_serve_decode_gathered_tokens_total")
+    ids = [eng.submit(_tokens(rng, n), new) for n, new in ((21, 9), (5, 12), (16, 3))]
+    eng.run()
+    gathered = _counter("znicz_serve_decode_gathered_tokens_total") - before
+    assert sum(steps for steps, _ in chunks) > 0
+    if tower == "latent_in_place":
+        # a request of n prompt tokens decodes its k-th token at position
+        # n + k, attending n + k + 1 keys; its first token came from the
+        # prefill and its last is fed to no step
+        done = [eng.completions[rid] for rid in ids]
+        assert gathered == sum(
+            -(-(len(c.tokens) - c.n_new + k + 1) // BS) * BS
+            for c in done for k in range(c.n_new - 1)
+        )
+    else:
+        assert gathered == sum(
+            steps * eng.batch_size * window * BS for steps, window in chunks
+        )
 
 
 def test_what_the_tower_is_not_served_with_is_refused_by_name(toy):

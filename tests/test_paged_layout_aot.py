@@ -268,7 +268,8 @@ def _axk1_params(spec):
     )
 
 
-AXK1_TEMP_LIMIT_GB = {"decode_chunk": 3.2, "prefill": 0.6, "cow_copy": 0.01}
+AXK1_TEMP_LIMIT_GB = {"decode_chunk": 1.0, "prefill": 0.6, "cow_copy": 0.01}
+_SHAPE = re.compile(r"= \(?\w+\[([\d,]+)\]")
 
 
 @pytest.mark.parametrize("program", list(AXK1_TEMP_LIMIT_GB))
@@ -329,3 +330,17 @@ def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypat
         # the three grouped products of each routed layer are the kernel
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
         assert text.count("tpu_custom_call") >= 3 * (a["layers"] - 1)
+    if program == "decode_chunk":
+        # every layer's absorbed attention is a kernel too, and nothing
+        # the program computes is as large as a gathered window
+        assert text.count("tpu_custom_call") >= 3 * (a["layers"] - 1) + a["layers"]
+        window_elements = a["slots"] * a["window"] * a["block"] * 640
+        gathered = sorted(
+            {
+                dims for dims in _SHAPE.findall(text)
+                if window_elements
+                <= int(np.prod([int(d) for d in dims.split(",")]))
+                < pool_elements
+            }
+        )
+        assert not gathered, f"the decode chunk still holds a window: {gathered}"

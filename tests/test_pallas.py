@@ -593,3 +593,154 @@ class TestPallasKohonen:
         np.testing.assert_allclose(
             fused["weights"], ref["weights"], rtol=1e-4, atol=1e-5
         )
+
+
+class TestPallasLatentDecodeAttention:
+    """The absorbed decode attention that reads the paged pool in place
+    (ops/pallas/latent_attention.py), through the op that picks it, against
+    both gathered forms of :func:`ops.attention.paged_latent_attention`."""
+
+    BS, H, DC, DR, DN, DV, W, N_BLOCKS = 8, 4, 16, 8, 8, 8, 128, 24
+    NULL = 0  # workflow.generate.NULL_BLOCK
+
+    # name -> (keys each row attends, its block table; short tables are
+    # filled with the null block).  A table is 10 blocks wide: the kernel
+    # walks it in chunks of 8, and the second chunk runs past its end
+    CASES = {
+        # one batch, from a single key to every key of the table
+        "rows_of_different_lengths": (
+            [1, 19, 80, 30, 70],
+            [[1], [7, 8, 9], [10, 11, 12, 13, 14, 15, 16, 17, 18, 19],
+             [2, 3, 4, 5], [20, 21, 22, 23, 6, 5, 4, 3, 2]],
+        ),
+        # 21 ends inside its third block, 24 on that block's last key, 64
+        # on the last key of the kernel's first chunk, 65 one past it
+        "a_length_ends_mid_block_and_one_on_the_boundary": (
+            [21, 24, 64, 65],
+            [[1, 2, 3], [4, 5, 6], [7, 8, 9, 10, 11, 12, 13, 14],
+             [7, 8, 9, 10, 11, 12, 13, 14, 15]],
+        ),
+        # idle rows before, between and after the live ones; their tables
+        # name the null block or blocks that row 1 owns
+        "idle_rows_between_live_ones": (
+            [0, 27, 0, 0, 75, 0],
+            [[], [1, 2, 3, 4], [1, 2, 3, 4], [],
+             [5, 6, 7, 8, 9, 10, 11, 12, 13, 14], [2, 1]],
+        ),
+        # a shared prefix: the rows map the same physical blocks and stop
+        # at different keys of them
+        "two_rows_alias_the_same_blocks": (
+            [18, 23, 69, 72],
+            [[1, 2, 3], [1, 2, 3], [1, 2, 3, 4, 5, 6, 7, 8, 9],
+             [1, 2, 3, 4, 5, 6, 7, 8, 10]],
+        ),
+        # stale entries past a row's last block name other rows' blocks
+        "a_table_wider_than_any_row_needs": (
+            [5, 9],
+            [[1, 3, 4, 3, 4, 3, 4, 3, 4, 3], [2, 3, 1, 1, 1, 1, 1, 1, 1, 1]],
+        ),
+        "every_row_idles": ([0, 0], [[1, 2], []]),
+    }
+
+    def _case(self, name):
+        lengths, tables = self.CASES[name]
+        return jnp.asarray(lengths, jnp.int32), jnp.asarray(
+            [row + [self.NULL] * (10 - len(row)) for row in tables], jnp.int32
+        )
+
+    def _operands(self, n_rows, dtype, seed=0):
+        ks = jax.random.split(jax.random.key(seed), 5)
+        rows = jax.random.normal(
+            ks[0], (self.N_BLOCKS, self.BS, self.DC + self.DR), jnp.float32
+        )
+        pool = jnp.zeros((self.N_BLOCKS, self.BS, self.W), dtype)
+        pool = pool.at[..., : self.DC + self.DR].set(rows.astype(dtype))
+        q_nope = jax.random.normal(ks[1], (n_rows, 1, self.H, self.DN))
+        q_rope = jax.random.normal(ks[2], (n_rows, 1, self.H, self.DR))
+        wk_b = jax.random.normal(ks[3], (self.DC, self.H * self.DN), dtype) / 4
+        wv_b = jax.random.normal(ks[4], (self.DC, self.H * self.DV), dtype) / 4
+        return q_nope, q_rope, pool, wk_b, wv_b
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_reads_in_place_what_the_gathered_forms_compute(
+        self, case, dtype, monkeypatch
+    ):
+        from znicz_tpu.ops import attention as att
+
+        lengths, tables = self._case(case)
+        q_nope, q_rope, pool, wk_b, wv_b = self._operands(len(lengths), dtype)
+        live = np.asarray(lengths) > 0
+
+        def attend(absorbed, lengths=lengths):
+            return np.asarray(att.paged_latent_attention(
+                q_nope, q_rope, pool, tables,
+                jnp.maximum(lengths - 1, 0)[:, None], wk_b, wv_b,
+                block_size=self.BS, scale=0.25, absorbed=absorbed,
+                lengths=lengths if absorbed else None,
+            ))
+
+        gathered, materialised = attend(True), attend(False)
+        calls = []
+        kernel = att.latent_decode_attention
+        monkeypatch.setattr(
+            att, "latent_decode_attention",
+            lambda *a, **kw: calls.append(kw) or kernel(*a, **kw),
+        )
+        # this process computes on the CPU: the op is told that a decode
+        # step reads in place, and the kernel runs interpreted
+        monkeypatch.setattr(att, "_reads_pool_in_place", lambda tq: tq == 1)
+        in_place = attend(True)
+        assert len(calls) == 1 and calls[0]["d_out"] == self.W
+        assert in_place.shape == (len(live), 1, self.H * self.DV)
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(
+            in_place[live], gathered[live], rtol=tol, atol=tol
+        )
+        np.testing.assert_allclose(
+            in_place[live], materialised[live], rtol=tol, atol=tol
+        )
+        # a row that idles fetches nothing and gives zeros, in both forms
+        assert not in_place[~live].any() and not gathered[~live].any()
+
+    def test_a_decode_step_without_lengths_attends_up_to_its_position(
+        self, monkeypatch
+    ):
+        from znicz_tpu.ops import attention as att
+
+        q_nope, q_rope, pool, wk_b, wv_b = self._operands(2, jnp.float32, 3)
+        tables = jnp.asarray([[1, 2, 3, 0, 0, 0], [4, 5, 0, 0, 0, 0]])
+        q_pos = jnp.asarray([[17], [8]])
+
+        def attend(**kw):
+            return np.asarray(att.paged_latent_attention(
+                q_nope, q_rope, pool, tables, q_pos, wk_b, wv_b,
+                block_size=self.BS, scale=0.25, absorbed=True, **kw,
+            ))
+
+        want = attend()
+        monkeypatch.setattr(att, "_reads_pool_in_place", lambda tq: tq == 1)
+        np.testing.assert_allclose(attend(), want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            attend(lengths=jnp.asarray([18, 9])), want, rtol=2e-5, atol=2e-5
+        )
+        with pytest.raises(ValueError, match="decode step"):
+            att.paged_latent_attention(
+                jnp.tile(q_nope, (1, 2, 1, 1)), jnp.tile(q_rope, (1, 2, 1, 1)),
+                pool, tables, jnp.tile(q_pos, (1, 2)), wk_b, wv_b,
+                block_size=self.BS, scale=0.25, absorbed=True,
+                lengths=jnp.asarray([18, 9]),
+            )
+
+    def test_rows_read_follow_the_form_that_runs(self, monkeypatch):
+        from znicz_tpu.ops import attention as att
+
+        tables = jnp.zeros((3, 6), jnp.int32)
+        lengths = jnp.asarray([17, 0, 8])
+        assert int(att.paged_latent_rows_read(
+            tables, lengths, block_size=8
+        )) == 3 * 6 * 8  # gathered: every slot's window
+        monkeypatch.setattr(att, "_reads_pool_in_place", lambda tq: tq == 1)
+        assert int(att.paged_latent_rows_read(
+            tables, lengths, block_size=8
+        )) == 24 + 0 + 8  # in place: live rows, whole blocks

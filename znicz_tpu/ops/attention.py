@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from znicz_tpu.core import prng
+from znicz_tpu.core import backend, prng
 from znicz_tpu.ops.filling import fill
+from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
 
 
 def dot_product_attention(
@@ -156,6 +157,7 @@ def paged_latent_attention(
     block_size: int,
     scale: float,
     absorbed: bool,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
 ) -> jnp.ndarray:
     """Multi-head LATENT attention over a paged pool of latent rows;
     returns [B, Tq, H * d_v] in float32.
@@ -165,11 +167,11 @@ def paged_latent_attention(
     128-lane tiles: a 576-wide minor dimension fills none, and the TPU's
     compiler then stores the pool tokens-minor and every program copies
     every pool into the layout it computes in) shared by every head (DeepSeek-V2 lineage): head ``h``'s key is ``[c wk_b[:,
-    h], rot(k_r)]`` and its value ``c wv_b[:, h]``.  The row's window is
-    gathered through the block table exactly like
-    :func:`paged_attention`'s (same aliasing, same validity by ABSOLUTE
-    key index ``<= q_pos``, same f32 stable softmax), and the two forms
-    give the same numbers:
+    h], rot(k_r)]`` and its value ``c wv_b[:, h]``.  A row finds its
+    cached rows through the block table with :func:`paged_attention`'s
+    contract (same aliasing, same validity by ABSOLUTE key index ``<=
+    q_pos``, same f32 stable softmax), and the two forms give the same
+    numbers:
 
     * ``absorbed=False`` MATERIALISES K and V of the whole window (``2 *
       window * d_latent * H * (d_nope + d_v)`` FLOPs, whatever ``Tq``) and
@@ -179,9 +181,23 @@ def paged_latent_attention(
       wk_b[h]^T``, ``d_latent`` wide) and ``wv_b`` into the output, so
       scores and the weighted sum run against the latent rows as stored:
       a decode step reads ``d_latent + d_rope`` values a cached token and
-      never forms K or V.  The weighted sum runs over the whole row and
-      drops the tail of the small result, so the window is never sliced
-      (a slice of it is a copy of it).
+      never forms K or V.
+
+    ``lengths`` says how many keys each row of a decode step (``Tq`` 1)
+    attends: ``q_pos + 1`` where it is left out, 0 for a row that idles,
+    whose result is zeros that nobody reads.
+
+    Where the rows are read from.  The materialised form, and the
+    absorbed form off the TPU, GATHER every row's window
+    (``pool[block_table]``, the table's whole width for every slot, idle
+    or not) and compute on the copy; the absorbed weighted sum then runs
+    over the whole row and drops the tail of the small result, so the
+    window is never sliced (a slice of it is a copy of it).  On the TPU a
+    decode step's absorbed form is the Pallas kernel
+    :func:`znicz_tpu.ops.pallas.latent_attention.latent_decode_attention`:
+    the pool is read in place, block by block through the table, as far
+    as each row's length; no window exists
+    (:func:`paged_latent_rows_read` counts both).
 
     Products take the pool's dtype (bfloat16 in serving) and accumulate
     in float32.
@@ -190,34 +206,55 @@ def paged_latent_attention(
         return _paged_latent_attention(
             q_nope, q_rope, pool, block_table, q_pos, wk_b, wv_b,
             block_size=block_size, scale=scale, absorbed=absorbed,
+            lengths=lengths,
         )
+
+
+def _reads_pool_in_place(tq: int) -> bool:
+    """Whether the absorbed form of ``tq`` queries a row is the kernel
+    that reads the pool in place."""
+    return tq == 1 and backend.on_tpu()
+
+
+def paged_latent_rows_read(
+    block_table: jnp.ndarray,  # [B, M]
+    lengths: jnp.ndarray,  # [B] int32
+    *,
+    block_size: int,
+) -> jnp.ndarray:
+    """Cached rows ONE layer's absorbed attention reads in a decode step
+    (int32 scalar), by the form that runs here: in place, each row's
+    length rounded up to whole blocks; gathered, every slot's window."""
+    if _reads_pool_in_place(1):
+        return jnp.sum(-(-lengths // block_size) * block_size, dtype=jnp.int32)
+    return jnp.int32(block_table.size * block_size)
+
+
+def _window_softmax(s, q_pos, scale, dtype):
+    """Stable softmax of scores [B, Tq, H, keys] over the keys at or
+    before each query's position, rounded to ``dtype``."""
+    keys = jnp.arange(s.shape[-1])[None, None, None, :]
+    valid = keys <= q_pos[:, :, None, None]
+    s = jnp.where(valid, s * scale, -jnp.inf)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    return (p / jnp.sum(p, axis=-1, keepdims=True)).astype(dtype)
 
 
 def _paged_latent_attention(
     q_nope, q_rope, pool, block_table, q_pos, wk_b, wv_b, *, block_size,
-    scale, absorbed,
+    scale, absorbed, lengths,
 ):
     b, tq, h, d_nope = q_nope.shape
     d_latent, d_rope = wk_b.shape[0], q_rope.shape[-1]
     n_keys = block_table.shape[1] * block_size
     dtype, width = pool.dtype, pool.shape[-1]
-    window = pool[block_table].reshape(b, n_keys, width)
     f32 = dict(preferred_element_type=jnp.float32)
-    if absorbed:
-        q_abs = jnp.einsum(
-            "bthn,chn->bthc", q_nope.astype(dtype),
-            wk_b.reshape(d_latent, h, d_nope), **f32,
+    if lengths is not None and tq != 1:
+        raise ValueError(
+            f"lengths are a decode step's; got {tq} queries a row"
         )
-        q_row = jnp.concatenate(
-            [
-                q_abs.astype(dtype), q_rope.astype(dtype),
-                jnp.zeros((b, tq, h, width - d_latent - d_rope), dtype),
-            ],
-            axis=-1,
-        ).reshape(b, tq * h, width)
-        s = jnp.einsum("bre,bke->brk", q_row, window, **f32)
-        s = s.reshape(b, tq, h, n_keys)
-    else:
+    if not absorbed:
+        window = pool[block_table].reshape(b, n_keys, width)
         latent = window[..., :d_latent]
         k_rope = window[..., d_latent:d_latent + d_rope]
         k_nope = jnp.dot(latent, wk_b, **f32).astype(dtype)
@@ -226,23 +263,44 @@ def _paged_latent_attention(
             "bthn,bkhn->bthk", q_nope.astype(dtype),
             k_nope.reshape(b, n_keys, h, d_nope), **f32,
         ) + jnp.einsum("bthr,bkr->bthk", q_rope.astype(dtype), k_rope, **f32)
-    valid = jnp.arange(n_keys)[None, None, None, :] <= q_pos[:, :, None, None]
-    s = jnp.where(valid, s * scale, -jnp.inf)
-    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-    p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(dtype)
-    if absorbed:
-        o_latent = jnp.einsum(
-            "brk,bke->bre", p.reshape(b, tq * h, n_keys), window, **f32
-        )[..., :d_latent].reshape(b, tq, h, d_latent)
-        o = jnp.einsum(
-            "bthc,chv->bthv", o_latent.astype(dtype),
-            wv_b.reshape(d_latent, h, -1), **f32,
-        )
-    else:
+        p = _window_softmax(s, q_pos, scale, dtype)
         o = jnp.einsum(
             "bthk,bkhv->bthv", p, v.reshape(b, n_keys, h, -1), **f32
         )
-    return o.reshape(b, tq, -1)
+        return o.reshape(b, tq, -1)
+    q_abs = jnp.einsum(
+        "bthn,chn->bthc", q_nope.astype(dtype),
+        wk_b.reshape(d_latent, h, d_nope), **f32,
+    )
+    q_row = jnp.concatenate(
+        [
+            q_abs.astype(dtype), q_rope.astype(dtype),
+            jnp.zeros((b, tq, h, width - d_latent - d_rope), dtype),
+        ],
+        axis=-1,
+    ).reshape(b, tq * h, width)
+    if _reads_pool_in_place(tq):
+        # the kernel's result is a whole number of 128-lane tiles wide
+        o_latent = latent_decode_attention(
+            q_row, pool, block_table,
+            q_pos[:, 0] + 1 if lengths is None else lengths,
+            scale=scale, d_out=min(-(-d_latent // 128) * 128, width),
+        )
+    else:
+        window = pool[block_table].reshape(b, n_keys, width)
+        s = jnp.einsum("bre,bke->brk", q_row, window, **f32)
+        p = _window_softmax(s.reshape(b, tq, h, n_keys), q_pos, scale, dtype)
+        o_latent = jnp.einsum(
+            "brk,bke->bre", p.reshape(b, tq * h, n_keys), window, **f32
+        )
+    o = jnp.einsum(
+        "bthc,chv->bthv",
+        o_latent[..., :d_latent].reshape(b, tq, h, d_latent).astype(dtype),
+        wv_b.reshape(d_latent, h, -1), **f32,
+    ).reshape(b, tq, -1)
+    if lengths is not None:  # an idle row's result is zeros in either form
+        o = jnp.where(lengths[:, None, None] > 0, o, 0.0)
+    return o
 
 
 def init_mha_params(

@@ -1525,9 +1525,10 @@ class PagedDecodeEngine(DecodeEngine):
             "accepted draft tokens per row per verify step",
             buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0),
         )
-        # what the decode and verify programs gather, which is what
-        # their device time scales with: every slot's window of K/V
-        # positions per step, active or not
+        # what the decode and verify programs read of the cache, which
+        # is what their device time scales with: for the K/V tower every
+        # slot's window of positions per step, active or not; for a tower
+        # that reads its pool in place, the live rows' whole blocks
         self._m_decode_steps = observability.counter(
             "znicz_serve_decode_steps_total",
             "token steps run by the paged decode and verify programs "
@@ -1535,8 +1536,10 @@ class PagedDecodeEngine(DecodeEngine):
         )
         self._m_decode_gathered = observability.counter(
             "znicz_serve_decode_gathered_tokens_total",
-            "K/V positions gathered by the paged decode and verify "
-            "programs: steps x slots x window blocks x block size",
+            "cached positions a layer of the paged decode and verify "
+            "programs read: steps x slots x window blocks x block size "
+            "where the window is gathered; the decoding rows' lengths in "
+            "whole blocks, step by step, where the pool is read in place",
         )
         self._m_decode_chunks = observability.counter(
             "znicz_serve_decode_chunks_total",
@@ -1581,7 +1584,7 @@ class PagedDecodeEngine(DecodeEngine):
         """Fold the expert-load sums of some calls (finished by now:
         ONE fetch for all of them) into the registry; each covers
         ``calls`` token steps or chunks."""
-        loads = [load for load in loads if load is not None]
+        loads = [load for load in loads if load and "pairs" in load]
         if not loads or not calls:
             return
         fetched = jax.device_get(loads)
@@ -2181,10 +2184,14 @@ class PagedDecodeEngine(DecodeEngine):
             window *= 2
         return min(window, self.blocks_per_row)
 
-    def _count_gathered(self, steps: int, window: int) -> None:
+    def _count_gathered(self, steps: int, window: int, read=None) -> None:
+        """``read``: the cached rows the chunk's tower says a layer of it
+        read (``cached_rows`` of its load sums), where it says so; the
+        K/V tower's gather reads every slot's window, active or not."""
         self._m_decode_steps.inc(steps)
         self._m_decode_gathered.inc(
             steps * self.batch_size * window * self.block_size
+            if read is None else int(read)
         )
         self._m_decode_chunks.labels(window=window).inc()
 
@@ -2405,13 +2412,16 @@ class PagedDecodeEngine(DecodeEngine):
             out = np.asarray(out)
             steps = int(steps)
             self._drain_load_backlog()
+            load = jax.device_get(load)
             self._count_expert_load("decode", load, steps)
             self._tok = np.array(tok)
             self._pos = np.array(pos)
             self._done = np.array(done)
             self._remaining = np.array(remaining)
         dt = time.perf_counter() - t0
-        self._count_gathered(steps, window)
+        self._count_gathered(
+            steps, window, load[0].get("cached_rows") if load else None
+        )
         for r in residents:
             r.timings.decode_s += dt
         for slot, st in enumerate(self._slots):

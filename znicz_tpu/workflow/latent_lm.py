@@ -39,7 +39,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from znicz_tpu.ops import moe as moe_op
-from znicz_tpu.ops.attention import paged_latent_attention
+from znicz_tpu.ops.attention import (
+    paged_latent_attention,
+    paged_latent_rows_read,
+)
 from znicz_tpu.ops.normalization import rms_norm
 from znicz_tpu.ops.rope import (
     apply_rotary,
@@ -163,7 +166,7 @@ class LatentMoEModel:
 
     def _block_step(
         self, block, x, pool, write, tables, q_pos, row_mask, *, block_size,
-        absorbed,
+        absorbed, lengths,
     ):
         """One block over ``x`` [B, Tq, D] float32: ``write`` scatters the
         new latent rows into the pool, attention gathers through the
@@ -193,7 +196,7 @@ class LatentMoEModel:
         o = paged_latent_attention(
             q_nope, q_rope, kv_pool, tables, q_pos, block["wk_b"],
             block["wv_b"], block_size=block_size, scale=self.softmax_scale,
-            absorbed=absorbed,
+            absorbed=absorbed, lengths=lengths,
         )
         x = x + _dot(o, block["wo"])
         h = rms_norm(x, block["ffn_norm"], eps=eps).reshape(b * tq, d)
@@ -219,12 +222,12 @@ class LatentMoEModel:
         return x + y.reshape(b, tq, d), {"kv": kv_pool}, pairs
 
     def _tower(self, params, x, pools, write, tables, q_pos, row_mask, *,
-               block_size, absorbed):
+               block_size, absorbed, lengths=None):
         new_pools, load = [], []
         for block, pool in zip(params[1:-1], pools):
             x, pool, pairs = self._block_step(
                 block, x, pool, write, tables, q_pos, row_mask,
-                block_size=block_size, absorbed=absorbed,
+                block_size=block_size, absorbed=absorbed, lengths=lengths,
             )
             new_pools.append(pool)
             if pairs is not None:
@@ -275,11 +278,16 @@ class LatentMoEModel:
         """One incremental step by the ABSORBED attention form: ``token``
         [B] at per-row positions ``pos`` [B] -> ``(pools, logits [B,
         vocab], load)``.  Rows with ``write_mask`` False (done, idle)
-        write to ``NULL_BLOCK`` and are routed to no expert."""
+        write to ``NULL_BLOCK``, attend nothing and are routed to no
+        expert.  ``load`` also holds ``cached_rows``: the cached rows a
+        layer's attention read in this step, as the form that ran counts
+        them (:func:`~znicz_tpu.ops.attention.paged_latent_rows_read`)."""
         rows = jnp.arange(token.shape[0])
         blk = tables[rows, pos // block_size]
+        lengths = pos + 1
         if write_mask is not None:
             blk = jnp.where(write_mask, blk, NULL_BLOCK)
+            lengths = jnp.where(write_mask, lengths, 0)
         slot = pos % block_size
         x = params[0]["embed"][token[:, None]].astype(jnp.float32)
 
@@ -289,7 +297,13 @@ class LatentMoEModel:
         x, pools, load = self._tower(
             params, x, pools, write, tables, pos[:, None],
             None if write_mask is None else write_mask[:, None],
-            block_size=block_size, absorbed=True,
+            block_size=block_size, absorbed=True, lengths=lengths,
+        )
+        load = dict(
+            load or {},
+            cached_rows=paged_latent_rows_read(
+                tables, lengths, block_size=block_size
+            ),
         )
         return pools, self._logits(params, x[:, 0]), load
 
