@@ -814,11 +814,10 @@ LM_B = 8
 # not framework-bound
 LM_MID = dict(vocab=8192, d_model=512, n_layers=12, n_heads=8)
 LM_MID_B = 16
-LM_SERVE_LENS = (16, 40, 64, 120)  # buckets 16 / 64 / 64 / 128
+LM_SERVE_LENS = (16, 40, 64, 120)  # padded to 32 / 64 / 64 / 128
 LM_SERVE_NEW = 64
 # block 32: at the mid config the fatter prefill chunk/window halves
-# host dispatches for the same pool memory (32-multiple padding on this
-# stream matches the dense bucket ladder's anyway)
+# host dispatches for the same pool memory
 LM_SERVE_PAGED_BLOCK = 32
 # shared-system-prompt stream for the prefix-cache bench: 160 tokens =
 # 5 full blocks of 32, cached once and mapped by every later request
@@ -1141,90 +1140,16 @@ def _lm_serve_params():
     )
 
 
-@_section("lm_serve")
-def _sec_lm_serve(ctx):
-    # decode SERVING (ISSUE 2): continuous batching over a mixed-
-    # prompt-length request stream.  The engine coalesces ragged prompts
-    # into a fixed-slot batch over static KV buffers: admit programs
-    # compile once per prompt-length bucket, the chunked per-row decode
-    # program compiles ONCE, and rows retire/admit independently — so
-    # the whole stream runs recompile-free (lm_serve_compiles is the
-    # total distinct-program count, reported to catch regressions).
-    import numpy as np
-
-    from znicz_tpu.services.engine import DecodeEngine
-
-    cfg, b = LM_MID, LM_MID_B
-    try:
-        params = _lm_serve_params()
-        reqs = np.random.default_rng(12)
-
-        def make_engine():
-            return DecodeEngine(
-                params, n_heads=cfg["n_heads"], eos_id=0, batch_size=b,
-                admit_every=8, max_seq=256,
-            )
-
-        def stream(eng, n):
-            for j in range(n):
-                length = LM_SERVE_LENS[j % len(LM_SERVE_LENS)]
-                eng.submit(
-                    reqs.integers(1, cfg["vocab"], (length,)).astype(
-                        np.int32
-                    ),
-                    max_new_tokens=LM_SERVE_NEW,
-                )
-            return eng.run()
-
-        stream(make_engine(), len(LM_SERVE_LENS))  # compile every bucket
-        eng = make_engine()  # fresh engine rides the warm jit cache
-        t0 = time.time()
-        comps = stream(eng, 4 * b)
-        wall = time.time() - t0
-        toks = sum(c.n_new for c in comps)
-        rate, st = toks / wall, eng.stats()
-        ctx["lm_serve_tokens_per_sec"] = rate
-    finally:
-        _lm_cleanup()
-    print(
-        f"LM serving (continuous batching, mixed prompts "
-        f"{LM_SERVE_LENS}): {rate:.0f} tok/s, "
-        f"{st.get('n_programs', 0)} compiled programs, "
-        f"latency {st.get('latency', {})}",
-        file=sys.stderr,
-    )
-    return [
-        {
-            "metric": "lm_serve_tokens_per_sec",
-            "value": round(rate, 1),
-            "unit": "tokens/sec",
-            "lm_serve_config": (
-                f"mid config engine: B={b} slots, mixed "
-                f"prompts {LM_SERVE_LENS}, budget {LM_SERVE_NEW}, "
-                "admit_every 8, eos 0, greedy"
-            ),
-            "lm_serve_compiles": st.get("n_programs", 0),
-            "lm_serve_requests": st.get("completed", 0),
-            "lm_serve_latency_ms": {
-                k: round(v, 1)
-                for k, v in st.get("latency", {}).items()
-            },
-        }
-    ]
-
-
 @_section("lm_serve_paged")
 def _sec_lm_serve_paged(ctx):
-    # PAGED serving (ISSUE 4): the same mixed stream through the
-    # block-pool engine, pool sized to the dense engine's EXACT KV
-    # footprint (B slots x t_max tokens) so tokens/s is an apples-to-
-    # apples layout comparison, plus a max-sustained-concurrency probe:
-    # 2x the slots against that same pool with short requests — the
-    # dense layout caps at B rows in this memory; the paged pool packs
-    # them by blocks actually used (peak_active is the measured answer,
-    # preemptions how often pressure forced an eviction).  Prefix cache
-    # OFF here: the stream shares no prefixes, and the layout comparison
-    # must not pay (or gain) anything cache-related.
+    # PAGED serving (ISSUE 4): a mixed-prompt-length stream through the
+    # block-pool engine, pool sized to a full t_max window a slot (B
+    # slots x t_max tokens), plus a max-sustained-concurrency probe:
+    # 2x the slots against that same pool with short requests — a
+    # [B, t_max] reservation would cap at B rows in this memory; the
+    # paged pool packs them by blocks actually used (peak_active is the
+    # measured answer, preemptions how often pressure forced an
+    # eviction).  Prefix cache OFF here: the stream shares no prefixes.
     import numpy as np
 
     from znicz_tpu.services.engine import PagedDecodeEngine
@@ -1234,7 +1159,7 @@ def _sec_lm_serve_paged(ctx):
         params = _lm_serve_params()
         reqs = np.random.default_rng(12)
         block = LM_SERVE_PAGED_BLOCK
-        n_blocks = b * (256 // block) + 1  # dense footprint + null block
+        n_blocks = b * (256 // block) + 1  # a full window a slot + null block
 
         def make_engine(slots):
             return PagedDecodeEngine(
@@ -1279,10 +1204,9 @@ def _sec_lm_serve_paged(ctx):
         f"({st.get('n_programs', 0)} programs, "
         f"{st.get('preemptions', 0)} preemptions); "
         f"concurrency probe peak {probe_st.get('peak_active', 0)} "
-        f"rows (dense layout caps at {b} in the same memory)",
+        f"rows (a [B, t_max] reservation caps at {b} in the same memory)",
         file=sys.stderr,
     )
-    dense_rate = ctx.get("lm_serve_tokens_per_sec", 0.0)
     return [
         {
             "metric": "lm_serve_paged_tokens_per_sec",
@@ -1290,13 +1214,10 @@ def _sec_lm_serve_paged(ctx):
             "unit": "tokens/sec",
             "lm_serve_paged_config": (
                 f"mid config paged engine: B={b} slots, "
-                f"block {LM_SERVE_PAGED_BLOCK}, pool == dense "
-                f"footprint ({b}x256 tokens), mixed prompts "
+                f"block {LM_SERVE_PAGED_BLOCK}, pool == a full "
+                f"window a slot ({b}x256 tokens), mixed prompts "
                 f"{LM_SERVE_LENS}, budget {LM_SERVE_NEW}; probe: "
                 f"2x slots, 16+16-token requests, same pool"
-            ),
-            "lm_serve_paged_vs_dense": round(
-                rate / dense_rate if dense_rate else 0.0, 4
             ),
             "lm_serve_paged_compiles": st.get("n_programs", 0),
             "lm_serve_paged_preemptions": st.get("preemptions", 0),

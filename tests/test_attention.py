@@ -80,19 +80,18 @@ class TestPagedAttention:
         return k, v, k_pool, v_pool, table
 
     @staticmethod
-    def _dense_ref(q, k, v, q_pos, start):
+    def _dense_ref(q, k, v, q_pos):
         """Masked stable softmax per row, numpy — the paged contract."""
         b, tq, h, d = q.shape
         out = np.zeros_like(q)
         for row in range(b):
             for qi in range(tq):
                 p = q_pos[row, qi]
-                lo = min(start[row], p)
                 s = np.einsum(
                     "hd,khd->hk", q[row, qi], k[row]
                 ) / np.sqrt(d)
                 mask = np.zeros(k.shape[1], bool)
-                mask[lo: p + 1] = True
+                mask[: p + 1] = True
                 s = np.where(mask[None, :], s, -np.inf)
                 e = np.exp(s - s.max(axis=-1, keepdims=True))
                 w = e / e.sum(axis=-1, keepdims=True)
@@ -105,33 +104,28 @@ class TestPagedAttention:
         rng = np.random.default_rng(1)
         q = rng.normal(size=(2, 1, 2, 8)).astype(np.float32)
         pos = np.asarray([[13], [29]], np.int32)
-        start = np.asarray([3, 0], np.int32)
         out = attention.paged_attention(
             jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-            start=jnp.asarray(start),
         )
-        ref = self._dense_ref(q, k, v, pos, start)
+        ref = self._dense_ref(q, k, v, pos)
         np.testing.assert_allclose(
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
         )
 
     def test_prefill_chunk_queries_match(self, stored):
         # a whole chunk of queries at consecutive positions (the
-        # chunked-prefill shape), pad-region queries included: their
-        # window collapses to the self position and stays finite
+        # chunked-prefill shape)
         bs = 8
         k, v, k_pool, v_pool, table = self._paged_setup(bs=bs)
         rng = np.random.default_rng(2)
         q = rng.normal(size=(2, bs, 2, 8)).astype(np.float32)
         q_pos = np.broadcast_to(np.arange(bs), (2, bs)).astype(np.int32)
-        start = np.asarray([5, 0], np.int32)  # row 0: pad queries 0..4
         out = attention.paged_attention(
             jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(q_pos), block_size=bs,
-            start=jnp.asarray(start),
         )
-        ref = self._dense_ref(q, k, v, q_pos, start)
+        ref = self._dense_ref(q, k, v, q_pos)
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
@@ -146,11 +140,9 @@ class TestPagedAttention:
         rng = np.random.default_rng(3)
         q = rng.normal(size=(2, 1, 2, 8)).astype(np.float32)
         pos = np.asarray([[10], [3]], np.int32)
-        start = np.asarray([2, 0], np.int32)
         clean = attention.paged_attention(
             jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-            start=jnp.asarray(start),
         )
         kp, vp = k_pool.copy(), v_pool.copy()
         for row in range(2):
@@ -166,7 +158,6 @@ class TestPagedAttention:
         poisoned = attention.paged_attention(
             jnp.asarray(q), stored(kp), stored(vp),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-            start=jnp.asarray(start),
         )
         np.testing.assert_allclose(
             np.asarray(clean), np.asarray(poisoned), rtol=1e-6
@@ -207,13 +198,11 @@ class TestPagedAttention:
         # rows at DIFFERENT depths through the same shared block: row 0
         # still inside it, rows 1/2 past it
         pos = np.asarray([[5], [13], [21]], np.int32)
-        start = np.zeros((b,), np.int32)
         out = attention.paged_attention(
             jnp.asarray(q), stored(k_pool), stored(v_pool),
             jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-            start=jnp.asarray(start),
         )
-        ref = self._dense_ref(q, k, v, pos, start)
+        ref = self._dense_ref(q, k, v, pos)
         np.testing.assert_allclose(
             np.asarray(out), ref, rtol=2e-5, atol=2e-6
         )

@@ -97,7 +97,7 @@ class TestSectionIsolation:
         names = [name for name, _ in bench._SECTIONS]
         assert names == sorted(set(names), key=names.index)  # unique
         for expected in (
-            "alexnet_step", "lm_train", "lm_serve", "lm_serve_paged",
+            "alexnet_step", "lm_train", "lm_serve_paged",
             "lm_serve_prefix", "lm_serve_frontdoor",
         ):
             assert expected in names

@@ -1,0 +1,103 @@
+"""What ``benchmarks/`` reads from the serving program, held in tier-1.
+
+``benchmarks/tests/`` is not part of tier-1, so a refactor of the engine
+would otherwise learn of these pins on the chip: device traces are reduced
+by the programs' names (``jit__paged_decode_chunk`` /
+``jit__paged_prefill_prog``), ``benchmarks/tests/test_broken_path.py`` and
+``test_axk1_cell.py`` wrap ``engine._paged_decode_chunk`` and unpack its 7
+(classic tower) or 8 (a ``model=`` tower) values, and the drivers and
+``chip_smoke.py`` build ``PagedDecodeEngine`` and ``ServingFrontDoor`` by
+keyword."""
+
+import ast
+import inspect
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from test_latent_lm import Toy
+from znicz_tpu.core import prng
+from znicz_tpu.services import engine
+from znicz_tpu.services.frontdoor import ServingFrontDoor
+from znicz_tpu.workflow.transformer import init_lm_params
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BUILDERS = (
+    "benchmarks/drivers/serve_open_loop.py",
+    "benchmarks/drivers/serve_latent_moe.py",
+    "chip_smoke.py",
+)
+
+
+def _classic_engine():
+    prng.seed_all(27)
+    params = init_lm_params(17, 32, 2, 4, max_seq=64)
+    return engine.PagedDecodeEngine(
+        params, n_heads=4, eos_id=14, batch_size=2, block_size=8
+    )
+
+
+def _calls_of_one_served_request(eng, monkeypatch):
+    """Serve one request; returns {program name: (argument shapes, keyword
+    arguments, what it returned)} of the last call of each program."""
+    seen = {}
+    for name in ("_paged_decode_chunk", "_paged_prefill_prog"):
+        real = getattr(engine, name)
+
+        def spy(*args, _real=real, _name=name, **kwargs):
+            shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args
+            )
+            out = _real(*args, **kwargs)
+            seen[_name] = (shapes, kwargs, out)
+            return out
+
+        spy._cache_size = real._cache_size
+        monkeypatch.setattr(engine, name, spy)
+    eng.submit(np.arange(1, 12, dtype=np.int32), 6)
+    eng.run()
+    return seen
+
+
+def test_the_traced_programs_keep_their_names(monkeypatch):
+    real = {
+        name: getattr(engine, name)
+        for name in ("_paged_decode_chunk", "_paged_prefill_prog")
+    }
+    seen = _calls_of_one_served_request(_classic_engine(), monkeypatch)
+    for name, fn in real.items():
+        shapes, kwargs, _ = seen[name]
+        assert f"@jit_{name}" in fn.lower(*shapes, **kwargs).as_text()
+
+
+@pytest.mark.parametrize("tower, values", [("classic", 7), ("latent", 8)])
+def test_the_decode_chunk_returns_what_the_benchmark_unpacks(
+    tower, values, monkeypatch
+):
+    assert callable(engine._paged_decode_chunk._cache_size)
+    eng = _classic_engine() if tower == "classic" else Toy().engine()
+    seen = _calls_of_one_served_request(eng, monkeypatch)
+    assert len(seen["_paged_decode_chunk"][2]) == values
+    assert "paged_chunk_jit_entries" in eng.compile_stats()
+
+
+def test_the_builders_keywords_are_accepted():
+    accepts = {
+        "PagedDecodeEngine": inspect.signature(engine.PagedDecodeEngine),
+        "ServingFrontDoor": inspect.signature(ServingFrontDoor),
+    }
+    for path in BUILDERS:
+        with open(os.path.join(REPO, path)) as f:
+            tree = ast.parse(f.read())
+        built = [
+            node for node in ast.walk(tree)
+            if isinstance(node, ast.Call)
+            and getattr(node.func, "id", None) in accepts
+        ]
+        assert {node.func.id for node in built} == set(accepts), path
+        for node in built:
+            accepts[node.func.id].bind(
+                *node.args, **{kw.arg: None for kw in node.keywords}
+            )

@@ -36,7 +36,7 @@ from znicz_tpu.core import prng
 from znicz_tpu.observability.aggregate import MetricsAggregator
 from znicz_tpu.services import PagedDecodeEngine, ServingFrontDoor
 from znicz_tpu.services import serve as serve_mod
-from znicz_tpu.services.engine import DecodeEngine, prefix_block_keys
+from znicz_tpu.services.engine import prefix_block_keys
 from znicz_tpu.utils import faults
 from znicz_tpu.workflow import generate as G
 from znicz_tpu.workflow.transformer import init_lm_params
@@ -294,15 +294,20 @@ class TestPrefixProbe:
         other[2] = (other[2] + 1) % 17
         assert eng.prefix_probe(other)["cached_blocks"] == 0
 
-    def test_dense_probe_is_empty(self, params):
-        eng = DecodeEngine(
-            params, n_heads=HEADS, eos_id=EOS, batch_size=2,
-            max_seq=T_MAX,
+    def test_probe_without_a_prefix_cache_finds_nothing(self, params):
+        # the keys are a pure function of the prompt; with sharing off
+        # nothing is ever resident, served or not
+        eng = PagedDecodeEngine(
+            params, **_engine_kwargs(), prefix_cache=False
         )
-        probe = eng.prefix_probe(np.arange(12, dtype=np.int32))
+        prompt = np.arange(12, dtype=np.int32)
+        eng.submit(prompt, 4)
+        eng.run()
+        probe = eng.prefix_probe(prompt)
         assert probe == {
-            "prefix_cache": False, "block_size": None,
-            "block_keys": [], "cached_blocks": 0, "cached_tokens": 0,
+            "prefix_cache": False, "block_size": BS,
+            "block_keys": prefix_block_keys(prompt, BS),
+            "cached_blocks": 0, "cached_tokens": 0,
         }
 
     def test_frontdoor_delegates_and_http_endpoint(self, fleet, params):

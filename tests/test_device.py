@@ -24,8 +24,6 @@ from znicz_tpu.core import prng
 from znicz_tpu.observability import device
 from znicz_tpu.services import PagedDecodeEngine, ServingFrontDoor
 from znicz_tpu.services import serve as serve_mod
-from znicz_tpu.services.engine import DecodeEngine
-from znicz_tpu.workflow import generate as G
 from znicz_tpu.workflow.transformer import init_lm_params
 
 EOS = 11
@@ -88,42 +86,6 @@ class TestProgramLedger:
         assert device.program_count() == ledger0
         assert _compiles_total() == counter0
 
-    def test_dense_engine_records_admit_and_chunk(self, params):
-        ledger0 = device.program_count(source="engine")
-        eng = DecodeEngine(
-            params, n_heads=HEADS, eos_id=EOS, batch_size=2,
-            max_seq=T_MAX, admit_every=4,
-        )
-        gen = np.random.default_rng(11)
-        eng.submit(gen.integers(0, 19, (9,)).astype(np.int32), 8)
-        eng.run()
-        delta = device.program_count(source="engine") - ledger0
-        assert delta >= 2
-        fresh = device.programs(source="engine")[-delta:]
-        kinds = {entry["kind"] for entry in fresh}
-        assert {"admit", "chunk"} <= kinds
-
-    def test_serve_cache_compile_records_cost_and_memory(self, params):
-        before = device.program_count(source="serve_cache")
-        gen = np.random.default_rng(13)
-        prompt = gen.integers(0, 19, (1, 7)).astype(np.int32)
-        G.generate_serve(
-            params, prompt, n_heads=HEADS, max_new_tokens=5, eos_id=EOS
-        )
-        progs = device.programs(source="serve_cache")
-        assert len(progs) == before + 1
-        entry = progs[-1]
-        assert entry["compile_s"] > 0.0
-        assert entry["flops"] and entry["flops"] > 0
-        # the AOT path has the Compiled in hand: memory analysis too
-        assert entry["memory"] is not None
-        assert entry["memory"]["argument_size_in_bytes"] > 0
-        # a second identical call is a cache hit: no new entry
-        G.generate_serve(
-            params, prompt, n_heads=HEADS, max_new_tokens=5, eos_id=EOS
-        )
-        assert device.program_count(source="serve_cache") == before + 1
-
     def test_ledger_snapshot_shape(self):
         snap = device.ledger_snapshot()
         assert snap["count"] == len(snap["programs"])
@@ -141,7 +103,6 @@ class TestGracefulHelpers:
 
         assert device.stage_cost(Boom()) is None
         assert device.stage_cost(object()) is None
-        assert device.compiled_memory(object()) is None
         assert device.lowered_cost(lambda x: x, (1,), {}) is None
 
     def test_stage_cost_normalizes_list_and_dict(self):

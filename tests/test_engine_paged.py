@@ -1,7 +1,7 @@
 """Paged KV-cache engine: goldens, block pool, preemption, compiles.
 
-The paged engine (`services.engine.PagedDecodeEngine`) must be a
-TRANSPARENT batching layer exactly like the dense one: every
+The engine (`services.engine.PagedDecodeEngine`) must be a
+TRANSPARENT batching layer: every
 completion's tokens equal the single-request ``generate()`` output for
 that prompt (up to EOS), through chunked prefill, lazy block
 allocation, block reuse after retirement, and preemption-with-
@@ -22,7 +22,7 @@ import pytest
 
 from znicz_tpu import observability as obs
 from znicz_tpu.core import prng
-from znicz_tpu.services.engine import DecodeEngine, PagedDecodeEngine
+from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.workflow import generate as G
 from znicz_tpu.workflow.transformer import init_lm_params
 
@@ -86,21 +86,37 @@ def _hist_count(name):
 
 
 class TestPagedGoldens:
-    def test_mixed_lengths_including_left_padded_rows(self):
-        # 5 ragged requests through 2 slots: lengths 5 and 3 left-pad
-        # inside one block, 12 and 17 span multiple chunks; slot reuse,
-        # chunked prefill and the shared pool must all stay invisible
+    @pytest.mark.parametrize(
+        "seed, lengths, budgets, kw",
+        [
+            # 5 ragged requests through 2 slots: lengths 5 and 3 pad
+            # inside one block, 12 and 17 span multiple chunks
+            (7, (5, 12, 3, 9, 17), (6, 4, 8, 5, 7), {}),
+            # the tier-1 smoke: two mixed-length requests at the
+            # constructor's own block size
+            (3, (5, 12), (6, 5), {"block_size": 16}),
+            # the same five through 16-token blocks, admitting every 3
+            (7, (5, 12, 3, 9, 17), (6, 4, 8, 5, 7),
+             {"block_size": 16, "admit_every": 3}),
+            # three budget-1 requests retire AT admission: the slot
+            # must go on to the next queued request, not sit out a chunk
+            (17, (4, 6, 8, 5, 7), (1, 1, 1, 6, 5), {"block_size": 16}),
+        ],
+    )
+    def test_mixed_lengths_golden(self, seed, lengths, budgets, kw):
+        # ragged requests through 2 slots: slot reuse, chunked prefill
+        # and the shared pool must all stay invisible
         params = _params()
-        gen = np.random.default_rng(7)
+        gen = np.random.default_rng(seed)
         prompts = [
-            gen.integers(0, 17, (n,)).astype(np.int32)
-            for n in (5, 12, 3, 9, 17)
+            gen.integers(0, 17, (n,)).astype(np.int32) for n in lengths
         ]
-        budgets = [6, 4, 8, 5, 7]
-        eng = _engine(params)
+        eng = _engine(params, **kw)
         ids = [eng.submit(p, b) for p, b in zip(prompts, budgets)]
+        assert eng.pending == len(prompts)
         comps = eng.run()
-        assert len(comps) == 5 and eng.pending == 0 and eng.active == 0
+        assert len(comps) == len(prompts)
+        assert eng.pending == 0 and eng.active == 0
         for p, b, rid in zip(prompts, budgets, ids):
             np.testing.assert_array_equal(
                 eng.completions[rid].tokens, _reference(params, p, b)
@@ -112,7 +128,9 @@ class TestPagedGoldens:
         assert st["preemptions"] == 0
         c = comps[0]
         assert c.latency_s > 0 and c.tokens_per_sec > 0
-        assert set(eng.stats()["phases"]) >= {"admit", "decode"}
+        assert eng.latency.summary()["count"] == len(prompts)
+        assert st["generated_tokens"] == sum(c.n_new for c in comps)
+        assert set(st["phases"]) >= {"admit", "decode"}
 
     def test_long_prompt_prefills_in_chunks(self):
         # a 17-token prompt pads to 24 = 3 chunks of the ONE compiled
@@ -131,11 +149,16 @@ class TestPagedGoldens:
         chunks1 = _counter_value("znicz_serve_prefill_chunks_total")
         assert chunks1 - chunks0 == 3
 
-    def test_budget_one_and_immediate_eos_retire_at_admit(self):
+    @pytest.mark.parametrize(
+        "seed, length, block", [(13, 6, BS), (19, 11, 16)]
+    )
+    def test_budget_one_and_immediate_eos_retire_at_admit(
+        self, seed, length, block
+    ):
         params = _params()
-        gen = np.random.default_rng(13)
-        p = gen.integers(0, 17, (6,)).astype(np.int32)
-        eng = _engine(params)
+        gen = np.random.default_rng(seed)
+        p = gen.integers(0, 17, (length,)).astype(np.int32)
+        eng = _engine(params, block_size=block)
         rid = eng.submit(p, 1)
         (comp,) = eng.run()
         assert comp.id == rid and comp.n_new == 1
@@ -145,17 +168,28 @@ class TestPagedGoldens:
         )
         assert eng.stats()["pool_blocks_free"] == eng.usable_blocks
 
-    def test_sampling_mode_deterministic_and_in_vocab(self):
+    @pytest.mark.parametrize(
+        "seed, lengths, kw",
+        [
+            (11, (4, 10, 6), {}),
+            (23, (9, 3, 17, 5), {"block_size": 16, "batch_size": 3}),
+        ],
+    )
+    def test_sampling_mode_deterministic_and_in_vocab(
+        self, seed, lengths, kw
+    ):
+        # same rng + same submission order -> identical streams; tokens
+        # stay in-vocab under temperature sampling
         params = _params()
-        gen = np.random.default_rng(11)
+        gen = np.random.default_rng(seed)
         prompts = [
-            gen.integers(0, 17, (n,)).astype(np.int32) for n in (4, 10, 6)
+            gen.integers(0, 17, (n,)).astype(np.int32) for n in lengths
         ]
 
         def serve():
             eng = _engine(
                 params, admit_every=3, temperature=0.9,
-                rng=jax.random.key(8),
+                rng=jax.random.key(8), **kw,
             )
             ids = [eng.submit(p, 5) for p in prompts]
             eng.run()
@@ -424,11 +458,23 @@ class TestPagedValidation:
         with pytest.raises(ValueError, match="positional window"):
             eng.submit(np.arange(5, dtype=np.int32), 60)  # 8+60 > t_max
 
-    def test_dense_submit_names_the_dense_backend(self):
+    def test_default_pool_runs_out_of_positions_first(self):
+        # the default pool holds a full window a slot, so what a request
+        # can outgrow there is the positional window, and the error
+        # says so
         params = _params()
-        eng = DecodeEngine(params, n_heads=HEADS, eos_id=EOS, batch_size=2)
-        with pytest.raises(ValueError, match="dense KV buffer"):
-            eng.submit(np.arange(5, dtype=np.int32), 60)
+        eng = PagedDecodeEngine(
+            params, n_heads=HEADS, eos_id=EOS, batch_size=2
+        )
+        assert eng.usable_blocks == 2 * (T_MAX // 16)
+        with pytest.raises(ValueError, match="empty"):
+            eng.submit(np.asarray([], np.int32), 4)
+        with pytest.raises(ValueError, match="max_new_tokens"):
+            eng.submit(np.asarray([1, 2], np.int32), 0)
+        with pytest.raises(ValueError, match="positional window"):
+            eng.submit(np.arange(5, dtype=np.int32), 60)  # 16 + 60 > 64
+        with pytest.raises(ValueError, match="eos_id"):
+            PagedDecodeEngine(params, n_heads=HEADS, eos_id=99)
 
     def test_constructor_validation(self):
         params = _params()

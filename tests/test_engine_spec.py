@@ -19,7 +19,7 @@ import pytest
 
 from znicz_tpu import observability as obs
 from znicz_tpu.core import prng
-from znicz_tpu.services.engine import DecodeEngine, PagedDecodeEngine
+from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import SpeculationUnsupportedError
 from znicz_tpu.workflow import generate as G
 from znicz_tpu.workflow.generate import PromptLookupDrafter
@@ -427,29 +427,13 @@ class TestSampledSpec:
 
 
 class TestSpecConfig:
-    def test_dense_engine_rejects_speculation(self):
+    def test_stats_carry_a_disabled_spec_subdict_with_speculation_off(self):
+        # callers read ONE shape whether or not the engine speculates
         params = _params()
-        with pytest.raises(ValueError, match="paged backend"):
-            DecodeEngine(params, n_heads=HEADS, eos_id=EOS, spec_k=2)
-        # typed: the ValueError IS the config-error subclass
-        with pytest.raises(SpeculationUnsupportedError):
-            DecodeEngine(params, n_heads=HEADS, eos_id=EOS, spec_k=2)
-        # a drafter or bucket ladder without spec_k is config noise on
-        # the dense backend too — same typed rejection
-        with pytest.raises(SpeculationUnsupportedError):
-            DecodeEngine(
-                params, n_heads=HEADS, eos_id=EOS,
-                drafter=PromptLookupDrafter(),
-            )
-        with pytest.raises(SpeculationUnsupportedError):
-            DecodeEngine(
-                params, n_heads=HEADS, eos_id=EOS, spec_buckets=(2, 4),
-            )
-
-    def test_dense_stats_carry_disabled_spec_subdict(self):
-        params = _params()
-        eng = DecodeEngine(params, n_heads=HEADS, eos_id=EOS)
-        assert eng.stats()["spec"] == {"enabled": False}
+        eng = _engine(params, spec_k=0)
+        sp = eng.stats()["spec"]
+        assert sp["enabled"] is False and sp["k"] == 0
+        assert sp["drafted"] == sp["accepted"] == sp["verify_steps"] == 0
 
     def test_paged_validates_spec_args(self):
         params = _params()
@@ -460,7 +444,6 @@ class TestSpecConfig:
         with pytest.raises(ValueError, match="spec_buckets"):
             _engine(params, spec_buckets=(4, 2))
         # a drafter with speculation OFF is a config trap, not a no-op
-        # (the dense backend raises for the same noise)
         with pytest.raises(ValueError, match="spec_k"):
             _engine(params, spec_k=0, drafter=PromptLookupDrafter())
         eng = _engine(params, spec_k=0)

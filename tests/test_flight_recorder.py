@@ -479,12 +479,12 @@ class TestDoctorCLI:
 class TestTickOccupancy:
     def test_engine_tick_occupancy_fractions(self):
         from znicz_tpu.core import prng
-        from znicz_tpu.services.engine import DecodeEngine
+        from znicz_tpu.services.engine import PagedDecodeEngine
         from znicz_tpu.workflow.transformer import init_lm_params
 
         prng.seed_all(27)
         params = init_lm_params(17, 32, 2, 4, max_seq=64)
-        eng = DecodeEngine(
+        eng = PagedDecodeEngine(
             params, n_heads=4, eos_id=14, batch_size=2, admit_every=4
         )
         # the registry family is process-wide — zero it so earlier
@@ -500,7 +500,7 @@ class TestTickOccupancy:
         assert set(occ["frac"]) == {"prefill", "decode", "spec_verify"}
         assert sum(occ["frac"].values()) <= 1.0 + 1e-6
         assert occ["frac"]["decode"] > 0
-        assert occ["frac"]["spec_verify"] == 0.0  # dense: no spec
+        assert occ["frac"]["spec_verify"] == 0.0  # speculation is off
         # the registry twin exists with fraction-ladder buckets
         hist = get_registry().metrics()["znicz_serve_tick_occupancy"]
         by = {k[0]: c for k, c in hist.children().items()}

@@ -20,7 +20,6 @@ import pytest
 from znicz_tpu import observability as obs
 from znicz_tpu.core import prng
 from znicz_tpu.services import (
-    DecodeEngine,
     EngineClosedError,
     PagedDecodeEngine,
     RejectedError,
@@ -183,21 +182,6 @@ class TestStreaming:
             st = door.stats()
             assert st["submitted"] == 3 and st["completed"] == 3
 
-    def test_dense_backend_works_too(self, params):
-        def factory():
-            return DecodeEngine(
-                params, n_heads=HEADS, eos_id=EOS, batch_size=2,
-                max_seq=T_MAX, admit_every=4,
-            )
-
-        prompts = _prompts(2)
-        with ServingFrontDoor(factory) as door:
-            handles = [door.submit(p, 5) for p in prompts]
-            for h, p in zip(handles, prompts):
-                comp = h.result(timeout=30.0)
-                np.testing.assert_array_equal(
-                    comp.tokens, _reference(params, p, 5)
-                )
 
     def test_handle_result_timeout_raises(self, params):
         with ServingFrontDoor(
@@ -215,7 +199,7 @@ class TestAdmission:
         with ServingFrontDoor(_engine_factory(params)) as door:
             with pytest.raises(ValueError, match="empty prompt"):
                 door.submit([], 4)
-            with pytest.raises(RequestTooLargeError, match="paged"):
+            with pytest.raises(RequestTooLargeError, match="positional window"):
                 door.submit([1, 2, 3], 10_000)
             # malformed prompt/deadline surface as ValueError at the
             # caller — a str deadline must never reach the engine
@@ -806,12 +790,18 @@ class TestSpeculativeFrontDoor:
             )
             assert door.stats()["watchdog_restarts"] == 1
 
-    def test_dense_factory_with_spec_fails_construction(self, params):
+    def test_factory_of_a_tower_without_verify_fails_construction(
+        self, params
+    ):
         from znicz_tpu.services import SpeculationUnsupportedError
 
+        class NoVerifyTower:
+            """A tower of another kind: it brings no verify program."""
+
         def bad_factory():
-            return DecodeEngine(
-                params, n_heads=HEADS, eos_id=EOS, spec_k=4
+            return PagedDecodeEngine(
+                params, n_heads=HEADS, eos_id=EOS, spec_k=4,
+                model=NoVerifyTower(),
             )
 
         with pytest.raises(SpeculationUnsupportedError):

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from znicz_tpu.core import prng
+from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.workflow import generate as G
 from znicz_tpu.workflow.transformer import init_lm_params, lm_apply
 
@@ -302,12 +303,8 @@ class TestSamplingTruncation:
 
 
 class TestServingDecode:
-    """Bucketed / left-padded / EOS serving semantics (docs/SERVING.md).
-
-    The contract: bucketing is INVISIBLE — left-padded decode matches
-    the unpadded reference position-by-position, EOS early-exit matches
-    the full-budget run up to EOS, and request streams inside one bucket
-    never recompile."""
+    """EOS and argument semantics the engine's reference must keep: EOS
+    early-exit matches the full-budget run up to EOS."""
 
     def test_bucket_for_ladder(self):
         assert G.bucket_for(1, (16, 32)) == 16
@@ -318,61 +315,6 @@ class TestServingDecode:
         assert G.bucket_for(200, (16, 32)) == 256
         with pytest.raises(ValueError, match="positive"):
             G.bucket_for(0, (16,))
-
-    def test_pack_prompts_left_pads_ragged(self):
-        toks, start = G.pack_prompts(
-            [np.asarray([1, 2, 3]), np.asarray([4])], 8, pad_id=7
-        )
-        np.testing.assert_array_equal(
-            np.asarray(toks),
-            [[7, 7, 7, 7, 7, 1, 2, 3], [7, 7, 7, 7, 7, 7, 7, 4]],
-        )
-        np.testing.assert_array_equal(np.asarray(start), [5, 7])
-        with pytest.raises(ValueError, match="empty"):
-            G.pack_prompts([np.asarray([], np.int32)], 8, pad_id=0)
-        with pytest.raises(ValueError, match="exceeds bucket"):
-            G.pack_prompts([np.arange(9)], 8, pad_id=0)
-
-    def test_left_padded_decode_matches_unpadded_per_position(self):
-        # golden parity: pad 3 prompts of length 5 into a 16-bucket and
-        # teacher-force the rest — every logit vector must match the
-        # full unpadded forward position-by-position
-        params, tokens, heads, _ = _setup()
-        full = np.asarray(
-            lm_apply(params, jnp.asarray(tokens), n_heads=heads)
-        )
-        bucket = 16
-        padded, start = G.pack_prompts(list(tokens[:, :5]), bucket, pad_id=0)
-        caches = G.init_kv_cache(params, 3, bucket + 7, n_heads=heads)
-        caches, lg = G.prefill(
-            params, padded, caches, n_heads=heads, start=start
-        )
-        np.testing.assert_allclose(
-            np.asarray(lg), full[:, 4], rtol=1e-4, atol=1e-5
-        )
-        for p in range(5, 12):
-            caches, lg = G.decode_step(
-                params, caches, jnp.asarray(tokens[:, p]),
-                bucket + p - 5, n_heads=heads, start=start,
-            )
-            np.testing.assert_allclose(
-                np.asarray(lg), full[:, p], rtol=1e-4, atol=1e-5
-            )
-
-    def test_generate_serve_matches_generate_token_for_token(self):
-        params, tokens, heads, _ = _setup()
-        ref = np.asarray(
-            G.generate(
-                params, jnp.asarray(tokens[:, :5]),
-                n_heads=heads, max_new_tokens=6,
-            )
-        )
-        out = np.asarray(
-            G.generate_serve(
-                params, tokens[:, :5], n_heads=heads, max_new_tokens=6
-            )
-        )
-        np.testing.assert_array_equal(ref, out)
 
     def test_eos_early_exit_matches_full_budget_up_to_eos(self):
         # pick an EOS id the greedy run actually emits; rows must match
@@ -398,102 +340,26 @@ class TestServingDecode:
             np.testing.assert_array_equal(new_out[:k], new_ref[:k])
             assert (new_out[k:] == eos).all()
 
-    def test_serve_eos_matches_generate_eos(self):
-        params, tokens, heads, _ = _setup()
-        prompt = tokens[:, :5]
-        ref = np.asarray(
-            G.generate(
-                params, jnp.asarray(prompt), n_heads=heads,
-                max_new_tokens=7,
-            )
-        )
-        eos = int(ref[1, 5 + 1])
-        a = np.asarray(
-            G.generate(
-                params, jnp.asarray(prompt), n_heads=heads,
-                max_new_tokens=7, eos_id=eos,
-            )
-        )
-        b = np.asarray(
-            G.generate_serve(
-                params, prompt, n_heads=heads, max_new_tokens=7,
-                eos_id=eos,
-            )
-        )
-        np.testing.assert_array_equal(a, b)
-
-    def test_second_request_same_bucket_zero_recompiles(self):
-        # the serving acceptance criterion: a second request with a
-        # DIFFERENT prompt length in the same bucket (and a different
-        # budget on the same rung) reuses the compiled executable
-        params, tokens, heads, _ = _setup()
-        G.reset_serve_cache()
-        G.generate_serve(params, tokens[:, :5], n_heads=heads,
-                         max_new_tokens=6)
-        st0 = G.serve_cache_stats()
-        assert st0["programs"] == 1 and st0["hits"] == 0
-        out = np.asarray(
-            G.generate_serve(params, tokens[:, :9], n_heads=heads,
-                             max_new_tokens=3)
-        )
-        st1 = G.serve_cache_stats()
-        assert st1["programs"] == 1  # same (bucket, structure): no compile
-        assert st1["hits"] == 1 and st1["requests"] == 2
-        ref = np.asarray(
-            G.generate(params, jnp.asarray(tokens[:, :9]),
-                       n_heads=heads, max_new_tokens=3)
-        )
-        np.testing.assert_array_equal(ref, out)
-        # a different sampling STRUCTURE is a different program
-        G.generate_serve(
-            params, tokens[:, :5], n_heads=heads, max_new_tokens=6,
-            temperature=0.8, rng=jax.random.key(1),
-        )
-        assert G.serve_cache_stats()["programs"] == 2
-
-    def test_serve_sampling_reproducible(self):
-        params, tokens, heads, vocab = _setup()
-        kw = dict(n_heads=heads, max_new_tokens=5, temperature=0.9)
-        a = np.asarray(
-            G.generate_serve(params, tokens[:, :5],
-                             rng=jax.random.key(4), **kw)
-        )
-        b = np.asarray(
-            G.generate_serve(params, tokens[:, :5],
-                             rng=jax.random.key(4), **kw)
-        )
-        np.testing.assert_array_equal(a, b)
-        assert (a[:, 5:] >= 0).all() and (a[:, 5:] < vocab).all()
-
     def test_zero_budget_rejected_with_clear_error(self):
         params, tokens, heads, _ = _setup()
-        for fn in (G.generate, G.generate_serve):
-            with pytest.raises(ValueError, match="max_new_tokens"):
-                fn(params, tokens[:, :4], n_heads=heads, max_new_tokens=0)
+        with pytest.raises(ValueError, match="max_new_tokens"):
+            G.generate(
+                params, tokens[:, :4], n_heads=heads, max_new_tokens=0
+            )
 
-    def test_serve_capacity_clamps_then_falls_back_exact(self):
-        # rounding a budget up a rung must never reject a request the
-        # positional table can serve: the rung clamps into the table,
-        # and if that underruns the request, shapes go exact
-        params, tokens, heads, _ = _setup(t_max=24)
-        ref = np.asarray(
-            G.generate(params, jnp.asarray(tokens[:, :5]),
-                       n_heads=heads, max_new_tokens=8)
-        )
-        out = np.asarray(
-            G.generate_serve(params, tokens[:, :5], n_heads=heads,
-                             max_new_tokens=8)  # 16 + 16 > 24: clamps
-        )
-        np.testing.assert_array_equal(ref, out)
-        ref9 = np.asarray(
-            G.generate(params, jnp.asarray(tokens[:, :5]),
-                       n_heads=heads, max_new_tokens=9)
-        )
-        out9 = np.asarray(
-            G.generate_serve(params, tokens[:, :5], n_heads=heads,
-                             max_new_tokens=9)  # clamp underruns: exact
-        )
-        np.testing.assert_array_equal(ref9, out9)
-        with pytest.raises(ValueError, match="positional table"):
-            G.generate_serve(params, tokens[:, :5], n_heads=heads,
-                             max_new_tokens=25)
+    @pytest.mark.parametrize("through", ["generate", "engine"])
+    def test_negative_temperature_rejected(self, through):
+        # a negative temperature would sample the INVERTED distribution
+        params, tokens, heads, _ = _setup()
+        with pytest.raises(ValueError, match="temperature >= 0"):
+            if through == "generate":
+                G.generate(
+                    params, tokens[:, :4], n_heads=heads,
+                    max_new_tokens=2, temperature=-1.0,
+                    rng=jax.random.key(0),
+                )
+            else:
+                PagedDecodeEngine(
+                    params, n_heads=heads, eos_id=0, temperature=-1.0,
+                    rng=jax.random.key(0),
+                )

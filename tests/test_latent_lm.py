@@ -20,7 +20,7 @@ from znicz_tpu import observability
 from znicz_tpu.ops import moe, rope
 from znicz_tpu.ops.attention import paged_latent_attention
 from znicz_tpu.ops.normalization import rms_norm
-from znicz_tpu.services.engine import DecodeEngine, PagedDecodeEngine
+from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import SpeculationUnsupportedError
 from znicz_tpu.workflow import latent_lm
 from znicz_tpu.workflow.generate import copy_paged_block
@@ -524,8 +524,6 @@ def test_the_gathered_counter_counts_what_the_attention_form_read(
 def test_what_the_tower_is_not_served_with_is_refused_by_name(toy):
     with pytest.raises(SpeculationUnsupportedError, match="LatentMoEModel"):
         toy.engine(spec_k=2)
-    with pytest.raises(ValueError, match="paged backend"):
-        DecodeEngine(toy.params, n_heads=4, eos_id=0, model=toy.model)
     with pytest.raises(ValueError, match="topk_method"):
         latent_lm.LatentMoEModel.from_config(
             dict(toy.cfg, topk_method="noaux_tc"), first_expert=0, max_positions=64

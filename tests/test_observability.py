@@ -3,7 +3,7 @@
 Covers the ISSUE 3 acceptance criteria end to end: registry unit
 semantics (labels, cardinality cap, histogram bucket edges, Prometheus
 text that a parser accepts), tracer nesting + valid Chrome-trace JSONL,
-and the engine integration — one serve run through ``DecodeEngine``
+and the engine integration — one serve run through ``PagedDecodeEngine``
 must yield a parseable ``/metrics`` exposition with non-zero
 tokens/compile/latency series over real HTTP, and a trace whose
 ``serve/admit`` span count equals the requests processed, with the
@@ -671,7 +671,7 @@ class TestEngineTelemetry:
     def test_serve_run_feeds_registry_tracer_and_metrics_endpoint(
         self, tmp_path
     ):
-        from znicz_tpu.services.engine import DecodeEngine
+        from znicz_tpu.services.engine import PagedDecodeEngine
 
         params = _params()
         base = {
@@ -697,7 +697,7 @@ class TestEngineTelemetry:
         tracer = obs.get_tracer()
         tracer.start(path=str(trace_path))
         try:
-            eng = DecodeEngine(
+            eng = PagedDecodeEngine(
                 params, n_heads=HEADS, eos_id=EOS, batch_size=2,
                 admit_every=4,
             )
@@ -755,7 +755,7 @@ class TestEngineTelemetry:
         # a SECOND engine with the same geometry rides the shared jit
         # caches — the process-wide compile counter must not re-count
         compiles_after = _counter_total("znicz_serve_compiles_total")
-        eng2 = DecodeEngine(
+        eng2 = PagedDecodeEngine(
             params, n_heads=HEADS, eos_id=EOS, batch_size=2,
             admit_every=4,
         )
@@ -789,3 +789,44 @@ class TestEngineTelemetry:
             pass
         else:
             assert list(text_string_to_metric_families(body))
+
+
+def _documented_serve_families(path):
+    """Every ``znicz_serve_*`` family a document names, a ``{a,b}`` inside
+    a name written out (``_prefix_{hits,misses}_total``); a ``{...}`` that
+    ends a name lists its labels and is dropped."""
+    import re
+
+    with open(path) as f:
+        text = f.read()
+    names = set()
+    for m in re.finditer(
+        r"(znicz_serve_[a-z_]*)(?:\{([a-z_,]+)\}([a-z_]+))?", text
+    ):
+        stem, choices, rest = m.groups()
+        if choices:
+            names.update(stem + c + rest for c in choices.split(","))
+        elif stem != "znicz_serve_":  # the prose's "znicz_serve_*"
+            names.add(stem)
+    return names
+
+
+@pytest.mark.parametrize("doc", ["docs/SERVING.md", "docs/OBSERVABILITY.md"])
+def test_every_documented_serve_family_is_registered(doc):
+    # a row must not outlive what it describes: one request through the
+    # front door registers every family the serving path owns
+    import os
+
+    from znicz_tpu.services import PagedDecodeEngine, ServingFrontDoor
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    documented = _documented_serve_families(os.path.join(repo, doc))
+    assert len(documented) > 20, documented
+    params = _params()
+    with ServingFrontDoor(
+        lambda: PagedDecodeEngine(
+            params, n_heads=HEADS, eos_id=EOS, batch_size=2, block_size=8
+        )
+    ) as door:
+        door.submit(np.arange(1, 12, dtype=np.int32), 5).result(timeout=60)
+    assert documented <= set(obs.get_registry().metrics())

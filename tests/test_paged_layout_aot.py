@@ -108,8 +108,8 @@ def _lower(program, chip):
     key = spec((2,), jnp.uint32)
     if program == "decode_chunk":
         args = (
-            params, pools, tables, rows_i32, rows_i32, rows_i32, done,
-            rows_i32, scalar_f32, scalar_f32, key,
+            params, pools, tables, rows_i32, rows_i32, done, rows_i32,
+            scalar_f32, scalar_f32, key,
         )
         lowered = engine._paged_decode_chunk.lower(
             *args, chunk=CHUNK, t_max=T_MAX, eos_id=0, **SAMPLING, **TOWER
@@ -117,15 +117,14 @@ def _lower(program, chip):
     elif program == "prefill":
         args = (
             params, pools, spec((WINDOW,), i32), spec((1, BLOCK), i32),
-            scalar_i32, spec((1,), i32), scalar_i32, scalar_f32, scalar_f32,
-            key,
+            scalar_i32, scalar_i32, scalar_f32, scalar_f32, key,
         )
         lowered = engine._paged_prefill_prog.lower(*args, **SAMPLING, **TOWER)
     elif program == "verify":
         args = (
             params, pools, tables, spec((SLOTS, VERIFY_WIDTH), i32),
-            rows_i32, rows_i32, done, rows_i32, rows_i32, scalar_f32,
-            scalar_f32, key,
+            rows_i32, done, rows_i32, rows_i32, scalar_f32, scalar_f32,
+            key,
         )
         lowered = engine._paged_verify_prog.lower(
             *args, width=VERIFY_WIDTH, **SAMPLING, **TOWER
@@ -304,15 +303,15 @@ def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypat
         if program == "decode_chunk":
             lowered = engine._paged_decode_chunk.lower(
                 params, pools, spec((a["slots"], a["window"]), i32), rows_i32,
-                rows_i32, rows_i32, spec((a["slots"],), jnp.bool_), rows_i32,
+                rows_i32, spec((a["slots"],), jnp.bool_), rows_i32,
                 scalar_f32, scalar_f32, key, chunk=CHUNK, t_max=12288,
                 eos_id=0, **SAMPLING, **tower,
             )
         elif program == "prefill":
             lowered = engine._paged_prefill_prog.lower(
                 params, pools, spec((a["window"],), i32),
-                spec((1, a["block"]), i32), scalar_i32, spec((1,), i32),
-                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+                spec((1, a["block"]), i32), scalar_i32, scalar_i32,
+                scalar_f32, scalar_f32, key, **SAMPLING, **tower,
             )
         else:
             lowered = engine._cow_copy_prog.lower(pools, scalar_i32, scalar_i32)

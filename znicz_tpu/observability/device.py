@@ -6,18 +6,17 @@ timing and memory signals as input — yet until now nothing observed the
 device side at all.  This module is that layer:
 
 * **Program ledger** — every TRUE first compile of a serving program
-  (the engine's ``admit``/``chunk``/``prefill``/``paged_chunk``/``cow``
-  programs, deduped exactly like ``znicz_serve_compiles_total``; the
-  ``generate_serve`` AOT cache) records one entry: compile wall time,
-  the lowering's cost analysis (FLOPs / bytes accessed) and — where the
-  jax version exposes it — the executable's memory analysis.  Served at
+  (the engine's ``prefill``/``paged_chunk``/``spec_verify``/``cow``
+  programs, deduped exactly like ``znicz_serve_compiles_total``)
+  records one entry: compile wall time and the lowering's cost
+  analysis (FLOPs / bytes accessed).  Served at
   ``GET /debug/programs``; the engine-sourced entry count matches the
   engine ledger and ``znicz_serve_compiles_total`` by construction.
 * **Metrics** — ``znicz_compile_seconds{kind}`` (histogram),
   ``znicz_program_cost_flops_total{kind}`` /
   ``znicz_program_cost_bytes_total{kind}`` (static per-program costs,
   summed over compiles), ``znicz_device_memory_bytes{kind,device}``
-  (executable sizes + live ``memory_stats`` where the backend reports
+  (live ``memory_stats`` where the backend reports
   them — CPU answers None and the gauges simply stay absent).
 * **On-demand device capture** — :func:`capture_profile` runs a
   ``jax.profiler`` trace for N seconds (``POST /debug/profile`` on the
@@ -52,15 +51,6 @@ _PROGRAMS: "OrderedDict[str, dict]" = OrderedDict()
 _PROFILE_LOCK = threading.Lock()
 PROFILE_MAX_SECONDS = 30.0
 
-_MEMORY_FIELDS = (
-    "generated_code_size_in_bytes",
-    "argument_size_in_bytes",
-    "output_size_in_bytes",
-    "temp_size_in_bytes",
-    "alias_size_in_bytes",
-)
-
-
 def _m_compile_seconds():
     return get_registry().histogram(
         "znicz_compile_seconds",
@@ -88,13 +78,12 @@ def _m_cost_bytes():
 def _m_device_memory():
     return get_registry().gauge(
         "znicz_device_memory_bytes",
-        "device memory by kind: executable sizes (summed over compiled "
-        "programs) and live memory_stats where the backend reports them",
+        "live device memory_stats by kind, where the backend reports them",
         ("kind", "device"),
     )
 
 
-# -- cost / memory extraction (never raise) ---------------------------------
+# -- cost extraction (never raise) -------------------------------------------
 
 
 def stage_cost(stage) -> Optional[dict]:
@@ -131,24 +120,6 @@ def lowered_cost(fn, args, kwargs) -> Optional[dict]:
     return stage_cost(lowered)
 
 
-def compiled_memory(compiled) -> Optional[dict]:
-    """Normalized ``memory_analysis()`` of a jax ``Compiled``: the
-    ``*_size_in_bytes`` fields as a dict.  None when unavailable."""
-    try:
-        m = compiled.memory_analysis()
-    except Exception:
-        logger.debug("memory_analysis unavailable", exc_info=True)
-        return None
-    if m is None:
-        return None
-    out = {}
-    for field in _MEMORY_FIELDS:
-        v = getattr(m, field, None)
-        if v is not None:
-            out[field] = int(v)
-    return out or None
-
-
 # -- the ledger -------------------------------------------------------------
 
 
@@ -159,16 +130,15 @@ def record_program(
     kind: Optional[str] = None,
     source: str = "engine",
     cost: Optional[dict] = None,
-    memory: Optional[dict] = None,
     dedup=None,
 ) -> dict:
     """Ledger one compiled program.  ``key`` is the display key (the
-    engine's program-ledger tuple, or the serve cache's); ``dedup``
+    engine's program-ledger tuple); ``dedup``
     (default: the key itself) is the uniqueness key — the engine passes
     its ``(params-geometry, key)`` pair so two geometries compiling the
     same program key stay two entries, exactly like
     ``znicz_serve_compiles_total``.  Call ONLY on a true first compile;
-    the caller owns that dedup (``DecodeEngine._program``)."""
+    the caller owns that dedup (``PagedDecodeEngine._program``)."""
     kind = kind if kind is not None else (
         key[0] if isinstance(key, tuple) and key else str(key)
     )
@@ -179,7 +149,6 @@ def record_program(
         "compile_s": round(float(compile_s), 6),
         "flops": (cost or {}).get("flops"),
         "bytes_accessed": (cost or {}).get("bytes_accessed"),
-        "memory": memory,
         "recorded_unix": time.time(),  # timestamp, not a delta
     }
     ledger_key = f"{source}:{dedup if dedup is not None else key}"
@@ -194,24 +163,12 @@ def record_program(
         _m_cost_bytes().labels(kind=entry["kind"]).inc(
             entry["bytes_accessed"]
         )
-    if memory and memory.get("generated_code_size_in_bytes"):
-        # executable footprint, accumulated across compiles
-        with _LOCK:
-            total = sum(
-                (e.get("memory") or {}).get(
-                    "generated_code_size_in_bytes", 0
-                )
-                for e in _PROGRAMS.values()
-            )
-        _m_device_memory().labels(
-            kind="executable", device="all"
-        ).set(float(total))
     return entry
 
 
 def programs(source: Optional[str] = None) -> List[dict]:
     """The ledger entries in compile order (copies; filter by
-    ``source`` — ``"engine"`` / ``"serve_cache"``)."""
+    ``source`` — ``"engine"``)."""
     with _LOCK:
         return [
             dict(e) for e in _PROGRAMS.values()

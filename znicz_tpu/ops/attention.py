@@ -56,7 +56,6 @@ def paged_attention(
     q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
     *,
     block_size: int,
-    start: Optional[jnp.ndarray] = None,  # [B] first real position
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Block-table attention over paged KV pools; returns [B, Tq, H, D].
@@ -103,11 +102,9 @@ def paged_attention(
 
     Validity is by ABSOLUTE key index, exactly like the dense cache
     path (:mod:`znicz_tpu.workflow.generate`): key position must be
-    ``<= q_pos`` and (under left-padding) ``>= start``, so unallocated
-    or stale table entries — whose positions fall outside every valid
-    window — are masked out by INDEX, never read through.  A pad-region
-    query keeps its own position so its softmax stays finite (same
-    NaN-poisoning guard as the dense mask).  Numerics mirror
+    ``<= q_pos``, so unallocated or stale table entries — whose
+    positions fall outside every valid window — are masked out by
+    INDEX, never read through.  Numerics mirror
     :func:`dot_product_attention`: f32 score accumulation, stable
     softmax, f32 value accumulation.
     """
@@ -127,12 +124,7 @@ def paged_attention(
         preferred_element_type=jnp.float32,
     ).reshape(b, tq, h, n_keys) * scale
     k_idx = jnp.arange(n_keys)[None, None, None, :]
-    qp = q_pos[:, :, None, None]
-    valid = k_idx <= qp
-    if start is not None:
-        st = start[:, None, None, None]
-        valid = valid & (k_idx >= jnp.minimum(st, qp))
-    s = jnp.where(valid, s, -jnp.inf)
+    s = jnp.where(k_idx <= q_pos[:, :, None, None], s, -jnp.inf)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum(

@@ -19,7 +19,7 @@ kernel replaces the gather with table-indexed DMA:
 * **Body** — the online-softmax accumulation of
   :mod:`znicz_tpu.ops.pallas.attention` (running max / normalizer /
   f32 accumulator in VMEM scratch), with validity by absolute key
-  index: ``j*bs + lane <= pos`` and ``>= start``.  Blocks entirely past
+  index: ``j*bs + lane <= pos``.  Blocks entirely past
   ``pos`` are ``@pl.when``-skipped, so a short row touches only its own
   blocks regardless of the table width M.
 * **Output** — ``[B, 1, H, D]`` per decode step (or one chunk per
@@ -55,7 +55,6 @@ def paged_attention(
     q_pos: jnp.ndarray,  # [B, Tq] int32 absolute positions
     *,
     block_size: int,
-    start: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Drop-in twin of :func:`znicz_tpu.ops.attention.paged_attention`.
@@ -67,5 +66,5 @@ def paged_attention(
     """
     return _ref.paged_attention(
         q, k_pool, v_pool, block_table, q_pos,
-        block_size=block_size, start=start, scale=scale,
+        block_size=block_size, scale=scale,
     )

@@ -15,7 +15,6 @@ from znicz_tpu.services.plotting import (  # noqa: F401
 )
 from znicz_tpu.services.engine import (  # noqa: F401
     Completion,
-    DecodeEngine,
     PagedDecodeEngine,
 )
 from znicz_tpu.services.errors import (  # noqa: F401

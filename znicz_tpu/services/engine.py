@@ -1,27 +1,13 @@
 """Continuous micro-batching decode engine: the LM serving front-end.
 
-Orca-style continuous batching (PAPERS.md lineage) over the bucketed
-decode fast path (:mod:`znicz_tpu.workflow.generate`, docs/SERVING.md):
-a request queue coalesces pending prompts into a fixed B-slot batch over
-STATIC [B, T_max] KV buffers; when a row retires (EOS or budget), its
-slot is re-used by prefilling the next queued prompt into it while the
-other rows keep decoding.  Two compiled programs cover any request
-stream:
-
-* **admit** — prefill ONE left-padded [1, bucket] prompt into a fresh
-  zeroed cache row and scatter it into the batch at the slot index; one
-  compile per prompt-length bucket (geometric ladder, so a handful).
-* **decode chunk** — up to ``admit_every`` incremental steps for the
-  whole batch in one ``lax.while_loop`` (early exit once every row is
-  done), with PER-ROW positions (the cache write is vmapped into a
-  scatter), so rows at different depths decode together and no prompt
-  length or admission pattern ever recompiles it.
-
-**Paged backend** (:class:`PagedDecodeEngine`, PAPERS.md vLLM/Sarathi/
-RadixAttention lineage): instead of a dense ``[B, T_max]`` reservation
-per slot, K/V live in a shared block pool (``[n_blocks, block_size,
-H*hd]`` per layer: heads merged, so the minor dimensions fill the TPU's
-(8, 128) tiles and no program re-tiles a pool —
+Orca-style continuous batching (PAPERS.md lineage) over a PAGED K/V
+cache (:class:`PagedDecodeEngine`, PAPERS.md vLLM/Sarathi/RadixAttention
+lineage; docs/SERVING.md): a request queue coalesces pending prompts
+into a fixed B-slot batch; when a row retires (EOS or budget), its slot
+is re-used by prefilling the next queued prompt into it while the other
+rows keep decoding.  K/V live in a shared block pool (``[n_blocks,
+block_size, H*hd]`` per layer: heads merged, so the minor dimensions
+fill the TPU's (8, 128) tiles and no program re-tiles a pool —
 tests/test_paged_layout_aot.py) and each slot owns a block table over
 REFCOUNTED blocks.  Admission maps the longest prefix of the prompt
 already in the content-hash PREFIX CACHE (chained block hashes — an
@@ -36,6 +22,17 @@ releases its blocks, requeues it for recompute-on-readmission — instead
 of rejecting.  Concurrency is bounded by memory actually used, not by
 ``n_slots * T_max`` worst case; docs/SERVING.md has the tuning table.
 
+Four compiled programs cover any request stream:
+
+* **prefill chunk** — ONE ``[1, block_size]`` prompt chunk into the
+  row's blocks plus the first-token sample; every prompt length and
+  chunk index is the same shape.
+* **decode chunk** — up to ``admit_every`` incremental steps for the
+  whole batch in one ``lax.while_loop`` (early exit once every row is
+  done), with PER-ROW positions through the block tables, keyed only by
+  the x2 rung of the gathered block window.
+* **verify** (speculative decoding) and **copy-on-write block copy**.
+
 Telemetry rides :mod:`znicz_tpu.observability`: admissions, retirements
 (by reason), generated tokens and per-(kind, bucket) compiles are
 registry counters; queue depth and active slots are gauges; per-request
@@ -46,9 +43,8 @@ window feeding the shared latency histogram; ``timer`` is a
 :class:`~znicz_tpu.observability.PhaseTimer` whose admit/decode phases
 also emit tracer spans — one ``serve/admit`` span per request), and
 compile counts are introspectable via
-:meth:`DecodeEngine.compile_stats`.
+:meth:`PagedDecodeEngine.compile_stats`.
 """
-
 from __future__ import annotations
 
 import dataclasses
@@ -70,7 +66,6 @@ from znicz_tpu.services.errors import (
 )
 from znicz_tpu.utils import faults, profiling
 from znicz_tpu.workflow.generate import (
-    DEFAULT_PROMPT_BUCKETS,
     DEFAULT_SPEC_BUCKETS,
     NULL_BLOCK,
     PromptLookupDrafter,
@@ -80,14 +75,10 @@ from znicz_tpu.workflow.generate import (
     _sample,
     bucket_for,
     copy_paged_block,
-    decode_step,
-    init_kv_cache,
     init_paged_kv,
-    pack_prompts,
     paged_decode_step,
     paged_prefill_chunk,
     paged_verify_chunk,
-    prefill,
 )
 
 # process-wide first-compile ledger backing znicz_serve_compiles_total:
@@ -126,7 +117,7 @@ def prefix_block_keys(prompt, block_size: int) -> List[str]:
     """Public prefix-cache block keys for ``prompt`` (hex, full blocks
     only) — the routing key a :class:`~znicz_tpu.cluster.router
     .ServingRouter` indexes replicas by, and what
-    :meth:`DecodeEngine.prefix_probe` returns.  Pure function of the
+    :meth:`PagedDecodeEngine.prefix_probe` returns.  Pure function of the
     token content (prompts are hashed as int32, matching the engine's
     internal chain), independent of any live engine state."""
     p = np.asarray(prompt, np.int32).reshape(-1)
@@ -143,7 +134,7 @@ class RequestTimings:
       admission, plus every re-queue wait after a preemption; the
       front door adds its own pending-queue wait on top).
     * ``prefill_s`` — wall time of this request's own admit/prefill
-      program calls (per-chunk on the paged backend).
+      program calls (one per prompt chunk).
     * ``decode_s`` — wall time of the decode chunks this request was
       RESIDENT in.  Chunks are batched, so concurrent residents each
       count the full chunk — a per-request share of shared tower work,
@@ -184,7 +175,7 @@ class Request:
     id: int
     prompt: np.ndarray  # 1-D int32
     max_new_tokens: int
-    bucket: int  # prompt-length bucket it will be admitted at
+    bucket: int  # admission width: the prompt padded to whole blocks
     watch: profiling.Stopwatch  # started at submit; read at retirement
     ttft_s: Optional[float] = None  # set once at FIRST admission
     # memoized prefix-cache hash chain (pure function of the prompt —
@@ -243,135 +234,20 @@ def _sample_tok(logits, key, temperature, top_p, *, greedy, top_k, nucleus):
 @partial(
     jax.jit,
     static_argnames=(
-        "n_heads", "greedy", "top_k", "nucleus", "moe_top_k",
-        "moe_dispatch",
-    ),
-    donate_argnums=(1,),
-)
-def _admit_row(
-    params, caches, prompt, start, slot, temperature, top_p, key, *,
-    n_heads, greedy, top_k, nucleus, moe_top_k, moe_dispatch,
-):
-    """Prefill ONE left-padded [1, bucket] prompt into row ``slot`` of
-    the batch caches and sample its first token.
-
-    The row is rebuilt from a fresh ZEROED [1, T_max] cache, so the
-    previous occupant's K/V cannot leak into the new request (causality
-    already guarantees it — a query at position q only attends
-    positions <= q, all rewritten by the current occupant — the zeroed
-    row makes it true by construction too).  Compiles once per prompt
-    bucket (shape-keyed); the slot index is a traced operand."""
-    t_max = caches[0]["k"].shape[1]
-    row = init_kv_cache(params, 1, t_max, n_heads=n_heads)
-    row, logits = prefill(
-        params, prompt, row, n_heads=n_heads, start=start,
-        moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
-    )
-    new = []
-    for big, r in zip(caches, row):
-        new.append(
-            {
-                "k": jax.lax.dynamic_update_slice(
-                    big["k"], r["k"], (slot, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    big["v"], r["v"], (slot, 0, 0, 0)
-                ),
-            }
-        )
-    first = _sample_tok(
-        logits, key, temperature, top_p, greedy=greedy, top_k=top_k,
-        nucleus=nucleus,
-    )
-    return new, first[0]
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "chunk", "n_heads", "eos_id", "greedy", "top_k", "nucleus",
-        "moe_top_k", "moe_dispatch",
-    ),
-    donate_argnums=(1,),
-)
-def _decode_chunk(
-    params, caches, tok, pos, start, done, remaining, temperature,
-    top_p, rng, *, chunk, n_heads, eos_id, greedy, top_k, nucleus,
-    moe_top_k, moe_dispatch,
-):
-    """Up to ``chunk`` decode steps for the whole batch in ONE compiled
-    program, exiting early once every row is done.
-
-    Positions are PER-ROW — the cache write is vmapped into a scatter —
-    so rows admitted at different times (different prompt lengths,
-    different depths) decode together, and NO prompt length or admission
-    pattern ever recompiles this program: the zero-recompile core of the
-    engine.  Rows already done emit ``eos_id`` and idle in place (their
-    clamped cache write is dead — the slot is rebuilt at re-admission).
-
-    Returns (caches, tok, pos, done, remaining, out [B, chunk], steps):
-    the host reads ``out[:, :steps]`` to collect emissions and retire
-    rows."""
-    b = tok.shape[0]
-    t_max = caches[0]["k"].shape[1]
-    fill = jnp.int32(eos_id)
-    out = jnp.full((b, chunk), fill, jnp.int32)
-
-    def step_rows(caches, tok, pos):
-        def one(cache_row, t, p, s):
-            c1 = jax.tree_util.tree_map(lambda a: a[None], cache_row)
-            c2, lg = decode_step(
-                params, c1, t[None], p, n_heads=n_heads, start=s[None],
-                moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
-            )
-            return jax.tree_util.tree_map(lambda a: a[0], c2), lg[0]
-
-        return jax.vmap(one)(caches, tok, pos, start)
-
-    def cond(carry):
-        i, _, _, _, done, _, _ = carry
-        return (i < chunk) & ~jnp.all(done)
-
-    def body(carry):
-        i, caches, tok, pos, done, remaining, out = carry
-        caches, logits = step_rows(caches, tok, pos)
-        nxt = _sample_tok(
-            logits, jax.random.fold_in(rng, i), temperature, top_p,
-            greedy=greedy, top_k=top_k, nucleus=nucleus,
-        )
-        nxt = jnp.where(done, fill, nxt)
-        remaining = jnp.where(done, remaining, remaining - 1)
-        done = done | (nxt == eos_id) | (remaining <= 0)
-        out = jax.lax.dynamic_update_slice(out, nxt[:, None], (0, i))
-        pos = jnp.minimum(pos + 1, t_max - 1)
-        return (i + 1, caches, nxt, pos, done, remaining, out)
-
-    i, caches, tok, pos, done, remaining, out = jax.lax.while_loop(
-        cond, body,
-        (jnp.int32(0), caches, tok, pos, done, remaining, out),
-    )
-    return caches, tok, pos, done, remaining, out, i
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
         "block_size", "n_heads", "greedy", "top_k", "nucleus",
         "moe_top_k", "moe_dispatch", "model",
     ),
     donate_argnums=(1,),
 )
 def _paged_prefill_prog(
-    params, pools, table, tokens, offset, start, last, temperature,
-    top_p, key, *, block_size, n_heads, greedy, top_k, nucleus,
+    params, pools, table, tokens, offset, last, temperature, top_p,
+    key, *, block_size, n_heads, greedy, top_k, nucleus,
     moe_top_k, moe_dispatch, model=None,
 ):
     """One aligned prompt chunk into the row's blocks + first-token
     sample.  ONE compiled shape covers every prompt length and every
     chunk index (``offset``/``table``/``last`` are traced operands; the
-    chunk is always ``[1, block_size]``) — chunked prefill's compile
-    story beats the dense path's one-admit-program-per-bucket.  ``last``
-    is the in-chunk index of the prompt's final real token (the tail of
+    chunk is always ``[1, block_size]``).  ``last`` is the in-chunk index of the prompt's final real token (the tail of
     the final chunk is RIGHT-pad — prefix-cache alignment); the sample
     only matters on the final chunk; computing it unconditionally keeps
     the program single and costs one argmax/categorical per chunk.
@@ -383,8 +259,8 @@ def _paged_prefill_prog(
     if model is None:
         pools, logits = paged_prefill_chunk(
             params, pools, table, tokens, offset, n_heads=n_heads,
-            block_size=block_size, start=start, last=last,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            block_size=block_size, last=last, moe_top_k=moe_top_k,
+            moe_dispatch=moe_dispatch,
         )
     else:
         pools, logits, load = model.prefill_chunk(
@@ -417,21 +293,19 @@ def _cow_copy_prog(pools, src, dst):
     donate_argnums=(1,),
 )
 def _paged_decode_chunk(
-    params, pools, tables, tok, pos, start, done, remaining,
-    temperature, top_p, rng, *, chunk, block_size, t_max, n_heads,
+    params, pools, tables, tok, pos, done, remaining, temperature,
+    top_p, rng, *, chunk, block_size, t_max, n_heads,
     eos_id, greedy, top_k, nucleus, moe_top_k, moe_dispatch, model=None,
 ):
     """Up to ``chunk`` paged decode steps for the whole batch in ONE
-    compiled program (the paged twin of :func:`_decode_chunk`).
+    compiled program, exiting early once every row is done.
 
     Per-row positions are native to the paged step (the block table is
     the indirection — no vmap-into-scatter), so no prompt length,
     admission pattern, block assignment or pool occupancy ever
     recompiles this.  Done/idle rows write to the reserved null block
     and their positions FREEZE (a clamped position could walk into a
-    table entry the allocator already handed to another row — the
-    dense chunk's clamp-and-ignore trick is not safe against a shared
-    pool).
+    table entry the allocator already handed to another row).
 
     With a ``model`` each step runs through ITS tower (same loop, same
     sampling, same freezing of done rows) and the call returns an eighth
@@ -457,7 +331,7 @@ def _paged_decode_chunk(
         if model is None:
             return paged_decode_step(
                 params, pools, tables, tok, pos, n_heads=n_heads,
-                block_size=block_size, start=start, write_mask=~done,
+                block_size=block_size, write_mask=~done,
                 moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
             ) + (None,)
         return model.decode_step(
@@ -503,8 +377,8 @@ def _paged_decode_chunk(
     donate_argnums=(1,),
 )
 def _paged_verify_prog(
-    params, pools, tables, tokens, pos, start, done, n_write,
-    draft_len, temperature, top_p, rng, *, width, block_size, n_heads,
+    params, pools, tables, tokens, pos, done, n_write, draft_len,
+    temperature, top_p, rng, *, width, block_size, n_heads,
     greedy, top_k, nucleus, moe_top_k, moe_dispatch,
 ):
     """Speculative VERIFY: score ``width`` input tokens per row — the
@@ -541,8 +415,8 @@ def _paged_verify_prog(
     wmask = (~done)[:, None] & (idx < n_write[:, None])
     pools, logits = paged_verify_chunk(
         params, pools, tables, tokens, pos, n_heads=n_heads,
-        block_size=block_size, start=start, write_mask=wmask,
-        moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+        block_size=block_size, write_mask=wmask, moe_top_k=moe_top_k,
+        moe_dispatch=moe_dispatch,
     )
     # position i predicts the token AFTER input token i; the draft for
     # it is tokens[:, i+1], which exists iff i < draft_len
@@ -580,23 +454,84 @@ def _paged_verify_prog(
     return pools, out, n_accept
 
 
-class DecodeEngine:
-    """Continuous micro-batching front-end over the KV-cache decoder.
+class PagedDecodeEngine:
+    """Continuous micro-batching over a paged K/V cache: refcounted
+    copy-on-write block pool, cross-request prefix cache, chunked
+    prefill, preemption under pressure (docs/SERVING.md "Paged KV
+    serving").
 
     Usage::
 
-        eng = DecodeEngine(params, n_heads=8, eos_id=0, batch_size=8)
+        eng = PagedDecodeEngine(params, n_heads=8, eos_id=0, batch_size=8)
         ids = [eng.submit(prompt, max_new_tokens=64) for prompt in reqs]
         completions = eng.run()          # drain the queue
         eng.stats()                      # latency / tokens/s / compiles
 
     Greedy by default; ``temperature``/``top_k``/``top_p`` select the
-    same sampling structures as :func:`generate` (one compiled program
-    set per structure).  ``admit_every`` is the admission granularity:
-    the batch decodes in chunks of that many steps between retirement
-    checks — small values admit sooner, large values sync less."""
+    same sampling structures as :func:`~znicz_tpu.workflow.generate
+    .generate` (one compiled program set per structure).
+    ``admit_every`` is the admission granularity: the batch decodes in
+    chunks of that many steps between retirement checks — small values
+    admit sooner, large values sync less.
 
-    kv_backend = "dense"
+    K/V live in a shared ``[n_blocks, block_size, H*hd]`` pool per
+    layer; each slot owns an
+    ordered block table and every pool block carries a REFCOUNT — the
+    same physical block can appear in many tables at once.  Four
+    properties follow:
+
+    * **memory-proportional concurrency** — a slot consumes blocks for
+      the tokens it has actually decoded, not a ``T_max`` reservation;
+      ``n_blocks`` (not ``batch_size * T_max``) is the real capacity,
+      so short requests pack many-deep into the same memory.
+    * **prefix reuse (RadixAttention/vLLM lineage)** — retiring (and
+      preempted) requests publish their COMPLETED full blocks into a
+      prefix cache keyed by CHAINED content hash (block j's key commits
+      to all tokens before it — an implicit radix structure); admission
+      maps the longest cached block-chain prefix of the prompt into the
+      new table with refcount bumps and chunk-prefills only the
+      uncached tail.  A fully-cached system prompt costs zero prefill
+      FLOPs (one chunk reruns for the first-token logits) and TTFT
+      collapses to the tail.  Shared blocks are READ-ONLY: a write into
+      a block other tables or the cache reference COW-splits it first.
+      Prompts anchor at position 0 and right-pad the final chunk so a
+      shared prefix fills identical block contents whatever the full
+      prompt's length.
+    * **chunked prefill** — prompts are processed in block-sized chunks
+      under a per-tick TOKEN budget (``prefill_budget``,
+      Sarathi-style), interleaved with decode chunks: admitting a long
+      prompt steals a bounded slice of tower work between decode chunks
+      instead of stalling rows mid-decode.
+    * **eviction before preemption** — when the free list is dry,
+      allocation first EVICTS the least-recently-used cache-only block
+      (refcount 0, cache-referenced); only when the cache too is empty
+      is the YOUNGEST occupant preempted: publishes its full blocks to
+      the cache, releases its references, requeues at the queue head
+      for recompute on readmission (often straight out of its own
+      just-cached blocks).  Refcounts keep survivors' shared blocks
+      alive through any preemption.  If the starved slot is itself the
+      youngest it requeues itself and waits for older rows to retire;
+      submit-time validation guarantees any single request fits an
+      empty pool, so the wait always terminates.
+
+    ONE prefill program plus a short x2 ladder of decode-chunk
+    variants cover any stream: the ``[1, block_size]`` prefill chunk
+    serves every prompt length, and the decode chunk is keyed only by
+    the active block-WINDOW rung (the gather spans the blocks active
+    rows actually hold, rounded up a power of two — so short requests
+    don't pay ``T_max``-wide attention and the variant count stays
+    logarithmic); block tables, chunk offsets, pool occupancy,
+    admission patterns AND prefix-cache hits are all traced operands —
+    prefix reuse adds ZERO compiled programs, it only skips iterations
+    of the existing chunk program.
+
+    ``block_size`` trades utilization against program width;
+    ``n_blocks`` defaults to a full ``T_max`` window a slot
+    (``batch_size * ceil(T_max/block_size) + 1``) — size it DOWN to
+    serve the same stream in less memory, or raise ``batch_size``
+    against the same pool to convert reclaimed padding into
+    concurrency.  ``prefix_cache=False`` disables sharing (blocks then
+    free directly at release, LIFO)."""
 
     def __init__(
         self,
@@ -606,7 +541,10 @@ class DecodeEngine:
         eos_id: int,
         batch_size: int = 8,
         max_seq: Optional[int] = None,
-        prompt_buckets: Sequence[int] = DEFAULT_PROMPT_BUCKETS,
+        block_size: int = 16,
+        n_blocks: Optional[int] = None,
+        prefill_budget: Optional[int] = None,
+        prefix_cache: Optional[bool] = None,
         admit_every: int = 8,
         pad_id: Optional[int] = None,
         temperature: float = 0.0,
@@ -615,10 +553,9 @@ class DecodeEngine:
         rng: Optional[jax.Array] = None,
         moe_top_k: int = 1,
         moe_dispatch: str = "dense",
-        prefix_cache: Optional[bool] = None,
         spec_k: int = 0,
         drafter=None,
-        spec_buckets: Optional[Sequence[int]] = None,
+        spec_buckets: Sequence[int] = DEFAULT_SPEC_BUCKETS,
         model=None,
     ):
         if batch_size < 1 or admit_every < 1:
@@ -626,36 +563,68 @@ class DecodeEngine:
                 f"want batch_size >= 1 and admit_every >= 1; got "
                 f"{batch_size}, {admit_every}"
             )
-        if prefix_cache:
-            raise ValueError(
-                "prefix cache requires the paged backend "
-                "(PagedDecodeEngine): the dense [B, T_max] KV layout has "
-                "no shareable blocks to map across requests"
-            )
-        if spec_k or drafter is not None or spec_buckets is not None:
-            # typed CONFIG error (docs/SERVING.md failure taxonomy):
-            # rollback of rejected drafts is a block-table truncate,
-            # which the dense layout has no tables to perform
+        if block_size < 1:
+            raise ValueError(f"want block_size >= 1; got {block_size}")
+        self.block_size = int(block_size)
+        # ON by default: sharing is free when nothing matches (a few
+        # sha256 per admission) and the headline win when it does
+        self.prefix_cache = True if prefix_cache is None else bool(
+            prefix_cache
+        )
+        # speculative decoding (docs/SERVING.md "Speculative decoding"):
+        # spec_k == 0 is OFF (the plain decode chunk runs); > 0 drafts
+        # up to spec_k tokens per decoding row each tick and verifies
+        # them in one bucketed forward pass.  The drafter is duck-typed
+        # (``propose(context, k)``) — prompt-lookup by default, a
+        # draft-model drafter plugs into the same hook.
+        if spec_k < 0:
+            raise ValueError(f"want spec_k >= 0; got {spec_k}")
+        if spec_k and model is not None:
             raise SpeculationUnsupportedError(
-                "speculative decoding requires the paged backend "
-                "(PagedDecodeEngine): rejected draft tokens roll back "
-                "by truncating the row's block table — the dense "
-                "[B, T_max] KV layout has no block tables to truncate"
+                f"a {type(model).__name__} tower has no verify program "
+                "yet: speculative decoding is served for the classic "
+                "tower only"
             )
-        if not hasattr(self, "spec_k"):
-            self.spec_k = 0  # the stats() spec sub-dict reads this
-            # (the paged subclass sets its own before delegating here)
+        self.spec_k = int(spec_k)
+        self.spec_buckets = tuple(int(w) for w in spec_buckets)
+        if (
+            not self.spec_buckets
+            or min(self.spec_buckets) < 2
+            or list(self.spec_buckets)
+            != sorted(set(self.spec_buckets))
+        ):
+            raise ValueError(
+                "spec_buckets must be strictly increasing verify "
+                f"widths >= 2 (k+1 rungs); got {spec_buckets}"
+            )
+        if drafter is not None and not self.spec_k:
+            # silently serving with speculation OFF would be a config
+            # trap
+            raise ValueError(
+                "a drafter was given but spec_k == 0 keeps speculation "
+                "off; pass spec_k >= 1 to enable it"
+            )
+        self.drafter = (
+            drafter if drafter is not None else PromptLookupDrafter()
+        ) if self.spec_k else None
+        # per-tick prefill token budget: how much admission work may
+        # ride between two decode chunks.  The default matches one
+        # decode chunk's per-row depth (admit_every steps) in tokens —
+        # admission and decode then make comparable progress per tick
+        self.prefill_budget = int(
+            prefill_budget if prefill_budget is not None
+            else max(admit_every, 1) * self.block_size
+        )
+        if self.prefill_budget < 1:
+            raise ValueError(
+                f"want prefill_budget >= 1; got {self.prefill_budget}"
+            )
         # the tower's KIND: None is the classic block of
         # workflow/transformer.py (learned positions, a k/v pair a
         # layer); anything else brings its own paged tower
         # (``init_pools`` / ``prefill_chunk`` / ``decode_step``, e.g.
         # workflow/latent_lm.LatentMoEModel) and its own context limit
         self.model = model
-        if model is not None and self.kv_backend != "paged":
-            raise ValueError(
-                f"a {type(model).__name__} tower is served by the paged "
-                "backend only (PagedDecodeEngine)"
-            )
         max_pos = (
             params[0]["pos"].shape[0] if model is None
             else int(model.max_positions)
@@ -675,7 +644,6 @@ class DecodeEngine:
         self.eos_id = int(eos_id)
         self.pad_id = int(pad_id if pad_id is not None else eos_id)
         self.batch_size = int(batch_size)
-        self.prompt_buckets = tuple(prompt_buckets)
         self.admit_every = int(admit_every)
         self.moe_top_k = moe_top_k
         self.moe_dispatch = moe_dispatch
@@ -687,7 +655,6 @@ class DecodeEngine:
         b = self.batch_size
         self._tok = np.zeros((b,), np.int32)
         self._pos = np.zeros((b,), np.int32)
-        self._start = np.zeros((b,), np.int32)
         self._done = np.ones((b,), bool)  # empty slots idle as done
         self._remaining = np.zeros((b,), np.int32)
         self._slots: List[Optional[dict]] = [None] * b
@@ -760,9 +727,6 @@ class DecodeEngine:
         }
         self._occ_wall = 0.0
         self._occ_ticks = 0
-        # which kind of chunk the last _run_chunk ran ("decode" or
-        # "spec_verify") — written by the paged subclass's spec path
-        self._last_chunk_kind = "decode"
         self.latency = profiling.LatencyStats(
             observe=self._m_latency.observe
         )
@@ -783,650 +747,12 @@ class DecodeEngine:
         self._chunk_idx = 0
         self._total_new = 0
         self._peak_active = 0
-        self._init_kv_state()
-
-    def _init_kv_state(self) -> None:
-        """Allocate the dense ``[B, T_max]`` KV buffers (the paged
-        subclass overrides this with a block pool + tables)."""
-        self._caches = init_kv_cache(
-            self.params, self.batch_size, self.t_max, n_heads=self.n_heads
-        )
-
-    # -- request intake ---------------------------------------------------
-
-    def _validate_request(self, p: np.ndarray, max_new_tokens: int) -> int:
-        """Check the request against THIS backend's real KV capacity;
-        returns the admission width (prompt bucket).  Backend-specific so
-        the error names what actually ran out — the dense buffer's
-        ``t_max`` window here, the block pool in the paged subclass."""
-        bucket = bucket_for(p.size, self.prompt_buckets)
-        if bucket + max_new_tokens > self.t_max:
-            raise RequestTooLargeError(
-                f"prompt bucket {bucket} (len {p.size}) + max_new_tokens "
-                f"{max_new_tokens} exceeds the dense KV buffer "
-                f"(t_max={self.t_max})"
-            )
-        return bucket
-
-    def submit(
-        self,
-        prompt,
-        max_new_tokens: int,
-        *,
-        trace_id: Optional[str] = None,
-    ) -> int:
-        """Queue one prompt (1-D token ids); returns the request id.
-        Validated against the active backend's real KV capacity, so
-        admission can never fail later.  ``trace_id`` (the front door's
-        client-visible id) rides into the request's lifecycle spans and
-        its completion."""
-        p = np.asarray(prompt, np.int32).reshape(-1)
-        if p.size == 0:
-            raise ValueError("empty prompt")
-        if max_new_tokens < 1:
-            raise ValueError(f"want max_new_tokens >= 1; got {max_new_tokens}")
-        bucket = self._validate_request(p, max_new_tokens)
-        rid = self._next_id
-        self._next_id += 1
-        self._queue.append(
-            Request(rid, p, int(max_new_tokens), bucket,
-                    profiling.Stopwatch(), trace_id=trace_id)
-        )
-        self._m_submitted.inc()
-        self._m_queue_depth.set(len(self._queue))
-        observability.instant(
-            "serve/queued", id=rid, **self._trace_args(trace_id)
-        )
-        return rid
-
-    def _trace_args(self, trace_id: Optional[str]) -> Dict:
-        """Span/instant args for a trace id — empty when none, so
-        engine-direct callers add no noise to the timeline.  When the
-        front door names this engine's instance
-        (:attr:`trace_instance`), every span carries it too — the
-        fleet trace collector groups the merged timeline by that tag
-        (pid=instance in Perfetto)."""
-        args: Dict = {}
-        if not observability.get_tracer().recording:
-            return args  # nobody records the span: build nothing for it
-        if trace_id:
-            args["trace"] = trace_id
-        if self.trace_instance:
-            args["instance"] = self.trace_instance
-        return args
-
-    def _decode_trace_args(self, residents) -> Dict:
-        """Decode chunks are batched: the span carries EVERY resident's
-        trace id (comma-joined) so ONE Perfetto trace-id filter also
-        surfaces the decode chunks a request was resident in."""
-        args: Dict = {}
-        if not observability.get_tracer().recording:
-            return args  # nobody records the span: join nothing for it
-        traces = ",".join(
-            r.trace_id for r in residents if r.trace_id
-        )
-        if traces:
-            args["traces"] = traces
-        if self.trace_instance:
-            args["instance"] = self.trace_instance
-        return args
-
-    @property
-    def pending(self) -> int:
-        return len(self._queue)
-
-    @property
-    def active(self) -> int:
-        return sum(s is not None for s in self._slots)
-
-    # -- the serving loop -------------------------------------------------
-
-    def run(self) -> List[Completion]:
-        """Drain the queue: admit into free slots, decode in chunks,
-        retire finished rows, re-admit — until every submitted request
-        has completed.  Returns this call's completions in retirement
-        order (also kept in :attr:`completions` by id)."""
-        n0 = len(self._order)
-        while self.tick():
-            pass
-        return self._order[n0:]
-
-    def tick(self) -> bool:
-        """ONE engine tick — admit + prefill, then a decode (or
-        spec-verify) chunk — with the per-phase wall split observed
-        into ``znicz_serve_tick_occupancy{phase}``.  Returns False when
-        there is no work (nothing ran).  Both :meth:`run` and the front
-        door's engine thread drive the engine through this, so the
-        occupancy series is the one truth for tick composition."""
-        if not self._has_work():
-            return False
-        t0 = time.perf_counter()
-        self._admit_pending()
-        self._prefill_tick()
-        t1 = time.perf_counter()
-        chunk_kind = None
-        if self.active:
-            self._last_chunk_kind = "decode"
-            self._run_chunk()
-            chunk_kind = self._last_chunk_kind
-        t2 = time.perf_counter()
-        self._observe_tick(t1 - t0, t2 - t1, chunk_kind)
-        return True
-
-    def _observe_tick(
-        self,
-        prefill_s: float,
-        chunk_s: float,
-        chunk_kind: Optional[str],
-    ) -> None:
-        wall = prefill_s + chunk_s
-        if wall <= 0:
-            return
-        frac = {"prefill": prefill_s / wall}
-        if chunk_kind is not None:
-            frac[chunk_kind] = chunk_s / wall
-        for phase, f in frac.items():
-            self._m_tick_occ.labels(phase=phase).observe(f)
-        self._occ_seconds["prefill"] += prefill_s
-        if chunk_kind is not None:
-            self._occ_seconds[chunk_kind] += chunk_s
-        self._occ_wall += wall
-        self._occ_ticks += 1
-
-    def tick_occupancy(self) -> Dict:
-        """Lifetime tick-composition report (the ``stats()`` entry):
-        tick count, total tick wall, and each phase's fraction of it."""
-        wall = self._occ_wall
-        return {
-            "ticks": self._occ_ticks,
-            "wall_s": round(wall, 6),
-            "frac": {
-                k: round(v / wall, 4) if wall > 0 else 0.0
-                for k, v in self._occ_seconds.items()
-            },
-        }
-
-    def _has_work(self) -> bool:
-        return bool(self._queue) or self.active > 0
-
-    def _prefill_tick(self) -> None:
-        """Dense admission prefills whole prompts inside
-        :meth:`_admit_pending`; the paged subclass interleaves one
-        prompt CHUNK per prefilling slot here, between decode chunks."""
-
-    def _program(self, key: tuple) -> bool:
-        """Ledger one executable per key: the compile-count hook's
-        ground truth (tests cross-check it against the jit cache).
-        Registry mirror: ``znicz_serve_compiles_total{kind,bucket}``
-        counts TRUE first compiles per (params geometry, key) across the
-        whole process — a second engine with the same geometry rides the
-        shared jit caches and adds nothing.  ``key[1]`` is the prompt
-        bucket for admits, the chunk size for the decode program.
-        Returns True exactly when this call IS a true first compile
-        (the device-ledger hook in :meth:`_timed_program` keys off
-        it, so ``/debug/programs`` stays count-identical to the
-        counter)."""
-        if key in self._programs:
-            self._program_hits += 1
-            self._m_program_hits.inc()
-            return False
-        self._programs[key] = 1
-        full_key = (self._params_fp, key)
-        if full_key in _COMPILED_KEYS:
-            return False
-        _COMPILED_KEYS.add(full_key)
-        self._m_compiles.labels(kind=key[0], bucket=key[1]).inc()
-        return True
-
-    def _timed_program(self, key: tuple, fn, *args, **kwargs):
-        """Ledger + invoke one compiled program.  On its TRUE first
-        compile (process-wide, :meth:`_program`'s dedup) the call is
-        wall-timed and recorded into the device ledger
-        (``/debug/programs``, ``znicz_compile_seconds``,
-        ``znicz_program_cost_*``) together with the lowering's cost
-        analysis; steady-state invocations pay one dict lookup and
-        nothing else.  The recorded wall time is the first dispatch —
-        trace + compile + the first execution — which on a first
-        compile is compile-dominated."""
-        if not self._program(key):
-            return fn(*args, **kwargs)
-        cost = device_telemetry.lowered_cost(fn, args, kwargs)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        device_telemetry.record_program(
-            key,
-            time.perf_counter() - t0,
-            cost=cost,
-            dedup=(self._params_fp, key),
-        )
-        return out
-
-    def _admit_pending(self) -> None:
-        for slot in range(self.batch_size):
-            # keep pulling from the queue until the slot holds an ACTIVE
-            # row: a request that retires at admission itself (first
-            # token is EOS, or budget 1) must not idle the slot for a
-            # whole decode chunk
-            while self._queue and self._slots[slot] is None:
-                self._admit_into(slot, self._queue.popleft())
-        self._m_queue_depth.set(len(self._queue))
-        self._m_active.set(self.active)
-
-    def _leave_queue(self, req: Request) -> None:
-        """A slot takes ``req``: close out this spell of queueing."""
-        waited = req.watch.elapsed() - req.last_queued_at
-        req.timings.queue_s += waited
-        self._m_queue_wait.observe(waited)
-
-    def _admit_into(self, slot: int, req: Request) -> None:
-        self._leave_queue(req)
-        t0 = time.perf_counter()
-        with self.timer.phase(
-            "admit", request=req.id, bucket=req.bucket,
-            **self._trace_args(req.trace_id),
-        ):
-            tokens, start = pack_prompts(
-                [req.prompt], req.bucket, self.pad_id
-            )
-            key = jax.random.fold_in(self._rng, self._n_admits)
-            self._n_admits += 1
-            greedy, top_k, nucleus = self._structure
-            self._caches, first = self._timed_program(
-                ("admit", req.bucket, self._structure),
-                _admit_row,
-                self.params, self._caches, tokens, start,
-                jnp.int32(slot), self._temperature, self._top_p, key,
-                n_heads=self.n_heads, greedy=greedy, top_k=top_k,
-                nucleus=nucleus, moe_top_k=self.moe_top_k,
-                moe_dispatch=self.moe_dispatch,
-            )
-            first = int(first)
-        req.timings.prefill_s += time.perf_counter() - t0
-        self._m_admitted.inc()
-        req.ttft_s = req.watch.elapsed()
-        self._m_ttft.observe(req.ttft_s)
-        if first == self.eos_id:
-            self._retire(req, [first], "eos")
-        elif req.max_new_tokens == 1:
-            self._retire(req, [first], "budget")
-        else:
-            self._slots[slot] = {"req": req, "emitted": [first]}
-            self._tok[slot] = first
-            self._pos[slot] = req.bucket
-            self._start[slot] = req.bucket - req.prompt.size
-            self._done[slot] = False
-            self._remaining[slot] = req.max_new_tokens - 1
-
-    def _run_chunk(self) -> None:
-        faults.fire("engine.decode_step")
-        self._peak_active = max(self._peak_active, self.active)
-        residents = [
-            st["req"] for st in self._slots if st is not None
-        ]
-        t0 = time.perf_counter()
-        with self.timer.phase(
-            "decode", active=self.active,
-            **self._decode_trace_args(residents),
-        ):
-            rng = jax.random.fold_in(self._rng, 1 << 20 | self._chunk_idx)
-            self._chunk_idx += 1
-            greedy, top_k, nucleus = self._structure
-            (caches, tok, pos, done, remaining, out, steps) = (
-                self._timed_program(
-                    ("chunk", self.admit_every, self.batch_size,
-                     self._structure),
-                    _decode_chunk,
-                    self.params, self._caches, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), jnp.asarray(self._start),
-                    jnp.asarray(self._done),
-                    jnp.asarray(self._remaining),
-                    self._temperature, self._top_p, rng,
-                    chunk=self.admit_every, n_heads=self.n_heads,
-                    eos_id=self.eos_id, greedy=greedy, top_k=top_k,
-                    nucleus=nucleus, moe_top_k=self.moe_top_k,
-                    moe_dispatch=self.moe_dispatch,
-                )
-            )
-            self._caches = caches
-            # ONE host sync per chunk — the admission granularity; the
-            # [B]-sized state and [B, chunk] emissions are tiny next to
-            # the device-resident KV buffers
-            out = np.asarray(out)
-            steps = int(steps)
-            # np.array (not asarray): host state stays mutable — asarray
-            # of a device array is a read-only view
-            self._tok = np.array(tok)
-            self._pos = np.array(pos)
-            self._done = np.array(done)
-            self._remaining = np.array(remaining)
-        dt = time.perf_counter() - t0
-        for r in residents:
-            r.timings.decode_s += dt
-        for slot, st in enumerate(self._slots):
-            if st is None:
-                continue
-            req, emitted = st["req"], st["emitted"]
-            reason = None
-            for t in out[slot, :steps]:
-                emitted.append(int(t))
-                if int(t) == self.eos_id:
-                    reason = "eos"
-                    break
-                if len(emitted) >= req.max_new_tokens:
-                    reason = "budget"
-                    break
-            if reason is not None:
-                self._retire(req, emitted, reason)
-                self._slots[slot] = None
-                self._done[slot] = True
-                self._remaining[slot] = 0
-        self._m_active.set(self.active)
-
-    def _retire(self, req: Request, emitted: List[int], reason: str):
-        dt = req.watch.elapsed()
-        comp = Completion(
-            id=req.id,
-            tokens=np.concatenate(
-                [req.prompt, np.asarray(emitted, np.int32)]
-            ),
-            n_new=len(emitted),
-            finish_reason=reason,
-            latency_s=dt,
-            tokens_per_sec=len(emitted) / max(dt, 1e-9),
-            bucket=req.bucket,
-            ttft_s=req.ttft_s,
-            trace_id=req.trace_id,
-            timings=req.timings.as_dict(),
-        )
-        self._order.append(comp)
-        self.completions[req.id] = comp
-        # feeds the shared registry histogram via the observe hook
-        self.latency.record(dt)
-        self._total_new += len(emitted)
-        self._m_retired.labels(reason=reason).inc()
-        self._m_tokens.inc(len(emitted))
-        observability.instant(
-            "serve/retired", id=req.id, reason=reason,
-            **self._trace_args(req.trace_id),
-        )
-
-    # -- out-of-band retirement (cancellation / deadlines) ----------------
-
-    def abort(self, request_id: int, reason: str) -> Optional[Completion]:
-        """Retire a request OUT OF BAND with a typed completion —
-        cancellation or deadline expiry, driven by the front door
-        between ticks.  Works wherever the request currently lives:
-        still queued (removed, zero tokens) or occupying a slot
-        (tokens emitted so far are kept; the slot — and on the paged
-        backend its blocks — is reclaimed immediately).  Returns the
-        typed :class:`Completion`, or None when the id is unknown or
-        already completed (the normal completion wins the race).
-
-        NOT thread-safe: call only from the thread that drives the
-        engine (the front door's engine thread)."""
-        for i, req in enumerate(self._queue):
-            if req.id == request_id:
-                del self._queue[i]
-                self._m_queue_depth.set(len(self._queue))
-                # the whole wait so far was queueing: close it out so
-                # the timings of a queued abort say where the time went
-                req.timings.queue_s += (
-                    req.watch.elapsed() - req.last_queued_at
-                )
-                self._retire(req, [], reason)
-                return self.completions[request_id]
-        for slot, st in enumerate(self._slots):
-            if st is not None and st["req"].id == request_id:
-                self._abort_slot(slot, reason)
-                return self.completions[request_id]
-        return None
-
-    def reap(self, request_id: int) -> None:
-        """Forget a completed request's record.  The front door copies
-        each completion into its own handle as it collects it — keeping
-        the engine-side ``completions``/retirement-order ledgers for
-        every request ever served would leak on a long-lived service.
-        Batch-style callers that use :meth:`run` never need this."""
-        if self.completions.pop(request_id, None) is not None:
-            self._order = [c for c in self._order if c.id != request_id]
-
-    def _abort_slot(self, slot: int, reason: str) -> None:
-        """Dense out-of-band slot retirement: the slot just empties —
-        its stale K/V is rebuilt from a zeroed row at re-admission."""
-        st = self._slots[slot]
-        self._retire(st["req"], list(st.get("emitted") or []), reason)
-        self._slots[slot] = None
-        self._done[slot] = True
-        self._remaining[slot] = 0
-        self._m_active.set(self.active)
-
-    # -- introspection ----------------------------------------------------
-
-    def prefix_probe(self, prompt) -> Dict:
-        """Public prefix-cache probe: the prompt's chained block keys
-        (:func:`prefix_block_keys`) and how many of its lead blocks are
-        already cached HERE.  The dense backend has no shareable blocks,
-        so its answer is the empty probe — the router (and tests) read
-        this hook instead of engine privates; the paged subclass
-        overrides it with the real cache walk."""
-        np.asarray(prompt, np.int32).reshape(-1)  # same coercion contract
-        return {
-            "prefix_cache": False,
-            "block_size": None,
-            "block_keys": [],
-            "cached_blocks": 0,
-            "cached_tokens": 0,
-        }
-
-    def compile_stats(self) -> Dict:
-        """Compile-count hook: ``programs`` maps each
-        ``("admit", bucket, structure)`` / ``("chunk", chunk, B,
-        structure)`` key to 1 — one executable per key over the engine's
-        lifetime; ``program_hits`` counts invocations that reused one.
-        ``*_jit_entries`` are the process-wide jax caches backing them
-        (shared across engines: a second engine with the same geometry
-        compiles nothing new)."""
-        return {
-            "programs": dict(self._programs),
-            "n_programs": len(self._programs),
-            "program_hits": self._program_hits,
-            "admit_jit_entries": _admit_row._cache_size(),
-            "chunk_jit_entries": _decode_chunk._cache_size(),
-        }
-
-    def spec_stats(self) -> Dict:
-        """The ``spec`` sub-dict of :meth:`stats`: the dense backend
-        cannot speculate (construction rejects it), so its answer is
-        the disabled report — callers read ONE shape whichever backend
-        serves (the paged subclass overrides with the live tallies)."""
-        return {"enabled": False}
-
-    def stats(self) -> Dict:
-        """Serving report: completions, generated tokens, the per-request
-        latency aggregate, per-phase host timings, compile counts and
-        the speculative-decoding sub-dict (:meth:`spec_stats`).
-        ``peak_active`` is the max rows decoding in one chunk — the
-        engine's observed concurrency (the paged backend's headline)."""
-        return {
-            "kv_backend": self.kv_backend,
-            "completed": len(self.completions),
-            "generated_tokens": self._total_new,
-            "peak_active": self._peak_active,
-            "latency": self.latency.summary(),
-            "phases": self.timer.summary(),
-            "tick_occupancy": self.tick_occupancy(),
-            "spec": self.spec_stats(),
-            **self.compile_stats(),
-        }
-
-
-class PagedDecodeEngine(DecodeEngine):
-    """Paged-KV continuous batching: refcounted copy-on-write block
-    pool, cross-request prefix cache, chunked prefill, preemption under
-    pressure (docs/SERVING.md "Paged KV serving").
-
-    Same queue surface as :class:`DecodeEngine` (``submit``/``run``/
-    ``stats``), different memory model: K/V live in a shared
-    ``[n_blocks, block_size, H*hd]`` pool per layer; each slot owns an
-    ordered block table and every pool block carries a REFCOUNT — the
-    same physical block can appear in many tables at once.  Four
-    properties follow:
-
-    * **memory-proportional concurrency** — a slot consumes blocks for
-      the tokens it has actually decoded, not a ``T_max`` reservation;
-      ``n_blocks`` (not ``batch_size * T_max``) is the real capacity,
-      so short requests pack many-deep into the same memory.
-    * **prefix reuse (RadixAttention/vLLM lineage)** — retiring (and
-      preempted) requests publish their COMPLETED full blocks into a
-      prefix cache keyed by CHAINED content hash (block j's key commits
-      to all tokens before it — an implicit radix structure); admission
-      maps the longest cached block-chain prefix of the prompt into the
-      new table with refcount bumps and chunk-prefills only the
-      uncached tail.  A fully-cached system prompt costs zero prefill
-      FLOPs (one chunk reruns for the first-token logits) and TTFT
-      collapses to the tail.  Shared blocks are READ-ONLY: a write into
-      a block other tables or the cache reference COW-splits it first.
-      Prompts anchor at position 0 and right-pad the final chunk so a
-      shared prefix fills identical block contents whatever the full
-      prompt's length.
-    * **chunked prefill** — prompts are processed in block-sized chunks
-      under a per-tick TOKEN budget (``prefill_budget``,
-      Sarathi-style), interleaved with decode chunks: admitting a long
-      prompt steals a bounded slice of tower work between decode chunks
-      instead of stalling rows mid-decode.
-    * **eviction before preemption** — when the free list is dry,
-      allocation first EVICTS the least-recently-used cache-only block
-      (refcount 0, cache-referenced); only when the cache too is empty
-      is the YOUNGEST occupant preempted: publishes its full blocks to
-      the cache, releases its references, requeues at the queue head
-      for recompute on readmission (often straight out of its own
-      just-cached blocks).  Refcounts keep survivors' shared blocks
-      alive through any preemption.  If the starved slot is itself the
-      youngest it requeues itself and waits for older rows to retire;
-      submit-time validation guarantees any single request fits an
-      empty pool, so the wait always terminates.
-
-    ONE prefill program plus a short x2 ladder of decode-chunk
-    variants cover any stream (vs the dense engine's
-    one-admit-per-bucket): the ``[1, block_size]`` prefill chunk
-    serves every prompt length, and the decode chunk is keyed only by
-    the active block-WINDOW rung (the gather spans the blocks active
-    rows actually hold, rounded up a power of two — so short requests
-    don't pay ``T_max``-wide attention and the variant count stays
-    logarithmic); block tables, chunk offsets, pool occupancy,
-    admission patterns AND prefix-cache hits are all traced operands —
-    prefix reuse adds ZERO compiled programs, it only skips iterations
-    of the existing chunk program.
-
-    ``block_size`` trades utilization against program width;
-    ``n_blocks`` defaults to the dense-equivalent footprint
-    (``batch_size * ceil(T_max/block_size) + 1``) — size it DOWN to
-    serve the same stream in less memory, or raise ``batch_size``
-    against the same pool to convert reclaimed padding into
-    concurrency.  ``prefix_cache=False`` disables sharing (blocks then
-    free directly at release, LIFO)."""
-
-    kv_backend = "paged"
-
-    def __init__(
-        self,
-        params,
-        *,
-        n_heads: int,
-        eos_id: int,
-        batch_size: int = 8,
-        max_seq: Optional[int] = None,
-        block_size: int = 16,
-        n_blocks: Optional[int] = None,
-        prefill_budget: Optional[int] = None,
-        prefix_cache: Optional[bool] = None,
-        admit_every: int = 8,
-        pad_id: Optional[int] = None,
-        temperature: float = 0.0,
-        top_k: int = 0,
-        top_p: float = 1.0,
-        rng: Optional[jax.Array] = None,
-        moe_top_k: int = 1,
-        moe_dispatch: str = "dense",
-        spec_k: int = 0,
-        drafter=None,
-        spec_buckets: Sequence[int] = DEFAULT_SPEC_BUCKETS,
-        model=None,
-    ):
-        if block_size < 1:
-            raise ValueError(f"want block_size >= 1; got {block_size}")
-        self.block_size = int(block_size)
-        self._n_blocks_arg = n_blocks
-        # ON by default: sharing is free when nothing matches (a few
-        # sha256 per admission) and the headline win when it does
-        self.prefix_cache = True if prefix_cache is None else bool(
-            prefix_cache
-        )
-        # speculative decoding (docs/SERVING.md "Speculative decoding"):
-        # spec_k == 0 is OFF (the plain decode chunk runs); > 0 drafts
-        # up to spec_k tokens per decoding row each tick and verifies
-        # them in one bucketed forward pass.  The drafter is duck-typed
-        # (``propose(context, k)``) — prompt-lookup by default, a
-        # draft-model drafter plugs into the same hook.
-        if spec_k < 0:
-            raise ValueError(f"want spec_k >= 0; got {spec_k}")
-        if spec_k and model is not None:
-            raise SpeculationUnsupportedError(
-                f"a {type(model).__name__} tower has no verify program "
-                "yet: speculative decoding is served for the classic "
-                "tower only"
-            )
-        self.spec_k = int(spec_k)
-        self.spec_buckets = tuple(int(w) for w in spec_buckets)
-        if (
-            not self.spec_buckets
-            or min(self.spec_buckets) < 2
-            or list(self.spec_buckets)
-            != sorted(set(self.spec_buckets))
-        ):
-            raise ValueError(
-                "spec_buckets must be strictly increasing verify "
-                f"widths >= 2 (k+1 rungs); got {spec_buckets}"
-            )
-        if drafter is not None and not self.spec_k:
-            # silently serving with speculation OFF would be a config
-            # trap (the dense backend raises for the same noise)
-            raise ValueError(
-                "a drafter was given but spec_k == 0 keeps speculation "
-                "off; pass spec_k >= 1 to enable it"
-            )
-        self.drafter = (
-            drafter if drafter is not None else PromptLookupDrafter()
-        ) if self.spec_k else None
-        # per-tick prefill token budget: how much admission work may
-        # ride between two decode chunks.  The default matches one
-        # decode chunk's per-row depth (admit_every steps) in tokens —
-        # admission and decode then make comparable progress per tick
-        self.prefill_budget = int(
-            prefill_budget if prefill_budget is not None
-            else max(admit_every, 1) * self.block_size
-        )
-        if self.prefill_budget < 1:
-            raise ValueError(
-                f"want prefill_budget >= 1; got {self.prefill_budget}"
-            )
-        super().__init__(
-            params, n_heads=n_heads, eos_id=eos_id,
-            batch_size=batch_size, max_seq=max_seq,
-            admit_every=admit_every, pad_id=pad_id,
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=rng,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch, model=model,
-        )
-
-    def _init_kv_state(self) -> None:
         m = -(-self.t_max // self.block_size)  # table width: ceil
-        if self._n_blocks_arg is None:
-            # dense-equivalent default: every slot could hold a full
-            # T_max window (plus the reserved null block) — same memory
-            # as the dense engine, minus nothing; shrink it to save
-            self.n_blocks = self.batch_size * m + 1
-        else:
-            self.n_blocks = int(self._n_blocks_arg)
+        # default: every slot could hold a full T_max window (plus the
+        # reserved null block); shrink it to save memory
+        self.n_blocks = int(
+            n_blocks if n_blocks is not None else self.batch_size * m + 1
+        )
         self.blocks_per_row = m
         self._pools = (
             init_paged_kv if self.model is None else self.model.init_pools
@@ -1606,21 +932,20 @@ class PagedDecodeEngine(DecodeEngine):
         backlog, self._load_backlog = self._load_backlog, []
         self._count_expert_load("prefill", backlog, 1)
 
-    # -- capacity & the block allocator -----------------------------------
-
-    @property
-    def usable_blocks(self) -> int:
-        """Pool capacity available to requests (null block excluded)."""
-        return self.n_blocks - 1
+    # -- request intake ---------------------------------------------------
 
     def _validate_request(self, p: np.ndarray, max_new_tokens: int) -> int:
+        """Check the request against the real KV capacity (the
+        positional window, then the block pool — the error names which
+        ran out); returns the admission width, the prompt padded to
+        whole blocks."""
         padded = -(-p.size // self.block_size) * self.block_size
         total = padded + max_new_tokens
         need = -(-total // self.block_size)
         if total > self.t_max:
             raise RequestTooLargeError(
                 f"prompt (len {p.size}, padded {padded}) + max_new_tokens "
-                f"{max_new_tokens} exceeds the paged backend's positional "
+                f"{max_new_tokens} exceeds the positional "
                 f"window (t_max={self.t_max})"
             )
         if need > self.usable_blocks:
@@ -1631,6 +956,287 @@ class PagedDecodeEngine(DecodeEngine):
                 f"{self.block_size} tokens)"
             )
         return padded  # admission width: the padded prompt length
+
+    def submit(
+        self,
+        prompt,
+        max_new_tokens: int,
+        *,
+        trace_id: Optional[str] = None,
+    ) -> int:
+        """Queue one prompt (1-D token ids); returns the request id.
+        Validated against the real KV capacity, so
+        admission can never fail later.  ``trace_id`` (the front door's
+        client-visible id) rides into the request's lifecycle spans and
+        its completion."""
+        p = np.asarray(prompt, np.int32).reshape(-1)
+        if p.size == 0:
+            raise ValueError("empty prompt")
+        if max_new_tokens < 1:
+            raise ValueError(f"want max_new_tokens >= 1; got {max_new_tokens}")
+        bucket = self._validate_request(p, max_new_tokens)
+        rid = self._next_id
+        self._next_id += 1
+        self._queue.append(
+            Request(rid, p, int(max_new_tokens), bucket,
+                    profiling.Stopwatch(), trace_id=trace_id)
+        )
+        self._m_submitted.inc()
+        self._m_queue_depth.set(len(self._queue))
+        observability.instant(
+            "serve/queued", id=rid, **self._trace_args(trace_id)
+        )
+        return rid
+
+    def _trace_args(self, trace_id: Optional[str]) -> Dict:
+        """Span/instant args for a trace id — empty when none, so
+        engine-direct callers add no noise to the timeline.  When the
+        front door names this engine's instance
+        (:attr:`trace_instance`), every span carries it too — the
+        fleet trace collector groups the merged timeline by that tag
+        (pid=instance in Perfetto)."""
+        args: Dict = {}
+        if not observability.get_tracer().recording:
+            return args  # nobody records the span: build nothing for it
+        if trace_id:
+            args["trace"] = trace_id
+        if self.trace_instance:
+            args["instance"] = self.trace_instance
+        return args
+
+    def _decode_trace_args(self, residents) -> Dict:
+        """Decode chunks are batched: the span carries EVERY resident's
+        trace id (comma-joined) so ONE Perfetto trace-id filter also
+        surfaces the decode chunks a request was resident in."""
+        args: Dict = {}
+        if not observability.get_tracer().recording:
+            return args  # nobody records the span: join nothing for it
+        traces = ",".join(
+            r.trace_id for r in residents if r.trace_id
+        )
+        if traces:
+            args["traces"] = traces
+        if self.trace_instance:
+            args["instance"] = self.trace_instance
+        return args
+
+    @property
+    def pending(self) -> int:
+        return len(self._queue)
+
+    @property
+    def active(self) -> int:
+        return sum(
+            1 for s in self._slots
+            if s is not None and s["mode"] == "decode"
+        )
+
+    @property
+    def prefilling(self) -> int:
+        return sum(
+            1 for s in self._slots
+            if s is not None and s["mode"] == "prefill"
+        )
+
+    # -- the serving loop -------------------------------------------------
+
+    def run(self) -> List[Completion]:
+        """Drain the queue: admit into free slots, decode in chunks,
+        retire finished rows, re-admit — until every submitted request
+        has completed.  Returns this call's completions in retirement
+        order (also kept in :attr:`completions` by id)."""
+        n0 = len(self._order)
+        while self.tick():
+            pass
+        return self._order[n0:]
+
+    def tick(self) -> bool:
+        """ONE engine tick — admit + prefill, then a decode (or
+        spec-verify) chunk — with the per-phase wall split observed
+        into ``znicz_serve_tick_occupancy{phase}``.  Returns False when
+        there is no work (nothing ran).  Both :meth:`run` and the front
+        door's engine thread drive the engine through this, so the
+        occupancy series is the one truth for tick composition."""
+        if not self._has_work():
+            return False
+        t0 = time.perf_counter()
+        self._admit_pending()
+        self._prefill_tick()
+        t1 = time.perf_counter()
+        chunk_kind = self._run_chunk() if self.active else None
+        t2 = time.perf_counter()
+        self._observe_tick(t1 - t0, t2 - t1, chunk_kind)
+        return True
+
+    def _observe_tick(
+        self,
+        prefill_s: float,
+        chunk_s: float,
+        chunk_kind: Optional[str],
+    ) -> None:
+        wall = prefill_s + chunk_s
+        if wall <= 0:
+            return
+        frac = {"prefill": prefill_s / wall}
+        if chunk_kind is not None:
+            frac[chunk_kind] = chunk_s / wall
+        for phase, f in frac.items():
+            self._m_tick_occ.labels(phase=phase).observe(f)
+        self._occ_seconds["prefill"] += prefill_s
+        if chunk_kind is not None:
+            self._occ_seconds[chunk_kind] += chunk_s
+        self._occ_wall += wall
+        self._occ_ticks += 1
+
+    def tick_occupancy(self) -> Dict:
+        """Lifetime tick-composition report (the ``stats()`` entry):
+        tick count, total tick wall, and each phase's fraction of it."""
+        wall = self._occ_wall
+        return {
+            "ticks": self._occ_ticks,
+            "wall_s": round(wall, 6),
+            "frac": {
+                k: round(v / wall, 4) if wall > 0 else 0.0
+                for k, v in self._occ_seconds.items()
+            },
+        }
+
+    def _has_work(self) -> bool:
+        return bool(self._queue) or self.active > 0 or self.prefilling > 0
+
+    def _program(self, key: tuple) -> bool:
+        """Ledger one executable per key: the compile-count hook's
+        ground truth (tests cross-check it against the jit cache).
+        Registry mirror: ``znicz_serve_compiles_total{kind,bucket}``
+        counts TRUE first compiles per (params geometry, key) across the
+        whole process — a second engine with the same geometry rides the
+        shared jit caches and adds nothing.  ``key[1]`` is the block size
+        for the prefill program, the chunk size for the decode program.
+        Returns True exactly when this call IS a true first compile
+        (the device-ledger hook in :meth:`_timed_program` keys off
+        it, so ``/debug/programs`` stays count-identical to the
+        counter)."""
+        if key in self._programs:
+            self._program_hits += 1
+            self._m_program_hits.inc()
+            return False
+        self._programs[key] = 1
+        full_key = (self._params_fp, key)
+        if full_key in _COMPILED_KEYS:
+            return False
+        _COMPILED_KEYS.add(full_key)
+        self._m_compiles.labels(kind=key[0], bucket=key[1]).inc()
+        return True
+
+    def _timed_program(self, key: tuple, fn, *args, **kwargs):
+        """Ledger + invoke one compiled program.  On its TRUE first
+        compile (process-wide, :meth:`_program`'s dedup) the call is
+        wall-timed and recorded into the device ledger
+        (``/debug/programs``, ``znicz_compile_seconds``,
+        ``znicz_program_cost_*``) together with the lowering's cost
+        analysis; steady-state invocations pay one dict lookup and
+        nothing else.  The recorded wall time is the first dispatch —
+        trace + compile + the first execution — which on a first
+        compile is compile-dominated."""
+        if not self._program(key):
+            return fn(*args, **kwargs)
+        cost = device_telemetry.lowered_cost(fn, args, kwargs)
+        t0 = time.perf_counter()
+        out = fn(*args, **kwargs)
+        device_telemetry.record_program(
+            key,
+            time.perf_counter() - t0,
+            cost=cost,
+            dedup=(self._params_fp, key),
+        )
+        return out
+
+    def _leave_queue(self, req: Request) -> None:
+        """A slot takes ``req``: close out this spell of queueing."""
+        waited = req.watch.elapsed() - req.last_queued_at
+        req.timings.queue_s += waited
+        self._m_queue_wait.observe(waited)
+
+    def _retire(self, req: Request, emitted: List[int], reason: str):
+        dt = req.watch.elapsed()
+        comp = Completion(
+            id=req.id,
+            tokens=np.concatenate(
+                [req.prompt, np.asarray(emitted, np.int32)]
+            ),
+            n_new=len(emitted),
+            finish_reason=reason,
+            latency_s=dt,
+            tokens_per_sec=len(emitted) / max(dt, 1e-9),
+            bucket=req.bucket,
+            ttft_s=req.ttft_s,
+            trace_id=req.trace_id,
+            timings=req.timings.as_dict(),
+        )
+        self._order.append(comp)
+        self.completions[req.id] = comp
+        # feeds the shared registry histogram via the observe hook
+        self.latency.record(dt)
+        self._total_new += len(emitted)
+        self._m_retired.labels(reason=reason).inc()
+        self._m_tokens.inc(len(emitted))
+        observability.instant(
+            "serve/retired", id=req.id, reason=reason,
+            **self._trace_args(req.trace_id),
+        )
+
+    # -- out-of-band retirement (cancellation / deadlines) ----------------
+
+    def abort(self, request_id: int, reason: str) -> Optional[Completion]:
+        """Retire a request OUT OF BAND with a typed completion —
+        cancellation or deadline expiry, driven by the front door
+        between ticks.  Works wherever the request currently lives:
+        still queued (removed, zero tokens) or occupying a slot
+        (tokens emitted so far are kept; the slot and its blocks are
+        reclaimed immediately).  Returns the
+        typed :class:`Completion`, or None when the id is unknown or
+        already completed (the normal completion wins the race).
+
+        NOT thread-safe: call only from the thread that drives the
+        engine (the front door's engine thread)."""
+        for i, req in enumerate(self._queue):
+            if req.id == request_id:
+                del self._queue[i]
+                self._m_queue_depth.set(len(self._queue))
+                # the whole wait so far was queueing: close it out so
+                # the timings of a queued abort say where the time went
+                req.timings.queue_s += (
+                    req.watch.elapsed() - req.last_queued_at
+                )
+                self._retire(req, [], reason)
+                return self.completions[request_id]
+        for slot, st in enumerate(self._slots):
+            if st is not None and st["req"].id == request_id:
+                # the normal retire hook: completed full blocks publish
+                # to the prefix cache (a cancelled request's prefix is
+                # still reusable) and every table reference is released,
+                # so the blocks are reclaimable the moment the typed
+                # completion exists
+                self._retire_slot(slot, list(st["emitted"]), reason)
+                self._m_active.set(self.active)
+                return self.completions[request_id]
+        return None
+
+    def reap(self, request_id: int) -> None:
+        """Forget a completed request's record.  The front door copies
+        each completion into its own handle as it collects it — keeping
+        the engine-side ``completions``/retirement-order ledgers for
+        every request ever served would leak on a long-lived service.
+        Batch-style callers that use :meth:`run` never need this."""
+        if self.completions.pop(request_id, None) is not None:
+            self._order = [c for c in self._order if c.id != request_id]
+
+    # -- capacity & the block allocator -----------------------------------
+
+    @property
+    def usable_blocks(self) -> int:
+        """Pool capacity available to requests (null block excluded)."""
+        return self.n_blocks - 1
 
     def _update_pool_gauges(self) -> None:
         free = len(self._free)
@@ -1734,7 +1340,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._remaining[slot] = 0
         self._tok[slot] = 0
         self._pos[slot] = 0
-        self._start[slot] = 0
         self._queue.appendleft(st["req"])
         req = st["req"]
         req.timings.preemptions += 1
@@ -1803,7 +1408,8 @@ class PagedDecodeEngine(DecodeEngine):
         yield from _chain_digests(tokens, self.block_size)
 
     def prefix_probe(self, prompt) -> Dict:
-        """Paged probe: the prompt's chained block keys plus how many
+        """Public prefix-cache probe: the prompt's chained block keys
+        (:func:`prefix_block_keys`) plus how many
         lead blocks are CURRENTLY resident in this engine's prefix
         cache (``cached_blocks`` is the longest cached chain prefix —
         exactly what admission would map).  Advisory: the cache mutates
@@ -2055,7 +1661,6 @@ class PagedDecodeEngine(DecodeEngine):
                     ]
                 ),
                 jnp.int32(c * self.block_size),
-                jnp.zeros((1,), jnp.int32),
                 jnp.int32(
                     (size - 1) % self.block_size
                     if last
@@ -2090,7 +1695,6 @@ class PagedDecodeEngine(DecodeEngine):
             st["emitted"] = [first]
             self._tok[slot] = first
             self._pos[slot] = size
-            self._start[slot] = 0
             self._done[slot] = False
             self._remaining[slot] = req.max_new_tokens - 1
         return False
@@ -2107,36 +1711,6 @@ class PagedDecodeEngine(DecodeEngine):
         # a narrowed decode window
         self._tok[slot] = 0
         self._pos[slot] = 0
-        self._start[slot] = 0
-
-    def _abort_slot(self, slot: int, reason: str) -> None:
-        """Paged out-of-band retirement rides the normal retire hook:
-        completed full blocks publish to the prefix cache (their K/V is
-        valid — a cancelled request's prefix is still reusable) and
-        every table reference is released, so the blocks are
-        reclaimable the moment the typed completion exists."""
-        st = self._slots[slot]
-        self._retire_slot(slot, list(st.get("emitted") or []), reason)
-        self._m_active.set(self.active)
-
-    # -- the serving loop -------------------------------------------------
-
-    @property
-    def active(self) -> int:
-        return sum(
-            1 for s in self._slots
-            if s is not None and s["mode"] == "decode"
-        )
-
-    @property
-    def prefilling(self) -> int:
-        return sum(
-            1 for s in self._slots
-            if s is not None and s["mode"] == "prefill"
-        )
-
-    def _has_work(self) -> bool:
-        return bool(self._queue) or self.active > 0 or self.prefilling > 0
 
     def _grow_for_chunk(self, steps_for) -> bool:
         """Pre-chunk allocation + write guard, oldest first: each
@@ -2282,8 +1856,8 @@ class PagedDecodeEngine(DecodeEngine):
                 self.params, self._pools,
                 jnp.asarray(self._tables[:, :window]),
                 jnp.asarray(tokens), jnp.asarray(self._pos),
-                jnp.asarray(self._start), jnp.asarray(self._done),
-                jnp.asarray(n_write), jnp.asarray(draft_len),
+                jnp.asarray(self._done), jnp.asarray(n_write),
+                jnp.asarray(draft_len),
                 self._temperature, self._top_p, rng,
                 width=w, block_size=self.block_size,
                 n_heads=self.n_heads, greedy=greedy, top_k=top_k,
@@ -2353,14 +1927,16 @@ class PagedDecodeEngine(DecodeEngine):
         self._tables[slot, keep:] = NULL_BLOCK
         self._update_pool_gauges()
 
-    def _run_chunk(self) -> None:
+    def _run_chunk(self) -> str:
+        """One decode chunk, or one verify chunk where a row drafted;
+        returns which (``"decode"`` / ``"spec_verify"``), the phase the
+        tick's occupancy is booked under."""
         faults.fire("engine.decode_step")
         if self.spec_k:
             drafts = self._draft_pending()
             if drafts:
-                self._last_chunk_kind = "spec_verify"
                 self._verify_chunk(drafts)
-                return
+                return "spec_verify"
             # no row produced a draft this tick: fall through to the
             # plain (already-compiled) decode chunk — an unpredictable
             # stream pays ZERO verify overhead and ZERO new programs
@@ -2371,7 +1947,7 @@ class PagedDecodeEngine(DecodeEngine):
         if not self._grow_for_chunk(
             lambda slot: min(self.admit_every, int(self._remaining[slot]))
         ):
-            return  # allocation pressure preempted every decoder
+            return "decode"  # allocation pressure preempted every decoder
         self._peak_active = max(self._peak_active, self.active)
         # decode WINDOW (:meth:`_decode_window`): allocation above
         # already covers this chunk's growth, so the window cannot be
@@ -2398,7 +1974,7 @@ class PagedDecodeEngine(DecodeEngine):
                     self.params, self._pools,
                     jnp.asarray(self._tables[:, :window]),
                     jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._start), jnp.asarray(self._done),
+                    jnp.asarray(self._done),
                     jnp.asarray(self._remaining), self._temperature,
                     self._top_p, rng, chunk=self.admit_every,
                     block_size=self.block_size, t_max=self.t_max,
@@ -2440,16 +2016,19 @@ class PagedDecodeEngine(DecodeEngine):
             if reason is not None:
                 self._retire_slot(slot, emitted, reason)
         self._m_active.set(self.active)
+        return "decode"
 
     # -- introspection ----------------------------------------------------
 
     def compile_stats(self) -> Dict:
-        """Paged ledger: one ``("prefill", block_size, structure)``
-        entry plus one ``("paged_chunk", chunk, B, window, structure)``
-        entry per x2 window rung the stream's occupancy ever reached —
-        logarithmic in T_max/block_size, independent of request count —
-        cross-checked against the paged programs' jit caches (shared
-        process-wide, like the dense ones)."""
+        """Compile-count hook: ``programs`` holds one ``("prefill",
+        block_size, structure)`` entry plus one ``("paged_chunk", chunk,
+        B, window, structure)`` entry per x2 window rung the stream's
+        occupancy ever reached — logarithmic in T_max/block_size,
+        independent of request count; ``program_hits`` counts
+        invocations that reused one.  ``*_jit_entries`` are the
+        process-wide jax caches backing them (shared across engines: a
+        second engine with the same geometry compiles nothing new)."""
         return {
             "programs": dict(self._programs),
             "n_programs": len(self._programs),
@@ -2489,12 +2068,24 @@ class PagedDecodeEngine(DecodeEngine):
         }
 
     def stats(self) -> Dict:
-        """Adds the block-pool + prefix-cache view to the base report.
+        """Serving report: completions, generated tokens, the per-request
+        latency aggregate, per-phase host timings, compile counts, the
+        speculative-decoding sub-dict (:meth:`spec_stats`) and the
+        block-pool + prefix-cache view.  ``peak_active`` is the max rows
+        decoding in one chunk — the engine's observed concurrency.
         ``pool_blocks_free`` counts ALLOCATABLE blocks — the free list
         plus evictable cache-only blocks (``pool_blocks_cached``); a
         cached block a live request also maps counts as used."""
         return {
-            **super().stats(),
+            "kv_backend": "paged",
+            "completed": len(self.completions),
+            "generated_tokens": self._total_new,
+            "peak_active": self._peak_active,
+            "latency": self.latency.summary(),
+            "phases": self.timer.summary(),
+            "tick_occupancy": self.tick_occupancy(),
+            "spec": self.spec_stats(),
+            **self.compile_stats(),
             "pool_blocks": self.usable_blocks,
             "pool_blocks_free": len(self._free) + len(self._lru),
             "pool_blocks_cached": len(self._lru),

@@ -25,8 +25,8 @@ class RequestTooLargeError(ValueError):
 
 
 class SpeculationUnsupportedError(ValueError):
-    """Speculative decoding was configured on a backend that cannot
-    roll rejected tokens back — a CONFIG error, raised at engine
+    """Speculative decoding was configured for a tower that has no
+    verify program — a CONFIG error, raised at engine
     construction, never per request.  Subclasses ``ValueError`` (the
     same contract as :class:`RequestTooLargeError`): callers that
     validate engine config with a bare ``except ValueError`` keep

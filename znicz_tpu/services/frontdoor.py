@@ -1,7 +1,7 @@
 """The serving front door: async submit/stream/cancel over the engine.
 
-:class:`DecodeEngine`/:class:`PagedDecodeEngine` are synchronous-tick
-LIBRARIES — ``run()`` drains a queue and returns.  Real traffic needs a
+:class:`PagedDecodeEngine` is a synchronous-tick LIBRARY — ``run()``
+drains a queue and returns.  Real traffic needs a
 SERVICE: callers on many threads submitting concurrently, reading
 tokens as they are produced, abandoning requests (crashed client,
 user hit stop), and bounded by explicit deadlines and admission
@@ -13,12 +13,12 @@ revived as a serving concern):
   (admit → prefill chunk → decode chunk — the same programs ``run()``
   uses; the front door adds ZERO compiled programs).  All engine state
   stays single-threaded; callers talk to it through queues.
-  Speculative decoding rides this unchanged: a paged engine factory
+  Speculative decoding rides this unchanged: an engine factory
   built with ``spec_k > 0`` drafts/verifies inside the same tick (a
   watchdog restart rebuilds from the factory, so the spec config — and
   the warm verify programs — survive a crash), every completion's
-  ``timings`` carries ``spec_drafted``/``spec_accepted``, and the dense
-  backend's factory fails construction with the typed
+  ``timings`` carries ``spec_drafted``/``spec_accepted``, and a factory
+  whose tower has no verify program fails construction with the typed
   :class:`~znicz_tpu.services.errors.SpeculationUnsupportedError`
   before the door ever starts.
 * **submit() → handle**: validation runs single-flight BEFORE enqueue
@@ -34,7 +34,7 @@ revived as a serving concern):
   ``retry_after_s`` (the HTTP surface maps it to 503 + Retry-After).
 * **per-request deadlines**: ``deadline_s`` (relative to submit) is
   checked every tick; an expired request is retired MID-FLIGHT with a
-  ``deadline_exceeded`` completion and, on the paged backend, its
+  ``deadline_exceeded`` completion and its
   blocks released immediately (the PR 4-5 preemption machinery makes
   reclaim cheap).  Queued requests expire without ever touching the
   engine.
@@ -85,7 +85,7 @@ from znicz_tpu.observability.collector import (
 from znicz_tpu.observability.slo import FRONTDOOR_TARGETS, SLOMonitor
 from znicz_tpu.services.engine import (
     Completion,
-    DecodeEngine,
+    PagedDecodeEngine,
     RequestTimings,
 )
 from znicz_tpu.services.errors import (
@@ -221,7 +221,7 @@ class ServingFrontDoor:
 
     def __init__(
         self,
-        engine_factory: Callable[[], DecodeEngine],
+        engine_factory: Callable[[], PagedDecodeEngine],
         *,
         max_pending: int = 64,
         default_deadline_s: Optional[float] = None,
@@ -251,7 +251,7 @@ class ServingFrontDoor:
         self.idle_tick_s = float(idle_tick_s)
         self.retry_after_s = float(retry_after_s)
         self.name = name
-        self._engine: Optional[DecodeEngine] = engine_factory()
+        self._engine: Optional[PagedDecodeEngine] = engine_factory()
         self.engine_queue_limit = int(
             engine_queue_limit
             if engine_queue_limit is not None
@@ -424,7 +424,7 @@ class ServingFrontDoor:
     # -- client surface ---------------------------------------------------
 
     @property
-    def engine(self) -> Optional[DecodeEngine]:
+    def engine(self) -> Optional[PagedDecodeEngine]:
         """The CURRENT engine (replaced on watchdog restart)."""
         return self._engine
 
@@ -633,13 +633,13 @@ class ServingFrontDoor:
             "inflight": len(self._inflight),
             # the per-replica load signal a cluster router tiebreaks on
             # (rides /healthz, so one heartbeat carries liveness AND
-            # load; 1.0 on the dense backend — no pool to run dry)
+            # load)
             "pool_free_frac": round(self._pool_free_frac, 4),
         }
 
     def prefix_probe(self, prompt) -> Dict:
         """Delegate to the CURRENT engine's public
-        :meth:`~znicz_tpu.services.engine.DecodeEngine.prefix_probe`:
+        :meth:`~znicz_tpu.services.engine.PagedDecodeEngine.prefix_probe`:
         the prompt's chained block keys plus the cached-block count —
         what a prefix-affinity router (or a test) reads instead of
         engine privates.  Advisory snapshot (the engine thread mutates
@@ -673,7 +673,7 @@ class ServingFrontDoor:
 
     def stats(self) -> Dict:
         """Front-door report: the admission/termination tallies plus
-        the live engine's own :meth:`~DecodeEngine.stats`."""
+        the live engine's own :meth:`~PagedDecodeEngine.stats`."""
         eng = self._engine
         with self._lock:  # _reject mutates the dict under the lock
             rejected = dict(self._n_rejected)
@@ -769,7 +769,7 @@ class ServingFrontDoor:
         self,
         fr: _FrontRequest,
         reason: str,
-        eng: Optional[DecodeEngine],
+        eng: Optional[PagedDecodeEngine],
     ) -> None:
         """Retire ``fr`` with a typed completion wherever it lives."""
         if fr.engine_id is not None and fr.engine_id in self._inflight:

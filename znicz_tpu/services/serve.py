@@ -13,7 +13,7 @@ Endpoints beyond the static files:
   ``metrics.prom`` the training process drops into the status directory
   (textfile-collector pattern: the scrape reflects the TRAINING
   process's registry); falls back to this server process's own registry
-  when the file is absent (e.g. an in-process DecodeEngine server).
+  when the file is absent (e.g. an in-process PagedDecodeEngine server).
 * ``/metrics.json`` — the same data as a JSON snapshot, with the same
   file-first preference (the ``"metrics"`` snapshot StatusWriter embeds
   in ``status.json``), so the two endpoints never contradict each

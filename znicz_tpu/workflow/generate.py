@@ -11,16 +11,6 @@ the whole generation loop is ONE ``lax.while_loop`` — a single compiled
 program, no per-token dispatch, that exits as soon as every row has hit
 the EOS id (or the budget).
 
-Serving fast path (docs/SERVING.md): prompts are LEFT-padded to a small
-geometric ladder of length buckets and budgets round up a rung, so any
-request stream hits a handful of compiled programs instead of one per
-shape.  Padding is numerically inert — per-row ``start`` offsets mask the
-pad slots out of attention and shift positional embeddings, which the
-golden tests assert against the unpadded reference position-by-position.
-:func:`generate_serve` fronts this with an explicit executable cache
-keyed on ``(bucket_tp, bucket_new, B, sampling-structure)`` and a
-compile-count introspection hook (:func:`serve_cache_stats`).
-
 Numerics match :func:`znicz_tpu.workflow.transformer.lm_apply` exactly
 (same projection/attention formulation, f32 accumulation), which the golden
 tests assert position-by-position.
@@ -28,7 +18,6 @@ tests assert position-by-position.
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Optional, Sequence
 
@@ -36,8 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from znicz_tpu import observability
-from znicz_tpu.observability import device as device_telemetry
 from znicz_tpu.ops.attention import paged_attention
 from znicz_tpu.ops.normalization import layer_norm
 from znicz_tpu.workflow.transformer import _block_ffn
@@ -58,17 +45,14 @@ def init_kv_cache(params, batch: int, max_seq: int, *, n_heads: int):
 
 
 def _block_step(
-    block, x, cache, offset, *, n_heads, start=None, moe_top_k=1,
-    moe_dispatch="dense",
+    block, x, cache, offset, *, n_heads, moe_top_k=1, moe_dispatch="dense",
 ):
     """One pre-LN block over ``x`` [B, Tq, D] at absolute positions
     ``offset .. offset+Tq-1``, reading/writing the KV cache.  Tq is the
     prompt length during prefill and 1 during decode — one definition for
     both, so they cannot drift from each other (and the attention math
     mirrors ``ops.attention.mha`` + ``dot_product_attention``: f32 score
-    accumulation, stable softmax).  ``start`` [B] marks each row's first
-    real (non-pad) position under left-padding; keys before it are masked
-    out of attention."""
+    accumulation, stable softmax)."""
     b, tq, _ = x.shape
     h = layer_norm(x, block["ln1_scale"], block["ln1_bias"])
 
@@ -90,16 +74,7 @@ def _block_step(
     # (unwritten cache slots are > offset+Tq-1, so they mask out too)
     k_idx = jnp.arange(t_max)[None, None, None, :]
     q_idx = offset + jnp.arange(tq)[None, None, :, None]
-    valid = k_idx <= q_idx
-    if start is not None:
-        # left-padding: keys before the row's first real token are inert.
-        # A pad-region query (q < start) keeps exactly its own position so
-        # its softmax stays finite (all--inf rows would breed NaNs that
-        # 0*NaN-poison real rows through the value einsum); its output is
-        # discarded and its k/v never enter a real query's window.
-        st = start[:, None, None, None]
-        valid = valid & (k_idx >= jnp.minimum(st, q_idx))
-    s = jnp.where(valid, s, -jnp.inf)
+    s = jnp.where(k_idx <= q_idx, s, -jnp.inf)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     o = jnp.einsum(
@@ -117,53 +92,41 @@ def _block_step(
     return x, {"k": k_cache, "v": v_cache}
 
 
-def _embed_at(embed, tokens, offset, start=None):
-    """Token + positional embedding for tokens [B, Tq] at ``offset``.
-
-    With ``start`` [B] (left-padding), each row's positional index is
-    RELATIVE to its first real token (absolute - start), so a padded row
-    sees exactly the position ids the unpadded prompt would — positional
-    parity is what makes left-padding numerically inert."""
+def _embed_at(embed, tokens, offset):
+    """Token + positional embedding for tokens [B, Tq] at ``offset``."""
     tq = tokens.shape[1]
-    if start is None:
-        pos = jax.lax.dynamic_slice_in_dim(embed["pos"], offset, tq, axis=0)
-        return embed["embed"][tokens] + pos[None, :, :]
-    rel = offset + jnp.arange(tq)[None, :] - start[:, None]
-    rel = jnp.clip(rel, 0, embed["pos"].shape[0] - 1)
-    return embed["embed"][tokens] + embed["pos"][rel]
+    pos = jax.lax.dynamic_slice_in_dim(embed["pos"], offset, tq, axis=0)
+    return embed["embed"][tokens] + pos[None, :, :]
 
 
 def prefill(
-    params, tokens, caches, *, n_heads, start=None, moe_top_k=1,
-    moe_dispatch="dense",
+    params, tokens, caches, *, n_heads, moe_top_k=1, moe_dispatch="dense",
 ):
     """Run the prompt [B, Tp] through the tower, filling positions
-    ``0..Tp-1`` of the caches; returns (caches, last-position logits).
-    ``start`` [B]: first real position per row of a LEFT-padded prompt
-    (the last position is always real, so the returned logits are too)."""
-    x = _embed_at(params[0], tokens, 0, start)
+    ``0..Tp-1`` of the caches; returns (caches, last-position logits)."""
+    x = _embed_at(params[0], tokens, 0)
     new_caches = []
     for block, cache in zip(params[1:-1], caches):
         x, cache = _block_step(
-            block, x, cache, 0, n_heads=n_heads, start=start,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            block, x, cache, 0, n_heads=n_heads, moe_top_k=moe_top_k,
+            moe_dispatch=moe_dispatch,
         )
         new_caches.append(cache)
     return new_caches, x[:, -1] @ params[-1]["head"]
 
 
 def decode_step(
-    params, caches, token, pos, *, n_heads, start=None, moe_top_k=1,
+    params, caches, token, pos, *, n_heads, moe_top_k=1,
     moe_dispatch="dense",
 ):
     """One incremental step: ``token`` [B] at position ``pos`` -> (caches,
     next-position logits [B, vocab])."""
-    x = _embed_at(params[0], token[:, None], pos, start)
+    x = _embed_at(params[0], token[:, None], pos)
     new_caches = []
     for block, cache in zip(params[1:-1], caches):
         x, cache = _block_step(
-            block, x, cache, pos, n_heads=n_heads, start=start,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            block, x, cache, pos, n_heads=n_heads, moe_top_k=moe_top_k,
+            moe_dispatch=moe_dispatch,
         )
         new_caches.append(cache)
     return new_caches, x[:, 0] @ params[-1]["head"]
@@ -207,7 +170,7 @@ def init_paged_kv(params, n_blocks: int, block_size: int):
 
 def _paged_block_step(
     block, x, pool, write, tables, q_pos, *, n_heads, block_size,
-    start=None, moe_top_k=1, moe_dispatch="dense",
+    moe_top_k=1, moe_dispatch="dense",
 ):
     """One pre-LN block over ``x`` [B, Tq, D] with paged KV: ``write``
     scatters this layer's new K/V, heads merged as the pool stores them,
@@ -228,7 +191,7 @@ def _paged_block_step(
     v_pool = write(pool["v"], proj(block["wv"]))
     o = paged_attention(
         proj(block["wq"]).reshape(b, tq, n_heads, -1), k_pool, v_pool,
-        tables, q_pos, block_size=block_size, start=start,
+        tables, q_pos, block_size=block_size,
     )
     o = o.reshape(b, tq, -1)
     x = x + jnp.dot(
@@ -243,7 +206,7 @@ def _paged_block_step(
 
 def paged_prefill_chunk(
     params, pools, table, tokens, offset, *, n_heads, block_size,
-    start=None, last=None, moe_top_k=1, moe_dispatch="dense",
+    last=None, moe_top_k=1, moe_dispatch="dense",
 ):
     """Process ONE aligned chunk of a single prompt through the tower,
     writing its K/V into the row's blocks; returns ``(pools, logits)``
@@ -268,8 +231,7 @@ def paged_prefill_chunk(
     (garbage) K/V at absolute positions past the prompt, but validity
     is by absolute index — no query ever attends a position it hasn't
     reached — and incremental decode overwrites each pad slot before
-    its position becomes visible.  ``start`` [1] is retained for
-    left-padded callers (legacy tests); the engine passes zeros."""
+    its position becomes visible."""
     c = tokens.shape[1]
     if c != block_size:
         raise ValueError(
@@ -277,7 +239,7 @@ def paged_prefill_chunk(
             "(one chunk == one block)"
         )
     blk = table[offset // block_size]
-    x = _embed_at(params[0], tokens, offset, start)
+    x = _embed_at(params[0], tokens, offset)
     q_pos = offset + jnp.arange(c)[None, :]
 
     def write(pool, new):
@@ -287,7 +249,7 @@ def paged_prefill_chunk(
     for block, pool in zip(params[1:-1], pools):
         x, pool = _paged_block_step(
             block, x, pool, write, table[None], q_pos, n_heads=n_heads,
-            block_size=block_size, start=start, moe_top_k=moe_top_k,
+            block_size=block_size, moe_top_k=moe_top_k,
             moe_dispatch=moe_dispatch,
         )
         new_pools.append(pool)
@@ -315,7 +277,7 @@ def copy_paged_block(pools, src, dst):
 
 def paged_decode_step(
     params, pools, tables, token, pos, *, n_heads, block_size,
-    start=None, write_mask=None, moe_top_k=1, moe_dispatch="dense",
+    write_mask=None, moe_top_k=1, moe_dispatch="dense",
 ):
     """One incremental paged step: ``token`` [B] at PER-ROW positions
     ``pos`` [B] -> ``(pools, next logits [B, vocab])``.
@@ -326,15 +288,15 @@ def paged_decode_step(
     ``write_mask`` False (done/idle slots) write to the reserved
     ``NULL_BLOCK`` instead, so a retired-but-still-carried row can
     never scribble into a block the allocator has handed to someone
-    else.  Per-row positions are native here (no vmap-into-scatter as
-    in the dense engine chunk): the block table IS the indirection."""
+    else.  Per-row positions are native here: the block table IS the
+    indirection."""
     b = token.shape[0]
     rows = jnp.arange(b)
     blk = tables[rows, pos // block_size]
     if write_mask is not None:
         blk = jnp.where(write_mask, blk, NULL_BLOCK)
     slot = pos % block_size
-    x = _embed_rows(params[0], token, pos, start)
+    x = _embed_rows(params[0], token, pos)
 
     def write(pool, new):
         return pool.at[blk, slot].set(new[:, 0])
@@ -343,31 +305,28 @@ def paged_decode_step(
     for block, pool in zip(params[1:-1], pools):
         x, pool = _paged_block_step(
             block, x, pool, write, tables, pos[:, None], n_heads=n_heads,
-            block_size=block_size, start=start, moe_top_k=moe_top_k,
+            block_size=block_size, moe_top_k=moe_top_k,
             moe_dispatch=moe_dispatch,
         )
         new_pools.append(pool)
     return new_pools, x[:, 0] @ params[-1]["head"]
 
 
-def _embed_rows(embed, token, pos, start=None):
+def _embed_rows(embed, token, pos):
     """Token + positional embedding at PER-ROW absolute positions (the
     paged twin of :func:`_embed_at`, which takes one shared offset).
     ``token``/``pos`` are ``[B]`` (one decode step) or ``[B, W]`` (a
-    speculative verify chunk — W consecutive positions per row).  With
-    ``start`` the position index is row-relative, same left-padding
-    contract."""
+    speculative verify chunk — W consecutive positions per row)."""
     if token.ndim == 1:
         token = token[:, None]
         pos = pos[:, None]
-    rel = pos if start is None else pos - start[:, None]
-    rel = jnp.clip(rel, 0, embed["pos"].shape[0] - 1)
-    return embed["embed"][token] + embed["pos"][rel]
+    pos = jnp.clip(pos, 0, embed["pos"].shape[0] - 1)
+    return embed["embed"][token] + embed["pos"][pos]
 
 
 def paged_verify_chunk(
     params, pools, tables, tokens, pos, *, n_heads, block_size,
-    start=None, write_mask=None, moe_top_k=1, moe_dispatch="dense",
+    write_mask=None, moe_top_k=1, moe_dispatch="dense",
 ):
     """Score W tokens per row at per-row positions ``pos .. pos+W-1``
     through the paged tower in ONE forward pass — the speculative-
@@ -403,7 +362,7 @@ def paged_verify_chunk(
     if write_mask is not None:
         blk = jnp.where(write_mask, blk, NULL_BLOCK)
     slot = pos_w % block_size
-    x = _embed_rows(params[0], tokens, pos_w, start)
+    x = _embed_rows(params[0], tokens, pos_w)
 
     def write(pool, new):
         return pool.at[blk, slot].set(new)
@@ -412,7 +371,7 @@ def paged_verify_chunk(
     for block, pool in zip(params[1:-1], pools):
         x, pool = _paged_block_step(
             block, x, pool, write, tables, pos_w, n_heads=n_heads,
-            block_size=block_size, start=start, moe_top_k=moe_top_k,
+            block_size=block_size, moe_top_k=moe_top_k,
             moe_dispatch=moe_dispatch,
         )
         new_pools.append(pool)
@@ -525,9 +484,11 @@ def _sample(logits, key, temperature, top_k, nucleus, top_p):
 
 
 def _check_sampling_args(params, temperature, top_k, top_p, rng, eos_id):
-    """Shared argument validation for generate()/generate_serve()/the
-    engine; returns (top_k, rng) with the full-support clamp and greedy
+    """Shared argument validation for generate() and the engine;
+    returns (top_k, rng) with the full-support clamp and greedy
     dummy key applied."""
+    if temperature < 0.0:
+        raise ValueError(f"want temperature >= 0; got {temperature}")
     if temperature != 0.0 and rng is None:
         raise ValueError("temperature > 0 needs an rng key")
     if top_k < 0 or not 0.0 < top_p <= 1.0:
@@ -592,8 +553,6 @@ def generate(
     return _generate_impl(
         params,
         jnp.asarray(prompt, jnp.int32),
-        None,
-        jnp.int32(max_new_tokens),
         jnp.float32(temperature),
         jnp.float32(top_p),
         rng,
@@ -616,23 +575,15 @@ def generate(
     ),
 )
 def _generate_impl(
-    params, prompt, start, budget, temperature, top_p, rng, *, n_heads,
-    max_new_tokens, greedy, top_k, nucleus, eos_id, moe_top_k,
-    moe_dispatch,
+    params, prompt, temperature, top_p, rng, *, n_heads, max_new_tokens,
+    greedy, top_k, nucleus, eos_id, moe_top_k, moe_dispatch,
 ):
     """One compiled decode program: prefill + a while_loop over decode
-    steps carrying a per-row done-mask.  ``start`` is None for unpadded
-    prompts (None is an empty pytree, so the lean no-mask program
-    compiles) or [B] first-real-position offsets for left-padded ones.
-    ``budget`` is the REQUESTED token count as a traced operand:
-    ``max_new_tokens`` (the budget-ladder rung) sizes the buffers, but
-    the loop stops at ``budget`` — rounding a request up a rung costs
-    compiled shapes, never decode steps.  Per-step sampling keys are
+    steps carrying a per-row done-mask.  Per-step sampling keys are
     ``fold_in(rng, step)`` — derivable at any step index without
     materializing a presplit key array in the carry."""
     b, tp = prompt.shape
     t_max = tp + max_new_tokens
-    budget = jnp.minimum(budget, max_new_tokens)  # out-buffer bound
 
     def sample(logits, i):
         if greedy:
@@ -644,8 +595,8 @@ def _generate_impl(
 
     caches = init_kv_cache(params, b, t_max, n_heads=n_heads)
     caches, logits = prefill(
-        params, prompt, caches, n_heads=n_heads, start=start,
-        moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+        params, prompt, caches, n_heads=n_heads, moe_top_k=moe_top_k,
+        moe_dispatch=moe_dispatch,
     )
     first = sample(logits, 0)
     fill = jnp.int32(eos_id if eos_id is not None else 0)
@@ -658,13 +609,13 @@ def _generate_impl(
 
     def cond(carry):
         _, _, i, done, _ = carry
-        return (i < budget) & ~jnp.all(done)
+        return (i < max_new_tokens) & ~jnp.all(done)
 
     def body(carry):
         caches, token, i, done, out = carry
         caches, logits = decode_step(
             params, caches, token, tp + i - 1, n_heads=n_heads,
-            start=start, moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
+            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
         )
         nxt = sample(logits, i)
         if eos_id is not None:
@@ -677,16 +628,6 @@ def _generate_impl(
         cond, body, (caches, first, jnp.int32(1), done, out)
     )
     return jnp.concatenate([prompt, out], axis=1)
-
-
-# ---------------------------------------------------------------------------
-# Serving fast path: shape buckets + an explicit executable cache.
-
-# Geometric x2 ladders: a request stream of arbitrary prompt lengths /
-# token budgets compiles at most len(ladder) programs per sampling
-# structure instead of one per distinct shape.
-DEFAULT_PROMPT_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048)
-DEFAULT_BUDGET_LADDER = (16, 32, 64, 128, 256, 512, 1024)
 
 
 def bucket_for(n: int, ladder: Sequence[int]) -> int:
@@ -704,28 +645,6 @@ def bucket_for(n: int, ladder: Sequence[int]) -> int:
     return rung
 
 
-def pack_prompts(prompts, bucket: int, pad_id: int):
-    """LEFT-pad ragged prompts into one [B, bucket] int32 batch.
-
-    Returns ``(tokens, start)`` where ``start[b]`` is the index of row
-    b's first real token — the attention mask and positional embeddings
-    consume it to make the padding numerically inert (left-padding keeps
-    every row's LAST position real, so prefill logits need no gather)."""
-    tokens = np.full((len(prompts), bucket), pad_id, np.int32)
-    start = np.zeros((len(prompts),), np.int32)
-    for i, p in enumerate(prompts):
-        p = np.asarray(p, np.int32).reshape(-1)
-        if p.size == 0:
-            raise ValueError(f"prompt {i} is empty")
-        if p.size > bucket:
-            raise ValueError(
-                f"prompt {i} length {p.size} exceeds bucket {bucket}"
-            )
-        tokens[i, bucket - p.size:] = p
-        start[i] = bucket - p.size
-    return jnp.asarray(tokens), jnp.asarray(start)
-
-
 def _params_fingerprint(params):
     """Hashable (treedef, shapes/dtypes) key component: one executable
     serves one parameter GEOMETRY (values may change, e.g. after more
@@ -734,194 +653,3 @@ def _params_fingerprint(params):
     return treedef, tuple(
         (tuple(leaf.shape), str(leaf.dtype)) for leaf in leaves
     )
-
-
-class _ServeCache:
-    """Explicit executable cache for the serving decode path.
-
-    ``jax.jit`` already memoizes by (shapes, statics); this layer makes
-    the serving contract INSPECTABLE: every distinct key is one real
-    AOT-compiled executable (``lower().compile()``), so ``programs`` is
-    an exact compile count, not an inference from timing.  The
-    request/hit/compile tallies live in the process-wide metrics
-    registry (``znicz_serve_cache_*_total`` — visible on ``/metrics``
-    and in ``status.json``); the attributes here are read-through
-    views, not a second ledger."""
-
-    def __init__(self):
-        self.programs = {}  # key -> compiled executable
-        self._requests = observability.counter(
-            "znicz_serve_cache_requests_total",
-            "generate_serve() invocations",
-        )
-        self._hits = observability.counter(
-            "znicz_serve_cache_hits_total",
-            "generate_serve() calls served without compiling",
-        )
-        self._compiles = observability.counter(
-            "znicz_serve_cache_compiles_total",
-            "generate_serve() AOT compiles (distinct executable keys)",
-        )
-
-    @property
-    def hits(self) -> int:
-        return int(self._hits.value)
-
-    @property
-    def requests(self) -> int:
-        return int(self._requests.value)
-
-    @property
-    def compiles(self) -> int:
-        return int(self._compiles.value)
-
-    def record_request(self) -> None:
-        self._requests.inc()
-
-    def record_hit(self) -> None:
-        self._hits.inc()
-
-    def record_compile(self) -> None:
-        self._compiles.inc()
-
-    def reset(self):
-        self.programs.clear()
-        self._requests.reset()
-        self._hits.reset()
-        self._compiles.reset()
-
-
-_serve_cache = _ServeCache()
-
-
-def serve_cache_stats() -> dict:
-    """Compile-count introspection hook for the serving path: one entry
-    in ``programs`` per (bucket_tp, bucket_new, B, sampling-structure)
-    ever compiled; ``hits`` counts requests served without compiling."""
-    return {
-        "programs": len(_serve_cache.programs),
-        "hits": _serve_cache.hits,
-        "requests": _serve_cache.requests,
-        "compiles": _serve_cache.compiles,
-        "keys": sorted(
-            str(k[:-1]) for k in _serve_cache.programs
-        ),  # drop the params fingerprint — noise for humans
-        "jit_entries": _generate_impl._cache_size(),
-    }
-
-
-def reset_serve_cache() -> None:
-    """Drop all cached serving executables and zero the counters."""
-    _serve_cache.reset()
-
-
-def generate_serve(
-    params,
-    prompt,  # [B, Tp] int32 (rectangular; ragged streams -> engine.py)
-    *,
-    n_heads: int,
-    max_new_tokens: int,
-    eos_id: Optional[int] = None,
-    pad_id: Optional[int] = None,
-    prompt_buckets: Sequence[int] = DEFAULT_PROMPT_BUCKETS,
-    budget_ladder: Sequence[int] = DEFAULT_BUDGET_LADDER,
-    temperature: float = 0.0,
-    top_k: int = 0,
-    top_p: float = 1.0,
-    rng: Optional[jax.Array] = None,
-    moe_top_k: int = 1,
-    moe_dispatch: str = "dense",
-):
-    """Shape-bucketed serving twin of :func:`generate`.
-
-    Left-pads the prompt to the next prompt-length bucket and rounds the
-    token budget up a ladder rung, so any request stream hits a handful
-    of compiled programs; the executable is fetched from (or AOT-compiled
-    into) the explicit :data:`_serve_cache` keyed on
-    ``(bucket_tp, bucket_new, B, sampling-structure)``.  Returns
-    [B, Tp + max_new_tokens] tokens exactly like ``generate()`` — padding
-    stripped, budget trimmed back to the request — and matches it
-    token-for-token up to EOS (golden-tested)."""
-    if max_new_tokens < 1:
-        raise ValueError(f"want max_new_tokens >= 1; got {max_new_tokens}")
-    prompt = jnp.asarray(prompt, jnp.int32)
-    b, tp = prompt.shape
-    max_pos = params[0]["pos"].shape[0]
-    if tp + max_new_tokens > max_pos:
-        raise ValueError(
-            f"prompt {tp} + max_new_tokens {max_new_tokens} exceeds the "
-            f"positional table ({max_pos}); re-init the LM with a larger "
-            "max_seq"
-        )
-    bucket_tp = bucket_for(tp, prompt_buckets)
-    bucket_new = bucket_for(max_new_tokens, budget_ladder)
-    if bucket_tp + bucket_new > max_pos:
-        # rounding up must never reject a feasible request: shrink the
-        # budget rung into the table, then fall back to exact shapes
-        # (a rare capacity-edge compile beats a refused request)
-        bucket_new = max_pos - bucket_tp
-        if bucket_new < max_new_tokens:
-            bucket_tp, bucket_new = tp, max_new_tokens
-    top_k, rng = _check_sampling_args(
-        params, temperature, top_k, top_p, rng, eos_id
-    )
-    if pad_id is None:
-        pad_id = eos_id if eos_id is not None else 0
-    pad = bucket_tp - tp
-    if pad:
-        padded = jnp.concatenate(
-            [jnp.full((b, pad), pad_id, jnp.int32), prompt], axis=1
-        )
-    else:
-        padded = prompt
-    # always pass start (even all-zeros at exact bucket size) so ONE
-    # program per bucket serves every prompt length inside it
-    start = jnp.full((b,), pad, jnp.int32)
-    greedy = temperature == 0.0
-    nucleus = top_p < 1.0
-    # n_heads is in the key although it rarely differs between equal
-    # param geometries: head splits of the same [D, D] projections
-    # compile DIFFERENT programs, and a shared-shape cache hit across
-    # head counts would be silently wrong
-    key = (
-        bucket_tp, bucket_new, b, n_heads, greedy, top_k, nucleus,
-        eos_id, moe_top_k, moe_dispatch, _params_fingerprint(params),
-    )
-    temperature = jnp.float32(temperature)
-    top_p = jnp.float32(top_p)
-    _serve_cache.record_request()
-    # the rung sizes the compiled buffers; the REQUESTED budget rides in
-    # as a traced operand, so the loop never decodes past the request
-    budget = jnp.int32(max_new_tokens)
-    compiled = _serve_cache.programs.get(key)
-    if compiled is None:
-        t0 = time.perf_counter()
-        lowered = _generate_impl.lower(
-            params, padded, start, budget, temperature, top_p, rng,
-            n_heads=n_heads, max_new_tokens=bucket_new, greedy=greedy,
-            top_k=top_k, nucleus=nucleus, eos_id=eos_id,
-            moe_top_k=moe_top_k, moe_dispatch=moe_dispatch,
-        )
-        compiled = lowered.compile()
-        compile_s = time.perf_counter() - t0
-        _serve_cache.programs[key] = compiled
-        _serve_cache.record_compile()
-        # device/compile telemetry: the AOT path has the real compile
-        # wall time AND the Compiled in hand, so the ledger entry gets
-        # exact cost + memory analysis (graceful None where jax lacks
-        # the API)
-        device_telemetry.record_program(
-            ("serve", bucket_tp, bucket_new, b, greedy, top_k, nucleus),
-            compile_s,
-            source="serve_cache",
-            cost=(
-                device_telemetry.stage_cost(compiled)
-                or device_telemetry.stage_cost(lowered)
-            ),
-            memory=device_telemetry.compiled_memory(compiled),
-            dedup=key,
-        )
-    else:
-        _serve_cache.record_hit()
-    out = compiled(params, padded, start, budget, temperature, top_p, rng)
-    return out[:, pad: pad + tp + max_new_tokens]
