@@ -646,9 +646,11 @@ def _sec_mnist(ctx):
         )
         ewf.initialize(seed=3)
         ewf.run_epoch()  # compile + warmup
+        ewf.sync_epoch()  # the window opens on a parked prefetch producer
         t0 = time.time()
         for _ in range(3):
             ewf.run_epoch()
+        ewf.sync_epoch()
         return 3 * len(m_imgs) / (time.time() - t0)
 
     mnist_epoch_scan = mnist_epoch_rate("scan")
@@ -715,12 +717,16 @@ def _sec_mnist_stream(ctx):
     swf.initialize(seed=3)
     swf.run_epoch()  # compile + warmup
     # steady-state attribution window: exclude the compile epoch's
-    # stall from the fractions the record reports
+    # stall from the fractions the record reports.  It opens and closes
+    # on a parked prefetch producer (sync_epoch), so its counts hold no
+    # part of a run-ahead
+    swf.sync_epoch()
     pipeline_obs.reset_window()
     n_ep = 2
     t0 = time.time()
     for _ in range(n_ep):
         swf.run_epoch()
+    swf.sync_epoch()
     stream_rate = n_ep * len(m_imgs) / (time.time() - t0)
     att = PipelineAttribution.from_registry().attribution()
     fr = att.get("fractions", {})

@@ -347,6 +347,76 @@ class TestPrefetch:
         with pytest.raises(RuntimeError, match="decode failed"):
             list(it)
 
+    def test_an_epoch_end_passes_through_and_restarts_the_wait_labels(self):
+        from znicz_tpu.loader.prefetch import EpochEnd, prefetch
+        from znicz_tpu.observability import get_registry, pipeline
+
+        def two_epochs():
+            yield from (1, 2, 3)
+            yield EpochEnd("a")
+            yield from (4, 5)
+            yield EpochEnd("b")
+
+        pipeline.reset_window()
+        out = list(prefetch(two_epochs(), depth=2, transform=lambda v: -v))
+        # the marker is handed over as it is, in its place
+        assert [v.state if isinstance(v, EpochEnd) else v for v in out] == [
+            -1, -2, -3, "a", -4, -5, "b",
+        ]
+        fams = get_registry().metrics()
+        waits = {
+            k[0]: c.count
+            for k, c in fams[pipeline.WAIT_METRIC].children().items()
+        }
+        # two markers and the iterable's own end; a first after each start
+        assert waits == {"first": 2, "steady": 3, "end": 3}
+        starts = {
+            k[0]: c.value
+            for k, c in fams[pipeline.PREFETCH_EPOCHS_METRIC]
+            .children().items()
+        }
+        assert starts == {"cold": 1, "carried": 1}
+
+    def test_carried_epochs_park_puts_the_loader_back_to_the_boundary(self):
+        import pickle
+        import threading
+
+        from znicz_tpu.loader.prefetch import CarriedEpochs, THREAD_NAME
+
+        def loader():
+            prng.reset()
+            prng.seed_all(9)
+            return datasets.mnist(n_train=96, n_test=32, minibatch_size=32)
+
+        plain = loader()
+        want, states = [], []
+        for _ in range(2):
+            want.append([(s, mb.indices.copy()) for s, mb in plain.epoch()])
+            states.append(pickle.dumps(plain.state_dict()))
+
+        carried = loader()
+        before = {t for t in threading.enumerate() if t.name == THREAD_NAME}
+        feed = CarriedEpochs(carried, 2, lambda item: item)
+        assert pickle.dumps(feed.boundary[0]) == pickle.dumps(
+            loader().state_dict()
+        )
+        for epoch in range(2):
+            got = [(s, mb.indices.copy()) for s, mb in feed.epoch()]
+            assert [s for s, _ in got] == [s for s, _ in want[epoch]]
+            for (_, a), (_, b) in zip(got, want[epoch]):
+                np.testing.assert_array_equal(a, b)
+            # the producer's copy from the boundary, not the loader's now
+            assert pickle.dumps(feed.boundary[0]) == states[epoch]
+        feed.park()
+        assert not {
+            t for t in threading.enumerate()
+            if t.name == THREAD_NAME and t.is_alive()
+        } - before
+        assert pickle.dumps(carried.state_dict()) == states[-1]
+        # a parked feed is over: its next epoch says so
+        with pytest.raises(Exception, match="parked"):
+            list(feed.epoch())
+
     def test_workflow_results_identical_with_and_without(self):
         from znicz_tpu.workflow import StandardWorkflow
 

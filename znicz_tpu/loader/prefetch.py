@@ -24,9 +24,32 @@ is seen.  The ``loader.fetch`` fault point fires inside the timed fetch,
 making a slow producer a deterministic CI fixture.
 
 The consumer's wait (``znicz_prefetch_wait_seconds``) is labelled by
-where in the epoch it fell: ``at="first"`` (the first batch — the
-producer thread starts with the epoch, so this fetch overlaps no step),
-``"steady"``, and ``"end"`` (the wait for the end-of-epoch sentinel).
+where in the epoch it fell: ``at="first"`` (the first batch of an epoch),
+``"steady"``, and ``"end"`` (the wait for the end of the epoch: an
+:class:`EpochEnd` marker, or the sentinel of an iterable that ends).
+
+**The producer outlives the epoch.**  An iterable that goes on past an
+epoch's end says so by yielding an :class:`EpochEnd` between epochs.
+The producer hands the marker over like a batch (no transform) and goes
+straight on with the next epoch, so that epoch's first ``depth`` batches
+are fetched, placed and landed while the consumer still runs the last
+steps of this one and waits for their metrics; the queue's depth bounds
+the run-ahead exactly as it does inside an epoch.  The consumer labels
+its wait for the marker ``end`` and the pull after it ``first`` again:
+with a carried producer ``first`` reads what is LEFT of the edge (near
+zero when the batch was already queued), where a producer started for
+the epoch pays a whole fetch and placement there.
+``znicz_prefetch_epochs_total{start}`` counts, as the consumer takes an
+epoch's first batch, whether the producer thread was started for that
+epoch (``cold``) or went on into it from the one before (``carried``).
+
+:class:`CarriedEpochs` is that arrangement for a :class:`Loader`: one
+producer over ``loader.epoch()`` after ``loader.epoch()``, each marker
+carrying the loader's state as it stood at the boundary (the producer
+is the only thread that can name that moment: by the time the consumer
+reads the marker the loader is already into the next epoch), and
+``park()`` to stop the thread and put the loader back to the last
+boundary the consumer reached.
 """
 
 from __future__ import annotations
@@ -34,15 +57,28 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Callable, Iterable, Iterator, Optional, TypeVar
+from typing import Any, Callable, Iterable, Iterator, Optional, TypeVar
 
 from znicz_tpu import observability
+from znicz_tpu.core import prng
 from znicz_tpu.observability import pipeline as _pipeline
 from znicz_tpu.utils import faults
 
 T = TypeVar("T")
 
 _SENTINEL = object()
+THREAD_NAME = "znicz-prefetch"
+
+
+class EpochEnd:
+    """What an iterable that spans epochs yields between two of them.
+    ``state`` is whatever the iterable wants the consumer to know about
+    the boundary (:class:`CarriedEpochs` puts the loader's state there)."""
+
+    __slots__ = ("state",)
+
+    def __init__(self, state: Any = None):
+        self.state = state
 
 
 class PrefetchProducerError(RuntimeError):
@@ -72,6 +108,10 @@ def prefetch(
     instrumentation, e.g. an :class:`~znicz_tpu.observability.H2DProbe`,
     which joins the producer's stage clock; what such a callable leaves
     unobserved belongs to no stage and shows as unattributed).
+
+    An :class:`EpochEnd` from the iterable passes through untransformed
+    and restarts the consumer's ``first`` / ``steady`` / ``end`` labels;
+    the producer does not pause at it (module docstring).
 
     Exceptions in the producer (fetch or transform) re-raise at the
     consumer's next pull.  If the consumer abandons the iterator
@@ -109,10 +149,17 @@ def prefetch(
                         faults.fire("loader.fetch")
                         item = next(it, _SENTINEL)
                     clock.lap(_pipeline.STAGE_FETCH)
-                    if item is _SENTINEL:
+                    if item is _SENTINEL or stop.is_set():
+                        # a consumer that went away during the fetch is
+                        # owed neither the placement nor the hand-over
                         clock.close_iteration()
                         break
-                    if transform is not None:
+                    # an EpochEnd is handed over as it is; its wait in
+                    # the queue is an enqueue like any batch's, so the
+                    # stages go on tiling the loop across the boundary
+                    if transform is not None and not isinstance(
+                        item, EpochEnd
+                    ):
                         if transform_stage is None:
                             # the callable laps its own stage on this
                             # clock (an H2DProbe does, and its hand-over
@@ -165,7 +212,9 @@ def prefetch(
     # mean the device is the limit; long waits mean the loader is
     wait = _pipeline.wait_seconds()
     at = _pipeline.WAIT_FIRST
-    t = threading.Thread(target=worker, daemon=True)
+    epochs = _pipeline.prefetch_epochs()
+    start = _pipeline.START_COLD
+    t = threading.Thread(target=worker, daemon=True, name=THREAD_NAME)
     t.start()
     try:
         while True:
@@ -191,15 +240,114 @@ def prefetch(
                 if error:
                     raise error[0]
                 return
-            wait.labels(at=at).observe(waited)
-            at = _pipeline.WAIT_STEADY
+            if isinstance(item, EpochEnd):
+                wait.labels(at=_pipeline.WAIT_END).observe(waited)
+                at, start = _pipeline.WAIT_FIRST, _pipeline.START_CARRIED
+            else:
+                if at == _pipeline.WAIT_FIRST:
+                    epochs.labels(start=start).inc()
+                wait.labels(at=at).observe(waited)
+                at = _pipeline.WAIT_STEADY
             yield item
     finally:
         # runs on normal exhaustion AND on generator close/abandonment
         stop.set()
+        # the worker sees ``stop`` at the end of the fetch or the
+        # hand-over it is in, and is not waited for here: the collector
+        # finalizes an abandoned generator wherever it happens to run,
+        # and a wait there could hold a lock the worker needs to get
+        # that far
         while True:  # unblock a worker stuck in put()
             try:
                 q.get_nowait()
             # drain-until-empty control flow, not a swallowed failure
             except queue.Empty:  # znicz-check: disable=ZNC008
                 break
+
+
+def _boundary(loader) -> tuple:
+    """The loader's state and its shuffle stream's, read one after the
+    other as ``Workflow.host_state()`` reads them (two reads, two
+    copies: a snapshot pickles both, and one shared object would not
+    serialize as two equal ones do)."""
+    return loader.state_dict(), prng.get(loader.rand_name).state_dict()
+
+
+class _EpochsWithoutEnd:
+    """``loader.epoch()`` after ``loader.epoch()`` with an
+    :class:`EpochEnd` between them, for one thread to pull and another
+    to ``halt()``."""
+
+    def __init__(self, loader):
+        self._items = self._chain(loader)
+        self._puller: Optional[threading.Thread] = None
+
+    @staticmethod
+    def _chain(loader) -> Iterator:
+        while True:
+            yield from loader.epoch()
+            # epoch_number has moved on, the next reshuffle has not drawn
+            yield EpochEnd(_boundary(loader))
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        self._puller = threading.current_thread()
+        return next(self._items)
+
+    def halt(self) -> None:
+        """End every later pull, and wait for the thread that has
+        pulled to finish (its consumer has told it to stop): on return
+        nobody reads the loader and the producer's accounting is whole.
+        In this order: a thread that names itself after the swap finds
+        the chain ended."""
+        self._items = iter(())
+        if self._puller is not None:
+            self._puller.join()
+
+
+class CarriedEpochs:
+    """One producer over a loader's epochs, for as long as the caller
+    keeps asking for the next one.
+
+    ``epoch()`` yields one epoch's (transformed) batches and returns at
+    the boundary; meanwhile the producer is already into the next epoch.
+    So while this object is live the loader and its shuffle stream are
+    the PRODUCER's: ``boundary`` holds what they read at the last
+    boundary the consumer reached (at the start, before the thread has
+    drawn anything), which is what the loader itself would read there
+    without a producer.  ``park()`` ends the arrangement: the producer
+    is stopped and waited for (a rewind under a thread that is still
+    drawing would race), the batches it had run ahead are dropped and
+    the loader is put back to ``boundary``.  A caller that
+    is dropped without parking takes the generator with it, which stops
+    the thread (nothing here refers to the caller); the loader is then
+    left where the producer stopped.
+    """
+
+    def __init__(self, loader, depth: int, transform: Callable):
+        self._loader = loader
+        self.boundary = _boundary(loader)
+        self._source = _EpochsWithoutEnd(loader)
+        # transform_stage=None: the workflow's placement closure times
+        # itself (an H2DProbe); fetch and enqueue come from prefetch
+        self._items = prefetch(
+            self._source, depth, transform=transform, transform_stage=None
+        )
+
+    def epoch(self) -> Iterator:
+        for item in self._items:
+            if isinstance(item, EpochEnd):
+                self.boundary = item.state
+                return
+            yield item
+        raise PrefetchProducerError(
+            "the prefetch producer ended inside an epoch (parked, or "
+            "dead after an error that was already raised)"
+        )
+
+    def park(self) -> None:
+        self._items.close()
+        self._source.halt()
+        self._loader.load_state_dict(self.boundary[0])

@@ -88,6 +88,10 @@ STAGE_H2D_LANDED = "h2d_landed"
 WAIT_FIRST = "first"
 WAIT_STEADY = "steady"
 WAIT_END = "end"
+# whether the producer thread was started for an epoch or went on into
+# it from the one before (znicz_prefetch_epochs_total{start})
+START_COLD = "cold"
+START_CARRIED = "carried"
 
 STEP_WALL_METRIC = "znicz_train_step_wall_seconds"
 WAIT_METRIC = "znicz_prefetch_wait_seconds"
@@ -97,6 +101,7 @@ PRODUCER_METRIC = "znicz_pipeline_producer_seconds"
 H2D_BPS_METRIC = "znicz_h2d_bytes_per_second"
 H2D_BYTES_METRIC = "znicz_h2d_bytes_total"
 QUEUE_FULL_METRIC = "znicz_prefetch_queue_full_total"
+PREFETCH_EPOCHS_METRIC = "znicz_prefetch_epochs_total"
 
 # anomaly surfaces the doctor reads from the same exposition
 ANOMALY_ACTIVE_METRIC = "znicz_train_anomaly_active"
@@ -126,6 +131,7 @@ WINDOW_METRICS = (
     PRODUCER_METRIC,
     H2D_BYTES_METRIC,
     QUEUE_FULL_METRIC,
+    PREFETCH_EPOCHS_METRIC,
 )
 
 
@@ -161,6 +167,19 @@ def wait_seconds(registry: Optional[MetricsRegistry] = None):
         "seconds the consumer blocked waiting for the next minibatch "
         "(at = first batch of an epoch / steady / end-of-epoch sentinel)",
         ("at",),
+    )
+
+
+def prefetch_epochs(registry: Optional[MetricsRegistry] = None):
+    """Epochs the consumer began behind a prefetch producer, by how the
+    producer came to them (get-or-create)."""
+    reg = registry if registry is not None else get_registry()
+    return reg.counter(
+        PREFETCH_EPOCHS_METRIC,
+        "epochs whose first batch the consumer took from a prefetch "
+        "producer (start = cold: the thread was started for the epoch / "
+        "carried: it went on from the previous epoch)",
+        ("start",),
     )
 
 

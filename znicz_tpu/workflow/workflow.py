@@ -14,6 +14,7 @@ device->host syncs happen once per epoch, not per minibatch.
 from __future__ import annotations
 
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, Optional
 
@@ -24,6 +25,7 @@ import numpy as np
 from znicz_tpu.core import prng
 from znicz_tpu.core.logger import Logger
 from znicz_tpu.loader.base import TRAIN, Loader
+from znicz_tpu.loader.prefetch import CarriedEpochs
 from znicz_tpu.nn import evaluator, optimizer
 from znicz_tpu.nn.decision import Decision
 from znicz_tpu.nn.train_state import TrainState
@@ -59,6 +61,38 @@ class _RollbackSignal(Exception):
 class _PreemptSignal(Exception):
     """Internal control flow: a requested stop reached a step boundary
     mid-epoch (the in-flight dispatch has drained)."""
+
+
+def _batch_stager(target_of, put, probe):
+    """``(split, minibatch) -> (split, x, y, mask)`` on the device: the
+    host's target pick and the ``device_put`` calls for one batch.  Run
+    inside the prefetch worker this overlaps the host->device transfer
+    with the previous step's compute (device_put is thread-safe and
+    async).  ``probe`` (an H2DProbe) owns the stage timing + bytes.
+    ``target_of`` is a weak reference to the workflow's
+    ``_batch_target`` (None: the target IS the input), so the thread
+    that runs this keeps no workflow alive."""
+
+    def stage_item(item):
+        split, mb = item
+        # autoencoder target IS the input: reuse the device array
+        # instead of transferring the batch twice
+        y_host = None if target_of is None else target_of()(mb)
+        nbytes = (
+            getattr(mb.data, "nbytes", 0)
+            + getattr(y_host, "nbytes", 0)
+            + getattr(mb.mask, "nbytes", 0)
+        )
+        with probe.measure(nbytes) as transfer:
+            x = put(mb.data)
+            y = x if y_host is None else put(y_host)
+            mask = put(mb.mask)
+            # the landing time is taken beside the loop: nobody here
+            # waits for the copy
+            transfer.watch(x, y, mask)
+        return split, x, y, mask
+
+    return stage_item
 
 
 def _is_additive(name: str) -> bool:
@@ -215,6 +249,9 @@ class Workflow(Logger):
         # scanned epochs' watch vectors, drained at the epoch's metric
         # sync ([n_steps, 2] device arrays, copies started at dispatch)
         self._pending_watch: list = []
+        # the prefetch producer of stepwise epochs, held from one
+        # run_epoch() to the next
+        self._feed: Optional[CarriedEpochs] = None
 
     # ------------------------------------------------------------------
     def _metrics(self, out, y, mask):
@@ -449,6 +486,7 @@ class Workflow(Logger):
         snapshot: Optional[str] = None,
     ) -> None:
         """Create (or resume) the train state and compile the steps."""
+        self._park_producer()
         if seed is not None:
             prng.seed_all(seed)
         if snapshot:
@@ -551,12 +589,36 @@ class Workflow(Logger):
             return mb.data
         raise ValueError(f"unknown target {self.target!r}")
 
+    def _input_state(self) -> Dict[str, Any]:
+        """Loader and prng state at the last epoch boundary.  Between
+        ``run_epoch()`` calls the prefetch producer is already drawing
+        the next epoch, so the loader and its shuffle stream are read
+        from the copy the producer took AT the boundary; with no
+        producer live they read what the loader reads now."""
+        if self._feed is None:
+            return {
+                "loader": self.loader.state_dict(),
+                "prng": prng.state_dict(),
+            }
+        loader_state, stream_state = self._feed.boundary
+        streams = prng.state_dict()
+        streams["generators"][self.loader.rand_name] = stream_state
+        return {"loader": loader_state, "prng": streams}
+
     def host_state(self) -> Dict[str, Any]:
         return {
             "decision": self.decision.state_dict(),
-            "loader": self.loader.state_dict(),
-            "prng": prng.state_dict(),
+            **self._input_state(),
         }
+
+    def _park_producer(self) -> None:
+        """Stop the prefetch producer, drop what it ran ahead and put
+        the loader back to the last epoch boundary (no-op without one).
+        Everything that leaves the ``run_epoch()`` loop, or touches the
+        loader from this thread, comes through here first."""
+        feed, self._feed = self._feed, None
+        if feed is not None:
+            feed.park()
 
     # ------------------------------------------------------------------
     def _use_epoch_scan(self) -> bool:
@@ -679,6 +741,9 @@ class Workflow(Logger):
         except _RollbackSignal as sig:
             self._execute_rollback(sig.reason)
             return None
+        except BaseException:
+            self._park_producer()
+            raise
 
     def _run_epoch_inner(self) -> Optional[Dict[str, Any]]:
         deferred = self.epoch_sync == "deferred"
@@ -750,8 +815,13 @@ class Workflow(Logger):
 
     def sync_epoch(self) -> Optional[Dict[str, Any]]:
         """Flush a deferred epoch's metrics (no-op returning None when
-        nothing is pending).  Call after a ``run_epoch`` loop in deferred
-        mode to observe the final epoch."""
+        nothing is pending).  Call after a ``run_epoch`` loop: in deferred
+        mode to observe the final epoch, and in either mode to park the
+        prefetch producer, which has run ahead into an epoch that this
+        loop will not run (``run()`` parks it at the decision's stop; a
+        ``run_epoch()`` that returns ``stop`` does not, since a caller
+        may go on regardless, as a timed benchmark does)."""
+        self._park_producer()
         if self._pending_accs is None:
             return None
         accs, self._pending_accs = self._pending_accs, None
@@ -774,10 +844,7 @@ class Workflow(Logger):
         correct AFTER the lagged ``on_epoch_end``, and is merged in at save
         time by :meth:`_finish_epoch`."""
         state = jax.tree_util.tree_map(jnp.copy, self.state)
-        return state, {
-            "loader": self.loader.state_dict(),
-            "prng": prng.state_dict(),
-        }
+        return state, self._input_state()
 
     # -- self-healing (docs/TRAINING.md) -------------------------------------
     def request_stop(self) -> None:
@@ -811,6 +878,7 @@ class Workflow(Logger):
         the placement policy) and the decision/loader/prng host state.
         Re-feeds the ALREADY-COMPILED step — shapes/dtypes/structure are
         unchanged, so restoring compiles nothing new (pinned in tier-1)."""
+        self._park_producer()  # before the loader's state is replaced
         st = state if isinstance(state, TrainState) else TrainState(*state)
         if self.parallel is not None:
             st = self.parallel.shard_state(st)
@@ -842,6 +910,7 @@ class Workflow(Logger):
         rollback budget — past it (or with no restore point) the typed
         :class:`RollbackExhaustedError` raises, with the give-up gauge
         set for ``znicz-doctor``."""
+        self._park_producer()
         pol = self.recovery
         step = self._host_step
         # poisoned in-flight bookkeeping dies with the aborted epoch
@@ -908,6 +977,7 @@ class Workflow(Logger):
         epoch-START buffer when stopping mid-epoch so the resume is
         exact; the current state between epochs) and raise the typed
         :class:`TrainingPreempted`."""
+        self._park_producer()
         path = None
         if self.snapshotter is not None:
             if mid_epoch and self._epoch_start is not None:
@@ -956,60 +1026,37 @@ class Workflow(Logger):
             snapshot_path=path,
         )
 
+    def _epoch_batches(self):
+        """One epoch's batches, placed on the device.  With
+        ``prefetch_batches`` a producer thread fetches and places them
+        ahead of the steps, and goes on past the epoch's end: the next
+        call finds its first batches on the device already."""
+        if self.prefetch_batches and self._feed is not None:
+            return self._feed.epoch()
+        self._park_producer()
+        stage_item = _batch_stager(
+            None
+            if self.target == "input"
+            else weakref.WeakMethod(self._batch_target),
+            self.parallel.shard_batch
+            if self.parallel is not None
+            else jnp.asarray,
+            self._h2d_probe,
+        )
+        if not self.prefetch_batches:
+            return map(stage_item, self.loader.epoch())
+        self._feed = CarriedEpochs(
+            self.loader, self.prefetch_batches, stage_item
+        )
+        return self._feed.epoch()
+
     def _run_epoch_stepwise(self) -> Dict[str, jax.Array]:
         accs: Dict[str, jax.Array] = {}  # per-split on-device accumulators
-        put = (
-            self.parallel.shard_batch if self.parallel is not None else jnp.asarray
-        )
-
-        def stage_item(item):
-            """Host gather AND device_put for one batch; run inside the
-            prefetch worker this overlaps the host->device transfer with
-            the previous step's compute (device_put is thread-safe and
-            async).  The H2D probe owns the stage timing + bytes (the
-            prefetch stage split is told NOT to double-time it)."""
-            split, mb = item
-            # autoencoder target IS the input: reuse the device array
-            # instead of transferring the batch twice
-            y_host = (
-                None
-                if self.target == "input"
-                else self._batch_target(mb)
-            )
-            nbytes = (
-                getattr(mb.data, "nbytes", 0)
-                + getattr(y_host, "nbytes", 0)
-                + getattr(mb.mask, "nbytes", 0)
-            )
-            with self._h2d_probe.measure(nbytes) as transfer:
-                x = put(mb.data)
-                y = x if y_host is None else put(y_host)
-                mask = put(mb.mask)
-                # the landing time is taken beside the loop: nobody here
-                # waits for the copy
-                transfer.watch(x, y, mask)
-            return split, x, y, mask
-
-        epoch_iter = self.loader.epoch()
-        if self.prefetch_batches:
-            from znicz_tpu.loader.prefetch import prefetch
-
-            # transform_stage=None: the probe above already observes
-            # the h2d stage — the producer's fetch/enqueue split still
-            # comes from prefetch itself
-            epoch_iter = prefetch(
-                epoch_iter,
-                self.prefetch_batches,
-                transform=stage_item,
-                transform_stage=None,
-            )
-        else:
-            epoch_iter = map(stage_item, epoch_iter)
         # lagged per-step anomaly watch: host copies start at dispatch,
         # values are read a few steps later — detection without a sync
         watch_q: deque = deque()
         t_prev = time.perf_counter()
-        for split, x, y, mask in epoch_iter:
+        for split, x, y, mask in self._epoch_batches():
             if self._preempt_requested:
                 # the previous dispatch is the in-flight step; it
                 # drains on its own — stop BEFORE dispatching another
@@ -1198,6 +1245,7 @@ class Workflow(Logger):
         """
         if self.state is None:
             self.initialize()
+        self._park_producer()  # this pass reads the loader on this thread
         if self.loader.class_lengths.get(split, 0) == 0:
             # evaluating zero samples would report a silent perfect score
             raise ValueError(
@@ -1277,4 +1325,5 @@ class Workflow(Logger):
                     verdict["best_value"],
                     verdict["best_epoch"],
                 )
+                self._park_producer()  # no next epoch to run ahead into
                 return self.decision
