@@ -4,10 +4,11 @@
 would otherwise learn of these pins on the chip: device traces are reduced
 by the programs' names (``jit__paged_decode_chunk`` /
 ``jit__paged_prefill_prog``), ``benchmarks/tests/test_broken_path.py`` and
-``test_axk1_cell.py`` wrap ``engine._paged_decode_chunk`` and unpack its 7
-(classic tower) or 8 (a ``model=`` tower) values, and the drivers and
-``chip_smoke.py`` build ``PagedDecodeEngine`` and ``ServingFrontDoor`` by
-keyword."""
+``test_axk1_cell.py`` / ``test_smallthinker_cell.py`` wrap
+``engine._paged_decode_chunk`` and unpack its 7 (classic tower) or 8 (a
+``model=`` tower) values, the drivers and ``chip_smoke.py`` build
+``PagedDecodeEngine`` and ``ServingFrontDoor`` by keyword, and the readers of
+``smallthinker-serve-mixed-lengths`` read series and scopes by name."""
 
 import ast
 import inspect
@@ -18,6 +19,7 @@ import numpy as np
 import pytest
 
 from test_latent_lm import Toy
+from test_window_gqa_lm import Toy as WindowToy, _series
 from znicz_tpu.core import prng
 from znicz_tpu.services import engine
 from znicz_tpu.services.frontdoor import ServingFrontDoor
@@ -27,6 +29,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUILDERS = (
     "benchmarks/drivers/serve_open_loop.py",
     "benchmarks/drivers/serve_latent_moe.py",
+    "benchmarks/drivers/serve_window_moe.py",
     "chip_smoke.py",
 )
 
@@ -72,12 +75,20 @@ def test_the_traced_programs_keep_their_names(monkeypatch):
         assert f"@jit_{name}" in fn.lower(*shapes, **kwargs).as_text()
 
 
-@pytest.mark.parametrize("tower, values", [("classic", 7), ("latent", 8)])
+TOWERS = {
+    "classic": _classic_engine, "latent": lambda: Toy().engine(),
+    "window": lambda: WindowToy().engine(),
+}
+
+
+@pytest.mark.parametrize(
+    "tower, values", [("classic", 7), ("latent", 8), ("window", 8)]
+)
 def test_the_decode_chunk_returns_what_the_benchmark_unpacks(
     tower, values, monkeypatch
 ):
     assert callable(engine._paged_decode_chunk._cache_size)
-    eng = _classic_engine() if tower == "classic" else Toy().engine()
+    eng = TOWERS[tower]()
     seen = _calls_of_one_served_request(eng, monkeypatch)
     assert len(seen["_paged_decode_chunk"][2]) == values
     assert "paged_chunk_jit_entries" in eng.compile_stats()
@@ -101,3 +112,30 @@ def test_the_builders_keywords_are_accepted():
             accepts[node.func.id].bind(
                 *node.args, **{kw.arg: None for kw in node.keywords}
             )
+
+
+def test_the_window_towers_series_and_scopes_keep_their_names(monkeypatch):
+    """What ``benchmarks/layer_metrics/{attn.*,moe.reglu*,moe.experts_hit*,
+    cache.*,engine.preemptions*}.py`` and ``harness/smallthinker_readers.py``
+    read: the registry series by name and label, and the scopes inside the
+    decode program."""
+    real = engine._paged_decode_chunk
+    seen = _calls_of_one_served_request(WindowToy().engine(), monkeypatch)
+    shapes, kwargs, _ = seen["_paged_decode_chunk"]
+    text = real.lower(*shapes, **kwargs).as_text(debug_info=True)
+    for scope in ("attn_window", "attn_global", "moe_dispatch", "moe_experts"):
+        assert f"/{scope}/" in text, scope
+    for kind in ("global", "window"):
+        assert _series("znicz_serve_decode_cached_rows_total", kind=kind)
+        assert _series("znicz_serve_pool_blocks_in_use", kind=kind)
+    for phase in ("prefill", "decode"):
+        for name in ("pairs", "busiest_pairs", "idle_experts", "layer_steps"):
+            assert _series(f"znicz_serve_moe_{name}_total", phase=phase), name
+    for name in (
+        "znicz_serve_window_blocks_released_total",
+        "znicz_serve_cache_bytes_per_resident_token",
+        "znicz_serve_preemptions_total", "znicz_serve_requests_admitted_total",
+        "znicz_serve_decode_steps_total",
+        "znicz_serve_decode_gathered_tokens_total",
+    ):
+        assert _series(name), name

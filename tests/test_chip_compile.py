@@ -137,6 +137,32 @@ def test_rbm_cd1_compiles_with_the_hardware_prng(chip, batch):
     assert "threefry" not in text
 
 
+@pytest.mark.parametrize(
+    "table_width, bounded", [(1, False), (128, False), (34, True)],
+    ids=["global-rung-1", "global-rung-128", "window-ring"],
+)
+def test_gqa_decode_attention_compiles(chip, table_width, bounded):
+    # smallthinker-21b-l8 as served: 64 slots, 28 query heads padded to 32
+    # rows, [v, k] rows of 1,024 bfloat16 lanes in blocks of 128; a global
+    # layer walks its rung, a window layer its ring turned to start at the
+    # window's first block, with the window's first key as a lower bound
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q_row, pool, tables, lengths, starts):
+        return latent_decode_attention(
+            q_row, pool, tables, lengths, scale=0.0884, d_out=512,
+            starts=starts if bounded else None,
+        )
+
+    _compile(
+        fn, spec((64, 32, 1024), jnp.bfloat16),
+        spec((1600, 128, 1024), jnp.bfloat16),
+        spec((64, table_width), jnp.int32), spec((64,), jnp.int32),
+        spec((64,), jnp.int32),
+    )
+
+
 @pytest.mark.parametrize("window", [1, 4, 96])
 def test_latent_decode_attention_compiles(chip, window):
     # axk1-ep16 as served: 128 slots, 64 heads, 4,096 blocks of 128 rows of

@@ -220,15 +220,15 @@ class TestBlockPool:
         # nothing is decoding, so the whole prompt prefills this tick:
         # both blocks of the padded-16 prompt get allocated
         eng._prefill_tick()
-        used_a = set(eng._row_blocks[0])
+        used_a = set(eng._kinds[0].row_blocks[0])
         assert len(used_a) == 2
         comps = eng.run()
         assert [c.id for c in comps] == [ra]
-        assert len(eng._free) == eng.usable_blocks  # all returned
+        assert len(eng._kinds[0].free) == eng.usable_blocks  # all returned
         rb = eng.submit(pb, 4)
         eng._admit_pending()
         eng._prefill_tick()
-        used_b = set(eng._row_blocks[0])
+        used_b = set(eng._kinds[0].row_blocks[0])
         assert used_b & used_a  # reuse, not fresh allocation
         eng.run()
         np.testing.assert_array_equal(
@@ -247,7 +247,7 @@ class TestBlockPool:
         used = m.children()[("used",)].value
         # gauges are last-setter-wins; this engine allocated last, so
         # they reflect ITS pool: one prompt block out
-        assert used == len(eng._row_blocks[0]) == 1
+        assert used == len(eng._kinds[0].row_blocks[0]) == 1
         assert free == eng.usable_blocks - 1
         assert free + used == eng.usable_blocks
         eng.run()
@@ -268,12 +268,12 @@ class TestBlockPool:
         eng.submit(p, 20)
         eng._admit_pending()
         eng._prefill_tick()
-        n0 = len(eng._row_blocks[0])
+        n0 = len(eng._kinds[0].row_blocks[0])
         assert n0 == 1  # prompt block only — nothing reserved for decode
         eng._run_chunk()
-        assert len(eng._row_blocks[0]) >= n0  # grew on demand
+        assert len(eng._kinds[0].row_blocks[0]) >= n0  # grew on demand
         eng.run()
-        assert len(eng._free) == eng.usable_blocks
+        assert len(eng._kinds[0].free) == eng.usable_blocks
 
 
 class TestPreemption:

@@ -72,10 +72,10 @@ def _assert_no_leaks(eng):
     null block, and no refcount is outstanding."""
     assert eng.active == 0 and eng.prefilling == 0 and eng.pending == 0
     eng.flush_prefix_cache()
-    assert len(eng._cache) == 0 and len(eng._block_hash) == 0
-    assert len(eng._lru) == 0
-    assert sorted(eng._free) == list(range(1, eng.n_blocks))
-    assert (eng._ref == 0).all()
+    assert len(eng._kinds[0].cache) == 0 and len(eng._kinds[0].block_hash) == 0
+    assert len(eng._kinds[0].lru) == 0
+    assert sorted(eng._kinds[0].free) == list(range(1, eng.n_blocks))
+    assert (eng._kinds[0].ref == 0).all()
 
 
 def _tokens(rng, n):
@@ -155,13 +155,13 @@ class TestSharedPrefix:
         eng = _engine(params, batch_size=1)
         eng.submit(np.concatenate([s, _tokens(rng, 3)]), 4)
         eng.run()
-        cached = len(eng._lru)
+        cached = len(eng._kinds[0].lru)
         assert cached >= 2  # S's blocks are cache-only now
         eng.submit(np.concatenate([s, _tokens(rng, 6)]), 4)
         eng._admit_pending()
-        row = eng._row_blocks[0]
+        row = eng._kinds[0].row_blocks[0]
         assert len(row) == 2  # mapped, not allocated: tail not yet run
-        assert all(eng._ref[b] == 1 for b in row)
+        assert all(eng._kinds[0].ref[b] == 1 for b in row)
         eng.run()
         _assert_no_leaks(eng)
 
@@ -241,9 +241,9 @@ class TestCopyOnWrite:
         eng.submit(p, 8)
         eng._admit_pending()
         eng._prefill_tick()  # admitted: block 0 holds the prompt K/V
-        blk = int(eng._row_blocks[0][0])
-        eng._cache[b"eager-fill"] = blk
-        eng._block_hash[blk] = b"eager-fill"
+        blk = int(eng._kinds[0].row_blocks[0][0])
+        eng._kinds[0].cache[b"eager-fill"] = blk
+        eng._kinds[0].block_hash[blk] = b"eager-fill"
         eng.run()
         comp = next(iter(eng.completions.values()))
         np.testing.assert_array_equal(
@@ -396,7 +396,7 @@ class TestLeakSweep:
         st = eng.stats()["prefix_cache"]
         assert not st["enabled"]
         assert st["hits"] == st["cached_tokens"] == 0
-        assert len(eng._free) == eng.usable_blocks
+        assert len(eng._kinds[0].free) == eng.usable_blocks
         _assert_no_leaks(eng)
 
 

@@ -75,9 +75,9 @@ def _compiles_total():
 def _assert_no_leaks(eng):
     assert eng.active == 0 and eng.prefilling == 0 and eng.pending == 0
     eng.flush_prefix_cache()
-    assert len(eng._lru) == 0
-    assert sorted(eng._free) == list(range(1, eng.n_blocks))
-    assert (eng._ref == 0).all()
+    assert len(eng._kinds[0].lru) == 0
+    assert sorted(eng._kinds[0].free) == list(range(1, eng.n_blocks))
+    assert (eng._kinds[0].ref == 0).all()
 
 
 class OracleDrafter:
@@ -226,26 +226,26 @@ class TestRollback:
         )
         rid = eng.submit(p, 30)
         # drive tick by tick so we can observe mid-stream state
-        free0 = len(eng._free)
+        free0 = len(eng._kinds[0].free)
         while eng._has_work():
             eng._admit_pending()
             eng._prefill_tick()
             if eng.active:
                 eng._run_chunk()
-            row = eng._row_blocks[0]
+            row = eng._kinds[0].row_blocks[0]
             # invariant after every tick: the table NEVER keeps blocks
             # past the valid-KV prefix + 0 or 1 in-progress block
             if eng._slots[0] is not None and eng._slots[0]["mode"] == "decode":
                 keep = (int(eng._pos[0]) - 1) // BS + 1
                 assert len(row) == keep
                 assert all(
-                    int(eng._tables[0, j]) == row[j]
+                    int(eng._kinds[0].tables[0, j]) == row[j]
                     for j in range(len(row))
                 )
         assert np.array_equal(
             eng.completions[rid].tokens, _reference(params, p, 30)
         )
-        assert len(eng._free) == free0
+        assert len(eng._kinds[0].free) == free0
         _assert_no_leaks(eng)
 
     def test_preemption_under_spec_pressure_stays_golden(self):

@@ -285,12 +285,12 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
 def test_a_sliced_head_gives_the_matching_columns_of_the_whole_head(toy):
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.standard_normal((5, 64)), jnp.float32)
-    whole = toy.model._logits(toy.params, x)
+    whole = latent_lm._head_logits(toy.params, x, toy.model.rms_eps)
     for lo in (0, 64, 192):
         sliced = list(toy.params)
         sliced[-1] = dict(toy.params[-1], head=toy.params[-1]["head"][:, lo:lo + 64])
         np.testing.assert_allclose(
-            toy.model._logits(sliced, x), whole[:, lo:lo + 64], rtol=1e-5, atol=1e-6
+            latent_lm._head_logits(sliced, x, toy.model.rms_eps), whole[:, lo:lo + 64], rtol=1e-5, atol=1e-6
         )
 
 
@@ -425,9 +425,9 @@ def test_the_decode_write_guard_copies_a_shared_latent_block(toy):
     eng.submit(_tokens(rng, 5), 8)
     eng._admit_pending()
     eng._prefill_tick()
-    blk = int(eng._row_blocks[0][0])
-    eng._cache[b"eager-fill"] = blk
-    eng._block_hash[blk] = b"eager-fill"
+    blk = int(eng._kinds[0].row_blocks[0][0])
+    eng._kinds[0].cache[b"eager-fill"] = blk
+    eng._kinds[0].block_hash[blk] = b"eager-fill"
     eng.run()
     completion = next(iter(eng.completions.values()))
     assert toy.served_gaps(completion).max() < 1e-4

@@ -343,3 +343,128 @@ def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypat
             }
         )
         assert not gathered, f"the decode chunk still holds a window: {gathered}"
+
+
+# -- the two kinds of pool of a WindowGQAMoEModel at smallthinker-21b-l8's --
+#
+# 8 layers (global, window, window, window, twice) at hidden 2560, 28 query
+# heads of 128 over 4 K/V heads, 64 experts of width 768 all held, the whole
+# vocabulary of 151936, bfloat16; 64 slots, block 128, 4096 global and 1600
+# window blocks of [v, k] rows (1024 lanes: whole tiles), the widest global
+# rung (128 blocks) beside the window kind's ring of 34.  My AOT compiles,
+# PR 31: 12.60 GB of arguments; the decode chunk holds 0.19 GB of
+# temporaries (0.76 while the six window layers gathered their rings: one
+# ring of 64 x 4352 x 1024 bf16 is 0.57), the prefill chunk 0.28; 24
+# grouped products, and in a decode chunk every layer's attention as the
+# kernel that reads the pool in place.
+
+ST = dict(d=2560, heads=28, kv_heads=4, dim=128, f=768, experts=64,
+          vocab=151936, layers=8, slots=64, block=128, rung=128, ring=34,
+          n_blocks={"global": 4096, "window": 1600})
+
+
+def _smallthinker_model():
+    from znicz_tpu.workflow.window_lm import WindowGQAMoEModel
+
+    return WindowGQAMoEModel(
+        n_heads=ST["heads"], n_kv_heads=ST["kv_heads"], head_dim=ST["dim"],
+        top_k=6, window=4096, windowed=(False, True, True, True) * 2,
+        max_positions=16384, rope_theta=1.5e6,
+    )
+
+
+def _smallthinker_params(spec):
+    a, bf, f32 = ST, jnp.bfloat16, jnp.float32
+    d, q, kv = a["d"], a["heads"] * a["dim"], a["kv_heads"] * a["dim"]
+    e, f = a["experts"], a["f"]
+    leaves = {
+        "attn_norm": ((d,), f32), "wq": ((d, q), bf), "wk": ((d, kv), bf),
+        "wv": ((d, kv), bf), "wo": ((q, d), bf), "ffn_norm": ((d,), f32),
+        "router": ((d, e), bf), "experts_gate": ((e, d, f), bf),
+        "experts_up": ((e, d, f), bf), "experts_down": ((e, f, d), bf),
+    }
+    return (
+        [{"embed": spec((a["vocab"], d), bf)}]
+        + [{k: spec(*v) for k, v in leaves.items()} for _ in range(a["layers"])]
+        + [{"final_norm": spec((d,), f32), "head": spec((d, a["vocab"]), bf)}]
+    )
+
+
+SMALLTHINKER_TEMP_LIMIT_GB = {"decode_chunk": 0.4, "prefill": 0.5}
+
+
+@pytest.mark.parametrize("program", list(SMALLTHINKER_TEMP_LIMIT_GB))
+def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
+    chip, program, monkeypatch
+):
+    from znicz_tpu.core import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    a, model = ST, _smallthinker_model()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = _smallthinker_params(spec)
+    pools = jax.tree.map(
+        lambda p: spec(p.shape, p.dtype),
+        jax.eval_shape(
+            lambda: model.init_pools(params, a["n_blocks"], a["block"])
+        ),
+    )
+    assert [p["kv"].shape[0] for p in pools] == [4096, 1600, 1600, 1600] * 2
+    assert pools[0]["kv"].shape[1:] == (a["block"], 1024)
+    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
+    tower = dict(
+        n_heads=a["heads"], block_size=a["block"], moe_top_k=1,
+        moe_dispatch="dense", model=model,
+    )
+    with jax.default_matmul_precision("default"):
+        if program == "decode_chunk":
+            tables = {
+                "global": spec((a["slots"], a["rung"]), i32),
+                "window": spec((a["slots"], a["ring"]), i32),
+            }
+            lowered = engine._paged_decode_chunk.lower(
+                params, pools, tables, rows_i32, rows_i32,
+                spec((a["slots"],), jnp.bool_), rows_i32, scalar_f32,
+                scalar_f32, key, chunk=CHUNK, t_max=16384, eos_id=0,
+                **SAMPLING, **tower,
+            )
+        else:
+            table = {
+                "global": spec((a["rung"],), i32),
+                "window": spec((a["ring"],), i32),
+            }
+            lowered = engine._paged_prefill_prog.lower(
+                params, pools, table, spec((1, a["block"]), i32), scalar_i32,
+                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+            )
+        compiled = lowered.compile()  # raises where the chip would refuse it
+    text = compiled.as_text()
+    row = a["block"] * 1024
+    ring = a["slots"] * a["ring"] * row
+    # no pool is copied or re-tiled, no gathered ring is (a slice of one,
+    # the value half, is a copy of half of it)
+    moved = _relayouts(text, 4096 * row, 1600 * row, ring, ring // 2)
+    assert not moved, f"{program} moves a pool or a ring: {sorted(set(moved))}"
+    assert not re.search(r"= bf16\[64,4352,512\]\S* (slice|fusion)\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < SMALLTHINKER_TEMP_LIMIT_GB[program] * GB, (
+        f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
+    )
+    # weights 7.93 GB + pools 4.67 GB + temporaries fit 15.75 GiB; the three
+    # grouped products of every layer are the kernel, and in a decode step
+    # every layer's attention is one too (no gathered window, no ring)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    kernels = text.count("tpu_custom_call")
+    assert kernels >= (4 if program == "decode_chunk" else 3) * a["layers"]
+    if program == "decode_chunk":
+        gathered = (a["slots"] * a["rung"] * row, ring)
+        assert not [
+            dims for dims in _SHAPE.findall(text)
+            if int(np.prod([int(d) for d in dims.split(",")])) in gathered
+        ], "the decode chunk gathers a window or a ring"

@@ -137,6 +137,191 @@ def paged_attention(
     return out.astype(q.dtype)
 
 
+def gqa_cache_row(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """The row :func:`paged_gqa_attention` caches for one token of one
+    layer: ``[v (G * D), k (G * D)]`` from ``k``, ``v`` [..., G, D]."""
+    lead = k.shape[:-2]
+    return jnp.concatenate(
+        [v.reshape(lead + (-1,)), k.reshape(lead + (-1,))], axis=-1
+    )
+
+
+def ring_key_positions(
+    width: int, block_size: int, q_pos: jnp.ndarray
+) -> jnp.ndarray:
+    """Absolute positions [B, width * block_size] of the keys a RING
+    table of ``width`` entries names, for rows whose queries lie in block
+    ``q_pos // block_size``: entry ``j`` holds the newest block ``b`` of
+    the row with ``b % width == j`` and ``b`` not past the queries' own.
+    An entry no block has reached yet comes out negative."""
+    q_blk = (q_pos // block_size)[:, None]
+    entry = jnp.arange(width)[None, :]
+    blk = q_blk - (q_blk - entry) % width
+    pos = blk[:, :, None] * block_size + jnp.arange(block_size)[None, None, :]
+    return pos.reshape(q_pos.shape[0], width * block_size)
+
+
+def paged_gqa_attention(
+    q: jnp.ndarray,  # [B, Tq, H, D]
+    pool: jnp.ndarray,  # [N_blocks, block_size, 2 * G * D]: [v, k] a token
+    block_table: jnp.ndarray,  # [B, M] int32 pool block ids
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    *,
+    block_size: int,
+    n_kv_heads: int,
+    window: Optional[int] = None,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+    scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """GROUPED-QUERY attention over a paged pool: ``H`` query heads read
+    ``G = n_kv_heads`` cached K/V heads, query head ``h`` the head ``h //
+    (H / G)``; returns [B, Tq, H * D] float32.
+
+    A cached token is ONE row ``[v (G * D), k (G * D)]``
+    (:func:`gqa_cache_row`): key and value are one fetch, and at ``G * D``
+    = 512 each half is whole 128-lane tiles (the layout rule of
+    :func:`paged_attention`).  Queries are laid out as there: row ``(t,
+    h)`` holds ``q[b, t, h]`` in the key columns of ITS K/V head and zeros
+    elsewhere, so one product against the rows as stored gives every
+    head's scores (the zeros add exactly), the weighted sum runs over the
+    value half, and row ``(t, h)`` keeps its head's columns of it.  That
+    is the form :func:`~znicz_tpu.ops.pallas.latent_attention
+    .latent_decode_attention` computes, so on the TPU a decode step
+    (``Tq`` 1) reads the pool IN PLACE through it, as far as each row's
+    length (a ring is first turned so that the window's first block
+    leads, :func:`_window_in_table_order`, and the kernel is told where
+    in that block the window starts); everything else gathers ``pool
+    [block_table]`` at the table's width and computes on the copy.
+
+    Validity is by ABSOLUTE key index.  ``window`` None: the table is
+    plain (entry ``j`` covers positions ``j * block_size ..``) and key
+    ``k`` is visible iff ``k <= q_pos``.  ``window`` W: the table is a
+    RING (:func:`ring_key_positions`; a call's queries of one row lie in
+    one block) and key ``k`` is visible iff ``q_pos - W < k <= q_pos``:
+    the last ``W`` keys, the query's own among them.  ``lengths`` is a
+    decode step's: 0 marks a row that idles, whose result is zeros.
+    Products take the pool's dtype and accumulate in float32; scores and
+    softmax are float32.
+    """
+    with jax.named_scope("attn_global" if window is None else "attn_window"):
+        return _paged_gqa_attention(
+            q, pool, block_table, q_pos, block_size=block_size,
+            n_kv_heads=n_kv_heads, window=window, lengths=lengths,
+            scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+        )
+
+
+def _window_in_table_order(block_table, lengths, *, block_size, window):
+    """A decode step's view of a RING table for the kernel that walks a
+    table from its first entry: ``(table, lengths, starts)`` with the
+    ring turned so that the block holding the window's first key leads,
+    ``lengths`` [B] counted from that block's first row (0 stays 0) and
+    ``starts`` [B] the window's first key within it.  ``lengths`` coming
+    in is ``position + 1``."""
+    width = block_table.shape[1]
+    first_key = jnp.maximum(lengths - window, 0)
+    first_blk = first_key // block_size
+    turned = jnp.take_along_axis(
+        block_table, (first_blk[:, None] + jnp.arange(width)[None, :]) % width,
+        axis=1,
+    )
+    base = first_blk * block_size
+    return turned, jnp.maximum(lengths - base, 0), first_key - base
+
+
+def paged_gqa_rows_read(
+    block_table: jnp.ndarray,  # [B, M]
+    lengths: jnp.ndarray,  # [B] int32: position + 1; 0: the row idles
+    *,
+    block_size: int,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """Cached rows ONE layer's grouped-query attention reads in a decode
+    step (int32 scalar), by the form that runs here: in place, each row's
+    keys from the first block it attends, rounded up to whole blocks;
+    gathered, every slot's table."""
+    if not _reads_pool_in_place(1):
+        return jnp.int32(block_table.size * block_size)
+    if window is not None:
+        _, lengths, _ = _window_in_table_order(
+            block_table, lengths, block_size=block_size, window=window
+        )
+    return jnp.sum(-(-lengths // block_size) * block_size, dtype=jnp.int32)
+
+
+def _paged_gqa_attention(
+    q, pool, block_table, q_pos, *, block_size, n_kv_heads, window, lengths,
+    scale,
+):
+    b, tq, h, d = q.shape
+    g, half = n_kv_heads, n_kv_heads * d
+    dtype = pool.dtype
+    f32 = dict(preferred_element_type=jnp.float32)
+    if pool.shape[-1] != 2 * half:
+        raise ValueError(
+            f"a cached row is [v, k] of {n_kv_heads} heads x {d}: want "
+            f"{2 * half} lanes, the pool has {pool.shape[-1]}"
+        )
+    if lengths is not None and tq != 1:
+        raise ValueError(
+            f"lengths are a decode step's; got {tq} queries a row"
+        )
+    # [B, Tq, G, H/G, G', D] -> [B, Tq * H, G' * D], zero where G' != G
+    q_keys = (
+        q.astype(dtype).reshape(b, tq, g, h // g, 1, d)
+        * jnp.eye(g, dtype=dtype)[:, None, :, None]
+    ).reshape(b, tq * h, half)
+    q_row = jnp.concatenate([jnp.zeros_like(q_keys), q_keys], axis=-1)
+    if _reads_pool_in_place(tq):
+        table, keys, starts = block_table, lengths, None
+        if keys is None:
+            keys = q_pos[:, 0] + 1
+        if window is not None:
+            table, keys, starts = _window_in_table_order(
+                table, keys, block_size=block_size, window=window
+            )
+        # the kernel wants whole (16, 128) tiles of query rows
+        pad = -h % 16
+        o = latent_decode_attention(
+            jnp.pad(q_row, ((0, 0), (0, pad), (0, 0))), pool, table, keys,
+            scale=scale, d_out=half, starts=starts,
+        )[:, :h].astype(jnp.float32)
+    else:
+        n_keys = block_table.shape[1] * block_size
+        rows = pool[block_table].reshape(b, n_keys, 2 * half)
+        if window is None:
+            k_pos = jnp.arange(n_keys)[None, :]
+        else:
+            k_pos = ring_key_positions(
+                block_table.shape[1], block_size, q_pos[:, -1]
+            )
+        k_pos, at = k_pos[:, None, None, :], q_pos[:, :, None, None]
+        valid = (k_pos <= at) & (k_pos >= 0)
+        if window is not None:
+            valid = valid & (k_pos > at - window)
+        s = jnp.einsum("bre,bke->brk", q_row, rows, **f32)
+        s = jnp.where(valid, s.reshape(b, tq, h, n_keys) * scale, -jnp.inf)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(dtype)
+        # over the whole row: a slice of the gathered rows is a copy of
+        # them, and the compiler turns a slice of this result into one
+        o = jnp.einsum(
+            "brk,bke->bre", p.reshape(b, tq * h, n_keys), rows, **f32
+        )
+    # row (t, h) keeps the value columns of its own K/V head, picked by a
+    # product with 0 / 1 (exact at HIGHEST, and small) rather than sliced
+    lane = jnp.arange(o.shape[-1])[None, :, None]
+    own = (jnp.arange(h) // (h // g) * d)[:, None, None] + jnp.arange(d)
+    o = jnp.einsum(
+        "bthe,hed->bthd", o.reshape(b, tq, h, -1),
+        (lane == own).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ).reshape(b, tq, h * d)
+    if lengths is not None:  # an idle row's result is zeros in either form
+        o = jnp.where(lengths[:, None, None] > 0, o, 0.0)
+    return o
+
+
 def paged_latent_attention(
     q_nope: jnp.ndarray,  # [B, Tq, H, d_nope]
     q_rope: jnp.ndarray,  # [B, Tq, H, d_rope], rotated

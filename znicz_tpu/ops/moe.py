@@ -288,6 +288,40 @@ def route_sigmoid_topk(
     return idx.astype(jnp.int32), top * scale
 
 
+def route_softmax_topk(
+    h: jnp.ndarray,  # [T, D]
+    router: jnp.ndarray,  # [D, E]
+    *,
+    top_k: int,
+    normalize: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Softmax-scored routing over ALL ``E`` experts: ``(chosen [T, k]
+    int32, weights [T, k] float32)``.  ``normalize`` takes the softmax
+    over the chosen logits alone (the weights of a token sum to 1);
+    without it the weights are the chosen experts' shares of the softmax
+    over all ``E``.  Scores are float32."""
+    logits = jnp.dot(h, router, preferred_element_type=jnp.float32)
+    top, idx = jax.lax.top_k(logits, top_k)
+    if normalize:
+        weight = jax.nn.softmax(top, axis=-1)
+    else:
+        weight = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1, keepdims=True))
+    return idx.astype(jnp.int32), weight
+
+
+def _tile(limit: int, dim: int) -> int:
+    """The widest tile of whole 128-lane columns that is at most ``limit``
+    and divides ``dim`` (the kernel masks the remainder of a tile that
+    does not, on every visit); ``dim`` itself where it is narrower, and
+    ``limit`` where nothing divides."""
+    if dim <= limit:
+        return dim
+    for tile in range(limit - limit % 128, 0, -128):
+        if dim % tile == 0:
+            return tile
+    return limit
+
+
 def grouped_matmul(
     lhs: jnp.ndarray,  # [M, K], rows sorted by group
     rhs: jnp.ndarray,  # [G, K, N]
@@ -304,8 +338,9 @@ def grouped_matmul(
 
         m, k = lhs.shape
         n = rhs.shape[2]
-        tiling = tuple(
-            min(t, d) for t, d in zip(GMM_TILING, (m, k, n))
+        tiling = (
+            min(GMM_TILING[0], m), _tile(GMM_TILING[1], k),
+            _tile(GMM_TILING[2], n),
         )
         return gmm(
             lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
@@ -326,12 +361,16 @@ def held_experts_apply(
     *,
     first_expert: int,
     row_mask: Optional[jnp.ndarray] = None,  # [T] bool
+    activation=jax.nn.silu,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The part of ``sum_e w_e down_e(silu(gate_e h) * up_e h)`` that the
+    """The part of ``sum_e w_e down_e(act(gate_e h) * up_e h)`` that the
     experts ``[first_expert, first_expert + G)`` give: ``(y [T, D]
     float32, pairs [G] int32)`` where ``pairs[g]`` counts the (token,
-    choice) pairs expert ``g`` computed.  Rows with ``row_mask`` False
-    (idle slots, padding) are routed nowhere.
+    choice) pairs expert ``g`` computed.  ``activation`` is the gate's
+    (``silu``: SwiGLU; ``relu``: ReGLU).  Rows with ``row_mask`` False
+    (idle slots, padding) are routed nowhere.  With ``first_expert`` 0
+    and ``G`` the router's width every expert is held here and the part
+    is the whole.
 
     Dropless sort dispatch: the ``T * k`` pairs are stably sorted by held
     expert (pairs of experts held elsewhere sort last, into no group), the
@@ -352,7 +391,7 @@ def held_experts_apply(
     )
     rows = h[order // k]  # [T * k, D], sorted by expert
     with jax.named_scope("moe_experts"):
-        act = jax.nn.silu(
+        act = activation(
             grouped_matmul(rows, gate, pairs)
         ) * grouped_matmul(rows, up, pairs)
         out = grouped_matmul(act.astype(h.dtype), down, pairs)

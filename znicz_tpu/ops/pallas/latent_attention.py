@@ -13,6 +13,12 @@ DMA through the block table, as far as the row's length and no further:
   flattened block table and ``next_live`` (the next slot with any length)
   are scalar-prefetch operands.  A slot of length 0 fetches nothing and
   writes zeros.
+- **A lower bound** (``starts``, optional): the first key of a row's
+  table that it attends, for a row that reads only the last keys of what
+  its table names (a window layer: the table starts at the window's first
+  block and the window's first key lies inside it).  It is one more
+  scalar-prefetch operand and one more comparison in the mask; without it
+  the kernel is what it was.
 - **A live slot** walks its keys in chunks of ``CHUNK_BLOCKS`` blocks,
   double-buffered: while chunk ``i`` is computed, chunk ``i + 1`` (or the
   first chunk of the next live slot, so that no row starts behind an
@@ -35,6 +41,7 @@ with the caller.  Measured on the v5e against the library's
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,16 +60,19 @@ CHUNK_BLOCKS = 8
 NEG_INF = -1e30
 
 
-def _decode_kernel(
-    lengths_ref, tables_ref, next_ref,  # scalar prefetch
-    q_ref,  # [H, W]
-    pool_ref,  # [N, block_size, W], in HBM
-    o_ref,  # [H, d_out]
-    buf, sems,  # [2, chunk_keys, W], DMA semaphore a buffer
-    state,  # SMEM [2]: the buffer the next chunk lands in; is it on its way
-    m_s, l_s, acc_s,  # [H, 1], [H, 1], [H, d_out] float32
-    *, scale, block_size, chunk_blocks, table_width,
-):
+def _decode_kernel(*refs, scale, block_size, chunk_blocks, table_width,
+                   bounded):
+    """``refs``: the scalar-prefetch operands ``lengths, tables, next_live``
+    (and ``starts`` when ``bounded``), then ``q`` [H, W], the pool [N,
+    block_size, W] in HBM, ``o`` [H, d_out], and the scratch: ``buf`` [2,
+    chunk_keys, W] with a DMA semaphore a buffer, ``state`` (SMEM [2]: the
+    buffer the next chunk lands in; is it on its way), ``m``, ``l`` [H, 1]
+    and ``acc`` [H, d_out] float32."""
+    lengths_ref, tables_ref, next_ref = refs[:3]
+    start_ref = refs[3] if bounded else None
+    (q_ref, pool_ref, o_ref, buf, sems, state, m_s, l_s, acc_s) = refs[
+        3 + bounded:
+    ]
     b, n_rows = pl.program_id(0), pl.num_programs(0)
     length = lengths_ref[b]
     chunk_keys = chunk_blocks * block_size
@@ -130,7 +140,12 @@ def _decode_kernel(
             key = i * chunk_keys + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
-            s = jnp.where(key < length, s, NEG_INF)
+            seen = key < length
+            if bounded:
+                # the bound lies inside the first chunk, which therefore
+                # still holds a key the row attends
+                seen = seen & (key >= start_ref[b])
+            s = jnp.where(seen, s, NEG_INF)
             m_prev = m_s[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             # a chunk that is walked holds at least one key under the
@@ -159,14 +174,17 @@ def latent_decode_attention(
     *,
     scale: float,
     d_out: int,
+    starts: Optional[jnp.ndarray] = None,  # [B] int32, < one chunk of keys
 ) -> jnp.ndarray:
     """``softmax(scale * q_row @ rows^T) @ rows[:, :d_out]`` over each
-    row's first ``lengths`` cached rows, found through its block table;
+    row's first ``lengths`` cached rows, found through its block table
+    (from its ``starts``-th on, where given: ``starts < lengths`` for a
+    live row, and inside the table's first ``CHUNK_BLOCKS`` blocks);
     ``[B, H, d_out]`` in the pool's dtype.  A row of length 0 gives
     zeros.  ``d_out`` is a whole number of 128-lane tiles (or ``W``)."""
     return _attend(
-        q_row, pool, block_table, lengths, scale=float(scale), d_out=d_out,
-        chunk_blocks=min(CHUNK_BLOCKS, block_table.shape[1]),
+        q_row, pool, block_table, lengths, starts, scale=float(scale),
+        d_out=d_out, chunk_blocks=min(CHUNK_BLOCKS, block_table.shape[1]),
         interpret=backend.pallas_interpret(),
     )
 
@@ -179,7 +197,8 @@ def latent_decode_attention(
     jax.jit, static_argnames=("scale", "d_out", "chunk_blocks", "interpret")
 )
 def _attend(
-    q_row, pool, block_table, lengths, *, scale, d_out, chunk_blocks, interpret
+    q_row, pool, block_table, lengths, starts, *, scale, d_out, chunk_blocks,
+    interpret,
 ):
     b, h, w = q_row.shape
     _, block_size, _ = pool.shape
@@ -193,13 +212,17 @@ def _attend(
     def row(i, *_):
         return (i, 0, 0)
 
+    bounded = starts is not None
+    prefetch = (
+        lengths, block_table.reshape(-1).astype(jnp.int32), next_live,
+    ) + ((starts.astype(jnp.int32),) if bounded else ())
     return pl.pallas_call(
         partial(
             _decode_kernel, scale=scale, block_size=block_size,
-            chunk_blocks=chunk_blocks, table_width=m,
+            chunk_blocks=chunk_blocks, table_width=m, bounded=bounded,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((None, h, w), row),
@@ -221,7 +244,4 @@ def _attend(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(
-        lengths, block_table.reshape(-1).astype(jnp.int32), next_live,
-        q_row, pool,
-    )
+    )(*prefetch, q_row, pool)

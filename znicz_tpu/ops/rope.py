@@ -1,4 +1,4 @@
-"""Rotary position embedding with YaRN-scaled frequencies.
+"""Rotary position embedding: plain frequencies, or YaRN-scaled ones.
 
 Pairs are split by halves: ``(a[..., i], a[..., i + half])`` turn together
 (the layout that keeps both operands of the rotation contiguous on the
@@ -11,6 +11,12 @@ from __future__ import annotations
 import math
 
 import jax.numpy as jnp
+
+
+def plain_inv_freq(dim: int, base: float) -> jnp.ndarray:
+    """[dim / 2] float32 frequencies ``base ** (-2i / dim)``: rotary
+    positions over a whole head of ``dim``, with no scaling."""
+    return 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
 
 
 def yarn_inv_freq(
@@ -33,7 +39,7 @@ def yarn_inv_freq(
     high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
     if low == high:
         high += 0.001
-    freqs = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    freqs = plain_inv_freq(dim, base)
     keep = 1 - jnp.clip(
         (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1
     )

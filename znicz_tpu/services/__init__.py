@@ -19,6 +19,7 @@ from znicz_tpu.services.engine import (  # noqa: F401
 )
 from znicz_tpu.services.errors import (  # noqa: F401
     EngineClosedError,
+    PrefixCacheUnsupportedError,
     RejectedError,
     RequestTooLargeError,
     SpeculationUnsupportedError,

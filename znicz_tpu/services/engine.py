@@ -9,7 +9,10 @@ rows keep decoding.  K/V live in a shared block pool (``[n_blocks,
 block_size, H*hd]`` per layer: heads merged, so the minor dimensions
 fill the TPU's (8, 128) tiles and no program re-tiles a pool —
 tests/test_paged_layout_aot.py) and each slot owns a block table over
-REFCOUNTED blocks.  Admission maps the longest prefix of the prompt
+REFCOUNTED blocks — one table for every layer, or one for each KIND of
+cached state where the tower declares kinds (window layers beside global
+ones: :class:`_BlockKind`; the window kind gives blocks back while the
+row lives).  Admission maps the longest prefix of the prompt
 already in the content-hash PREFIX CACHE (chained block hashes — an
 implicit radix structure; retiring and preempted requests publish their
 completed full blocks) and chunk-prefills only the uncached tail; shared
@@ -52,7 +55,7 @@ import hashlib
 import time
 from collections import OrderedDict, deque
 from functools import partial
-from typing import Deque, Dict, List, Optional, Sequence
+from typing import Deque, Dict, List, Mapping, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +64,7 @@ import numpy as np
 from znicz_tpu import observability
 from znicz_tpu.observability import device as device_telemetry
 from znicz_tpu.services.errors import (
+    PrefixCacheUnsupportedError,
     RequestTooLargeError,
     SpeculationUnsupportedError,
 )
@@ -68,6 +72,7 @@ from znicz_tpu.utils import faults, profiling
 from znicz_tpu.workflow.generate import (
     DEFAULT_SPEC_BUCKETS,
     NULL_BLOCK,
+    CacheKind,
     PromptLookupDrafter,
     _check_sampling_args,
     _filter_logits,
@@ -454,6 +459,142 @@ def _paged_verify_prog(
     return pools, out, n_accept
 
 
+class _BlockKind:
+    """The block allocator's state for ONE kind of cached state
+    (:class:`~znicz_tpu.workflow.generate.CacheKind`): which pool blocks
+    are free, how many tables reference each, the prefix cache's maps, and
+    a block table a row.  The engine holds one of these for each kind its
+    tower declares (a tower that declares none has one, every layer's) and
+    runs the same allocation, release and preemption through each.
+
+    A row's blocks cover a contiguous run of ABSOLUTE block indices
+    ``[row_base, row_base + len(row_blocks))``; the block of index ``b``
+    sits at table entry ``b % width``.  A kind that keeps every token has
+    ``row_base`` 0 and a table as wide as the longest row, so the entry IS
+    the index.  A WINDOW kind gives back the blocks that lie wholly behind
+    the last ``window`` keys of the row's next query, ``row_base`` moves
+    up and the table is a ring just wide enough that no two live blocks
+    meet in an entry: the window's blocks, the block being written, and
+    the blocks one decode chunk can add before the host looks again."""
+
+    def __init__(self, kind: CacheKind, *, n_blocks: Optional[int],
+                 batch_size: int, row_blocks: int, block_size: int,
+                 chunk: int):
+        self.name, self.window = kind.name, kind.window
+        self.block_size = block_size
+        self.width = row_blocks if kind.window is None else min(
+            row_blocks, -(-(kind.window + chunk - 2) // block_size) + 1
+        )
+        # default: every slot could hold its widest table (plus the
+        # reserved null block); shrink it to save memory
+        self.n_blocks = int(
+            n_blocks if n_blocks is not None else batch_size * self.width + 1
+        )
+        # LIFO free list: a just-freed (still cache/HBM-warm) block is
+        # the next one handed out; block 0 stays reserved as null
+        self.free: List[int] = list(range(1, self.n_blocks))
+        # per-block refcount = how many tables reference it; the cache
+        # reference is tracked separately by block_hash membership
+        self.ref = np.zeros(self.n_blocks, np.int64)
+        # prefix cache: chained content hash -> block, its inverse, and
+        # an LRU over CACHE-ONLY blocks (refcount 0: evictable)
+        self.cache: Dict[bytes, int] = {}
+        self.block_hash: Dict[int, bytes] = {}
+        self.lru: OrderedDict = OrderedDict()
+        self.row_blocks: List[List[int]] = [[] for _ in range(batch_size)]
+        self.row_base = [0] * batch_size
+        self.tables = np.full((batch_size, self.width), NULL_BLOCK, np.int32)
+        self.block_bytes = 0  # a block's footprint over this kind's layers
+        self.n_released = 0
+
+    @property
+    def usable(self) -> int:
+        """Capacity available to requests (null block excluded)."""
+        return self.n_blocks - 1
+
+    @property
+    def allocatable(self) -> int:
+        return len(self.free) + len(self.lru)
+
+    @property
+    def referenced(self) -> int:
+        return self.usable - self.allocatable
+
+    def first_needed(self, pos: int) -> int:
+        """The oldest block index a query at ``pos`` still reads."""
+        if self.window is None:
+            return 0
+        return max(pos - self.window + 1, 0) // self.block_size
+
+    def most_held(self, n_blocks: int) -> int:
+        """The most blocks a row of ``n_blocks`` blocks of tokens ever
+        holds at once."""
+        return min(n_blocks, self.width)
+
+    def incref(self, blk: int) -> None:
+        self.ref[blk] += 1
+
+    def decref(self, blk: int) -> None:
+        """Drop one table reference; at zero the block becomes
+        EVICTABLE cache (if published) or returns to the free list."""
+        self.ref[blk] -= 1
+        if self.ref[blk] == 0:
+            if blk in self.block_hash:
+                # fresh insertion lands at the MRU end (a block enters
+                # the LRU only here, and claiming removed it first)
+                self.lru[blk] = None
+            else:
+                self.free.append(blk)
+
+    def shared(self, blk: int) -> bool:
+        """A block a row must NOT write into: other tables still
+        reference it, or the prefix cache does (a write would corrupt
+        content a future lookup trusts)."""
+        return self.ref[blk] > 1 or blk in self.block_hash
+
+    def push(self, slot: int, blk: int) -> None:
+        """``blk`` (referenced by the caller) covers the row's next
+        block index."""
+        row = self.row_blocks[slot]
+        self.tables[slot, (self.row_base[slot] + len(row)) % self.width] = blk
+        row.append(blk)
+
+    def drop_behind(self, slot: int, first: int) -> int:
+        """Give back the row's blocks below block index ``first``;
+        returns how many."""
+        row, n = self.row_blocks[slot], 0
+        while row and self.row_base[slot] < first:
+            self.decref(row.pop(0))
+            self.tables[slot, self.row_base[slot] % self.width] = NULL_BLOCK
+            self.row_base[slot] += 1
+            n += 1
+        self.n_released += n
+        return n
+
+    def drop_past(self, slot: int, keep: int) -> None:
+        """Give back the row's blocks from block index ``keep`` on."""
+        row, base = self.row_blocks[slot], self.row_base[slot]
+        while row and base + len(row) > keep:
+            self.decref(row.pop())
+            self.tables[slot, (base + len(row)) % self.width] = NULL_BLOCK
+
+    def device_table(self, view: np.ndarray) -> jax.Array:
+        """``view`` of :attr:`tables` for a program.  A ring's entries are
+        rewritten while the row lives, and prefill chunks are dispatched
+        without waiting for the one before: on a backend that reads host
+        memory in place (the CPU does) the program must get a copy, or
+        the next chunk's slide races the chunk in flight.  A plain table's
+        written entries never change under a running program."""
+        return jnp.asarray(view if self.window is None else view.copy())
+
+    def release_row(self, slot: int) -> None:
+        """Drop every table reference of ``slot`` (reverse order keeps
+        the free list LIFO — last-allocated, still-warm block first)."""
+        self.drop_past(slot, 0)
+        self.row_base[slot] = 0
+        self.tables[slot, :] = NULL_BLOCK
+
+
 class PagedDecodeEngine:
     """Continuous micro-batching over a paged K/V cache: refcounted
     copy-on-write block pool, cross-request prefix cache, chunked
@@ -477,8 +618,12 @@ class PagedDecodeEngine:
     K/V live in a shared ``[n_blocks, block_size, H*hd]`` pool per
     layer; each slot owns an
     ordered block table and every pool block carries a REFCOUNT — the
-    same physical block can appear in many tables at once.  Four
-    properties follow:
+    same physical block can appear in many tables at once.  (A tower
+    that declares kinds of cached state, ``model.cache_kinds``, gets
+    pools, free list, refcounts and a table a row for EACH kind, through
+    the same allocator; ``n_blocks`` is then ``{kind: blocks}`` and the
+    prefix cache is not served: docs/SERVING.md "Block tables by layer
+    kind".)  Four properties follow:
 
     * **memory-proportional concurrency** — a slot consumes blocks for
       the tokens it has actually decoded, not a ``T_max`` reservation;
@@ -542,7 +687,7 @@ class PagedDecodeEngine:
         batch_size: int = 8,
         max_seq: Optional[int] = None,
         block_size: int = 16,
-        n_blocks: Optional[int] = None,
+        n_blocks: Union[int, Mapping[str, int], None] = None,
         prefill_budget: Optional[int] = None,
         prefix_cache: Optional[bool] = None,
         admit_every: int = 8,
@@ -566,10 +711,30 @@ class PagedDecodeEngine:
         if block_size < 1:
             raise ValueError(f"want block_size >= 1; got {block_size}")
         self.block_size = int(block_size)
+        self._m_unsupported = observability.counter(
+            "znicz_serve_unsupported_total",
+            "engine configurations refused because the tower does not "
+            "serve the feature yet",
+            ("feature",),
+        )
+        # the kinds of cached state the tower keeps: one (every layer's)
+        # unless it declares its own (workflow/generate.CacheKind)
+        kinds = getattr(model, "cache_kinds", None)
         # ON by default: sharing is free when nothing matches (a few
-        # sha256 per admission) and the headline win when it does
-        self.prefix_cache = True if prefix_cache is None else bool(
-            prefix_cache
+        # sha256 per admission) and the headline win when it does.  A
+        # tower of several kinds is served WITHOUT it: a chain could be
+        # handed to a new request only where every kind still holds it,
+        # and a window kind gives its blocks back while the row lives
+        if prefix_cache and kinds:
+            self._m_unsupported.labels(feature="prefix_cache").inc()
+            raise PrefixCacheUnsupportedError(
+                f"a {type(model).__name__} tower keeps "
+                f"{len(kinds)} kinds of cache blocks "
+                f"({', '.join(k.name for k in kinds)}): the prefix cache "
+                "is served for towers of one kind only"
+            )
+        self.prefix_cache = (
+            not kinds if prefix_cache is None else bool(prefix_cache)
         )
         # speculative decoding (docs/SERVING.md "Speculative decoding"):
         # spec_k == 0 is OFF (the plain decode chunk runs); > 0 drafts
@@ -580,6 +745,7 @@ class PagedDecodeEngine:
         if spec_k < 0:
             raise ValueError(f"want spec_k >= 0; got {spec_k}")
         if spec_k and model is not None:
+            self._m_unsupported.labels(feature="speculation").inc()
             raise SpeculationUnsupportedError(
                 f"a {type(model).__name__} tower has no verify program "
                 "yet: speculative decoding is served for the classic "
@@ -747,33 +913,33 @@ class PagedDecodeEngine:
         self._chunk_idx = 0
         self._total_new = 0
         self._peak_active = 0
-        m = -(-self.t_max // self.block_size)  # table width: ceil
-        # default: every slot could hold a full T_max window (plus the
-        # reserved null block); shrink it to save memory
-        self.n_blocks = int(
-            n_blocks if n_blocks is not None else self.batch_size * m + 1
-        )
+        m = -(-self.t_max // self.block_size)  # a full row in blocks: ceil
         self.blocks_per_row = m
+        # one allocator state a kind; ``n_blocks`` is a number for the
+        # one-kind tower and {kind: blocks} where the tower declares kinds
+        # (a kind left out gets a full table a slot)
+        if kinds and isinstance(n_blocks, int):
+            raise ValueError(
+                f"a {type(model).__name__} tower keeps blocks of kinds "
+                f"{[k.name for k in kinds]}: give n_blocks by kind"
+            )
+        self._kinds: List[_BlockKind] = [
+            _BlockKind(
+                kind,
+                n_blocks=(n_blocks or {}).get(kind.name) if kinds else n_blocks,
+                batch_size=self.batch_size, row_blocks=m,
+                block_size=self.block_size, chunk=self.admit_every,
+            )
+            for kind in (kinds or (CacheKind("kv"),))
+        ]
+        self._by_kind = bool(kinds)
+        self.n_blocks = (
+            {k.name: k.n_blocks for k in self._kinds} if kinds
+            else self._kinds[0].n_blocks
+        )
         self._pools = (
             init_paged_kv if self.model is None else self.model.init_pools
         )(self.params, self.n_blocks, self.block_size)
-        # LIFO free list: a just-freed (still cache/HBM-warm) block is
-        # the next one handed out; block 0 stays reserved as null
-        self._free: List[int] = list(range(1, self.n_blocks))
-        # per-block refcount = how many tables reference it; the cache
-        # reference is tracked separately by _block_hash membership
-        self._ref = np.zeros(self.n_blocks, np.int64)
-        # prefix cache: chained content hash -> block, its inverse, and
-        # an LRU over CACHE-ONLY blocks (refcount 0: evictable)
-        self._cache: Dict[bytes, int] = {}
-        self._block_hash: Dict[int, bytes] = {}
-        self._lru: OrderedDict = OrderedDict()
-        self._row_blocks: List[List[int]] = [
-            [] for _ in range(self.batch_size)
-        ]
-        self._tables = np.full(
-            (self.batch_size, m), NULL_BLOCK, np.int32
-        )
         self._n_prefix_hits = 0
         self._n_prefix_misses = 0
         self._n_cached_tokens = 0
@@ -785,14 +951,24 @@ class PagedDecodeEngine:
         # already produced once — re-observing would double-count)
         self._admitted_ids: set = set()
         self._n_preempted = 0
-        # per-block footprint across the whole tower (a k/v pair a
-        # layer, or one array of latent rows) — the byte twin of the
+        # per-block footprint across a kind's layers (a k/v pair a
+        # layer, or one array of cached rows) — the byte twin of the
         # block gauges, so pool pressure is readable in the same unit
         # device memory is
-        self.block_bytes = sum(
-            int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves(self._pools)
+        layer_kinds = (
+            self.model.layer_kinds if kinds
+            else [self._kinds[0].name] * len(self._pools)
         )
+        for kind in self._kinds:
+            kind.block_bytes = sum(
+                int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
+                for pool, name in zip(self._pools, layer_kinds)
+                if name == kind.name
+                for leaf in jax.tree_util.tree_leaves(pool)
+            )
+        # what block_size tokens cost across the whole tower while every
+        # kind holds them
+        self.block_bytes = sum(k.block_bytes for k in self._kinds)
         self._m_pool = observability.gauge(
             "znicz_serve_kv_pool_blocks",
             "paged KV pool blocks by state (the null block is excluded)",
@@ -803,6 +979,23 @@ class PagedDecodeEngine:
             "paged KV pool bytes by state (blocks x per-block K/V "
             "bytes across the tower; the null block is excluded)",
             ("state",),
+        )
+        self._m_in_use = observability.gauge(
+            "znicz_serve_pool_blocks_in_use",
+            "pool blocks that a row's table references, by kind of block",
+            ("kind",),
+        )
+        self._m_window_released = observability.counter(
+            "znicz_serve_window_blocks_released_total",
+            "blocks a window kind gave back while their row lived on, "
+            "because they lay wholly behind the row's window",
+        )
+        self._m_bytes_per_token = observability.histogram(
+            "znicz_serve_cache_bytes_per_resident_token",
+            "per tick: bytes of the pool blocks that rows' tables "
+            "reference (every kind) over the tokens resident in those "
+            "rows; sum / count is the mean over ticks",
+            buckets=(512.0, 2048.0, 8192.0, 32768.0, 131072.0, 524288.0),
         )
         self._m_preempted = observability.counter(
             "znicz_serve_preemptions_total",
@@ -866,6 +1059,13 @@ class PagedDecodeEngine:
             "programs read: steps x slots x window blocks x block size "
             "where the window is gathered; the decoding rows' lengths in "
             "whole blocks, step by step, where the pool is read in place",
+        )
+        self._m_decode_cached_rows = observability.counter(
+            "znicz_serve_decode_cached_rows_total",
+            "cached rows ONE layer of a kind read in the paged decode "
+            "program, summed over its steps, for a tower that declares "
+            "kinds (times the kind's layers: what the tower read)",
+            ("kind",),
         )
         self._m_decode_chunks = observability.counter(
             "znicz_serve_decode_chunks_total",
@@ -948,13 +1148,17 @@ class PagedDecodeEngine:
                 f"{max_new_tokens} exceeds the positional "
                 f"window (t_max={self.t_max})"
             )
-        if need > self.usable_blocks:
-            raise RequestTooLargeError(
-                f"prompt (len {p.size}, padded {padded}) + max_new_tokens "
-                f"{max_new_tokens} needs {need} KV blocks; exceeds the "
-                f"paged KV pool ({self.usable_blocks} usable blocks x "
-                f"{self.block_size} tokens)"
-            )
+        for kind in self._kinds:
+            held = kind.most_held(need)
+            if held > kind.usable:
+                of = f" of kind {kind.name!r}" if self._by_kind else ""
+                raise RequestTooLargeError(
+                    f"prompt (len {p.size}, padded {padded}) + "
+                    f"max_new_tokens {max_new_tokens} needs {held} KV "
+                    f"blocks{of}; exceeds the paged KV pool "
+                    f"({kind.usable} usable blocks x {self.block_size} "
+                    "tokens)"
+                )
         return padded  # admission width: the padded prompt length
 
     def submit(
@@ -1066,7 +1270,27 @@ class PagedDecodeEngine:
         chunk_kind = self._run_chunk() if self.active else None
         t2 = time.perf_counter()
         self._observe_tick(t1 - t0, t2 - t1, chunk_kind)
+        self._observe_residency()
         return True
+
+    def _observe_residency(self) -> None:
+        """What the rows resident after this tick cost in pool bytes a
+        token: the blocks their tables reference, every kind, over the
+        positions they have written."""
+        tokens = 0
+        for slot, st in enumerate(self._slots):
+            if st is None:
+                continue
+            tokens += (
+                int(self._pos[slot]) if st["mode"] == "decode"
+                else min(
+                    st["chunks_done"] * self.block_size, st["req"].prompt.size
+                )
+            )
+        if tokens:
+            self._m_bytes_per_token.observe(
+                sum(k.referenced * k.block_bytes for k in self._kinds) / tokens
+            )
 
     def _observe_tick(
         self,
@@ -1235,20 +1459,22 @@ class PagedDecodeEngine:
 
     @property
     def usable_blocks(self) -> int:
-        """Pool capacity available to requests (null block excluded)."""
-        return self.n_blocks - 1
+        """Pool capacity available to requests (null blocks excluded),
+        every kind's blocks counted."""
+        return sum(k.usable for k in self._kinds)
 
     def _update_pool_gauges(self) -> None:
-        free = len(self._free)
-        cached = len(self._lru)
-        used = self.usable_blocks - free - cached
-        self._m_pool.labels(state="free").set(free)
-        self._m_pool.labels(state="cached").set(cached)
-        self._m_pool.labels(state="used").set(used)
-        bb = self.block_bytes
-        self._m_pool_bytes.labels(state="free").set(free * bb)
-        self._m_pool_bytes.labels(state="cached").set(cached * bb)
-        self._m_pool_bytes.labels(state="used").set(used * bb)
+        blocks = {"free": 0, "cached": 0, "used": 0}
+        nbytes = dict(blocks)
+        for kind in self._kinds:
+            counts = (len(kind.free), len(kind.lru), kind.referenced)
+            for state, n in zip(blocks, counts):
+                blocks[state] += n
+                nbytes[state] += n * kind.block_bytes
+            self._m_in_use.labels(kind=kind.name).set(counts[2])
+        for state in blocks:
+            self._m_pool.labels(state=state).set(blocks[state])
+            self._m_pool_bytes.labels(state=state).set(nbytes[state])
 
     def _slots_by_age(self) -> List[int]:
         """Occupied slot indices, oldest admission first — allocation
@@ -1266,48 +1492,33 @@ class PagedDecodeEngine:
             key=lambda i: self._slots[i]["seq"],
         )
 
-    def _incref(self, blk: int) -> None:
-        self._ref[blk] += 1
-
-    def _decref(self, blk: int) -> None:
-        """Drop one table reference; at zero the block becomes
-        EVICTABLE cache (if published) or returns to the free list."""
-        self._ref[blk] -= 1
-        if self._ref[blk] == 0:
-            if blk in self._block_hash:
-                # fresh insertion lands at the MRU end (a block enters
-                # the LRU only here, and claiming removed it first)
-                self._lru[blk] = None
-            else:
-                self._free.append(blk)
-
-    def _alloc_block(self) -> int:
-        """One unreferenced, uncached block: free list first, then
-        EVICT the least-recently-used cache-only block — the cache
-        always yields before any live request is preempted.  Returns
-        -1 when both are dry (the caller preempts)."""
+    def _alloc_block(self, kind: _BlockKind) -> int:
+        """One unreferenced, uncached block of ``kind``: free list
+        first, then EVICT the least-recently-used cache-only block — the
+        cache always yields before any live request is preempted.
+        Returns -1 when both are dry (the caller preempts)."""
         faults.fire("pool.alloc")  # injected allocator failure (raises)
         if faults.fire("pool.pressure"):
             return -1  # injected exhaustion: free list AND cache "dry"
-        if self._free:
-            return self._free.pop()
-        if self._lru:
-            blk, _ = self._lru.popitem(last=False)
-            del self._cache[self._block_hash.pop(blk)]
+        if kind.free:
+            return kind.free.pop()
+        if kind.lru:
+            blk, _ = kind.lru.popitem(last=False)
+            del kind.cache[kind.block_hash.pop(blk)]
             self._n_evictions += 1
             self._m_prefix_evictions.inc()
             return blk
         return -1
 
-    def _alloc_for(self, slot: int) -> Optional[int]:
-        """One referenced block for ``slot``, preempting the youngest
-        occupant while the pool (free list AND evictable cache) stays
-        dry.  None when the starved slot was itself the youngest and
-        got preempted (its request is back in the queue)."""
+    def _alloc_for(self, kind: _BlockKind, slot: int) -> Optional[int]:
+        """One referenced block of ``kind`` for ``slot``, preempting the
+        youngest occupant while the kind's pool (free list AND evictable
+        cache) stays dry.  None when the starved slot was itself the
+        youngest and got preempted (its request is back in the queue)."""
         while True:
-            blk = self._alloc_block()
+            blk = self._alloc_block(kind)
             if blk >= 0:
-                self._incref(blk)
+                kind.incref(blk)
                 return blk
             victim = self._youngest_slot()
             self._preempt(victim)
@@ -1315,13 +1526,9 @@ class PagedDecodeEngine:
                 return None
 
     def _release_row(self, slot: int) -> None:
-        """Drop every table reference of ``slot`` (reverse order keeps
-        the free list LIFO — last-allocated, still-warm block first)."""
-        row = self._row_blocks[slot]
-        for blk in reversed(row):
-            self._decref(blk)
-        row.clear()
-        self._tables[slot, :] = NULL_BLOCK
+        """Drop every table reference of ``slot``, in every kind."""
+        for kind in self._kinds:
+            kind.release_row(slot)
         self._update_pool_gauges()
 
     def _preempt(self, slot: int) -> None:
@@ -1352,38 +1559,48 @@ class PagedDecodeEngine:
             **self._trace_args(req.trace_id),
         )
 
-    def _ensure_blocks(self, slot: int, need: int) -> bool:
-        """Grow ``slot``'s table to >= ``need`` blocks, preempting the
-        youngest occupant whenever the pool is dry.  Returns False when
-        the starved slot was itself the youngest and got preempted
-        (its request is back in the queue)."""
-        row = self._row_blocks[slot]
-        while len(row) < need:
-            blk = self._alloc_for(slot)
-            if blk is None:
-                return False
-            self._tables[slot, len(row)] = blk
-            row.append(blk)
+    def _ensure_blocks(self, slot: int, first_pos: int, last_pos: int) -> bool:
+        """Make ``slot``'s tables serve queries at positions
+        ``first_pos..last_pos``, kind by kind: a window kind first gives
+        back the blocks that lie wholly behind ``first_pos``'s window
+        (the table slides), then every kind grows to the block of
+        ``last_pos``, preempting the youngest occupant whenever its pool
+        is dry.  Returns False when the starved slot was itself the
+        youngest and got preempted (its request is back in the queue)."""
+        for kind in self._kinds:
+            released = kind.drop_behind(slot, kind.first_needed(first_pos))
+            if released:
+                self._m_window_released.inc(released)
+                observability.instant(
+                    "serve/window_release", slot=slot, blocks=released,
+                    kind=kind.name,
+                )
+            row = kind.row_blocks[slot]
+            while kind.row_base[slot] + len(row) <= last_pos // self.block_size:
+                blk = self._alloc_for(kind, slot)
+                if blk is None:
+                    return False
+                kind.push(slot, blk)
         self._update_pool_gauges()
         return True
 
-    def _shared(self, blk: int) -> bool:
-        """A block this row must NOT write into: other tables still
-        reference it, or the prefix cache does (a write would corrupt
-        content a future lookup trusts)."""
-        return self._ref[blk] > 1 or blk in self._block_hash
-
     def _cow_split(self, slot: int, j: int, *, copy: bool) -> bool:
-        """Copy-on-write: retarget table entry ``j`` of ``slot`` to a
-        fresh private block before a write into a shared/cached block.
+        """Copy-on-write: retarget entry ``j`` of ``slot``'s table (the
+        one kind's: sharing needs the prefix cache) to a fresh private
+        block before a write into a shared/cached block.
         ``copy=False`` when the impending write rewrites the whole
         block (a prefill chunk re-run) — the fresh block needs no
-        content.  No-op for private blocks.  False when allocation had
-        to preempt ``slot`` itself."""
-        blk = int(self._row_blocks[slot][j])
-        if not self._shared(blk):
+        content.  No-op for private blocks, and where the prefix cache is
+        off (only a cached chain is ever shared; a tower of several kinds
+        is served without one).  False when allocation had to preempt
+        ``slot`` itself."""
+        if not self.prefix_cache:
             return True
-        new = self._alloc_for(slot)
+        kind = self._kinds[0]
+        blk = int(kind.row_blocks[slot][j])
+        if not kind.shared(blk):
+            return True
+        new = self._alloc_for(kind, slot)
         if new is None:
             return False
         if copy:
@@ -1392,9 +1609,9 @@ class PagedDecodeEngine:
                 _cow_copy_prog,
                 self._pools, jnp.int32(blk), jnp.int32(new),
             )
-        self._decref(blk)
-        self._row_blocks[slot][j] = new
-        self._tables[slot, j] = new
+        kind.decref(blk)
+        kind.row_blocks[slot][j] = new
+        kind.tables[slot, j] = new
         self._n_cow += 1
         self._update_pool_gauges()
         return True
@@ -1419,10 +1636,10 @@ class PagedDecodeEngine:
         p = np.asarray(prompt, np.int32).reshape(-1)
         keys: List[str] = []
         cached = 0
-        walking = self.prefix_cache
+        walking, cache = self.prefix_cache, self._kinds[0].cache
         for h in _chain_digests(p, self.block_size):
             keys.append(h.hex())
-            if walking and h in self._cache:
+            if walking and h in cache:
                 cached += 1
             else:
                 walking = False
@@ -1445,8 +1662,9 @@ class PagedDecodeEngine:
             return hits
         if req.digests is None:
             req.digests = list(self._chain_hashes(req.prompt))
+        cache = self._kinds[0].cache
         for h in req.digests:
-            blk = self._cache.get(h)
+            blk = cache.get(h)
             if blk is None:
                 break
             hits.append(blk)
@@ -1457,9 +1675,11 @@ class PagedDecodeEngine:
         blocks (every position holds a real token's K/V) into the
         prefix cache.  First writer wins when two physical blocks hold
         the same content — the duplicate stays private and frees
-        normally at release."""
+        normally at release.  (The prefix cache is served for towers of
+        one kind of blocks: where it is on, that kind's.)"""
         if not self.prefix_cache:
             return
+        kind = self._kinds[0]
         st = self._slots[slot]
         req = st["req"]
         emitted = st.get("emitted") or []
@@ -1472,7 +1692,7 @@ class PagedDecodeEngine:
             # emitted token EXCEPT the last (sampled, never fed back,
             # so its K/V was never written)
             covered = req.prompt.size + max(len(emitted) - 1, 0)
-        row = self._row_blocks[slot]
+        row = kind.row_blocks[slot]
         n_full = min(covered // self.block_size, len(row))
         if not n_full:
             return
@@ -1481,20 +1701,21 @@ class PagedDecodeEngine:
         )[: n_full * self.block_size]
         for j, h in enumerate(self._chain_hashes(toks)):
             blk = int(row[j])
-            if h in self._cache or blk in self._block_hash:
+            if h in kind.cache or blk in kind.block_hash:
                 continue  # already published (a mapped prefix), or dup
-            self._cache[h] = blk
-            self._block_hash[blk] = h
+            kind.cache[h] = blk
+            kind.block_hash[blk] = h
 
     def flush_prefix_cache(self) -> int:
         """Drop every cache entry; cache-only blocks return to the
         free list (blocks live requests still reference just lose their
         hash and free normally at release).  Returns entries dropped."""
-        n = len(self._cache)
-        self._cache.clear()
-        self._block_hash.clear()
-        self._free.extend(self._lru)
-        self._lru.clear()
+        kind = self._kinds[0]
+        n = len(kind.cache)
+        kind.cache.clear()
+        kind.block_hash.clear()
+        kind.free.extend(kind.lru)
+        kind.lru.clear()
         self._update_pool_gauges()
         return n
 
@@ -1510,33 +1731,41 @@ class PagedDecodeEngine:
         # requeue every tick, burning prefill compute and inflating the
         # preemption counter for no progress.
         # owed == 0 with the row still in prefill mode is exactly the
-        # fully-cached case: its final chunk will COW-split one block
-        reserved = sum(
-            max(
-                s["req"].bucket // self.block_size
-                - len(self._row_blocks[i]),
-                1,
+        # fully-cached case: its final chunk will COW-split one block.
+        # Counted in EACH kind: a prompt holds at most a window kind's
+        # table of blocks at once, whatever its length
+        def owed(kind, req, held):
+            return max(
+                kind.most_held(req.bucket // self.block_size) - held, 1
             )
-            for i, s in enumerate(self._slots)
-            if s is not None and s["mode"] == "prefill"
-        )
+
+        reserved = [
+            sum(
+                owed(kind, s["req"], len(kind.row_blocks[i]))
+                for i, s in enumerate(self._slots)
+                if s is not None and s["mode"] == "prefill"
+            )
+            for kind in self._kinds
+        ]
         for slot in range(self.batch_size):
             if self._slots[slot] is None and self._queue:
                 req = self._queue[0]
                 hits = self._lookup_prefix(req)
                 # a fully-cached prompt still COW-reruns its final
-                # block's chunk for the first-token logits
-                need = max(req.bucket // self.block_size - len(hits), 1)
+                # block's chunk for the first-token logits (hits are of
+                # the one kind a tower with a prefix cache has)
+                need = [owed(kind, req, len(hits)) for kind in self._kinds]
                 # allocatable = free + evictable cache, NOT counting the
                 # hit blocks themselves (binding pins them)
-                pool = (
-                    len(self._free)
-                    + len(self._lru)
-                    - sum(1 for b in hits if b in self._lru)
-                )
-                if pool - reserved < need:
+                pool = [
+                    kind.allocatable - sum(1 for b in hits if b in kind.lru)
+                    for kind in self._kinds
+                ]
+                if any(
+                    p - r < n for p, r, n in zip(pool, reserved, need)
+                ):
                     break
-                reserved += need
+                reserved = [r + n for r, n in zip(reserved, need)]
                 self._start_prefill(slot, self._queue.popleft(), hits)
         self._m_queue_depth.set(len(self._queue))
         self._m_active.set(self.active)
@@ -1557,18 +1786,17 @@ class PagedDecodeEngine:
         size = req.prompt.size
         tokens = np.full((1, req.bucket), self.pad_id, np.int32)
         tokens[0, :size] = req.prompt
-        row = self._row_blocks[slot]
         if hits is None:
             # _admit_pending passes its own lookup through (nothing can
             # mutate the cache in between); this walk serves direct
             # white-box callers only
             hits = self._lookup_prefix(req)
-        for j, blk in enumerate(hits):
-            self._incref(blk)
-            if blk in self._lru:
-                del self._lru[blk]
-            self._tables[slot, j] = blk
-            row.append(blk)
+        kind = self._kinds[0]  # hits exist only where there is one kind
+        for blk in hits:
+            kind.incref(blk)
+            if blk in kind.lru:
+                del kind.lru[blk]
+            kind.push(slot, blk)
         # a fully-cached prompt still needs its first-token LOGITS: the
         # final block's chunk re-runs (the write guard COW-splits it off
         # the shared block), so at least one chunk always executes
@@ -1626,7 +1854,9 @@ class PagedDecodeEngine:
         req = st["req"]
         size = req.prompt.size
         c = st["chunks_done"]
-        if not self._ensure_blocks(slot, c + 1):
+        if not self._ensure_blocks(
+            slot, c * self.block_size, (c + 1) * self.block_size - 1
+        ):
             return False  # starved AND youngest: requeued itself
         # a prefill chunk rewrites its whole block: when the target is
         # a mapped cached block (the fully-cached-prompt re-run for
@@ -1653,8 +1883,7 @@ class PagedDecodeEngine:
             self._pools, first, *load = self._timed_program(
                 ("prefill", self.block_size, self._structure),
                 _paged_prefill_prog,
-                self.params, self._pools,
-                jnp.asarray(self._tables[slot]),
+                self.params, self._pools, self._row_tables(slot),
                 jnp.asarray(
                     st["tokens"][
                         :, c * self.block_size:(c + 1) * self.block_size
@@ -1730,9 +1959,7 @@ class PagedDecodeEngine:
                 continue
             p0 = int(self._pos[slot])
             last_pos = p0 + max(int(steps_for(slot)) - 1, 0)
-            if not self._ensure_blocks(
-                slot, last_pos // self.block_size + 1
-            ):
+            if not self._ensure_blocks(slot, p0, last_pos):
                 continue  # starved AND youngest: requeued itself
             for j in range(
                 p0 // self.block_size, last_pos // self.block_size + 1
@@ -1745,11 +1972,14 @@ class PagedDecodeEngine:
 
     def _decode_window(self) -> int:
         """The decode/verify gather WINDOW: the x2 rung covering the
-        blocks active rows actually hold — the compiled-variant count
+        blocks active rows actually hold in a kind that keeps every token
+        (a window kind's ring is passed whole) — the compiled-variant count
         stays logarithmic and short requests never pay ``T_max``-wide
         attention (docs/SERVING.md)."""
         need = max(
-            (len(self._row_blocks[i]) for i, s in enumerate(self._slots)
+            (len(kind.row_blocks[i]) for kind in self._kinds
+             if kind.window is None
+             for i, s in enumerate(self._slots)
              if s is not None and s["mode"] == "decode"),
             default=1,
         )
@@ -1758,15 +1988,46 @@ class PagedDecodeEngine:
             window *= 2
         return min(window, self.blocks_per_row)
 
-    def _count_gathered(self, steps: int, window: int, read=None) -> None:
-        """``read``: the cached rows the chunk's tower says a layer of it
-        read (``cached_rows`` of its load sums), where it says so; the
-        K/V tower's gather reads every slot's window, active or not."""
+    def _row_tables(self, slot: int):
+        """``slot``'s table as the prefill program takes it: the one
+        kind's, or ``{kind: table}`` where the tower declares kinds."""
+        if not self._by_kind:
+            return jnp.asarray(self._kinds[0].tables[slot])
+        return {k.name: k.device_table(k.tables[slot]) for k in self._kinds}
+
+    def _batch_tables(self, window: int):
+        """Every slot's table as the decode and verify programs take
+        them: cut to the ``window`` rung where the kind keeps every token
+        (:meth:`_decode_window`); a window kind's ring whole, which is one
+        width for any stream."""
+        def cut(kind):
+            return kind.device_table(
+                kind.tables[:, :window] if kind.window is None
+                else kind.tables
+            )
+
+        if not self._by_kind:
+            return cut(self._kinds[0])
+        return {k.name: cut(k) for k in self._kinds}
+
+    def _count_gathered(self, steps: int, window: int, load=None) -> None:
+        """What the chunk's layers read of the cache.  ``load``: the
+        tower's load sums, where it reports any.  Their ``cached_rows``
+        is the cached rows a layer of it read over the chunk's steps (a
+        tower of several kinds: the MEAN over its layers, so that steps x
+        layers x this is what the tower read, as for every other tower;
+        ``cached_rows_by_kind`` has a layer of each kind).  The classic
+        K/V tower reports none: its gather reads every slot's window,
+        active or not."""
+        load = load or {}
+        read = load.get("cached_rows")
         self._m_decode_steps.inc(steps)
         self._m_decode_gathered.inc(
             steps * self.batch_size * window * self.block_size
             if read is None else int(read)
         )
+        for kind, rows in load.get("cached_rows_by_kind", {}).items():
+            self._m_decode_cached_rows.labels(kind=kind).inc(int(rows))
         self._m_decode_chunks.labels(window=window).inc()
 
     # -- speculative decoding: draft -> verify -> accept -> rollback ------
@@ -1853,8 +2114,7 @@ class PagedDecodeEngine:
                 ("spec_verify", w, self.batch_size, window,
                  self._structure),
                 _paged_verify_prog,
-                self.params, self._pools,
-                jnp.asarray(self._tables[:, :window]),
+                self.params, self._pools, self._batch_tables(window),
                 jnp.asarray(tokens), jnp.asarray(self._pos),
                 jnp.asarray(self._done), jnp.asarray(n_write),
                 jnp.asarray(draft_len),
@@ -1911,20 +2171,15 @@ class PagedDecodeEngine:
         self._m_active.set(self.active)
 
     def _truncate_row(self, slot: int) -> None:
-        """Speculative ROLLBACK: drop the table entries past the last
-        position holding accepted K/V.  The truncated blocks were
+        """Speculative ROLLBACK: drop every kind's table entries past the
+        last position holding accepted K/V.  The truncated blocks were
         allocated (private, COW-guarded) for draft positions the
         verifier rejected — a decref walks each back to the free list
         (or the cache, had it been shared), so rollback is bookkeeping
         only: no device copies, no recompute."""
-        row = self._row_blocks[slot]
         keep = (int(self._pos[slot]) - 1) // self.block_size + 1
-        if len(row) <= keep:
-            return
-        for blk in reversed(row[keep:]):
-            self._decref(blk)
-        del row[keep:]
-        self._tables[slot, keep:] = NULL_BLOCK
+        for kind in self._kinds:
+            kind.drop_past(slot, keep)
         self._update_pool_gauges()
 
     def _run_chunk(self) -> str:
@@ -1971,8 +2226,7 @@ class PagedDecodeEngine:
                     ("paged_chunk", self.admit_every, self.batch_size,
                      window, self._structure),
                     _paged_decode_chunk,
-                    self.params, self._pools,
-                    jnp.asarray(self._tables[:, :window]),
+                    self.params, self._pools, self._batch_tables(window),
                     jnp.asarray(self._tok), jnp.asarray(self._pos),
                     jnp.asarray(self._done),
                     jnp.asarray(self._remaining), self._temperature,
@@ -1995,9 +2249,7 @@ class PagedDecodeEngine:
             self._done = np.array(done)
             self._remaining = np.array(remaining)
         dt = time.perf_counter() - t0
-        self._count_gathered(
-            steps, window, load[0].get("cached_rows") if load else None
-        )
+        self._count_gathered(steps, window, load[0] if load else None)
         for r in residents:
             r.timings.decode_s += dt
         for slot, st in enumerate(self._slots):
@@ -2042,11 +2294,10 @@ class PagedDecodeEngine:
     @property
     def pool_free_frac(self) -> float:
         """Fraction of the pool still ALLOCATABLE (free list plus
-        evictable cache-only blocks) — the one owner of the formula the
-        front door's pool-pressure watermark reads."""
-        return (len(self._free) + len(self._lru)) / max(
-            self.usable_blocks, 1
-        )
+        evictable cache-only blocks), of the kind that has least left —
+        the one owner of the formula the front door's pool-pressure
+        watermark reads."""
+        return min(k.allocatable / max(k.usable, 1) for k in self._kinds)
 
     def spec_stats(self) -> Dict:
         """The live speculative-decoding report (``stats()["spec"]``):
@@ -2087,15 +2338,24 @@ class PagedDecodeEngine:
             "spec": self.spec_stats(),
             **self.compile_stats(),
             "pool_blocks": self.usable_blocks,
-            "pool_blocks_free": len(self._free) + len(self._lru),
-            "pool_blocks_cached": len(self._lru),
+            "pool_blocks_free": sum(k.allocatable for k in self._kinds),
+            "pool_blocks_cached": sum(len(k.lru) for k in self._kinds),
             "block_size": self.block_size,
             "block_bytes": self.block_bytes,
-            "pool_bytes": self.usable_blocks * self.block_bytes,
+            "pool_bytes": sum(k.usable * k.block_bytes for k in self._kinds),
+            "kinds": {
+                k.name: {
+                    "window": k.window, "table_width": k.width,
+                    "blocks": k.usable, "blocks_free": k.allocatable,
+                    "block_bytes": k.block_bytes,
+                    "blocks_released_behind_window": k.n_released,
+                }
+                for k in self._kinds
+            },
             "preemptions": self._n_preempted,
             "prefix_cache": {
                 "enabled": self.prefix_cache,
-                "entries": len(self._cache),
+                "entries": len(self._kinds[0].cache),
                 "hits": self._n_prefix_hits,
                 "misses": self._n_prefix_misses,
                 "cached_tokens": self._n_cached_tokens,

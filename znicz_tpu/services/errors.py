@@ -33,6 +33,15 @@ class SpeculationUnsupportedError(ValueError):
     working, typed callers can route it specifically."""
 
 
+class PrefixCacheUnsupportedError(ValueError):
+    """The prefix cache was asked for by name for a tower that keeps
+    several kinds of cache blocks (a window kind gives its blocks back
+    while the row lives, so a cached chain could name rows their owner
+    released) — a CONFIG error with :class:`SpeculationUnsupportedError`'s
+    contract.  Left to its default the cache is simply off for such a
+    tower: nothing is published and nothing looked up."""
+
+
 class EngineClosedError(RuntimeError):
     """Submitted to a closed (or closing) front door / engine — the
     graceful-shutdown path; retry against a live replica."""

@@ -18,6 +18,7 @@ tests assert position-by-position.
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional, Sequence
 
@@ -144,6 +145,23 @@ def decode_step(
 # windows (tests/test_paged_layout_aot.py).
 
 NULL_BLOCK = 0  # reserved pool block: write target for idle/done rows
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """One kind of cached state a paged tower keeps, and how long a row
+    needs it.  A tower that mixes kinds of layers lists one of these a
+    kind (``model.cache_kinds``); the engine then keeps pools' blocks, a
+    free list and a block table a row FOR EACH, and hands the tower's
+    programs ``{name: table}``.  ``window`` None: every token of a live
+    row stays (table entry ``j`` covers positions ``j * block_size ..``).
+    ``window`` W: a layer of this kind attends the last ``W`` keys, the
+    query's own among them, so the engine gives back every block that lies
+    wholly behind them and the table is a RING: the block that covers
+    absolute block index ``b`` sits at entry ``b % width``."""
+
+    name: str
+    window: Optional[int] = None
 
 
 def init_paged_kv(params, n_blocks: int, block_size: int):

@@ -62,6 +62,20 @@ def _gated(h, gate, up, down):
     return _dot(jax.nn.silu(_dot(h, gate)) * _dot(h, up), down)
 
 
+def _head_logits(params, x, eps):
+    """The final norm and the head (whole, or this chip's slice of the
+    vocabulary) over ``x`` [B, D]."""
+    return _dot(rms_norm(x, params[-1]["final_norm"], eps=eps), params[-1]["head"])
+
+
+def _chunk_row(x, last):
+    """The row of a prefill chunk ``x`` [1, C, D] whose logits the call
+    returns: in-chunk index ``last``, the chunk's final one by default."""
+    if last is None:
+        return x[:, -1]
+    return jax.lax.dynamic_index_in_dim(x, last, axis=1, keepdims=False)
+
+
 @dataclasses.dataclass(frozen=True)
 class LatentMoEModel:
     """The sizes the parameters do not carry, and what of the model
@@ -234,10 +248,6 @@ class LatentMoEModel:
                 load.append(pairs)
         return x, new_pools, _expert_load(load)
 
-    def _logits(self, params, x):
-        h = rms_norm(x, params[-1]["final_norm"], eps=self.rms_eps)
-        return _dot(h, params[-1]["head"])
-
     def prefill_chunk(
         self, params, pools, table, tokens, offset, *, block_size, last=None,
     ):
@@ -265,11 +275,8 @@ class LatentMoEModel:
             params, x, pools, write, table[None], q_pos, real,
             block_size=block_size, absorbed=False,
         )
-        if last is None:
-            xl = x[:, -1]
-        else:
-            xl = jax.lax.dynamic_index_in_dim(x, last, axis=1, keepdims=False)
-        return pools, self._logits(params, xl), load
+        logits = _head_logits(params, _chunk_row(x, last), self.rms_eps)
+        return pools, logits, load
 
     def decode_step(
         self, params, pools, tables, token, pos, *, block_size,
@@ -305,7 +312,7 @@ class LatentMoEModel:
                 tables, lengths, block_size=block_size
             ),
         )
-        return pools, self._logits(params, x[:, 0]), load
+        return pools, _head_logits(params, x[:, 0], self.rms_eps), load
 
 
 def _expert_load(per_layer) -> Optional[dict]:
