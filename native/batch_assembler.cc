@@ -5,13 +5,18 @@
 // too (SURVEY.md 2.4 rebuild mapping).  Exposed as a plain C ABI for ctypes
 // (the environment has no pybind11).  All functions are thread-parallel.
 //
-// Build:  g++ -O3 -march=native -shared -fPIC -o libbatch_assembler.so \
-//             batch_assembler.cc -pthread
+// Build (loader/native.py does, once a source digest):
+//   g++ -O3 -shared -fPIC -std=c++17 -o libbatch_assembler.so
+//       batch_assembler.cc -pthread
 
 #include <cstdint>
 #include <cstring>
 #include <thread>
 #include <vector>
+
+#if defined(__x86_64__)
+#include <tmmintrin.h>
+#endif
 
 namespace {
 
@@ -31,6 +36,55 @@ void parallel_for(int64_t n, Fn fn) {
   }
   for (auto& th : threads) th.join();
 }
+
+// One row of a flipped crop, 3 bytes a pixel: drow[j] = srow[n - 1 - j].
+// The pixel's width is a constant here, so the moves are inlined (the
+// general loop below calls memcpy once a pixel with a runtime size).
+inline void flip_row_c3(const uint8_t* srow, uint8_t* drow, int64_t n) {
+  const uint8_t* s = srow + (n - 1) * 3;
+  for (int64_t j = 0; j < n; ++j, s -= 3, drow += 3) {
+    drow[0] = s[0];
+    drow[1] = s[1];
+    drow[2] = s[2];
+  }
+}
+
+#if defined(__x86_64__)
+// The same row five pixels a turn: a 16-byte load whose FIRST byte belongs
+// to the pixel left of the five, one byte shuffle, a 16-byte store whose
+// LAST byte falls on the next pixel of the destination and is written
+// again by the next turn or the tail.  A turn runs only while six or more
+// pixels remain, so the load never starts before the row's window and the
+// store never ends past the destination row: the last row of a
+// memory-mapped file and the last row of the output are safe.
+__attribute__((target("ssse3")))
+void flip_row_c3_ssse3(const uint8_t* srow, uint8_t* drow, int64_t n) {
+  const __m128i reverse = _mm_setr_epi8(13, 14, 15, 10, 11, 12, 7, 8, 9,
+                                        4, 5, 6, 1, 2, 3, -1);
+  int64_t left = n;  // source pixels [0, left) still to place
+  for (; left >= 6; left -= 5, drow += 15) {
+    __m128i v = _mm_loadu_si128(
+        reinterpret_cast<const __m128i*>(srow + (left - 5) * 3 - 1));
+    _mm_storeu_si128(reinterpret_cast<__m128i*>(drow),
+                     _mm_shuffle_epi8(v, reverse));
+  }
+  flip_row_c3(srow, drow, left);
+}
+
+#endif
+
+// chosen once, from what the CPU says it has: the library is built with
+// plain -O3 and cached by its source's digest, so it may be loaded on
+// another machine than the one that built it
+using FlipRowC3 = void (*)(const uint8_t*, uint8_t*, int64_t);
+FlipRowC3 pick_flip_row_c3() {
+#if defined(__x86_64__)
+  __builtin_cpu_init();
+  if (__builtin_cpu_supports("ssse3")) return flip_row_c3_ssse3;
+#endif
+  return flip_row_c3;
+}
+const FlipRowC3 kFlipRowC3 = pick_flip_row_c3();
 
 }  // namespace
 
@@ -83,6 +137,9 @@ void crop_gather_u8(const uint8_t* data, int64_t h, int64_t w, int64_t c,
         for (int64_t r = 0; r < out_h; ++r)
           std::memcpy(dst + r * out_w * c, src + r * w * c,
                       static_cast<size_t>(out_w) * c);
+      } else if (c == 3) {
+        for (int64_t r = 0; r < out_h; ++r)
+          kFlipRowC3(src + r * w * 3, dst + r * out_w * 3, out_w);
       } else {
         for (int64_t r = 0; r < out_h; ++r) {
           const uint8_t* srow = src + r * w * c;
@@ -94,6 +151,12 @@ void crop_gather_u8(const uint8_t* data, int64_t h, int64_t w, int64_t c,
       }
     }
   });
+}
+
+// Whether crop_gather_u8 reverses a flipped row of c-byte pixels sixteen
+// bytes at a time (1) or pixel by pixel (0): for the caller's counter.
+int32_t crop_flip_is_wide(int64_t c) {
+  return c == 3 && kFlipRowC3 != flip_row_c3;
 }
 
 // Plain u8 row gather (no conversion): feeds the u8->device path where the

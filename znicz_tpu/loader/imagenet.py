@@ -11,8 +11,11 @@ crop.  Re-founded TPU-first:
   side resized, center-cropped to ``size``x``size``).  The loader memory-maps
   them, so datasets larger than host RAM stream from disk.
 - **Crops are native.**  Per-minibatch random crop + flip runs in
-  ``native/batch_assembler.cc`` (``crop_gather_u8``) — a parallel memcpy,
-  not a Python loop.
+  ``native/batch_assembler.cc`` (``crop_gather_u8``), not a Python loop:
+  an unflipped row is a ``memcpy``, a flipped row is reversed sixteen
+  bytes (five pixels) a turn, so either costs what a copy costs;
+  ``znicz_loader_crop_images_total{path}`` says which path the images of
+  a run took.
 - **Normalization is on-device.**  Minibatches cross host->device as u8
   (4x fewer bytes than f32); the affine u8->f32 + channel-mean subtraction
   happens inside the jitted step (``device_preproc``), where XLA fuses it
@@ -23,6 +26,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 from typing import Dict, Optional, Tuple
 
@@ -44,6 +48,9 @@ from znicz_tpu.observability import pipeline as _pipeline
 
 MEAN_FILE = "mean_rgb.json"
 CLASSES_FILE = "classes.json"
+# crop buffers a loader keeps: the batch being cropped, the two a prefetch
+# queue holds and the one whose copy to the device is landing
+_STAGING_BUFFERS = 4
 
 
 def _resize_short_side(img: np.ndarray, size: int) -> np.ndarray:
@@ -197,6 +204,7 @@ class ImageNetLoader(PoolShardedMixin, Loader):
         self.random_flip = random_flip
         self.images: Dict[str, np.ndarray] = {}
         self.labels: Dict[str, np.ndarray] = {}
+        self._staging: list = []  # _staging_buffer()
         for split in SPLITS:
             ipath = os.path.join(data_dir, f"{split}_images.npy")
             if not os.path.exists(ipath):
@@ -281,6 +289,38 @@ class ImageNetLoader(PoolShardedMixin, Loader):
                     time.perf_counter() - t0
                 )
 
+    def _staging_buffer(self, shape: tuple, dtype) -> np.ndarray:
+        """Where one batch of crops is written: a buffer an earlier batch
+        was cropped into, once NOTHING else refers to it, else a fresh
+        ``np.empty`` (kept for later batches while the loader keeps fewer
+        than ``_STAGING_BUFFERS``).  A fresh 158-633 MB allocation is
+        mapped anew and zero-filled page by page under the crop threads'
+        first writes; a kept one has its pages.
+
+        The test is the array's reference count, which is what says that
+        its memory is no one else's: the consumer's ``Minibatch``, a view
+        of it, and jax all hold a reference for as long as they can read
+        the bytes.  ``device_put`` keeps the array until its copy to the
+        device has landed, and for the life of the device array where
+        the backend ALIASES host memory in place of copying it (the CPU
+        backend does, for an aligned array), so a batch a step has yet to
+        read is never written over."""
+        pool = self._staging
+        counted = _pipeline.staging_buffers()
+        for i in range(len(pool)):
+            # 2: the list's reference and this call's argument
+            if sys.getrefcount(pool[i]) == 2:
+                if pool[i].shape == shape and pool[i].dtype == dtype:
+                    counted.labels(source="recycled").inc()
+                    return pool[i]
+                del pool[i]  # a batch of another size: let it go
+                break
+        counted.labels(source="fresh").inc()
+        buf = np.empty(shape, dtype)
+        if len(pool) < _STAGING_BUFFERS:
+            pool.append(buf)
+        return buf
+
     def fill(self, indices: np.ndarray, split: str) -> Minibatch:
         oy, ox, flip = self._timed(
             _pipeline.STAGE_CROP_PARAMS, self._crop_params, indices, split
@@ -306,10 +346,21 @@ class ImageNetLoader(PoolShardedMixin, Loader):
             # one native call, one span, whatever the batch: handing the
             # assembler row chunks for shorter spans cost 8-14 % of the
             # crop's wall (a thread spawn and a join barrier per chunk)
+            images = self.images[split]
             data = self._timed(
                 _pipeline.STAGE_CROP, native.crop_gather_u8,
-                self.images[split], indices, oy, ox, flip, cs, cs,
+                images, indices, oy, ox, flip, cs, cs,
+                self._staging_buffer(
+                    (len(indices), cs, cs, images.shape[-1]), images.dtype
+                ),
             )
+            # a library that did not build, or a CPU without the wide
+            # flip, shows in one scrape
+            crops = _pipeline.crop_images()
+            unflipped, flipped = native.crop_paths(images)
+            n_flipped = int(np.count_nonzero(flip))
+            crops.labels(path=flipped).inc(n_flipped)
+            crops.labels(path=unflipped).inc(len(flip) - n_flipped)
         return Minibatch(
             data=data,
             labels=self.labels[split][indices],

@@ -97,6 +97,8 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             i64p, i64p, i64p, u8p, ctypes.c_int64,
             ctypes.c_int64, ctypes.c_int64, u8p,
         ]
+        lib.crop_flip_is_wide.argtypes = [ctypes.c_int64]
+        lib.crop_flip_is_wide.restype = ctypes.c_int32
         lib.gather_rows_u8_raw.argtypes = [
             u8p, ctypes.c_int64, i64p, ctypes.c_int64, u8p,
         ]
@@ -153,6 +155,27 @@ def gather_rows_u8_raw(data: np.ndarray, indices: np.ndarray) -> np.ndarray:
     return out.reshape((len(idx),) + data.shape[1:])
 
 
+def _crops_natively(data: np.ndarray) -> bool:
+    return (
+        _build_and_load() is not None
+        and data.dtype == np.uint8
+        and bool(data.flags["C_CONTIGUOUS"])
+    )
+
+
+def crop_paths(data: np.ndarray) -> tuple:
+    """``(unflipped, flipped)``: the path :func:`crop_gather_u8` takes for
+    an image of ``data`` by its flip bit, as the labels of
+    ``znicz_loader_crop_images_total{path}``.  ``("numpy", "numpy")``
+    where the library is not used at all; else ``"copy"`` (one ``memcpy``
+    a row) and, for a flipped image, ``"flip_wide"`` (3-byte pixels on a
+    CPU with SSSE3: sixteen bytes a turn) or ``"flip_pixel"``."""
+    if not _crops_natively(data):
+        return "numpy", "numpy"
+    wide = _build_and_load().crop_flip_is_wide(data.shape[-1])
+    return "copy", "flip_wide" if wide else "flip_pixel"
+
+
 def crop_gather_u8(
     data: np.ndarray,
     indices: np.ndarray,
@@ -161,6 +184,7 @@ def crop_gather_u8(
     flip: np.ndarray,
     out_h: int,
     out_w: int,
+    out: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Fused gather + crop + optional horizontal flip from packed u8 images.
 
@@ -168,6 +192,12 @@ def crop_gather_u8(
     size (out_h, out_w) is copied (W-reversed when flip[i]).  Output stays u8;
     normalization happens on-device.  Numpy fallback when the native library
     is unavailable or ``data`` is non-contiguous/mmap-backed-but-fancy.
+    An unflipped row is one ``memcpy``; a flipped row of 3-byte pixels is
+    reversed five pixels (a 16-byte load, one byte shuffle, a 16-byte
+    store) a turn where the CPU has SSSE3, so a flipped crop costs what
+    an unflipped one does (:func:`crop_paths` says which path runs here).
+    ``out`` (optional): a C-contiguous [B, out_h, out_w, C] array of
+    ``data``'s dtype to write into instead of a fresh one.
     """
     n, h, w, c = data.shape
     idx = _check_indices(indices, n)
@@ -179,21 +209,27 @@ def crop_gather_u8(
     ):
         raise IndexError("crop window out of image bounds")
     flip_u8 = np.ascontiguousarray(flip, np.uint8)
-    lib = _build_and_load()
+    shape = (len(idx), out_h, out_w, c)
+    if out is None:
+        out = np.empty(shape, data.dtype)
+    elif (
+        out.shape != shape
+        or out.dtype != data.dtype
+        or not out.flags["C_CONTIGUOUS"]
+        or not out.flags["WRITEABLE"]
+    ):
+        raise ValueError(
+            f"out must be a writable C-contiguous {data.dtype} array of "
+            f"shape {shape}"
+        )
     # np.memmap works here too: the C side reads through page faults, which
     # is exactly how a larger-than-RAM packed dataset streams from disk
-    if (
-        lib is not None
-        and data.dtype == np.uint8
-        and data.flags["C_CONTIGUOUS"]
-    ):
-        out = np.empty((len(idx), out_h, out_w, c), np.uint8)
-        lib.crop_gather_u8(
+    if _crops_natively(data):
+        _build_and_load().crop_gather_u8(
             data.reshape(-1), h, w, c, idx, oy, ox, flip_u8, len(idx),
             out_h, out_w, out.reshape(-1),
         )
         return out
-    out = np.empty((len(idx), out_h, out_w, c), data.dtype)
     for i, j in enumerate(idx):
         win = data[j, oy[i] : oy[i] + out_h, ox[i] : ox[i] + out_w]
         out[i] = win[:, ::-1] if flip_u8[i] else win

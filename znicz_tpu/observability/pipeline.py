@@ -102,6 +102,8 @@ H2D_BPS_METRIC = "znicz_h2d_bytes_per_second"
 H2D_BYTES_METRIC = "znicz_h2d_bytes_total"
 QUEUE_FULL_METRIC = "znicz_prefetch_queue_full_total"
 PREFETCH_EPOCHS_METRIC = "znicz_prefetch_epochs_total"
+CROP_IMAGES_METRIC = "znicz_loader_crop_images_total"
+STAGING_BUFFERS_METRIC = "znicz_loader_staging_buffers_total"
 
 # anomaly surfaces the doctor reads from the same exposition
 ANOMALY_ACTIVE_METRIC = "znicz_train_anomaly_active"
@@ -180,6 +182,34 @@ def prefetch_epochs(registry: Optional[MetricsRegistry] = None):
         "producer (start = cold: the thread was started for the epoch / "
         "carried: it went on from the previous epoch)",
         ("start",),
+    )
+
+
+def crop_images(registry: Optional[MetricsRegistry] = None):
+    """Images cropped on the host, by the path the crop took
+    (get-or-create)."""
+    reg = registry if registry is not None else get_registry()
+    return reg.counter(
+        CROP_IMAGES_METRIC,
+        "images ImageNetLoader.fill cropped on the host (path = copy: "
+        "unflipped, a memcpy a row / flip_wide: flipped, sixteen bytes a "
+        "turn / flip_pixel: flipped, pixel by pixel / numpy: the native "
+        "library was not used)",
+        ("path",),
+    )
+
+
+def staging_buffers(registry: Optional[MetricsRegistry] = None):
+    """Batches of host crops by where their buffer came from
+    (get-or-create)."""
+    reg = registry if registry is not None else get_registry()
+    return reg.counter(
+        STAGING_BUFFERS_METRIC,
+        "batches ImageNetLoader.fill cropped on the host (source = "
+        "recycled: into a buffer of an earlier batch that nothing refers "
+        "to any more / fresh: into a new allocation, which the kernel "
+        "zero-fills page by page under the crop's first writes)",
+        ("source",),
     )
 
 
