@@ -8,7 +8,8 @@ by the programs' names (``jit__paged_decode_chunk`` /
 ``engine._paged_decode_chunk`` and unpack its 7 (classic tower) or 8 (a
 ``model=`` tower) values, the drivers and ``chip_smoke.py`` build
 ``PagedDecodeEngine`` and ``ServingFrontDoor`` by keyword, and the readers of
-``smallthinker-serve-mixed-lengths`` read series and scopes by name."""
+``smallthinker-serve-mixed-lengths`` and ``dots3-serve-long-docs`` read
+series and scopes by name."""
 
 import ast
 import inspect
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 from test_latent_lm import Toy
+from test_sparse_latent_lm import Toy as SparseToy
 from test_window_gqa_lm import Toy as WindowToy, _series
 from znicz_tpu.core import prng
 from znicz_tpu.services import engine
@@ -30,6 +32,7 @@ BUILDERS = (
     "benchmarks/drivers/serve_open_loop.py",
     "benchmarks/drivers/serve_latent_moe.py",
     "benchmarks/drivers/serve_window_moe.py",
+    "benchmarks/drivers/serve_sparse_latent.py",
     "chip_smoke.py",
 )
 
@@ -78,11 +81,13 @@ def test_the_traced_programs_keep_their_names(monkeypatch):
 TOWERS = {
     "classic": _classic_engine, "latent": lambda: Toy().engine(),
     "window": lambda: WindowToy().engine(),
+    "sparse": lambda: SparseToy().engine(),
 }
 
 
 @pytest.mark.parametrize(
-    "tower, values", [("classic", 7), ("latent", 8), ("window", 8)]
+    "tower, values",
+    [("classic", 7), ("latent", 8), ("window", 8), ("sparse", 8)],
 )
 def test_the_decode_chunk_returns_what_the_benchmark_unpacks(
     tower, values, monkeypatch
@@ -139,3 +144,30 @@ def test_the_window_towers_series_and_scopes_keep_their_names(monkeypatch):
         "znicz_serve_decode_gathered_tokens_total",
     ):
         assert _series(name), name
+
+
+def test_the_selecting_towers_series_and_scopes_keep_their_names(monkeypatch):
+    """What ``benchmarks/layer_metrics/{dsa.*,mla.window*}.py`` and
+    ``harness/dots3_readers.py`` read: the registry series by name and
+    label, and the scopes inside BOTH programs."""
+    real = {
+        name: getattr(engine, name)
+        for name in ("_paged_decode_chunk", "_paged_prefill_prog")
+    }
+    seen = _calls_of_one_served_request(SparseToy().engine(), monkeypatch)
+    for name, fn in real.items():
+        shapes, kwargs, _ = seen[name]
+        text = fn.lower(*shapes, **kwargs).as_text(debug_info=True)
+        assert f"@jit_{name}" in text
+        for scope in (
+            "dsa_indexer", "dsa_select", "mla_sparse", "mla_window",
+            "moe_dispatch", "moe_experts",
+        ):
+            assert f"/{scope}/" in text, (name, scope)
+    for phase in ("prefill", "decode"):
+        for name in ("scored", "selected"):
+            assert _series(f"znicz_serve_sparse_keys_{name}_total", phase=phase)
+        assert _series("znicz_serve_moe_pairs_total", phase=phase)
+    for kind in ("global", "window"):
+        assert _series("znicz_serve_decode_cached_rows_total", kind=kind)
+        assert _series("znicz_serve_pool_blocks_in_use", kind=kind)

@@ -180,3 +180,62 @@ def test_latent_decode_attention_compiles(chip, window):
         spec((4096, 128, 640), jnp.bfloat16), spec((128, window), jnp.int32),
         spec((128,), jnp.int32),
     )
+
+
+@pytest.mark.parametrize("table_width", [32, 264])
+def test_index_decode_scores_compiles(chip, table_width):
+    # dots3-ep16-l5 as served: 64 slots, 64 indexer heads of 128, 8,192
+    # blocks of 128 indexer keys of 128 bfloat16; the engine's decode window
+    # doubles from 32 blocks (the shortest prompt's rung) to 264
+    from znicz_tpu.ops.pallas.sparse_index import index_decode_scores
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    _compile(
+        index_decode_scores, spec((64, 64, 128), jnp.bfloat16),
+        spec((64, 64), jnp.float32), spec((8192, 128, 128), jnp.bfloat16),
+        spec((64, table_width), jnp.int32), spec((64,), jnp.int32),
+    )
+
+
+def test_window_latent_decode_attention_compiles(chip):
+    # dots3-ep16-l5's window layers as served: 64 slots, 64 heads, 512
+    # blocks of 128 rows of 1,152 bfloat16 lanes, a ring of 6 turned to
+    # start at the window's first block, the window's first key a lower
+    # bound; the weighted sum over the 1,024 lanes of the latent
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q_row, pool, tables, lengths, starts):
+        return latent_decode_attention(
+            q_row, pool, tables, lengths, scale=0.0625, d_out=1024,
+            starts=starts,
+        )
+
+    _compile(
+        fn, spec((64, 64, 1152), jnp.bfloat16),
+        spec((512, 128, 1152), jnp.bfloat16), spec((64, 6), jnp.int32),
+        spec((64,), jnp.int32), spec((64,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("table_width", [32, 264])
+def test_latent_decode_attention_under_a_keep_mask_compiles(chip, table_width):
+    # dots3-ep16-l5's full layers as served: 64 slots, 128 heads, 8,192
+    # blocks of 128 rows of 640 bfloat16, and which of a row's keys its
+    # query keeps (2,048 of them) as a mask over the table's positions
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q_row, pool, tables, lengths, keep):
+        return latent_decode_attention(
+            q_row, pool, tables, lengths, scale=0.0722, d_out=512, keep=keep
+        )
+
+    _compile(
+        fn, spec((64, 128, 640), jnp.bfloat16),
+        spec((8192, 128, 640), jnp.bfloat16),
+        spec((64, table_width), jnp.int32), spec((64,), jnp.int32),
+        spec((64, table_width * 128), jnp.bool_),
+    )

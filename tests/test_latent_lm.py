@@ -251,35 +251,70 @@ def test_dropless_dispatch_matches_a_loop_over_experts(toy, masked):
     assert int(jnp.sum(pairs)) == held.sum()
 
 
-def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
-    """The guide's share test: each of the 4 chips of a 16-expert layer
-    computes its own experts' part; with the shared expert counted once
-    the parts add up to what the reference gives for the WHOLE layer."""
-    whole = Toy(first_expert=0, held=16, seed=5)
+def _whole_routed_layer(scored_with_bias: bool):
+    """``(reference's feed_forward, its cfg, a routed block holding all 16
+    experts, route)`` of a tower with a plain sigmoid router (this file's)
+    or with a score bias an expert (the selecting tower's, whose reference
+    is ``benchmarks/reference/dots3.py``)."""
+    if not scored_with_bias:
+        whole = Toy(first_expert=0, held=16, seed=5)
+        block = whole.params[2]
+
+        def route(h):
+            return moe.route_sigmoid_topk(h, block["router"], top_k=4, scale=2.5)
+
+        return whole.ref.feed_forward, whole.cfg, block, route
+    from test_sparse_latent_lm import Toy as SparseToy
+
+    whole = SparseToy(first_expert=0, held=16, seed=5)
     block = whole.params[2]
+    assert float(jnp.abs(block["router_bias"]).max()) > 0
+
+    def route(h):
+        return moe.route_sigmoid_topk(
+            h, block["router"], top_k=3, bias=block["router_bias"]
+        )
+
+    return whole.ref.feed_forward, whole.cfg, block, route
+
+
+@pytest.mark.parametrize(
+    "shares, scored_with_bias", [(4, False), (16, True)],
+    ids=["4-shares", "16-shares-score-bias"],
+)
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
+    shares, scored_with_bias
+):
+    """The guide's share test: each of the chips that share a 16-expert
+    layer computes its own experts' part; with the shared expert counted
+    once the parts add up to what the reference gives for the WHOLE
+    layer.  With a score bias the choice follows the biased scores and the
+    weights the unbiased ones, in every share alike."""
+    feed_forward, cfg, block, route = _whole_routed_layer(scored_with_bias)
     rng = np.random.default_rng(5)
     h = jnp.asarray(rng.standard_normal((13, 64)), jnp.float32)
 
     def mm(a, b):
         return a @ b
 
-    want = whole.ref.feed_forward(whole.cfg, block, h, mm, 0)
-    chosen, weight = moe.route_sigmoid_topk(h, block["router"], top_k=4, scale=2.5)
+    want = feed_forward(cfg, block, h, mm, 0)
+    chosen, weight = route(h)
     total = latent_lm._gated(
         h, block["shared_gate"], block["shared_up"], block["shared_down"]
     )
-    seen = 0
-    for chip in range(4):
-        held = slice(4 * chip, 4 * chip + 4)
+    seen, each = 0, 16 // shares
+    for chip in range(shares):
+        held = slice(each * chip, each * (chip + 1))
         part, pairs = moe.held_experts_apply(
             h, chosen, weight, block["experts_gate"][held],
             block["experts_up"][held], block["experts_down"][held],
-            first_expert=4 * chip,
+            first_expert=each * chip,
         )
         total = total + part
         seen += int(jnp.sum(pairs))
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
-    assert seen == 13 * 4  # every (token, choice) pair computed exactly once
+    # every (token, choice) pair computed exactly once
+    assert seen == 13 * chosen.shape[1]
 
 
 def test_a_sliced_head_gives_the_matching_columns_of_the_whole_head(toy):

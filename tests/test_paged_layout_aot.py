@@ -468,3 +468,168 @@ def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
             dims for dims in _SHAPE.findall(text)
             if int(np.prod([int(d) for d in dims.split(",")])) in gathered
         ], "the decode chunk gathers a window or a ring"
+
+
+# -- two kinds of LATENT rows of different widths, at dots3-ep16-l5's ------
+#
+# 5 layers (full and dense, full, window, window, window) at hidden 5120;
+# full layers 128 heads over a latent of 512 with an indexer of 64 heads
+# that keeps 2,048 keys, window layers 64 heads over a latent of 1,024
+# behind 513 keys; 16 of 256 experts of width 1,536 held, an eighth of the
+# vocabulary, bfloat16; 64 slots, block 128, 8,192 global blocks of 640
+# lanes of latent rows and 128 of indexer keys beside them, 512 window
+# blocks of 1,152 lanes (whole tiles all), the widest global rung (264
+# blocks = 33,792 positions) beside the window kind's ring of 6.  With the
+# indexer's key in the tail lanes of one 768-lane row the compiler re-laid
+# the whole global pool for the indexer's lane-sliced gather: a copy of
+# 1.6 GB a full layer in every call of either program (my AOT compiles,
+# PR 36); with a pool of its own there is none.
+
+D3 = dict(d=5120, vocab=19008, held=16, experts=256, f=1536, f_dense=13824,
+          slots=64, block=128, rung=264, ring=6, top_k=2048,
+          n_blocks={"global": 8192, "window": 512})
+
+
+def _dots3_model():
+    from znicz_tpu.workflow.sparse_latent_lm import (
+        LatentSizes, SparseLatentMoEModel,
+    )
+
+    return SparseLatentMoEModel(
+        full=LatentSizes(128, 512, 128, 64, 8e7, 5 ** 0.5, 10 ** 0.5),
+        swa=LatentSizes(64, 1024, 192, 64, 5e4, 5 ** 0.5, 5 ** 0.5),
+        full_layers=(True, True, False, False, False), window=513,
+        index_n_heads=64, index_head_dim=128, index_topk=D3["top_k"],
+        top_k=8, routed_scaling_factor=1.0, first_expert=80,
+        max_positions=524288,
+    )
+
+
+def _dots3_params(spec):
+    a, bf, f32 = D3, jnp.bfloat16, jnp.float32
+    d, f, held = a["d"], a["f"], a["held"]
+
+    def attention(heads, d_q, d_c, d_n, full):
+        leaves = {
+            "attn_norm": ((d,), f32), "wq_a": ((d, d_q), bf),
+            "q_norm": ((d_q,), f32), "wq_b_nope": ((d_q, heads * d_n), bf),
+            "wq_b_rope": ((d_q, heads * 64), bf), "wkv_a": ((d, d_c + 64), bf),
+            "kv_norm": ((d_c,), f32), "wk_b": ((d_c, heads * d_n), bf),
+            "wv_b": ((d_c, heads * 128), bf), "wg": ((d, heads), bf),
+            "wo": ((heads * 128, d), bf), "ffn_norm": ((d,), f32),
+        }
+        if full:
+            leaves.update(
+                wq_idx=((d_q, 64 * 128), bf), wk_idx=((d, 128), bf),
+                k_idx_gain=((128,), f32), k_idx_bias=((128,), f32),
+                w_idx=((d, 64), bf),
+            )
+        return leaves
+
+    dense = {"w_gate": ((d, a["f_dense"]), bf), "w_up": ((d, a["f_dense"]), bf),
+             "w_down": ((a["f_dense"], d), bf)}
+    routed = {
+        "router": ((d, a["experts"]), bf), "router_bias": ((a["experts"],), f32),
+        "experts_gate": ((held, d, f), bf), "experts_up": ((held, d, f), bf),
+        "experts_down": ((held, f, d), bf), "shared_gate": ((d, f), bf),
+        "shared_up": ((d, f), bf), "shared_down": ((f, d), bf),
+    }
+    full = attention(128, 1024, 512, 128, True)
+    swa = attention(64, 1024, 1024, 192, False)
+    blocks = [{**full, **dense}, {**full, **routed}] + [{**swa, **routed}] * 3
+    return (
+        [{"embed": spec((a["vocab"], d), bf)}]
+        + [{k: spec(*v) for k, v in block.items()} for block in blocks]
+        + [{"final_norm": spec((d,), f32), "head": spec((d, a["vocab"]), bf)}]
+    )
+
+
+# what my AOT compiles read, PR 36, in GB of temporaries: see the test
+DOTS3_TEMP_LIMIT_GB = {"decode_chunk": 0.7, "prefill": 0.6}  # read: 0.35, 0.27
+
+
+@pytest.mark.parametrize("program", list(DOTS3_TEMP_LIMIT_GB))
+def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
+    chip, program, monkeypatch
+):
+    from znicz_tpu.core import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    a, model = D3, _dots3_model()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = _dots3_params(spec)
+    pools = jax.tree.map(
+        lambda p: spec(p.shape, p.dtype),
+        jax.eval_shape(
+            lambda: model.init_pools(params, a["n_blocks"], a["block"])
+        ),
+    )
+    assert [p["kv"].shape for p in pools] == (
+        [(8192, 128, 640)] * 2 + [(512, 128, 1152)] * 3
+    )
+    assert [p["idx"].shape for p in pools[:2]] == [(8192, 128, 128)] * 2
+    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
+    tower = dict(
+        n_heads=128, block_size=a["block"], moe_top_k=1,
+        moe_dispatch="dense", model=model,
+    )
+    with jax.default_matmul_precision("default"):
+        if program == "decode_chunk":
+            tables = {
+                "global": spec((a["slots"], a["rung"]), i32),
+                "window": spec((a["slots"], a["ring"]), i32),
+            }
+            lowered = engine._paged_decode_chunk.lower(
+                params, pools, tables, rows_i32, rows_i32,
+                spec((a["slots"],), jnp.bool_), rows_i32, scalar_f32,
+                scalar_f32, key, chunk=CHUNK, t_max=33792, eos_id=0,
+                **SAMPLING, **tower,
+            )
+        else:
+            table = {
+                "global": spec((a["rung"],), i32),
+                "window": spec((a["ring"],), i32),
+            }
+            lowered = engine._paged_prefill_prog.lower(
+                params, pools, table, spec((1, a["block"]), i32), scalar_i32,
+                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+            )
+        compiled = lowered.compile()  # raises where the chip would refuse it
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(
+        f"dots3 {program}: arguments {mem.argument_size_in_bytes / GB:.2f} GB, "
+        f"temporaries {mem.temp_size_in_bytes / GB:.2f} GB, "
+        f"{text.count('tpu_custom_call')} kernels"
+    )
+    # neither pool is copied or re-tiled, in or out of either program, and
+    # no window of the table's width is gathered from the global pool (a
+    # full layer reads its indexer keys and its latent rows a chunk of the
+    # table at a time, or in place)
+    full_row, swa_row, idx_row = a["block"] * 640, a["block"] * 1152, a["block"] * 128
+    window = (1 if program == "prefill" else a["slots"]) * a["rung"] * full_row
+    moved = _relayouts(
+        text, 8192 * full_row, 8192 * idx_row, 512 * swa_row, window
+    )
+    assert not moved, f"{program} moves a pool or a window: {sorted(set(moved))}"
+    assert not [
+        dims for dims in _SHAPE.findall(text)
+        if int(np.prod([int(d) for d in dims.split(",")])) == window
+    ], f"{program} gathers the table's whole width"
+    assert mem.temp_size_in_bytes < DOTS3_TEMP_LIMIT_GB[program] * GB, (
+        f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
+    )
+    # weights 5.15 GB + pools 3.67 GB + temporaries fit 15.75 GiB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    # the grouped products of the four routed layers are the kernel, and in
+    # a decode step every layer's attention is one too, and the two full
+    # layers' indexers
+    assert text.count("tpu_custom_call") >= (
+        3 * 4 + (3 + 2 + 2 if program == "decode_chunk" else 0)
+    )

@@ -19,6 +19,7 @@ import numpy as np
 from znicz_tpu.core import backend, prng
 from znicz_tpu.ops.filling import fill
 from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
+from znicz_tpu.ops.pallas.sparse_index import index_decode_scores
 
 
 def dot_product_attention(
@@ -478,6 +479,328 @@ def _paged_latent_attention(
     if lengths is not None:  # an idle row's result is zeros in either form
         o = jnp.where(lengths[:, None, None] > 0, o, 0.0)
     return o
+
+
+def _absorbed_queries(q_nope, q_rope, wk_b, width, dtype):
+    """``[q_nope wk_b[h]^T, q_rope, zeros]`` [B, Tq, H, width]: the queries
+    of the absorbed form, laid out like the cached rows they meet."""
+    b, tq, h, d_nope = q_nope.shape
+    d_latent = wk_b.shape[0]
+    q_abs = jnp.einsum(
+        "bthn,chn->bthc", q_nope.astype(dtype),
+        wk_b.reshape(d_latent, h, d_nope), preferred_element_type=jnp.float32,
+    )
+    pad = width - d_latent - q_rope.shape[-1]
+    return jnp.concatenate(
+        [q_abs.astype(dtype), q_rope.astype(dtype),
+         jnp.zeros((b, tq, h, pad), dtype)],
+        axis=-1,
+    )
+
+
+def _unfolded(o_latent, wv_b, lengths):
+    """The absorbed form's result [B, Tq, H, >= d_latent] through
+    ``wv_b``: [B, Tq, H * d_v] float32, zeros for a decode step's idle
+    rows."""
+    b, tq, h, _ = o_latent.shape
+    d_latent = wv_b.shape[0]
+    o = jnp.einsum(
+        "bthc,chv->bthv", o_latent[..., :d_latent].astype(wv_b.dtype),
+        wv_b.reshape(d_latent, h, -1), preferred_element_type=jnp.float32,
+    ).reshape(b, tq, -1)
+    if lengths is not None:
+        o = jnp.where(lengths[:, None, None] > 0, o, 0.0)
+    return o
+
+
+# table entries whose indexer keys are gathered and scored at a time
+INDEX_CHUNK_BLOCKS = 16
+
+
+def _table_chunks(block_table, last, block_size):
+    """How the loops below walk a block table ``INDEX_CHUNK_BLOCKS`` entries
+    at a time: ``(table padded to whole chunks, entries a chunk, chunks,
+    chunks that reach position max(last))``."""
+    m = block_table.shape[1]
+    step = min(INDEX_CHUNK_BLOCKS, m)
+    n_chunks = -(-m // step)
+    table = jnp.pad(block_table, ((0, 0), (0, n_chunks * step - m)))
+    return table, step, n_chunks, jnp.max(last) // (block_size * step) + 1
+
+
+def paged_index_scores(
+    q_idx: jnp.ndarray,  # [B, Tq, J, d_idx], rotated
+    w_idx: jnp.ndarray,  # [B, Tq, J] float32, the heads' weights
+    idx_pool: jnp.ndarray,  # [N_blocks, block_size, d_idx]
+    block_table: jnp.ndarray,  # [B, M] int32 pool block ids
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    *,
+    block_size: int,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+) -> jnp.ndarray:
+    """The learned INDEXER's score of every cached token for every query
+    (DeepSeek-V3.2-Exp lineage): ``I[t, s] = sum_j w[t, j] * relu(q[t, j]
+    . k[s])`` over the ``J`` indexer heads, [B, Tq, M * block_size]
+    float32, ``-inf`` where key ``s`` lies past the query (or past a
+    decode row's ``lengths``).  The caller folds the constant factors
+    into ``w_idx``.
+
+    The indexer's keys live in a pool of their own, block for block
+    beside the latent rows (one table, one allocator state).  They are
+    read through the block table ``INDEX_CHUNK_BLOCKS`` entries at a time
+    and only as far as the furthest query: the loop's trip count follows
+    the positions, so a chunk early in a prompt does not pay for the
+    table's width, and the [queries, heads, keys] products exist one
+    chunk of keys at a time.  On the TPU a decode step (``Tq`` 1) reads
+    the pool IN PLACE instead, each live row as far as its own length
+    (:func:`~znicz_tpu.ops.pallas.sparse_index.index_decode_scores`).
+    Products take the pool's dtype and accumulate in float32; ReLU and the
+    sum over heads are float32."""
+    with jax.named_scope("dsa_indexer"):
+        b, tq, _, d_idx = q_idx.shape
+        m = block_table.shape[1]
+        key = jnp.arange(m * block_size)[None, None, :]
+        seen = key <= q_pos[:, :, None]
+        if lengths is not None:
+            seen = seen & (key < lengths[:, None, None])
+        if _reads_pool_in_place(tq):
+            scores = index_decode_scores(
+                q_idx[:, 0].astype(idx_pool.dtype), w_idx[:, 0], idx_pool,
+                block_table, q_pos[:, 0] + 1 if lengths is None else lengths,
+            )[:, None]
+            return jnp.where(seen, scores, -jnp.inf)
+        last = q_pos[:, -1] if lengths is None else jnp.maximum(lengths - 1, 0)
+        table, step, n_chunks, needed = _table_chunks(block_table, last, block_size)
+        q = q_idx.astype(idx_pool.dtype)
+
+        def chunk(i, scores):
+            blks = jax.lax.dynamic_slice_in_dim(table, i * step, step, axis=1)
+            keys = idx_pool[blks].reshape(b, step * block_size, d_idx)
+            s = jnp.einsum(
+                "btjd,bkd->btjk", q, keys, preferred_element_type=jnp.float32
+            )
+            s = jnp.einsum("btjk,btj->btk", jax.nn.relu(s), w_idx)
+            return jax.lax.dynamic_update_slice_in_dim(
+                scores, s, i * step * block_size, axis=2
+            )
+
+        scores = jax.lax.fori_loop(
+            0, needed, chunk,
+            jnp.zeros((b, tq, n_chunks * step * block_size), jnp.float32),
+        )[..., : m * block_size]
+        return jnp.where(seen, scores, -jnp.inf)
+
+
+def select_top_keys(scores: jnp.ndarray, top_k: int) -> jnp.ndarray:
+    """Which keys are among the ``top_k`` best-scored of their query,
+    EXACTLY: ``keep`` [B, Tq, keys] bool.  Keys whose score is ``-inf``
+    (not visible) are never kept, so a query that sees no more than
+    ``top_k`` keys keeps all of them; of equal scores at the cut the
+    earlier keys are kept.
+
+    No sort: the ``top_k``-th largest score of a row is found bit by bit
+    (a float32's bits, turned so that they order as integers: 32 counts
+    of ``score >= candidate`` over the row), which at 128 x 33,792 scores
+    costs a twelfth of ``jax.lax.top_k``'s sort on the v5e (PERF.md
+    section 6, PR 36), and the result is a mask over the keys as they lie
+    in the pool, which is what the attention over them reads."""
+    with jax.named_scope("dsa_select"):
+        bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+        order = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+        k = jnp.int32(top_k)
+
+        def count_at_least(t):
+            return jnp.sum(order >= t, axis=-1, keepdims=True, dtype=jnp.int32)
+
+        least = jnp.where(
+            count_at_least(jnp.int32(0)) >= k, jnp.int32(0),
+            jnp.int32(-(2 ** 31)),
+        )
+        for bit in range(30, -1, -1):
+            candidate = least | jnp.int32(1 << bit)
+            least = jnp.where(count_at_least(candidate) >= k, candidate, least)
+        # ``least`` is the top_k-th largest (the smallest of all where
+        # fewer than top_k are finite); ties at it go to the earlier keys
+        above, ties = order > least, order == least
+        room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+        ties = ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room)
+        return (above | ties) & (scores > -jnp.inf)
+
+
+# a masked key's score: finite, so that a chunk none of whose keys a query
+# keeps does not turn the running softmax into inf - inf
+_NEG = -1e30
+
+
+def kept_latent_attention(
+    q_nope: jnp.ndarray,  # [B, Tq, H, d_nope]
+    q_rope: jnp.ndarray,  # [B, Tq, H, d_rope], rotated
+    pool: jnp.ndarray,  # [N_blocks, block_size, W]: [c, rot(k_r), zeros]
+    block_table: jnp.ndarray,  # [B, M] int32 pool block ids
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    keep: jnp.ndarray,  # [B, Tq, M * block_size] bool: the keys attended
+    wk_b: jnp.ndarray,  # [d_latent, H * d_nope]
+    wv_b: jnp.ndarray,  # [d_latent, H * d_v]
+    *,
+    block_size: int,
+    scale: float,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+) -> jnp.ndarray:
+    """Absorbed latent attention of every query over the keys ``keep``
+    names for it; [B, Tq, H * d_v] float32.  A query that keeps no key
+    gives zeros.
+
+    The rows are read through the block table as they lie in the pool,
+    block by block, and a key a query does not keep is masked out of its
+    softmax: what the selection spares is not the fetch (with 2,048 of a
+    row's 3k-33k keys kept nearly every block of 128 holds one) but the
+    need to form anything at the table's width.  On the TPU a decode step
+    is :func:`~znicz_tpu.ops.pallas.latent_attention
+    .latent_decode_attention` with ``keep`` as its mask: each live row's
+    blocks by DMA as far as its own length.  A prefill chunk, and
+    everything off the TPU, walks the table ``INDEX_CHUNK_BLOCKS`` entries
+    at a time with a running softmax (the loop's trip count follows the
+    furthest query, as :func:`paged_index_scores`'s does), so scores exist
+    for one chunk of keys at a time and all ``Tq`` queries share each
+    fetch."""
+    with jax.named_scope("mla_sparse"):
+        b, tq, h, _ = q_nope.shape
+        d_latent = wk_b.shape[0]
+        dtype, width = pool.dtype, pool.shape[-1]
+        f32 = dict(preferred_element_type=jnp.float32)
+        q_row = _absorbed_queries(q_nope, q_rope, wk_b, width, dtype)
+        last = q_pos[:, -1] if lengths is None else jnp.maximum(lengths - 1, 0)
+        if _reads_pool_in_place(tq):
+            o_latent = latent_decode_attention(
+                q_row[:, 0], pool, block_table,
+                last + 1 if lengths is None else lengths, scale=scale,
+                d_out=min(-(-d_latent // 128) * 128, width), keep=keep[:, 0],
+            )[:, None]
+            return _unfolded(o_latent, wv_b, lengths)
+        table, step, n_chunks, needed = _table_chunks(block_table, last, block_size)
+        keys = step * block_size
+        keep = jnp.pad(
+            keep, ((0, 0), (0, 0), (0, n_chunks * keys - keep.shape[-1]))
+        )
+
+        def chunk(i, carry):
+            top, total, acc = carry
+            blks = jax.lax.dynamic_slice_in_dim(table, i * step, step, axis=1)
+            rows = pool[blks].reshape(b, keys, width)
+            kept = jax.lax.dynamic_slice_in_dim(keep, i * keys, keys, axis=2)
+            kept = kept[:, :, None, :]
+            s = jnp.einsum("bthe,bke->bthk", q_row, rows, **f32) * scale
+            s = jnp.where(kept, s, _NEG)
+            new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(kept, jnp.exp(s - new_top), 0.0)
+            turn = jnp.exp(top - new_top)
+            # over the whole row: a slice of the fetched rows is a copy
+            acc = turn * acc + jnp.einsum(
+                "bthk,bke->bthe", p.astype(dtype), rows, **f32
+            )
+            total = turn * total + jnp.sum(p, axis=-1, keepdims=True)
+            return new_top, total, acc
+
+        _, total, acc = jax.lax.fori_loop(
+            0, needed, chunk,
+            (
+                jnp.full((b, tq, h, 1), _NEG, jnp.float32),
+                jnp.zeros((b, tq, h, 1), jnp.float32),
+                jnp.zeros((b, tq, h, width), jnp.float32),
+            ),
+        )
+        return _unfolded(acc / jnp.maximum(total, 1e-30), wv_b, lengths)
+
+
+def paged_selected_latent_attention(
+    q_nope, q_rope, q_idx, w_idx, pool, idx_pool, block_table, q_pos, wk_b,
+    wv_b, *, block_size: int, scale: float, top_k: int,
+    lengths: Optional[jnp.ndarray] = None,
+):
+    """Latent attention that keeps ``top_k`` keys a query, chosen by a
+    learned indexer, over a paged pool of latent rows ``[c, rot(k_r),
+    zeros]`` and, block for block beside it, a pool of the indexer's keys:
+    :func:`paged_index_scores` of every cached token,
+    :func:`select_top_keys`, :func:`kept_latent_attention`.  The same
+    three steps serve a prefill chunk (``Tq`` queries of one row) and a
+    decode step (``Tq`` 1, ``lengths``: 0 marks a row that idles, whose
+    result is zeros).  Returns ``(o [B, Tq, H * d_v] float32, scored,
+    selected)``: the keys the indexer scored and the keys attention kept,
+    int32 sums over the call's queries."""
+    scores = paged_index_scores(
+        q_idx, w_idx, idx_pool, block_table, q_pos, block_size=block_size,
+        lengths=lengths,
+    )
+    keep = select_top_keys(scores, top_k)
+    o = kept_latent_attention(
+        q_nope, q_rope, pool, block_table, q_pos, keep, wk_b, wv_b,
+        block_size=block_size, scale=scale, lengths=lengths,
+    )
+    return (
+        o, jnp.sum(scores > -jnp.inf, dtype=jnp.int32),
+        jnp.sum(keep, dtype=jnp.int32),
+    )
+
+
+def paged_window_latent_attention(
+    q_nope: jnp.ndarray,  # [B, Tq, H, d_nope]
+    q_rope: jnp.ndarray,  # [B, Tq, H, d_rope], rotated
+    pool: jnp.ndarray,  # [N_blocks, block_size, >= d_latent + d_rope]
+    block_table: jnp.ndarray,  # [B, M] int32: a RING
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    wk_b: jnp.ndarray,  # [d_latent, H * d_nope]
+    wv_b: jnp.ndarray,  # [d_latent, H * d_v]
+    *,
+    block_size: int,
+    scale: float,
+    window: int,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+) -> jnp.ndarray:
+    """Absorbed latent attention over the last ``window`` keys, the
+    query's own among them, of a paged pool of latent rows whose table is
+    a RING (:func:`ring_key_positions`; a call's queries of one row lie in
+    one block); [B, Tq, H * d_v] float32.  On the TPU a decode step reads
+    the pool in place through :func:`~znicz_tpu.ops.pallas
+    .latent_attention.latent_decode_attention` (the ring turned so that
+    the window's first block leads, as :func:`paged_gqa_attention` does);
+    a prefill chunk, and everything off the TPU, gathers the ring, which
+    is a few blocks wide whatever the row's length."""
+    with jax.named_scope("mla_window"):
+        b, tq, h, _ = q_nope.shape
+        d_latent = wk_b.shape[0]
+        dtype, width = pool.dtype, pool.shape[-1]
+        if lengths is not None and tq != 1:
+            raise ValueError(
+                f"lengths are a decode step's; got {tq} queries a row"
+            )
+        q_row = _absorbed_queries(q_nope, q_rope, wk_b, width, dtype)
+        if _reads_pool_in_place(tq):
+            table, keys, starts = _window_in_table_order(
+                block_table, q_pos[:, 0] + 1 if lengths is None else lengths,
+                block_size=block_size, window=window,
+            )
+            pad = -h % 16  # the kernel wants whole (16, 128) tiles of rows
+            o_latent = latent_decode_attention(
+                jnp.pad(q_row[:, 0], ((0, 0), (0, pad), (0, 0))), pool, table,
+                keys, scale=scale, d_out=min(-(-d_latent // 128) * 128, width),
+                starts=starts,
+            )[:, None, :h]
+            return _unfolded(o_latent, wv_b, lengths)
+        n_keys = block_table.shape[1] * block_size
+        rows = pool[block_table].reshape(b, n_keys, width)
+        k_pos = ring_key_positions(
+            block_table.shape[1], block_size, q_pos[:, -1]
+        )[:, None, None, :]
+        at = q_pos[:, :, None, None]
+        valid = (k_pos <= at) & (k_pos >= 0) & (k_pos > at - window)
+        f32 = dict(preferred_element_type=jnp.float32)
+        s = jnp.einsum("bthe,bke->bthk", q_row, rows, **f32)
+        s = jnp.where(valid, s * scale, -jnp.inf)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(dtype)
+        return _unfolded(
+            jnp.einsum("bthk,bke->bthe", p, rows, **f32), wv_b, lengths
+        )
 
 
 def init_mha_params(

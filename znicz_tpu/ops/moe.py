@@ -274,15 +274,22 @@ def route_sigmoid_topk(
     top_k: int,
     scale: float = 1.0,
     normalize: bool = True,
+    bias: Optional[jnp.ndarray] = None,  # [E] float32
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sigmoid-scored routing over ALL ``E`` experts: ``(chosen [T, k]
     int32, weights [T, k] float32)`` with ``w = scale * s / sum(s)`` over
-    the chosen (DeepSeek-V3 lineage, without its group limit or score
-    bias).  Scores are float32."""
+    the chosen (DeepSeek-V3 lineage, without its group limit).  Scores
+    are float32.  ``bias`` (``topk_method: "noaux_tc"``) is added to the
+    scores for the CHOICE only: the weights are the chosen experts'
+    unbiased scores."""
     s = jax.nn.sigmoid(
         jnp.dot(h, router, preferred_element_type=jnp.float32)
     )
-    top, idx = jax.lax.top_k(s, top_k)
+    if bias is None:
+        top, idx = jax.lax.top_k(s, top_k)
+    else:
+        _, idx = jax.lax.top_k(s + bias, top_k)
+        top = jnp.take_along_axis(s, idx, axis=-1)
     if normalize:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), top * scale

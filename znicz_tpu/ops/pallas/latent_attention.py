@@ -19,6 +19,12 @@ DMA through the block table, as far as the row's length and no further:
   block and the window's first key lies inside it).  It is one more
   scalar-prefetch operand and one more comparison in the mask; without it
   the kernel is what it was.
+- **A mask over the keys** (``keep``, optional): which of a row's keys it
+  attends at all, for a row that keeps a chosen few of them (a layer that
+  selects its keys).  It comes to VMEM with the query, a row of
+  ``[chunks, chunk_keys]`` a slot, and is one more comparison in the mask;
+  every block is still fetched (the chosen keys lie in nearly all of
+  them).  Without it the kernel is what it was.
 - **A live slot** walks its keys in chunks of ``CHUNK_BLOCKS`` blocks,
   double-buffered: while chunk ``i`` is computed, chunk ``i + 1`` (or the
   first chunk of the next live slot, so that no row starts behind an
@@ -61,17 +67,20 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(*refs, scale, block_size, chunk_blocks, table_width,
-                   bounded):
+                   bounded, masked=False):
     """``refs``: the scalar-prefetch operands ``lengths, tables, next_live``
-    (and ``starts`` when ``bounded``), then ``q`` [H, W], the pool [N,
+    (and ``starts`` when ``bounded``), then ``q`` [H, W] (and ``keep``
+    [chunks, chunk_keys] float32 when ``masked``), the pool [N,
     block_size, W] in HBM, ``o`` [H, d_out], and the scratch: ``buf`` [2,
     chunk_keys, W] with a DMA semaphore a buffer, ``state`` (SMEM [2]: the
     buffer the next chunk lands in; is it on its way), ``m``, ``l`` [H, 1]
     and ``acc`` [H, d_out] float32."""
     lengths_ref, tables_ref, next_ref = refs[:3]
     start_ref = refs[3] if bounded else None
-    (q_ref, pool_ref, o_ref, buf, sems, state, m_s, l_s, acc_s) = refs[
-        3 + bounded:
+    q_ref = refs[3 + bounded]
+    keep_ref = refs[4 + bounded] if masked else None
+    (pool_ref, o_ref, buf, sems, state, m_s, l_s, acc_s) = refs[
+        4 + bounded + masked:
     ]
     b, n_rows = pl.program_id(0), pl.num_programs(0)
     length = lengths_ref[b]
@@ -145,13 +154,18 @@ def _decode_kernel(*refs, scale, block_size, chunk_blocks, table_width,
                 # the bound lies inside the first chunk, which therefore
                 # still holds a key the row attends
                 seen = seen & (key >= start_ref[b])
+            if masked:
+                seen = seen & (keep_ref[pl.ds(i, 1), :] > 0.0)
             s = jnp.where(seen, s, NEG_INF)
             m_prev = m_s[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             # a chunk that is walked holds at least one key under the
             # length, so m_new is a real score and a masked key's weight
-            # underflows to exactly 0
+            # underflows to exactly 0 (under a ``keep`` mask a chunk may
+            # hold none that is kept: its weights are set to 0)
             p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(seen, p, 0.0)
             alpha = jnp.exp(m_prev - m_new)
             l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
             acc_s[...] = alpha * acc_s[...] + jnp.dot(
@@ -163,7 +177,12 @@ def _decode_kernel(*refs, scale, block_size, chunk_blocks, table_width,
 
         state[0] = jax.lax.fori_loop(0, n_chunks, chunk_step, first)
         state[1] = (nxt < n_rows).astype(jnp.int32)
-        o_ref[...] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+        if masked:  # a row may keep no key at all: zeros, not 0 / 0
+            o_ref[...] = (
+                acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+            ).astype(o_ref.dtype)
+        else:
+            o_ref[...] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
 
 
 def latent_decode_attention(
@@ -175,17 +194,30 @@ def latent_decode_attention(
     scale: float,
     d_out: int,
     starts: Optional[jnp.ndarray] = None,  # [B] int32, < one chunk of keys
+    keep: Optional[jnp.ndarray] = None,  # [B, M * block_size] bool
 ) -> jnp.ndarray:
     """``softmax(scale * q_row @ rows^T) @ rows[:, :d_out]`` over each
     row's first ``lengths`` cached rows, found through its block table
     (from its ``starts``-th on, where given: ``starts < lengths`` for a
-    live row, and inside the table's first ``CHUNK_BLOCKS`` blocks);
-    ``[B, H, d_out]`` in the pool's dtype.  A row of length 0 gives
-    zeros.  ``d_out`` is a whole number of 128-lane tiles (or ``W``)."""
+    live row, and inside the table's first ``CHUNK_BLOCKS`` blocks; of
+    those, only the keys ``keep`` names, where given: a row that keeps
+    none gives zeros); ``[B, H, d_out]`` in the pool's dtype.  A row of
+    length 0 gives zeros.  ``d_out`` is a whole number of 128-lane tiles
+    (or ``W``)."""
     return _attend(
-        q_row, pool, block_table, lengths, starts, scale=float(scale),
+        q_row, pool, block_table, lengths, starts, keep, scale=float(scale),
         d_out=d_out, chunk_blocks=min(CHUNK_BLOCKS, block_table.shape[1]),
         interpret=backend.pallas_interpret(),
+    )
+
+
+def next_live_slot(lengths):
+    """For each slot the next slot after it with any length ([B] int32; B
+    where there is none): whose first chunk a slot's last step fetches."""
+    b = lengths.shape[0]
+    live = jnp.where(lengths > 0, jnp.arange(b, dtype=jnp.int32), b)
+    return jnp.concatenate(
+        [jax.lax.cummin(live, reverse=True)[1:], jnp.full((1,), b, jnp.int32)]
     )
 
 
@@ -197,22 +229,29 @@ def latent_decode_attention(
     jax.jit, static_argnames=("scale", "d_out", "chunk_blocks", "interpret")
 )
 def _attend(
-    q_row, pool, block_table, lengths, starts, *, scale, d_out, chunk_blocks,
-    interpret,
+    q_row, pool, block_table, lengths, starts, keep, *, scale, d_out,
+    chunk_blocks, interpret,
 ):
     b, h, w = q_row.shape
     _, block_size, _ = pool.shape
     m = block_table.shape[1]
     lengths = lengths.astype(jnp.int32)
-    live = jnp.where(lengths > 0, jnp.arange(b, dtype=jnp.int32), b)
-    next_live = jnp.concatenate(
-        [jax.lax.cummin(live, reverse=True)[1:], jnp.full((1,), b, jnp.int32)]
-    )
+    next_live = next_live_slot(lengths)
 
     def row(i, *_):
         return (i, 0, 0)
 
-    bounded = starts is not None
+    bounded, masked = starts is not None, keep is not None
+    inputs, in_specs = (q_row,), [pl.BlockSpec((None, h, w), row)]
+    if masked:
+        chunk_keys = chunk_blocks * block_size
+        n_chunks = -(-m // chunk_blocks)
+        keep = jnp.pad(
+            keep.astype(jnp.float32),
+            ((0, 0), (0, n_chunks * chunk_keys - keep.shape[1])),
+        ).reshape(b, n_chunks, chunk_keys)
+        inputs += (keep,)
+        in_specs.append(pl.BlockSpec((None, n_chunks, chunk_keys), row))
     prefetch = (
         lengths, block_table.reshape(-1).astype(jnp.int32), next_live,
     ) + ((starts.astype(jnp.int32),) if bounded else ())
@@ -220,14 +259,12 @@ def _attend(
         partial(
             _decode_kernel, scale=scale, block_size=block_size,
             chunk_blocks=chunk_blocks, table_width=m, bounded=bounded,
+            **({"masked": True} if masked else {}),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(b,),
-            in_specs=[
-                pl.BlockSpec((None, h, w), row),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, h, d_out), row),
             scratch_shapes=[
                 pltpu.VMEM((2, chunk_blocks * block_size, w), pool.dtype),
@@ -244,4 +281,4 @@ def _attend(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(*prefetch, q_row, pool)
+    )(*prefetch, *inputs, pool)

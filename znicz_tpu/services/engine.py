@@ -1104,17 +1104,40 @@ class PagedDecodeEngine:
             "whose expert load was counted",
             ("phase",),
         )
+        # a tower that selects the keys it attends (silent for every
+        # other): sums that come back with each call's load
+        self._m_sparse_scored = observability.counter(
+            "znicz_serve_sparse_keys_scored_total",
+            "cached keys the indexer of ONE selecting layer scored, summed "
+            "over the queries of the phase's calls",
+            ("phase",),
+        )
+        self._m_sparse_selected = observability.counter(
+            "znicz_serve_sparse_keys_selected_total",
+            "cached keys ONE selecting layer's attention read after the "
+            "selection, summed over the queries of the phase's calls",
+            ("phase",),
+        )
         self._update_pool_gauges()
 
-    def _count_expert_load(self, phase: str, loads, calls: int) -> None:
-        """Fold the expert-load sums of some calls (finished by now:
-        ONE fetch for all of them) into the registry; each covers
-        ``calls`` token steps or chunks."""
-        loads = [load for load in loads if load and "pairs" in load]
+    def _count_loads(self, phase: str, loads, calls: int) -> None:
+        """Fold the load sums of some calls (finished by now: ONE fetch
+        for all of them) into the registry; each covers ``calls`` token
+        steps or chunks."""
+        loads = [load for load in loads if load]
         if not loads or not calls:
             return
         fetched = jax.device_get(loads)
         for load in fetched:
+            if "sparse_scored" in load:
+                self._m_sparse_scored.labels(phase=phase).inc(
+                    int(load["sparse_scored"])
+                )
+                self._m_sparse_selected.labels(phase=phase).inc(
+                    int(load["sparse_selected"])
+                )
+            if "pairs" not in load:
+                continue
             for expert, n in enumerate(load["pairs"]):
                 self._m_moe_pairs.labels(phase=phase, expert=expert).inc(
                     int(n)
@@ -1130,7 +1153,7 @@ class PagedDecodeEngine:
         would wait for the chunk); they are folded in at the next point
         where the host waits for the device anyway."""
         backlog, self._load_backlog = self._load_backlog, []
-        self._count_expert_load("prefill", backlog, 1)
+        self._count_loads("prefill", backlog, 1)
 
     # -- request intake ---------------------------------------------------
 
@@ -2243,7 +2266,7 @@ class PagedDecodeEngine:
             steps = int(steps)
             self._drain_load_backlog()
             load = jax.device_get(load)
-            self._count_expert_load("decode", load, steps)
+            self._count_loads("decode", load, steps)
             self._tok = np.array(tok)
             self._pos = np.array(pos)
             self._done = np.array(done)
