@@ -346,6 +346,10 @@ def phase_train(sizes: Sizes, device: str, report: Dict) -> None:
             all(d.platform == device for leaf in leaves for d in leaf.devices()),
             f"parameters are not all resident on the {device} device",
         )
+        # both conv -> norm stages run the fused tail's kernels
+        _require_kernel(
+            _step_text(wf, _one_batch(wf)), "AlexNet step (fused conv tails)"
+        )
         path = wf.snapshotter.best_path
         state, host = load_snapshot(path)  # checks the sidecar's digest
         state = TrainState(*state)

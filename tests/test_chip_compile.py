@@ -5,7 +5,8 @@ DESCRIBED ``v5e:2x2`` topology (no device attached), so what Mosaic would
 refuse on the chip — a slice off the tiling, too much VMEM, an op with no
 lowering — fails here, in tier-1, at no chip time.  Shapes are the ones
 ``chip_smoke.py`` and the model zoo run: flash attention at the mid LM's
-``[8, 2048, 8, 64|128]``, LRN at AlexNet's two normalised activations, the
+``[8, 2048, 8, 64|128]``, LRN at AlexNet's two normalised activations,
+AlexNet's two conv stages with their fused tails at batch 1024, the
 SOM step and RBM CD-1 at the MNIST zoo sizes, the latent decode attention
 at ``axk1-ep16``'s pool and three rungs of the engine's decode window.  Nothing executes: a compile
 that passes is not a chip run.
@@ -17,34 +18,41 @@ golden tests set in conftest.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from znicz_tpu.core import backend
-from znicz_tpu.ops import kohonen as kh, normalization
+from znicz_tpu.ops import conv, kohonen as kh, normalization
 from znicz_tpu.ops.pallas import kohonen as pallas_kh, rbm as pallas_rbm
 from znicz_tpu.ops.pallas.attention import flash_attention
 from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e chip; skips where the TPU compiler
-    cannot describe the topology."""
+def topology():
+    """A described v5e 2x2; skips where the TPU compiler cannot describe
+    it."""
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as exc:  # no libtpu / unknown topology: nothing to test
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    """Sharding on one described v5e chip."""
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -98,6 +106,115 @@ def test_lrn_compiles(chip, shape, dtype, direction):
         return normalization.lrn(x, impl="pallas")
 
     _compile(fn if direction == "fwd" else _grad_of(fn, 1), x)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize(
+    "x_shape,w_shape,conv_kw,stage,view",
+    [
+        ((1024, 227, 227, 3), (11, 11, 3, 96), dict(sliding=(4, 4)),
+         "55,55,96,1024", "3025,96,1024"),
+        ((1024, 27, 27, 96), (5, 5, 96, 256), dict(padding=(2, 2, 2, 2)),
+         "27,27,1024,256", "729,1024,256"),
+    ],
+    ids=["alexnet-conv1", "alexnet-conv2"],
+)
+def test_fused_conv_stage_compiles_without_a_copy(
+    chip, monkeypatch, x_shape, w_shape, conv_kw, stage, view, direction
+):
+    """AlexNet's two conv -> norm stages at batch 1024, bf16: the conv is
+    asked for the order the compiler lays its output out in anyway (conv1:
+    the batch on the lanes; conv2: the channels), so the tail's kernels take
+    bitcasts and no stage-sized tensor is copied or transposed beside them."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct(x_shape, jnp.bfloat16, sharding=chip)
+    w = jax.ShapeDtypeStruct(w_shape, jnp.bfloat16, sharding=chip)
+    b = jax.ShapeDtypeStruct(w_shape[-1:], jnp.bfloat16, sharding=chip)
+
+    def fn(x, w, b):
+        return conv.apply_lrn(
+            {"weights": w, "bias": b}, x, activation="relu", n=5,
+            alpha=1e-4, beta=0.75, k=2.0, **conv_kw,
+        )
+
+    text = _compile(fn if direction == "fwd" else _grad_of(fn, 3), x, w, b)
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    # the gradient alone needs the backward kernel alone: it recomputes
+    assert len(kernels) == 1, kernels
+    assert f"bf16[{view}]" in kernels[0], (view, kernels)
+    moved = [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(
+            r"= bf16\[(%s|%s|1024,\d+,\d+,(96|256))\]\S* (copy|transpose)\("
+            % (stage, view), line,
+        )
+    ]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize(
+    "shape,channel_axis", [((55, 55, 96, 1024), 2), ((27, 27, 1024, 256), 3)]
+)
+def test_fused_tail_compiles_at_highest_matmul_precision(
+    chip, monkeypatch, shape, channel_axis, dtype
+):
+    """The golden tests and chip_smoke's serve phase run under
+    ``jax_default_matmul_precision=highest``: the kernels' bf16 band
+    products name their own precision, or Mosaic refuses them there
+    ("Bad lhs type": my chip run, PR 37)."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    y = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    b = jax.ShapeDtypeStruct((shape[channel_axis],), jnp.float32, sharding=chip)
+
+    def fn(y, b):
+        return normalization.act_lrn(y, b, channel_axis=channel_axis)
+
+    with jax.default_matmul_precision("highest"):
+        _compile(fn, y, b)
+        _compile(_grad_of(fn, 2), y, b)
+
+
+def test_fused_conv_stage_runs_per_shard_on_four_chips(topology, monkeypatch):
+    """conv1's stage at a global batch of 4096 over the four chips, traced
+    under ``DataParallel.scope()`` as the workflow traces a step: each chip's
+    kernel takes its own 1024 images (no partitioning rule exists for a
+    Pallas call, and ``custom_partitioning`` has no hook in libtpu: the op
+    is a ``shard_map`` region), nothing is gathered, and the weights'
+    gradient is what is all-reduced."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from znicz_tpu.parallel import DataParallel
+    from znicz_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    mesh = Mesh(
+        np.array(topology.devices).reshape(4, 1), (DATA_AXIS, MODEL_AXIS)
+    )
+    policy = DataParallel(mesh)
+    whole, split = NamedSharding(mesh, P()), NamedSharding(mesh, P(DATA_AXIS))
+    x = jax.ShapeDtypeStruct((4096, 227, 227, 3), jnp.bfloat16, sharding=split)
+    w = jax.ShapeDtypeStruct((11, 11, 3, 96), jnp.bfloat16, sharding=whole)
+    b = jax.ShapeDtypeStruct((96,), jnp.bfloat16, sharding=whole)
+
+    def fn(x, w, b):
+        with policy.scope():
+            return conv.apply_lrn(
+                {"weights": w, "bias": b}, x, activation="relu",
+                sliding=(4, 4), n=5, alpha=1e-4, beta=0.75, k=2.0,
+            )
+
+    text = _compile(_grad_of(fn, 3), x, w, b)
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(kernels) == 1 and "bf16[3025,96,1024]" in kernels[0], kernels
+    assert "all-gather" not in text
+    assert "all-reduce" in text
 
 
 @pytest.mark.parametrize("side,batch", [(8, 100), (16, 1000)])

@@ -515,6 +515,37 @@ class TestPallasLRNTimingTPU:
         # 1.1 margin absorbs host timing noise
         assert t_pal < t_xla * 1.1, (t_pal, t_xla)
 
+    def test_fused_conv_tail_pair_beats_the_three_layers(self):
+        """AlexNet conv1's tail at batch 1024, forward + backward: the one
+        op over the conv's output as the compiler lays it out (the batch on
+        the lanes) against bias + softplus + LRN as three bf16 layers under
+        autodiff."""
+        from znicz_tpu.ops import activation as act
+
+        bias = jax.random.normal(jax.random.key(1), (96,), jnp.float32)
+        y = jax.random.normal(
+            jax.random.key(0), (55, 55, 96, 1024), jnp.float32
+        ).astype(jnp.bfloat16)
+
+        def fused(y):
+            return normalization.act_lrn(
+                y, bias, activation="relu", channel_axis=2
+            )
+
+        def separate(x):  # NHWC, the layers' own order
+            return normalization.lrn(
+                act.relu(x + bias.astype(x.dtype)).astype(x.dtype)
+            )
+
+        def grad_of(f):
+            return jax.grad(lambda v: jnp.sum(f(v).astype(jnp.float32)))
+
+        t_fused = _device_ms_per_iter(grad_of(fused), y, n_inner=20)
+        t_sep = _device_ms_per_iter(
+            grad_of(separate), y.transpose(3, 0, 1, 2), n_inner=20
+        )
+        assert t_fused < t_sep, (t_fused, t_sep)
+
 
 class TestPallasKohonen:
     def _setup(self, b=100, sx=6, sy=6, f=784, seed=0):

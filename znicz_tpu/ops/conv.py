@@ -19,8 +19,8 @@ import jax.lax as lax
 import jax.numpy as jnp
 import numpy as np
 
-from znicz_tpu.core import prng
-from znicz_tpu.ops import activation as act
+from znicz_tpu.core import backend, prng
+from znicz_tpu.ops import activation as act, normalization
 from znicz_tpu.ops.filling import fill
 
 DIMENSION_NUMBERS = ("NHWC", "HWIO", "NHWC")
@@ -152,6 +152,51 @@ def apply(
         )
     y = y + params["bias"]
     return act.get(activation)(y).astype(x.dtype)
+
+
+def apply_lrn(
+    params: Dict[str, jnp.ndarray],
+    x: jnp.ndarray,
+    *,
+    sliding: Sequence[int] = (1, 1),
+    padding=(0, 0, 0, 0),
+    activation: str = "linear",
+    **lrn,
+) -> jnp.ndarray:
+    """``normalization.lrn(apply(...), **lrn)`` with the stage's tail (bias,
+    activation, LRN) as the one op :func:`normalization.act_lrn`, NHWC in
+    and out.
+
+    The tail is position-wise over N, H and W, so on the TPU the conv is
+    asked for its output in the order the compiler lays such a tensor out
+    anyway, and the kernel takes that array as it lies: no copy either side
+    of the call.  What the compiled AlexNet step shows (v5e, batch 1024): a
+    channel count that fills 128-lane tiles goes last with the batch on the
+    sublanes (conv2's [1024,27,27,256] is ``{3,0,2,1}``: "HWNC"); one that
+    does not (conv1's 96) leaves the lanes to a batch that does (``{0,3,2,1}``:
+    "HWCN").  The transpose back to NHWC is a relabelling of the same bytes
+    for the compiler's pool.  Off the TPU the jnp twin takes NHWC as it is."""
+    n, c = x.shape[0], params["bias"].shape[0]
+    if not backend.on_tpu():
+        order = "NHWC"
+    elif c % 128 and n % 128 == 0:
+        order = "HWCN"
+    else:
+        order = "HWNC"
+    y = lax.conv_general_dilated(
+        x,
+        params["weights"],
+        window_strides=(sliding[1], sliding[0]),
+        padding=_norm_padding(padding),
+        dimension_numbers=DIMENSION_NUMBERS[:2] + (order,),
+        # as in apply(): bf16 in, bf16 out, float32 accumulation inside
+        preferred_element_type=jnp.float32 if x.dtype == jnp.float32 else None,
+    )
+    out = normalization.act_lrn(
+        y, params["bias"], activation=activation,
+        channel_axis=order.index("C"), **lrn,
+    )
+    return out.transpose([order.index(a) for a in "NHWC"])
 
 
 def output_shape(

@@ -119,6 +119,13 @@ class DataParallel:
     def n_data(self) -> int:
         return self.mesh.shape[DATA_AXIS]
 
+    def scope(self):
+        """Context a step is TRACED under, so that an op the partitioner
+        cannot split (a Pallas kernel) finds the mesh
+        (``jax.sharding.get_abstract_mesh()``) and runs per shard under
+        ``shard_map``.  The axes are ``Auto``: nothing else reads it."""
+        return jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
+
     # -- batches -----------------------------------------------------------
     def shard_batch(self, arr, *, batch_dim: int = 0) -> jax.Array:
         """Place a host batch sharded over the data axis (the batch dim

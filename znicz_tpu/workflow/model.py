@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from znicz_tpu import observability
+from znicz_tpu.core import backend
 from znicz_tpu.nn import optimizer
 from znicz_tpu.ops import (
     activation as act_op,
@@ -99,6 +101,25 @@ _INIT_KEYS = (
 )
 
 
+_LRN_KEYS = ("alpha", "beta", "k", "n")
+
+
+def _fused_tail(kind: str, following) -> Optional[dict]:
+    """The LRN keywords of ``following`` if it is a ``norm`` layer whose tail
+    a ``kind`` conv layer can take over (ops/conv.py:apply_lrn), else None.
+    Decided from the layer list alone: a ``norm`` with an explicit ``impl``
+    keeps the implementation it names, and an activation the fused op does
+    not carry keeps the separate layers."""
+    if following is None or kind not in _CONV_ACT:
+        return None
+    next_kind, fwd, _ = _split_spec(following)
+    if next_kind != "norm" or "impl" in fwd:
+        return None
+    if _CONV_ACT[kind] not in normalization.FUSED_ACTIVATIONS:
+        return None
+    return {k: fwd[k] for k in _LRN_KEYS if k in fwd}
+
+
 def _init_kwargs(fwd: dict) -> dict:
     return {k: fwd[k] for k in _INIT_KEYS if k in fwd}
 
@@ -138,9 +159,13 @@ def build(
     types: List[str] = []
     shape = (1,) + tuple(int(s) for s in input_shape)  # batch placeholder
     returns_logits = False
+    tail = None  # LRN keywords the previous (conv) layer already applied
 
     for i, spec in enumerate(layers):
         kind, fwd, bwd = _split_spec(spec)
+        taken, tail = tail, _fused_tail(
+            kind, layers[i + 1] if i + 1 < len(layers) else None
+        )
         h = default_hyper._replace(**bwd) if bwd else default_hyper
         returns_logits = False
 
@@ -175,9 +200,31 @@ def build(
                 rand_name=rand_name, **_init_kwargs(fwd),
             )
             activation = _CONV_ACT[kind]
+            if tail is None:
 
-            def fn(p, x, train, rng, s=sliding, pad=padding, a=activation):
-                return conv.apply(p, x, sliding=s, padding=pad, activation=a)
+                def fn(p, x, train, rng, s=sliding, pad=padding, a=activation):
+                    return conv.apply(
+                        p, x, sliding=s, padding=pad, activation=a
+                    )
+
+            else:
+                # the stage's tail (bias, activation, the next layer's LRN)
+                # is one op here; the norm slot below passes through
+                observability.counter(
+                    "znicz_model_fused_conv_tails_total",
+                    "conv stages whose bias, activation and LRN build() "
+                    "compiled into the one op ops/normalization.act_lrn",
+                    ("activation", "path"),
+                ).labels(
+                    activation=activation,
+                    path="pallas" if backend.on_tpu() else "twin",
+                ).inc()
+
+                def fn(p, x, train, rng, s=sliding, pad=padding,
+                       a=activation, kw=tail):
+                    return conv.apply_lrn(
+                        p, x, sliding=s, padding=pad, activation=a, **kw
+                    )
 
             shape = conv.output_shape(
                 shape, n_kernels, kx, ky, sliding, padding
@@ -224,14 +271,17 @@ def build(
 
         elif kind == "norm":
             p = {}
-            kwargs = {
-                k: fwd[k]
-                for k in ("alpha", "beta", "k", "n", "impl")
-                if k in fwd
-            }
+            kwargs = {k: fwd[k] for k in _LRN_KEYS + ("impl",) if k in fwd}
 
-            def fn(p, x, train, rng, kw=kwargs):
-                return normalization.lrn(x, **kw)
+            if taken is not None:
+
+                def fn(p, x, train, rng):
+                    return x
+
+            else:
+
+                def fn(p, x, train, rng, kw=kwargs):
+                    return normalization.lrn(x, **kw)
 
         elif kind == "dropout":
             p = {}

@@ -13,6 +13,7 @@ device->host syncs happen once per epoch, not per minibatch.
 
 from __future__ import annotations
 
+import contextlib
 import time
 import weakref
 from collections import deque
@@ -361,9 +362,18 @@ class Workflow(Logger):
             x = pre(x, ctx)
             return x, (x if target_is_input else y)  # AE target = preproc'd x
 
+        # every step is TRACED under the placement policy's mesh: an op the
+        # partitioner cannot split (a Pallas kernel) finds it there and runs
+        # per shard (ops/pallas/lrn.py:_per_shard)
+        scope = (
+            self.parallel.scope if self.parallel is not None
+            else contextlib.nullcontext
+        )
+
         def train_step_full(state, x, y, mask, lr_scale, ctx):
-            x, y = prep(x, y, ctx)
-            return train_step(state, x, y, mask, lr_scale)
+            with scope():
+                x, y = prep(x, y, ctx)
+                return train_step(state, x, y, mask, lr_scale)
 
         # trace-time gate: with the detector off the watch output is
         # None, so the norm (and the grad_norm the steps put in their
@@ -399,8 +409,9 @@ class Workflow(Logger):
             return state2, combine(acc, m), watch
 
         def eval_acc(params, x, y, mask, acc, ctx):
-            x, y = prep(x, y, ctx)
-            return combine(acc, eval_step(params, x, y, mask))
+            with scope():
+                x, y = prep(x, y, ctx)
+                return combine(acc, eval_step(params, x, y, mask))
 
         # un-jitted step kept public: benchmarks/tools can embed it in their
         # own compiled programs (e.g. a lax.fori_loop of steps for device-
@@ -442,8 +453,9 @@ class Workflow(Logger):
         if eval_conf_step is not None:
 
             def eval_conf_acc(params, x, y, mask, acc, conf, ctx):
-                x, y = prep(x, y, ctx)
-                m = eval_conf_step(params, x, y, mask)
+                with scope():
+                    x, y = prep(x, y, ctx)
+                    m = eval_conf_step(params, x, y, mask)
                 c = m.pop("confusion")
                 return combine(acc, m), conf + c
 
