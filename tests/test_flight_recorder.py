@@ -476,8 +476,20 @@ class TestDoctorCLI:
         assert both["wall_seconds"] == pytest.approx(10.0)
 
 
-class TestTickOccupancy:
-    def test_engine_tick_occupancy_fractions(self):
+def _loop_ledger():
+    """{stage: (count, sum)} of the serving thread's stage clock, and the
+    (count, sum) of its iterations."""
+    fams = get_registry().metrics()
+    stages = {
+        k[0]: (c.count, c.sum)
+        for k, c in fams[pipeline.SERVE_LOOP_STAGE_METRIC].children().items()
+    }
+    turns = fams[pipeline.SERVE_LOOP_ITERATION_METRIC].children()[()]
+    return stages, (turns.count, turns.sum)
+
+
+class TestTickStages:
+    def test_stage_shares_sum_to_at_most_one_and_decode_is_present(self):
         from znicz_tpu.core import prng
         from znicz_tpu.services.engine import PagedDecodeEngine
         from znicz_tpu.workflow.transformer import init_lm_params
@@ -487,27 +499,28 @@ class TestTickOccupancy:
         eng = PagedDecodeEngine(
             params, n_heads=4, eos_id=14, batch_size=2, admit_every=4
         )
-        # the registry family is process-wide — zero it so earlier
-        # engine tests' ticks don't skew the count comparison below
-        get_registry().metrics()["znicz_serve_tick_occupancy"].reset()
+        # the registry families are process-wide — zero them so earlier
+        # engine tests' ticks don't skew the comparison below
+        for name in (
+            pipeline.SERVE_LOOP_STAGE_METRIC,
+            pipeline.SERVE_LOOP_ITERATION_METRIC,
+        ):
+            get_registry().metrics()[name].reset()
         gen = np.random.default_rng(3)
         for _ in range(3):
             eng.submit(gen.integers(0, 17, (6,)).astype(np.int32), 8)
         eng.run()
-        occ = eng.stats()["tick_occupancy"]
-        assert occ["ticks"] > 0
-        assert occ["wall_s"] > 0
-        assert set(occ["frac"]) == {"prefill", "decode", "spec_verify"}
-        assert sum(occ["frac"].values()) <= 1.0 + 1e-6
-        assert occ["frac"]["decode"] > 0
-        assert occ["frac"]["spec_verify"] == 0.0  # speculation is off
-        # the registry twin exists with fraction-ladder buckets
-        hist = get_registry().metrics()["znicz_serve_tick_occupancy"]
-        by = {k[0]: c for k, c in hist.children().items()}
-        assert by["decode"].count == occ["ticks"]
-        assert all(0.0 <= c._uppers[0] <= 0.01 for c in by.values())
+        stages, (ticks, wall) = _loop_ledger()
+        assert ticks > 0 and wall > 0
+        share = {k: s / wall for k, (_, s) in stages.items()}
+        assert sum(share.values()) <= 1.0 + 1e-6
+        decode = [k for k in share if k.startswith("serve/decode/")]
+        assert sum(share[k] for k in decode) > 0
+        # one decode chunk a tick here: each of its parts lapped once
+        assert {stages[k][0] for k in decode} == {ticks}
+        assert not [k for k in share if k.startswith("serve/verify/")]
 
-    def test_spec_verify_phase_counted(self):
+    def test_a_verify_chunk_books_under_serve_verify(self):
         from znicz_tpu.core import prng
         from znicz_tpu.services.engine import PagedDecodeEngine
         from znicz_tpu.workflow.transformer import init_lm_params
@@ -518,15 +531,19 @@ class TestTickOccupancy:
             params, n_heads=4, eos_id=16, batch_size=2,
             block_size=8, n_blocks=64, spec_k=4,
         )
+        get_registry().metrics()[pipeline.SERVE_LOOP_STAGE_METRIC].reset()
         # repeat-heavy prompt: prompt-lookup drafts, verify ticks run
         prompt = np.tile(
             np.array([1, 2, 3, 4], np.int32), 6
         )
         eng.submit(prompt, 16)
         eng.run()
-        occ = eng.stats()["tick_occupancy"]
-        if eng.stats()["spec"]["verify_steps"] > 0:
-            assert occ["frac"]["spec_verify"] > 0
+        stages, _ = _loop_ledger()
+        verify_steps = eng.stats()["spec"]["verify_steps"]
+        assert stages["serve/verify/draft"][0] > 0
+        for part in ("grow", "dispatch", "wait", "fetch", "emit"):
+            got = stages.get(f"serve/verify/{part}", (0, 0.0))[0]
+            assert got == verify_steps
 
 
 class TestBenchDiffMarkers:

@@ -140,7 +140,9 @@ def prefetch(
             it = iter(iterable)
             # one iteration is fetch -> transform or h2d -> enqueue; the
             # clock makes each stage end where the next starts
-            with _pipeline.StageClock() as clock:
+            with _pipeline.StageClock(
+                _pipeline.stage_seconds(), _pipeline.producer_seconds()
+            ) as clock:
                 while True:
                     with tracer.span("loader/fetch"):
                         # the fault fires INSIDE the timed window, so an

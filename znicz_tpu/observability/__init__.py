@@ -64,7 +64,6 @@ from znicz_tpu.observability.pipeline import (  # noqa: F401
     PipelineAttribution,
 )
 from znicz_tpu.observability.registry import (  # noqa: F401
-    DEFAULT_FRACTION_BUCKETS,
     DEFAULT_TIME_BUCKETS,
     Metric,
     MetricsRegistry,

@@ -24,6 +24,12 @@ instrumentation:
   and ``h2d_landed`` (``device_put`` call to the arrays being ready on
   the device, taken by the probe's watcher thread; bytes over these
   seconds is the ``znicz_h2d_bytes_per_second`` gauge).
+* **The serving thread's loop** — the same :class:`StageClock`, given
+  the serving families (:func:`serving_loop_clock`:
+  ``znicz_serve_loop_seconds{stage}`` and
+  ``znicz_serve_loop_iteration_seconds``), tiles one turn of
+  ``services/frontdoor.py`` and ``services/engine.py``
+  (:data:`SERVE_LOOP_STAGES`; docs/SERVING.md).
 * **:class:`PipelineAttribution`** — decomposes the per-step wall clock
   (``znicz_train_step_wall_seconds``) into fractions (compute /
   prefetch-wait / h2d / other) that sum to ~1.0, names the bottleneck
@@ -122,6 +128,21 @@ RESTART_BUDGET_METRIC = "znicz_train_restart_budget"
 LOADER_RETRIES_METRIC = "znicz_loader_retries_total"
 LOADER_SKIPPED_METRIC = "znicz_loader_skipped_batches_total"
 SNAPSHOT_FAILURES_METRIC = "znicz_train_snapshot_failures_total"
+
+# the serving thread's loop (services/frontdoor.py, services/engine.py;
+# docs/SERVING.md "Where a turn of the serving thread goes"): leaves that
+# tile one turn, label value = span name
+SERVE_LOOP_STAGE_METRIC = "znicz_serve_loop_seconds"
+SERVE_LOOP_ITERATION_METRIC = "znicz_serve_loop_iteration_seconds"
+_CHUNK_PARTS = ("grow", "prepare", "dispatch", "wait", "fetch", "emit")
+SERVE_LOOP_STAGES = (
+    "frontdoor/control", "frontdoor/pump", "serve/schedule",
+    "serve/prefill/host", "serve/prefill/wait",
+    *(f"serve/decode/{part}" for part in _CHUNK_PARTS),
+    "serve/verify/draft",
+    *(f"serve/verify/{part}" for part in _CHUNK_PARTS),
+    "frontdoor/stream", "frontdoor/housekeeping",
+)
 
 # the families a warm-up window reset clears (bench/tests exclude the
 # first epoch's compile stall from the attribution they report)
@@ -223,6 +244,27 @@ def step_wall_seconds(registry: Optional[MetricsRegistry] = None):
     )
 
 
+def serving_loop_clock() -> "StageClock":
+    """The serving thread's stage clock: one turn of
+    ``ServingFrontDoor._tick`` (or of ``PagedDecodeEngine.tick`` under
+    ``run()``) is an iteration, :data:`SERVE_LOOP_STAGES` its stages."""
+    reg = get_registry()
+    return StageClock(
+        reg.histogram(
+            SERVE_LOOP_STAGE_METRIC,
+            "serving-thread wall seconds by stage: the stages tile one "
+            "turn of the front door and the engine (frontdoor/* around "
+            "serve/*; */wait is where the thread blocks on the device)",
+            ("stage",),
+        ),
+        reg.histogram(
+            SERVE_LOOP_ITERATION_METRIC,
+            "wall seconds of one whole turn of the serving thread that "
+            "had work (the total the stages are held to)",
+        ),
+    )
+
+
 def reset_window(registry: Optional[MetricsRegistry] = None) -> None:
     """Zero the attribution-relevant series (warm-up exclusion: call
     after the compile epoch so the reported window is steady-state).
@@ -239,44 +281,83 @@ _clock_local = threading.local()
 
 
 class StageClock:
-    """Contiguous stage timing on one producer thread.
+    """Contiguous stage timing on one thread's loop.
+
+    ``stages`` is the histogram family (label ``stage``) the laps go to
+    and ``iterations`` the family that holds each whole turn of the loop:
+    the prefetch producer passes :func:`stage_seconds` /
+    :func:`producer_seconds`, the serving thread
+    :func:`serving_loop_clock`'s.
 
     ``lap(stage)`` observes the time since the previous lap (or skip)
     into the stage histogram and starts the next stage at that same
     clock read, so the stages of one iteration leave no hole between
     them; ``skip()`` moves the mark without observing — that time
     belongs to no stage and shows as unattributed.  ``close_iteration``
-    observes mark-to-mark into ``znicz_pipeline_producer_seconds``: the
-    sum of the laps equals the sum of the iterations exactly unless
-    something skipped.  While active (a ``with`` block) the clock is
-    this thread's current one, which is how :class:`H2DProbe` joins the
-    tile from inside a transform callable."""
+    observes mark-to-mark into the iteration family: the sum of the
+    laps equals the sum of the iterations exactly unless something
+    skipped.  ``stage(name)`` is a lap AND a tracer span of the same
+    name: the span opens where the caller says, the lap runs from the
+    previous mark to the span's end.
 
-    def __init__(self):
-        self._stages = stage_seconds()
-        self._iterations = producer_seconds()
+    The clock runs from ``start()`` to ``stop()`` (a ``with`` block does
+    both, and makes the clock this thread's current one, which is how
+    :class:`H2DProbe` joins the tile from inside a transform callable);
+    while it does not run a lap observes nothing, so a loop that turns
+    without work (the front door's idle tick) books neither stages nor
+    an iteration."""
+
+    def __init__(self, stages, iterations):
+        self._stages = stages
+        self._iterations = iterations
+        self._tracer = get_tracer()
+        self.running = False
         self.mark = self._iteration_start = 0.0
+
+    def start(self) -> None:
+        self.mark = self._iteration_start = time.perf_counter()
+        self.running = True
+
+    def stop(self) -> None:
+        self.running = False
 
     def __enter__(self) -> "StageClock":
         _clock_local.clock = self
-        self.mark = self._iteration_start = time.perf_counter()
+        self.start()
         return self
 
     def __exit__(self, *exc) -> None:
+        self.stop()
         _clock_local.clock = None
 
     def lap(self, stage: str, family=None) -> float:
         """Close ``stage`` at this clock read (``family``: the stage
         histogram of an instrument that keeps its own registry)."""
+        if not self.running:
+            return 0.0
         now = time.perf_counter()
         seconds, self.mark = now - self.mark, now
         (family or self._stages).labels(stage=stage).observe(seconds)
         return seconds
 
+    @contextlib.contextmanager
+    def stage(self, name: str) -> Iterator[None]:
+        """Span ``name`` around the block, then ``lap(name)``: on the
+        device trace's clock the span names what the thread did, in the
+        registry the lap also holds the untimed steps since the stage
+        before."""
+        try:
+            with self._tracer.span(name):
+                yield
+        finally:
+            self.lap(name)
+
     def skip(self) -> None:
         self.mark = time.perf_counter()
 
     def close_iteration(self) -> None:
+        if not self.running:
+            return
         self._iterations.observe(self.mark - self._iteration_start)
         self._iteration_start = self.mark
 

@@ -41,14 +41,6 @@ DEFAULT_TIME_BUCKETS = (
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
 
-# The fraction ladder (0..1) for occupancy/utilization-shaped
-# histograms — per-tick phase occupancy, attribution fractions.  Dense
-# near the edges where "idle" vs "saturated" verdicts live.
-DEFAULT_FRACTION_BUCKETS = (
-    0.01, 0.025, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5,
-    0.6, 0.7, 0.8, 0.9, 0.95, 0.99, 1.0,
-)
-
 
 def quantile_from_cumulative(
     cum: Sequence[Tuple[float, float]], q: float

@@ -47,6 +47,22 @@ window feeding the shared latency histogram; ``timer`` is a
 also emit tracer spans — one ``serve/admit`` span per request), and
 compile counts are introspectable via
 :meth:`PagedDecodeEngine.compile_stats`.
+
+Where the serving thread's time goes is ``loop_clock``'s to say (a
+:class:`~znicz_tpu.observability.pipeline.StageClock`; docs/SERVING.md
+"Where a turn of the serving thread goes"): every part of a tick is a
+stage of it, a lap into ``znicz_serve_loop_seconds{stage}`` and a span
+of the same name (``serve/schedule``, ``serve/prefill/{host,wait}``,
+``serve/{decode,verify}/{grow,prepare,dispatch,wait,fetch,emit}``,
+``serve/verify/draft``), and the stages tile the turn.  ``*/wait`` is
+the one place a chunk blocks on the device.  The parents
+``serve/admit``, ``serve/prefill``, ``serve/decode`` and
+``serve/verify`` keep their extent around ``host``/``wait`` and
+``prepare`` to ``fetch``: a decode span holds the prefill chunks queued
+ahead of its program, a prefill span the dispatch alone.
+``znicz_serve_decode_period_seconds`` and
+``znicz_serve_prefill_chunks_between_decodes`` say what stands between
+a decode step and the gap a client sees.
 """
 from __future__ import annotations
 
@@ -62,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from znicz_tpu import observability
-from znicz_tpu.observability import device as device_telemetry
+from znicz_tpu.observability import device as device_telemetry, pipeline
 from znicz_tpu.services.errors import (
     PrefixCacheUnsupportedError,
     RequestTooLargeError,
@@ -875,24 +891,29 @@ class PagedDecodeEngine:
             "a slot took it (once per admission; a preempted request "
             "is observed again when it is re-admitted)",
         )
-        # per-tick occupancy: what fraction of each engine tick's wall
-        # went to admission/prefill vs the decode chunk vs a spec-verify
-        # chunk — the measured input the spec-aware-SLO-tuning and
-        # scheduling rungs consume (ROADMAP).  Fractions, not seconds:
-        # a tick is the scheduling quantum, so its internal split is
-        # the signal (wall itself rides znicz_serve_phase_seconds)
-        self._m_tick_occ = observability.histogram(
-            "znicz_serve_tick_occupancy",
-            "per-tick fraction of wall spent by phase "
-            "(prefill / decode / spec_verify)",
-            ("phase",),
-            buckets=observability.DEFAULT_FRACTION_BUCKETS,
+        # what a client sees between two batches of tokens: the period
+        # from the end of one decode (or verify) chunk's wait to the end
+        # of the next one's, and the prefill chunks dispatched in it;
+        # observed only while rows decode at every turn in between
+        self._m_decode_period = observability.histogram(
+            "znicz_serve_decode_period_seconds",
+            "seconds from the end of one decode or verify chunk's wait "
+            "for the device to the end of the next one's, rows decoding "
+            "throughout (over the steps a chunk: the engine's own time "
+            "per output token)",
         )
-        self._occ_seconds = {
-            "prefill": 0.0, "decode": 0.0, "spec_verify": 0.0,
-        }
-        self._occ_wall = 0.0
-        self._occ_ticks = 0
+        self._m_prefill_between = observability.histogram(
+            "znicz_serve_prefill_chunks_between_decodes",
+            "prefill chunks dispatched since the decode or verify chunk "
+            "before (observed beside znicz_serve_decode_period_seconds)",
+            buckets=(0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0),
+        )
+        self._period_start: Optional[float] = None
+        self._prefill_since_decode = 0
+        # the stages that tile one turn of the serving thread; a front
+        # door puts its own clock here, so that its stages and the
+        # engine's are one iteration
+        self.loop_clock = pipeline.serving_loop_clock()
         self.latency = profiling.LatencyStats(
             observe=self._m_latency.observe
         )
@@ -1279,22 +1300,47 @@ class PagedDecodeEngine:
 
     def tick(self) -> bool:
         """ONE engine tick — admit + prefill, then a decode (or
-        spec-verify) chunk — with the per-phase wall split observed
-        into ``znicz_serve_tick_occupancy{phase}``.  Returns False when
-        there is no work (nothing ran).  Both :meth:`run` and the front
-        door's engine thread drive the engine through this, so the
-        occupancy series is the one truth for tick composition."""
+        spec-verify) chunk — every part of it a stage of
+        :attr:`loop_clock` (``znicz_serve_loop_seconds{stage}``).
+        Returns False when there is no work (nothing ran).  Both
+        :meth:`run` and the front door's engine thread drive the engine
+        through this; where no front door opened the turn, the tick is
+        the clock's iteration."""
         if not self._has_work():
             return False
-        t0 = time.perf_counter()
-        self._admit_pending()
-        self._prefill_tick()
-        t1 = time.perf_counter()
-        chunk_kind = self._run_chunk() if self.active else None
-        t2 = time.perf_counter()
-        self._observe_tick(t1 - t0, t2 - t1, chunk_kind)
-        self._observe_residency()
+        clock = self.loop_clock
+        own_turn = not clock.running
+        if own_turn:
+            clock.start()
+        try:
+            if not self.active:
+                # nothing decodes as the turn opens: whatever chunk ran
+                # last, no period runs from it
+                self._period_start = None
+            with clock.stage("serve/schedule"):
+                self._admit_pending()
+            self._prefill_tick()
+            if self.active:
+                self._run_chunk()
+            # a gauge of the turn, not a part of serving it: booked with
+            # the front door's own gauges (which follow, behind a door)
+            with clock.stage("frontdoor/housekeeping"):
+                self._observe_residency()
+        finally:
+            if own_turn:
+                clock.close_iteration()
+                clock.stop()
         return True
+
+    def _close_decode_period(self) -> None:
+        """At the end of a decode or verify chunk's wait: the period
+        since the chunk before, and the prefill chunks between them."""
+        now = time.perf_counter()
+        if self._period_start is not None:
+            self._m_decode_period.observe(now - self._period_start)
+            self._m_prefill_between.observe(self._prefill_since_decode)
+        self._period_start = now
+        self._prefill_since_decode = 0
 
     def _observe_residency(self) -> None:
         """What the rows resident after this tick cost in pool bytes a
@@ -1314,39 +1360,6 @@ class PagedDecodeEngine:
             self._m_bytes_per_token.observe(
                 sum(k.referenced * k.block_bytes for k in self._kinds) / tokens
             )
-
-    def _observe_tick(
-        self,
-        prefill_s: float,
-        chunk_s: float,
-        chunk_kind: Optional[str],
-    ) -> None:
-        wall = prefill_s + chunk_s
-        if wall <= 0:
-            return
-        frac = {"prefill": prefill_s / wall}
-        if chunk_kind is not None:
-            frac[chunk_kind] = chunk_s / wall
-        for phase, f in frac.items():
-            self._m_tick_occ.labels(phase=phase).observe(f)
-        self._occ_seconds["prefill"] += prefill_s
-        if chunk_kind is not None:
-            self._occ_seconds[chunk_kind] += chunk_s
-        self._occ_wall += wall
-        self._occ_ticks += 1
-
-    def tick_occupancy(self) -> Dict:
-        """Lifetime tick-composition report (the ``stats()`` entry):
-        tick count, total tick wall, and each phase's fraction of it."""
-        wall = self._occ_wall
-        return {
-            "ticks": self._occ_ticks,
-            "wall_s": round(wall, 6),
-            "frac": {
-                k: round(v / wall, 4) if wall > 0 else 0.0
-                for k, v in self._occ_seconds.items()
-            },
-        }
 
     def _has_work(self) -> bool:
         return bool(self._queue) or self.active > 0 or self.prefilling > 0
@@ -1865,6 +1878,11 @@ class PagedDecodeEngine:
                     break
                 budget -= self.block_size
         self._m_active.set(self.active)
+        # a chunk's lap runs from the stage before it, so it holds the
+        # blocks and the copy-on-write guard ahead of the call; this one
+        # closes what followed the last call (the admission's
+        # bookkeeping, a chunk that starved before its call)
+        self.loop_clock.lap("serve/prefill/host")
 
     def _prefill_chunk_for(self, slot: int) -> bool:
         """Run one prefill chunk for ``slot``; True while the slot
@@ -1894,6 +1912,7 @@ class PagedDecodeEngine:
         # admitted/TTFT series) exact under preemption
         first_time = req.id not in self._admitted_ids
         greedy, top_k, nucleus = self._structure
+        clock = self.loop_clock
         t0 = time.perf_counter()
         # the LAST chunk is the admission event (first token sampled);
         # earlier chunks trace as serve/prefill
@@ -1902,35 +1921,40 @@ class PagedDecodeEngine:
             request=req.id, bucket=req.bucket, chunk=c,
             **self._trace_args(req.trace_id),
         ):
-            key = jax.random.fold_in(self._rng, st["seq"])
-            self._pools, first, *load = self._timed_program(
-                ("prefill", self.block_size, self._structure),
-                _paged_prefill_prog,
-                self.params, self._pools, self._row_tables(slot),
-                jnp.asarray(
-                    st["tokens"][
-                        :, c * self.block_size:(c + 1) * self.block_size
-                    ]
-                ),
-                jnp.int32(c * self.block_size),
-                jnp.int32(
-                    (size - 1) % self.block_size
-                    if last
-                    else self.block_size - 1
-                ),
-                self._temperature, self._top_p, key,
-                block_size=self.block_size, n_heads=self.n_heads,
-                greedy=greedy, top_k=top_k, nucleus=nucleus,
-                moe_top_k=self.moe_top_k,
-                moe_dispatch=self.moe_dispatch, model=self.model,
-            )
-            self._load_backlog.extend(load)
-            st["chunks_done"] = c + 1
+            with clock.stage("serve/prefill/host"):
+                key = jax.random.fold_in(self._rng, st["seq"])
+                self._pools, first, *load = self._timed_program(
+                    ("prefill", self.block_size, self._structure),
+                    _paged_prefill_prog,
+                    self.params, self._pools, self._row_tables(slot),
+                    jnp.asarray(
+                        st["tokens"][
+                            :, c * self.block_size:(c + 1) * self.block_size
+                        ]
+                    ),
+                    jnp.int32(c * self.block_size),
+                    jnp.int32(
+                        (size - 1) % self.block_size
+                        if last
+                        else self.block_size - 1
+                    ),
+                    self._temperature, self._top_p, key,
+                    block_size=self.block_size, n_heads=self.n_heads,
+                    greedy=greedy, top_k=top_k, nucleus=nucleus,
+                    moe_top_k=self.moe_top_k,
+                    moe_dispatch=self.moe_dispatch, model=self.model,
+                )
+                self._load_backlog.extend(load)
+                st["chunks_done"] = c + 1
             if last:
-                first = int(first)  # host sync only at admission
-                self._drain_load_backlog()
+                # the one place a prefill chunk waits for the device,
+                # and with it for every chunk dispatched ahead of it
+                with clock.stage("serve/prefill/wait"):
+                    first = int(first)  # host sync only at admission
+                    self._drain_load_backlog()
         req.timings.prefill_s += time.perf_counter() - t0
         self._m_prefill_chunks.inc()
+        self._prefill_since_decode += 1
         if not last:
             return True
         if first_time:
@@ -2093,105 +2117,118 @@ class PagedDecodeEngine:
         BACK the rest by truncating the block table — refcounts reclaim
         the rejected blocks, no copies (docs/SERVING.md "Speculative
         decoding")."""
-        w = bucket_for(
-            max(d.size for d in drafts.values()) + 1, self.spec_buckets
-        )
-        b = self.batch_size
-        tokens = np.full((b, w), self.pad_id, np.int32)
-        n_write = np.zeros((b,), np.int32)
-        draft_len = np.zeros((b,), np.int32)
-        for slot, d in drafts.items():
-            st = self._slots[slot]
-            req = st["req"]
-            rem = req.max_new_tokens - len(st["emitted"])
-            dl = min(d.size, w - 1, max(rem - 1, 0))
-            tokens[slot, 0] = self._tok[slot]
-            tokens[slot, 1:1 + dl] = d[:dl]
-            draft_len[slot] = dl
-            # only positions 0..dl are ever READ back (t0 + accepted
-            # drafts; the bonus token's K/V is the next tick's write):
-            # masking the bucket pad in-program both avoids garbage
-            # writes and keeps _grow_for_chunk from allocating — and
-            # possibly preempting a younger row for — blocks that this
-            # same tick's rollback would hand straight back
-            n_write[slot] = dl + 1
-        if not self._grow_for_chunk(lambda slot: int(n_write[slot])):
-            return  # allocation pressure preempted every decoder
-        self._peak_active = max(self._peak_active, self.active)
-        window = self._decode_window()
-        residents = [
-            s["req"] for s in self._slots
-            if s is not None and s["mode"] == "decode"
-        ]
+        clock = self.loop_clock
+        with clock.stage("serve/verify/prepare"):
+            w = bucket_for(
+                max(d.size for d in drafts.values()) + 1, self.spec_buckets
+            )
+            b = self.batch_size
+            tokens = np.full((b, w), self.pad_id, np.int32)
+            n_write = np.zeros((b,), np.int32)
+            draft_len = np.zeros((b,), np.int32)
+            for slot, d in drafts.items():
+                st = self._slots[slot]
+                req = st["req"]
+                rem = req.max_new_tokens - len(st["emitted"])
+                dl = min(d.size, w - 1, max(rem - 1, 0))
+                tokens[slot, 0] = self._tok[slot]
+                tokens[slot, 1:1 + dl] = d[:dl]
+                draft_len[slot] = dl
+                # only positions 0..dl are ever READ back (t0 + accepted
+                # drafts; the bonus token's K/V is the next tick's
+                # write): masking the bucket pad in-program both avoids
+                # garbage writes and keeps _grow_for_chunk from
+                # allocating — and possibly preempting a younger row for
+                # — blocks that this same tick's rollback would hand
+                # straight back
+                n_write[slot] = dl + 1
+        with clock.stage("serve/verify/grow"):
+            if not self._grow_for_chunk(lambda slot: int(n_write[slot])):
+                return  # allocation pressure preempted every decoder
+            self._peak_active = max(self._peak_active, self.active)
+            window = self._decode_window()
+            residents = [
+                s["req"] for s in self._slots
+                if s is not None and s["mode"] == "decode"
+            ]
         t0 = time.perf_counter()
         with self.timer.phase(
             "verify", active=self.active, width=w,
             **self._decode_trace_args(residents),
         ):
-            rng = jax.random.fold_in(
-                self._rng, 1 << 20 | self._chunk_idx
-            )
-            self._chunk_idx += 1
-            greedy, top_k, nucleus = self._structure
-            pools, out, n_acc = self._timed_program(
-                ("spec_verify", w, self.batch_size, window,
-                 self._structure),
-                _paged_verify_prog,
-                self.params, self._pools, self._batch_tables(window),
-                jnp.asarray(tokens), jnp.asarray(self._pos),
-                jnp.asarray(self._done), jnp.asarray(n_write),
-                jnp.asarray(draft_len),
-                self._temperature, self._top_p, rng,
-                width=w, block_size=self.block_size,
-                n_heads=self.n_heads, greedy=greedy, top_k=top_k,
-                nucleus=nucleus, moe_top_k=self.moe_top_k,
-                moe_dispatch=self.moe_dispatch,
-            )
-            self._pools = pools
-            out = np.asarray(out)
-            n_acc = np.asarray(n_acc)
+            with clock.stage("serve/verify/prepare"):
+                rng = jax.random.fold_in(
+                    self._rng, 1 << 20 | self._chunk_idx
+                )
+                self._chunk_idx += 1
+                greedy, top_k, nucleus = self._structure
+                operands = (
+                    self._batch_tables(window),
+                    jnp.asarray(tokens), jnp.asarray(self._pos),
+                    jnp.asarray(self._done), jnp.asarray(n_write),
+                    jnp.asarray(draft_len),
+                )
+            with clock.stage("serve/verify/dispatch"):
+                pools, out, n_acc = self._timed_program(
+                    ("spec_verify", w, self.batch_size, window,
+                     self._structure),
+                    _paged_verify_prog,
+                    self.params, self._pools, *operands,
+                    self._temperature, self._top_p, rng,
+                    width=w, block_size=self.block_size,
+                    n_heads=self.n_heads, greedy=greedy, top_k=top_k,
+                    nucleus=nucleus, moe_top_k=self.moe_top_k,
+                    moe_dispatch=self.moe_dispatch,
+                )
+                self._pools = pools
+            with clock.stage("serve/verify/wait"):
+                out = np.asarray(out)
+            self._close_decode_period()
+            with clock.stage("serve/verify/fetch"):
+                n_acc = np.asarray(n_acc)
         dt = time.perf_counter() - t0
-        self._n_verify_steps += 1
-        self._count_gathered(1, window)
-        for r in residents:
-            r.timings.decode_s += dt
-        for slot, st in enumerate(self._slots):
-            # rows preempted during allocation never reached the
-            # program (their writes were masked via the done flag)
-            if st is None or st["mode"] != "decode":
-                continue
-            req, emitted = st["req"], st["emitted"]
-            dl = int(draft_len[slot])
-            na = min(int(n_acc[slot]), dl)
-            reason = None
-            appended = 0
-            for t in out[slot, :na + 1]:
-                emitted.append(int(t))
-                appended += 1
-                if int(t) == self.eos_id:
-                    reason = "eos"
-                    break
-                if len(emitted) >= req.max_new_tokens:
-                    reason = "budget"
-                    break
-            self._n_spec_drafted += dl
-            self._n_spec_accepted += na
-            self._n_spec_rejected += dl - na
-            req.timings.spec_drafted += dl
-            req.timings.spec_accepted += na
-            if dl:
-                self._m_spec_drafted.inc(dl)
-                self._m_spec_accepted.inc(na)
-                self._m_spec_rejected.inc(dl - na)
-                self._m_spec_accept_len.observe(float(na))
-            if reason is not None:
-                self._retire_slot(slot, emitted, reason)
-            else:
-                self._tok[slot] = emitted[-1]
-                self._pos[slot] = int(self._pos[slot]) + appended
-                self._remaining[slot] = req.max_new_tokens - len(emitted)
-                self._truncate_row(slot)
-        self._m_active.set(self.active)
+        with clock.stage("serve/verify/emit"):
+            self._n_verify_steps += 1
+            self._count_gathered(1, window)
+            for r in residents:
+                r.timings.decode_s += dt
+            for slot, st in enumerate(self._slots):
+                # rows preempted during allocation never reached the
+                # program (their writes were masked via the done flag)
+                if st is None or st["mode"] != "decode":
+                    continue
+                req, emitted = st["req"], st["emitted"]
+                dl = int(draft_len[slot])
+                na = min(int(n_acc[slot]), dl)
+                reason = None
+                appended = 0
+                for t in out[slot, :na + 1]:
+                    emitted.append(int(t))
+                    appended += 1
+                    if int(t) == self.eos_id:
+                        reason = "eos"
+                        break
+                    if len(emitted) >= req.max_new_tokens:
+                        reason = "budget"
+                        break
+                self._n_spec_drafted += dl
+                self._n_spec_accepted += na
+                self._n_spec_rejected += dl - na
+                req.timings.spec_drafted += dl
+                req.timings.spec_accepted += na
+                if dl:
+                    self._m_spec_drafted.inc(dl)
+                    self._m_spec_accepted.inc(na)
+                    self._m_spec_rejected.inc(dl - na)
+                    self._m_spec_accept_len.observe(float(na))
+                if reason is not None:
+                    self._retire_slot(slot, emitted, reason)
+                else:
+                    self._tok[slot] = emitted[-1]
+                    self._pos[slot] = int(self._pos[slot]) + appended
+                    self._remaining[slot] = req.max_new_tokens - len(emitted)
+                    self._truncate_row(slot)
+            self._m_active.set(self.active)
 
     def _truncate_row(self, slot: int) -> None:
         """Speculative ROLLBACK: drop every kind's table entries past the
@@ -2205,93 +2242,110 @@ class PagedDecodeEngine:
             kind.drop_past(slot, keep)
         self._update_pool_gauges()
 
-    def _run_chunk(self) -> str:
-        """One decode chunk, or one verify chunk where a row drafted;
-        returns which (``"decode"`` / ``"spec_verify"``), the phase the
-        tick's occupancy is booked under."""
+    def _run_chunk(self) -> None:
+        """One decode chunk, or one verify chunk where a row drafted."""
         faults.fire("engine.decode_step")
+        clock = self.loop_clock
         if self.spec_k:
-            drafts = self._draft_pending()
+            with clock.stage("serve/verify/draft"):
+                drafts = self._draft_pending()
             if drafts:
                 self._verify_chunk(drafts)
-                return "spec_verify"
+                return
             # no row produced a draft this tick: fall through to the
             # plain (already-compiled) decode chunk — an unpredictable
             # stream pays ZERO verify overhead and ZERO new programs
-        # lazy per-chunk allocation, oldest first: each decoding row
-        # gets blocks covering the positions THIS chunk can write
-        # (min(chunk, remaining) steps) — never the whole budget up
-        # front; exhaustion preempts the youngest occupant
-        if not self._grow_for_chunk(
-            lambda slot: min(self.admit_every, int(self._remaining[slot]))
-        ):
-            return "decode"  # allocation pressure preempted every decoder
-        self._peak_active = max(self._peak_active, self.active)
-        # decode WINDOW (:meth:`_decode_window`): allocation above
-        # already covers this chunk's growth, so the window cannot be
-        # outrun mid-chunk; retired/idle rows were zeroed and write to
-        # the null block regardless.
-        window = self._decode_window()
-        residents = [
-            s["req"] for s in self._slots
-            if s is not None and s["mode"] == "decode"
-        ]
+        with clock.stage("serve/decode/grow"):
+            # lazy per-chunk allocation, oldest first: each decoding row
+            # gets blocks covering the positions THIS chunk can write
+            # (min(chunk, remaining) steps) — never the whole budget up
+            # front; exhaustion preempts the youngest occupant
+            if not self._grow_for_chunk(
+                lambda slot: min(
+                    self.admit_every, int(self._remaining[slot])
+                )
+            ):
+                return  # allocation pressure preempted every decoder
+            self._peak_active = max(self._peak_active, self.active)
+            # decode WINDOW (:meth:`_decode_window`): allocation above
+            # already covers this chunk's growth, so the window cannot
+            # be outrun mid-chunk; retired/idle rows were zeroed and
+            # write to the null block regardless.
+            window = self._decode_window()
+            residents = [
+                s["req"] for s in self._slots
+                if s is not None and s["mode"] == "decode"
+            ]
         t0 = time.perf_counter()
         with self.timer.phase(
             "decode", active=self.active,
             **self._decode_trace_args(residents),
         ):
-            rng = jax.random.fold_in(self._rng, 1 << 20 | self._chunk_idx)
-            self._chunk_idx += 1
-            greedy, top_k, nucleus = self._structure
-            (pools, tok, pos, done, remaining, out, steps, *load) = (
-                self._timed_program(
-                    ("paged_chunk", self.admit_every, self.batch_size,
-                     window, self._structure),
-                    _paged_decode_chunk,
-                    self.params, self._pools, self._batch_tables(window),
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._done),
-                    jnp.asarray(self._remaining), self._temperature,
-                    self._top_p, rng, chunk=self.admit_every,
-                    block_size=self.block_size, t_max=self.t_max,
-                    n_heads=self.n_heads, eos_id=self.eos_id,
-                    greedy=greedy, top_k=top_k, nucleus=nucleus,
-                    moe_top_k=self.moe_top_k,
-                    moe_dispatch=self.moe_dispatch, model=self.model,
+            with clock.stage("serve/decode/prepare"):
+                rng = jax.random.fold_in(
+                    self._rng, 1 << 20 | self._chunk_idx
                 )
-            )
-            self._pools = pools
-            out = np.asarray(out)
-            steps = int(steps)
-            self._drain_load_backlog()
-            load = jax.device_get(load)
-            self._count_loads("decode", load, steps)
-            self._tok = np.array(tok)
-            self._pos = np.array(pos)
-            self._done = np.array(done)
-            self._remaining = np.array(remaining)
+                self._chunk_idx += 1
+                greedy, top_k, nucleus = self._structure
+                operands = (
+                    self._batch_tables(window),
+                    jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    jnp.asarray(self._done), jnp.asarray(self._remaining),
+                )
+            with clock.stage("serve/decode/dispatch"):
+                (pools, tok, pos, done, remaining, out, steps, *load) = (
+                    self._timed_program(
+                        ("paged_chunk", self.admit_every, self.batch_size,
+                         window, self._structure),
+                        _paged_decode_chunk,
+                        self.params, self._pools, *operands,
+                        self._temperature, self._top_p, rng,
+                        chunk=self.admit_every,
+                        block_size=self.block_size, t_max=self.t_max,
+                        n_heads=self.n_heads, eos_id=self.eos_id,
+                        greedy=greedy, top_k=top_k, nucleus=nucleus,
+                        moe_top_k=self.moe_top_k,
+                        moe_dispatch=self.moe_dispatch, model=self.model,
+                    )
+                )
+                self._pools = pools
+            # the chunk's first blocking read: it also waits for every
+            # prefill chunk dispatched ahead of the chunk
+            with clock.stage("serve/decode/wait"):
+                out = np.asarray(out)
+            self._close_decode_period()
+            # the chunk has finished: a read below that takes long is a
+            # second sync, not the device
+            with clock.stage("serve/decode/fetch"):
+                steps = int(steps)
+                self._drain_load_backlog()
+                load = jax.device_get(load)
+                self._count_loads("decode", load, steps)
+                self._tok = np.array(tok)
+                self._pos = np.array(pos)
+                self._done = np.array(done)
+                self._remaining = np.array(remaining)
         dt = time.perf_counter() - t0
-        self._count_gathered(steps, window, load[0] if load else None)
-        for r in residents:
-            r.timings.decode_s += dt
-        for slot, st in enumerate(self._slots):
-            if st is None or st["mode"] != "decode":
-                continue
-            req, emitted = st["req"], st["emitted"]
-            reason = None
-            for t in out[slot, :steps]:
-                emitted.append(int(t))
-                if int(t) == self.eos_id:
-                    reason = "eos"
-                    break
-                if len(emitted) >= req.max_new_tokens:
-                    reason = "budget"
-                    break
-            if reason is not None:
-                self._retire_slot(slot, emitted, reason)
-        self._m_active.set(self.active)
-        return "decode"
+        with clock.stage("serve/decode/emit"):
+            self._count_gathered(steps, window, load[0] if load else None)
+            for r in residents:
+                r.timings.decode_s += dt
+            for slot, st in enumerate(self._slots):
+                if st is None or st["mode"] != "decode":
+                    continue
+                req, emitted = st["req"], st["emitted"]
+                reason = None
+                for t in out[slot, :steps]:
+                    emitted.append(int(t))
+                    if int(t) == self.eos_id:
+                        reason = "eos"
+                        break
+                    if len(emitted) >= req.max_new_tokens:
+                        reason = "budget"
+                        break
+                if reason is not None:
+                    self._retire_slot(slot, emitted, reason)
+            self._m_active.set(self.active)
 
     # -- introspection ----------------------------------------------------
 
@@ -2357,7 +2411,6 @@ class PagedDecodeEngine:
             "peak_active": self._peak_active,
             "latency": self.latency.summary(),
             "phases": self.timer.summary(),
-            "tick_occupancy": self.tick_occupancy(),
             "spec": self.spec_stats(),
             **self.compile_stats(),
             "pool_blocks": self.usable_blocks,

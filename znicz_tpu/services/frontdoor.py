@@ -76,6 +76,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Set
 import numpy as np
 
 from znicz_tpu import observability
+from znicz_tpu.observability import pipeline
 from znicz_tpu.observability.aggregate import MetricsPusher
 from znicz_tpu.observability.collector import (
     TracePusher,
@@ -280,6 +281,10 @@ class ServingFrontDoor:
         # carries — the trace collector's per-instance track key
         self.instance = instance or f"{name}-{self._suffix}"
         self._engine.trace_instance = self.instance
+        # one stage clock for the serving thread: the engine laps its
+        # stages on the door's, so a turn of _tick is one iteration
+        self._loop_clock = pipeline.serving_loop_clock()
+        self._engine.loop_clock = self._loop_clock
         # /debug/requests ring: the last K request summaries (newest
         # last), appended by the engine thread, read under the lock
         self._recent: "deque" = deque(maxlen=max(int(debug_requests), 1))
@@ -714,26 +719,38 @@ class ServingFrontDoor:
 
     def _tick(self) -> None:
         self._tick_started = time.monotonic()
+        # a turn that opens with work is one iteration of the stage
+        # clock, the stages below and the engine's tiling it; a turn
+        # that finds none laps nothing
+        clock = self._loop_clock
+        if self.has_work():
+            clock.start()
         try:
-            faults.fire("frontdoor.slow_tick")
-            self._apply_control()
-            if self._shed_requested:
-                self._shed_all()
-            self._pump_pending()
+            with clock.stage("frontdoor/control"):
+                faults.fire("frontdoor.slow_tick")
+                self._apply_control()
+                if self._shed_requested:
+                    self._shed_all()
+            with clock.stage("frontdoor/pump"):
+                self._pump_pending()
             eng = self._engine
             if eng is not None:
-                # one occupancy-instrumented engine tick (admit +
-                # prefill + decode/verify chunk); no-op without work
+                # admit + prefill + decode/verify chunk, each a stage
+                # of the same clock; no-op without work
                 eng.tick()
-            self._stream_and_collect()
-            self._publish_gauges()
-            if self._slo.maybe_sample():
-                # the sample cadence is also the burn-gauge cadence:
-                # the router's load tiebreak reads this per-instance
-                # through the aggregator (ROADMAP: /slo burn rates in
-                # the tiebreak)
-                self._publish_burn()
+            with clock.stage("frontdoor/stream"):
+                self._stream_and_collect()
+            with clock.stage("frontdoor/housekeeping"):
+                self._publish_gauges()
+                if self._slo.maybe_sample():
+                    # the sample cadence is also the burn-gauge cadence:
+                    # the router's load tiebreak reads this per-instance
+                    # through the aggregator (ROADMAP: /slo burn rates in
+                    # the tiebreak)
+                    self._publish_burn()
         finally:
+            clock.close_iteration()
+            clock.stop()
             self._last_tick = time.monotonic()
             self._tick_started = None
 
@@ -1052,6 +1069,7 @@ class ServingFrontDoor:
             self._shed_requested = True  # next tick sheds the queue
             return
         new_engine.trace_instance = self.instance
+        new_engine.loop_clock = self._loop_clock
         with self._lock:
             self._engine = new_engine
         self._wake.set()
