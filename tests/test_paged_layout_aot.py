@@ -102,29 +102,26 @@ def _lower(program, chip):
         ),
     )
     tables = spec((SLOTS, WINDOW), i32)
-    rows_i32 = spec((SLOTS,), i32)
-    done = spec((SLOTS,), jnp.bool_)
     scalar_i32, scalar_f32 = spec((), i32), spec((), f32)
     key = spec((2,), jnp.uint32)
     if program == "decode_chunk":
         args = (
-            params, pools, tables, rows_i32, rows_i32, done, rows_i32,
-            scalar_f32, scalar_f32, key,
+            params, pools, tables, spec((5, SLOTS), i32), scalar_f32,
+            scalar_f32, key,
         )
         lowered = engine._paged_decode_chunk.lower(
             *args, chunk=CHUNK, t_max=T_MAX, eos_id=0, **SAMPLING, **TOWER
         )
     elif program == "prefill":
         args = (
-            params, pools, spec((WINDOW,), i32), spec((1, BLOCK), i32),
-            scalar_i32, scalar_i32, scalar_f32, scalar_f32, key,
+            params, pools, spec((WINDOW,), i32), spec((1, T_MAX), i32),
+            spec((3,), i32), scalar_f32, scalar_f32, key,
         )
         lowered = engine._paged_prefill_prog.lower(*args, **SAMPLING, **TOWER)
     elif program == "verify":
         args = (
-            params, pools, tables, spec((SLOTS, VERIFY_WIDTH), i32),
-            rows_i32, done, rows_i32, rows_i32, scalar_f32, scalar_f32,
-            key,
+            params, pools, tables, spec((SLOTS, VERIFY_WIDTH + 5), i32),
+            scalar_f32, scalar_f32, key,
         )
         lowered = engine._paged_verify_prog.lower(
             *args, width=VERIFY_WIDTH, **SAMPLING, **TOWER
@@ -293,7 +290,7 @@ def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypat
         ),
     )
     assert pools[0]["kv"].shape == (a["n_blocks"], a["block"], 640)
-    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    state, scalar_i32 = spec((5, a["slots"]), i32), spec((), i32)
     scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
     tower = dict(
         n_heads=a["heads"], block_size=a["block"], moe_top_k=1,
@@ -302,15 +299,14 @@ def test_the_latent_pool_is_stored_as_it_is_computed_on(chip, program, monkeypat
     with jax.default_matmul_precision("default"):
         if program == "decode_chunk":
             lowered = engine._paged_decode_chunk.lower(
-                params, pools, spec((a["slots"], a["window"]), i32), rows_i32,
-                rows_i32, spec((a["slots"],), jnp.bool_), rows_i32,
+                params, pools, spec((a["slots"], a["window"]), i32), state,
                 scalar_f32, scalar_f32, key, chunk=CHUNK, t_max=12288,
                 eos_id=0, **SAMPLING, **tower,
             )
         elif program == "prefill":
             lowered = engine._paged_prefill_prog.lower(
                 params, pools, spec((a["window"],), i32),
-                spec((1, a["block"]), i32), scalar_i32, scalar_i32,
+                spec((1, 12288), i32), spec((3,), i32),
                 scalar_f32, scalar_f32, key, **SAMPLING, **tower,
             )
         else:
@@ -416,7 +412,7 @@ def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
     )
     assert [p["kv"].shape[0] for p in pools] == [4096, 1600, 1600, 1600] * 2
     assert pools[0]["kv"].shape[1:] == (a["block"], 1024)
-    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    state = spec((5, a["slots"]), i32)
     scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
     tower = dict(
         n_heads=a["heads"], block_size=a["block"], moe_top_k=1,
@@ -429,8 +425,7 @@ def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
                 "window": spec((a["slots"], a["ring"]), i32),
             }
             lowered = engine._paged_decode_chunk.lower(
-                params, pools, tables, rows_i32, rows_i32,
-                spec((a["slots"],), jnp.bool_), rows_i32, scalar_f32,
+                params, pools, tables, state, scalar_f32,
                 scalar_f32, key, chunk=CHUNK, t_max=16384, eos_id=0,
                 **SAMPLING, **tower,
             )
@@ -440,8 +435,8 @@ def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
                 "window": spec((a["ring"],), i32),
             }
             lowered = engine._paged_prefill_prog.lower(
-                params, pools, table, spec((1, a["block"]), i32), scalar_i32,
-                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+                params, pools, table, spec((1, 16384), i32), spec((3,), i32),
+                scalar_f32, scalar_f32, key, **SAMPLING, **tower,
             )
         compiled = lowered.compile()  # raises where the chip would refuse it
     text = compiled.as_text()
@@ -573,7 +568,7 @@ def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
         [(8192, 128, 640)] * 2 + [(512, 128, 1152)] * 3
     )
     assert [p["idx"].shape for p in pools[:2]] == [(8192, 128, 128)] * 2
-    rows_i32, scalar_i32 = spec((a["slots"],), i32), spec((), i32)
+    state = spec((5, a["slots"]), i32)
     scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
     tower = dict(
         n_heads=128, block_size=a["block"], moe_top_k=1,
@@ -586,8 +581,7 @@ def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
                 "window": spec((a["slots"], a["ring"]), i32),
             }
             lowered = engine._paged_decode_chunk.lower(
-                params, pools, tables, rows_i32, rows_i32,
-                spec((a["slots"],), jnp.bool_), rows_i32, scalar_f32,
+                params, pools, tables, state, scalar_f32,
                 scalar_f32, key, chunk=CHUNK, t_max=33792, eos_id=0,
                 **SAMPLING, **tower,
             )
@@ -597,8 +591,8 @@ def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
                 "window": spec((a["ring"],), i32),
             }
             lowered = engine._paged_prefill_prog.lower(
-                params, pools, table, spec((1, a["block"]), i32), scalar_i32,
-                scalar_i32, scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+                params, pools, table, spec((1, 33792), i32), spec((3,), i32),
+                scalar_f32, scalar_f32, key, **SAMPLING, **tower,
             )
         compiled = lowered.compile()  # raises where the chip would refuse it
     text = compiled.as_text()

@@ -27,9 +27,10 @@ of rejecting.  Concurrency is bounded by memory actually used, not by
 
 Four compiled programs cover any request stream:
 
-* **prefill chunk** — ONE ``[1, block_size]`` prompt chunk into the
-  row's blocks plus the first-token sample; every prompt length and
-  chunk index is the same shape.
+* **prefill chunk** — ONE ``[1, block_size]`` prompt chunk, cut on the
+  device from the prompt as it went up at admission, into the row's
+  blocks plus the first-token sample; every prompt length and chunk
+  index is the same shape.
 * **decode chunk** — up to ``admit_every`` incremental steps for the
   whole batch in one ``lax.while_loop`` (early exit once every row is
   done), with PER-ROW positions through the block tables, keyed only by
@@ -63,6 +64,12 @@ ahead of its program, a prefill span the dispatch alone.
 ``znicz_serve_decode_period_seconds`` and
 ``znicz_serve_prefill_chunks_between_decodes`` say what stands between
 a decode step and the gap a client sees.
+
+A program call crosses the host-device link once each way: beside a
+block table a kind it sends ONE packed int32 array (built fresh for the
+call) and what it returns is read in ONE ``jax.device_get``; the rng key
+stays on the device and is folded inside the programs.
+``znicz_serve_link_crossings_total{program,direction}`` counts both.
 """
 from __future__ import annotations
 
@@ -261,22 +268,29 @@ def _sample_tok(logits, key, temperature, top_p, *, greedy, top_k, nucleus):
     donate_argnums=(1,),
 )
 def _paged_prefill_prog(
-    params, pools, table, tokens, offset, last, temperature, top_p,
-    key, *, block_size, n_heads, greedy, top_k, nucleus,
-    moe_top_k, moe_dispatch, model=None,
+    params, pools, table, prompt, where, temperature, top_p, rng, *,
+    block_size, n_heads, greedy, top_k, nucleus, moe_top_k, moe_dispatch,
+    model=None,
 ):
     """One aligned prompt chunk into the row's blocks + first-token
     sample.  ONE compiled shape covers every prompt length and every
-    chunk index (``offset``/``table``/``last`` are traced operands; the
-    chunk is always ``[1, block_size]``).  ``last`` is the in-chunk index of the prompt's final real token (the tail of
-    the final chunk is RIGHT-pad — prefix-cache alignment); the sample
-    only matters on the final chunk; computing it unconditionally keeps
-    the program single and costs one argmax/categorical per chunk.
+    chunk index: ``prompt`` is the whole right-padded prompt, ``[1,
+    PagedDecodeEngine.prompt_width]`` on the device since admission, and
+    the program cuts its ``[1, block_size]`` chunk at ``offset``;
+    ``where`` is ``[offset, last, seq]``, the one int32 array a chunk
+    sends beside its table.  ``last`` is the in-chunk index of the
+    prompt's final real token (the tail of the final chunk is RIGHT-pad —
+    prefix-cache alignment); the sample only matters on the final chunk;
+    computing it unconditionally keeps the program single and costs one
+    argmax/categorical per chunk.  A sampling structure folds the row's
+    ``seq`` into ``rng`` here; greedy never reads a key.
 
     With a ``model`` (a tower of another kind, e.g.
     :class:`~znicz_tpu.workflow.latent_lm.LatentMoEModel`) the chunk runs
     through ITS tower and the call returns a third value, the chunk's
     expert-load sums."""
+    offset, last, seq = where[0], where[1], where[2]
+    tokens = jax.lax.dynamic_slice(prompt, (0, offset), (1, block_size))
     if model is None:
         pools, logits = paged_prefill_chunk(
             params, pools, table, tokens, offset, n_heads=n_heads,
@@ -289,8 +303,8 @@ def _paged_prefill_prog(
             last=last,
         )
     first = _sample_tok(
-        logits, key, temperature, top_p, greedy=greedy, top_k=top_k,
-        nucleus=nucleus,
+        logits, None if greedy else jax.random.fold_in(rng, seq),
+        temperature, top_p, greedy=greedy, top_k=top_k, nucleus=nucleus,
     )
     if model is None:
         return pools, first[0]
@@ -305,6 +319,10 @@ def _cow_copy_prog(pools, src, dst):
     return copy_paged_block(pools, src, dst)
 
 
+# rows of the decode chunk's packed operand
+_TOK, _POS, _DONE, _REMAINING, _CHUNK = range(5)
+
+
 @partial(
     jax.jit,
     static_argnames=(
@@ -314,12 +332,18 @@ def _cow_copy_prog(pools, src, dst):
     donate_argnums=(1,),
 )
 def _paged_decode_chunk(
-    params, pools, tables, tok, pos, done, remaining, temperature,
-    top_p, rng, *, chunk, block_size, t_max, n_heads,
-    eos_id, greedy, top_k, nucleus, moe_top_k, moe_dispatch, model=None,
+    params, pools, tables, state, temperature, top_p, rng, *, chunk,
+    block_size, t_max, n_heads, eos_id, greedy, top_k, nucleus, moe_top_k,
+    moe_dispatch, model=None,
 ):
     """Up to ``chunk`` paged decode steps for the whole batch in ONE
     compiled program, exiting early once every row is done.
+
+    ``state`` is the one int32 ``[5, B]`` array a call sends beside the
+    block tables: the rows' ``tok``, ``pos``, ``done`` (a row is done
+    where it is not 0) and ``remaining``, then the chunk's index in
+    ``[_CHUNK, 0]``.  A sampling structure folds ``1 << 20 | index`` into
+    ``rng`` here; greedy never reads a key.
 
     Per-row positions are native to the paged step (the block table is
     the indirection — no vmap-into-scatter), so no prompt length,
@@ -333,6 +357,10 @@ def _paged_decode_chunk(
     value: the chunk's expert-load sums, added up step by step on the
     device so that they cost the host nothing but their fetch with the
     chunk's tokens."""
+    tok, pos, remaining = state[_TOK], state[_POS], state[_REMAINING]
+    done = state[_DONE] != 0
+    if not greedy:
+        rng = jax.random.fold_in(rng, (1 << 20) | state[_CHUNK, 0])
     b = tok.shape[0]
     # clamp against the FULL positional capacity, never the (possibly
     # narrower) gathered window: the final loop iteration pushes a live
@@ -365,8 +393,8 @@ def _paged_decode_chunk(
         pools, logits, step_load = step(pools, tok, pos, done)
         load = jax.tree_util.tree_map(jnp.add, load, step_load)
         nxt = _sample_tok(
-            logits, jax.random.fold_in(rng, i), temperature, top_p,
-            greedy=greedy, top_k=top_k, nucleus=nucleus,
+            logits, None if greedy else jax.random.fold_in(rng, i),
+            temperature, top_p, greedy=greedy, top_k=top_k, nucleus=nucleus,
         )
         nxt = jnp.where(done, fill, nxt)
         remaining = jnp.where(done, remaining, remaining - 1)
@@ -398,9 +426,8 @@ def _paged_decode_chunk(
     donate_argnums=(1,),
 )
 def _paged_verify_prog(
-    params, pools, tables, tokens, pos, done, n_write, draft_len,
-    temperature, top_p, rng, *, width, block_size, n_heads,
-    greedy, top_k, nucleus, moe_top_k, moe_dispatch,
+    params, pools, tables, batch, temperature, top_p, rng, *, width,
+    block_size, n_heads, greedy, top_k, nucleus, moe_top_k, moe_dispatch,
 ):
     """Speculative VERIFY: score ``width`` input tokens per row — the
     row's current last token plus its drafted continuation — in ONE
@@ -426,11 +453,20 @@ def _paged_verify_prog(
     with no draft samples ``p`` directly — the emitted marginal is the
     target distribution exactly (Leviathan et al. 2023).
 
-    ``width`` is the bucketed verify shape; ``draft_len``/``n_write``
-    are TRACED [B] operands, so rows with shorter drafts, smaller
-    budgets, or no draft at all (emit 1 token — a plain decode step's
-    worth) ride the same compiled program: zero new programs per
+    ``batch`` is the one int32 ``[B, width + 5]`` array a call sends
+    beside the block tables: a row's ``width`` input tokens, then its
+    ``pos``, ``done`` (not 0), ``n_write`` and ``draft_len``, then the
+    chunk's index (row 0 is read; a sampling structure folds ``1 << 20 |
+    index`` into ``rng`` here).  ``width`` is the bucketed verify shape;
+    ``draft_len``/``n_write`` are TRACED, so rows with shorter drafts,
+    smaller budgets, or no draft at all (emit 1 token — a plain decode
+    step's worth) ride the same compiled program: zero new programs per
     accepted length."""
+    tokens, pos, done = batch[:, :width], batch[:, width], batch[:, width + 1]
+    done = done != 0
+    n_write, draft_len = batch[:, width + 2], batch[:, width + 3]
+    if not greedy:
+        rng = jax.random.fold_in(rng, (1 << 20) | batch[0, width + 4])
     b = tokens.shape[0]
     idx = jnp.arange(width)[None, :]
     wmask = (~done)[:, None] & (idx < n_write[:, None])
@@ -834,12 +870,13 @@ class PagedDecodeEngine:
         self._rng = rng
         # static sampling structure: one compiled program set per value
         self._structure = (temperature == 0.0, top_k, top_p < 1.0)
-        b = self.batch_size
-        self._tok = np.zeros((b,), np.int32)
-        self._pos = np.zeros((b,), np.int32)
-        self._done = np.ones((b,), bool)  # empty slots idle as done
-        self._remaining = np.zeros((b,), np.int32)
-        self._slots: List[Optional[dict]] = [None] * b
+        # the rows' state is ONE array, so that a decode chunk sends it in
+        # one piece (a copy: :func:`_paged_decode_chunk`'s ``state``);
+        # the names are views of its rows
+        self._state = np.zeros((5, self.batch_size), np.int32)
+        self._tok, self._pos, self._done, self._remaining = self._state[:4]
+        self._done[:] = 1  # empty slots idle as done
+        self._slots: List[Optional[dict]] = [None] * self.batch_size
         self._queue: Deque[Request] = deque()
         self._order: List[Completion] = []
         self.completions: Dict[int, Completion] = {}
@@ -910,6 +947,23 @@ class PagedDecodeEngine:
         )
         self._period_start: Optional[float] = None
         self._prefill_since_decode = 0
+        # a program call should cross the host-device link once each way,
+        # beside a table a kind: what says whether it does
+        crossings = observability.counter(
+            "znicz_serve_link_crossings_total",
+            "host-device crossings the engine makes for its program "
+            "calls: up, the buffers it creates on the device (a prompt's "
+            "tokens, once, at admission among them); down, its blocking "
+            "reads (one read of a whole tuple is one)",
+            ("program", "direction"),
+        )
+        self._m_crossings = {
+            (program, direction): crossings.labels(
+                program=program, direction=direction
+            )
+            for program in ("prefill", "decode", "verify")
+            for direction in ("up", "down")
+        }
         # the stages that tile one turn of the serving thread; a front
         # door puts its own clock here, so that its stages and the
         # engine's are one iteration
@@ -936,6 +990,9 @@ class PagedDecodeEngine:
         self._peak_active = 0
         m = -(-self.t_max // self.block_size)  # a full row in blocks: ceil
         self.blocks_per_row = m
+        # every prompt goes up padded to this one width, so that one
+        # prefill program serves them all
+        self.prompt_width = m * self.block_size
         # one allocator state a kind; ``n_blocks`` is a number for the
         # one-kind tower and {kind: blocks} where the tower declares kinds
         # (a kind left out gets a full table a slot)
@@ -1141,15 +1198,34 @@ class PagedDecodeEngine:
         )
         self._update_pool_gauges()
 
+    def _upload(self, program: str, host: np.ndarray) -> jax.Array:
+        """One buffer on the device for a call of ``program``.  ``host``
+        is not written after this: a backend that reads host memory in
+        place (the CPU does) may still be reading it when the call
+        returns."""
+        self._m_crossings[program, "up"].inc()
+        return jnp.asarray(host)
+
+    def _read(self, program: str, outputs):
+        """ONE blocking read for a call of ``program``: its ``outputs``
+        and, with them, the load sums of the prefill chunks dispatched
+        since the last read (they ran ahead of this call on the device;
+        reading them any sooner would wait for their chunk).  Every
+        leaf's copy starts before the first is waited for.  Returns
+        (outputs, those load sums), all on the host."""
+        backlog, self._load_backlog = self._load_backlog, []
+        self._m_crossings[program, "down"].inc()
+        return jax.device_get((outputs, backlog))
+
     def _count_loads(self, phase: str, loads, calls: int) -> None:
-        """Fold the load sums of some calls (finished by now: ONE fetch
-        for all of them) into the registry; each covers ``calls`` token
+        """Fold the load sums of some calls, FETCHED already
+        (:meth:`_read`), into the registry; each covers ``calls`` token
         steps or chunks."""
-        loads = [load for load in loads if load]
-        if not loads or not calls:
+        if not calls:
             return
-        fetched = jax.device_get(loads)
-        for load in fetched:
+        for load in loads:
+            if not load:
+                continue
             if "sparse_scored" in load:
                 self._m_sparse_scored.labels(phase=phase).inc(
                     int(load["sparse_scored"])
@@ -1168,13 +1244,6 @@ class PagedDecodeEngine:
             self._m_moe_layer_steps.labels(phase=phase).inc(
                 calls * self._routed_layers
             )
-
-    def _drain_load_backlog(self) -> None:
-        """Prefill chunks hand their load sums over unread (reading
-        would wait for the chunk); they are folded in at the next point
-        where the host waits for the device anyway."""
-        backlog, self._load_backlog = self._load_backlog, []
-        self._count_loads("prefill", backlog, 1)
 
     # -- request intake ---------------------------------------------------
 
@@ -1820,7 +1889,8 @@ class PagedDecodeEngine:
         .paged_prefill_chunk`)."""
         self._leave_queue(req)
         size = req.prompt.size
-        tokens = np.full((1, req.bucket), self.pad_id, np.int32)
+        # the prompt goes up ONCE, whole: its chunks are cut on the device
+        tokens = np.full((1, self.prompt_width), self.pad_id, np.int32)
         tokens[0, :size] = req.prompt
         if hits is None:
             # _admit_pending passes its own lookup through (nothing can
@@ -1852,7 +1922,8 @@ class PagedDecodeEngine:
             self._m_prefix_tokens.inc(skip * self.block_size)
         self._slots[slot] = {
             "req": req, "emitted": [], "mode": "prefill",
-            "seq": self._n_admits, "tokens": tokens,
+            "seq": self._n_admits,
+            "tokens": self._upload("prefill", tokens),
             "chunks_done": skip,
         }
         self._n_admits += 1
@@ -1922,23 +1993,22 @@ class PagedDecodeEngine:
             **self._trace_args(req.trace_id),
         ):
             with clock.stage("serve/prefill/host"):
-                key = jax.random.fold_in(self._rng, st["seq"])
+                where = np.array(
+                    [
+                        c * self.block_size,
+                        (size - 1) % self.block_size
+                        if last
+                        else self.block_size - 1,
+                        st["seq"],
+                    ],
+                    np.int32,
+                )
                 self._pools, first, *load = self._timed_program(
                     ("prefill", self.block_size, self._structure),
                     _paged_prefill_prog,
                     self.params, self._pools, self._row_tables(slot),
-                    jnp.asarray(
-                        st["tokens"][
-                            :, c * self.block_size:(c + 1) * self.block_size
-                        ]
-                    ),
-                    jnp.int32(c * self.block_size),
-                    jnp.int32(
-                        (size - 1) % self.block_size
-                        if last
-                        else self.block_size - 1
-                    ),
-                    self._temperature, self._top_p, key,
+                    st["tokens"], self._upload("prefill", where),
+                    self._temperature, self._top_p, self._rng,
                     block_size=self.block_size, n_heads=self.n_heads,
                     greedy=greedy, top_k=top_k, nucleus=nucleus,
                     moe_top_k=self.moe_top_k,
@@ -1950,8 +2020,9 @@ class PagedDecodeEngine:
                 # the one place a prefill chunk waits for the device,
                 # and with it for every chunk dispatched ahead of it
                 with clock.stage("serve/prefill/wait"):
-                    first = int(first)  # host sync only at admission
-                    self._drain_load_backlog()
+                    first, loads = self._read("prefill", first)
+                    first = int(first)
+                    self._count_loads("prefill", loads, 1)
         req.timings.prefill_s += time.perf_counter() - t0
         self._m_prefill_chunks.inc()
         self._prefill_since_decode += 1
@@ -1968,6 +2039,7 @@ class PagedDecodeEngine:
             self._retire_slot(slot, [first], "budget")
         else:
             st["mode"] = "decode"
+            del st["tokens"]  # the prompt's device copy has done its work
             st["emitted"] = [first]
             self._tok[slot] = first
             self._pos[slot] = size
@@ -2038,15 +2110,18 @@ class PagedDecodeEngine:
     def _row_tables(self, slot: int):
         """``slot``'s table as the prefill program takes it: the one
         kind's, or ``{kind: table}`` where the tower declares kinds."""
+        self._m_crossings["prefill", "up"].inc(len(self._kinds))
         if not self._by_kind:
             return jnp.asarray(self._kinds[0].tables[slot])
         return {k.name: k.device_table(k.tables[slot]) for k in self._kinds}
 
-    def _batch_tables(self, window: int):
+    def _batch_tables(self, window: int, program: str = "decode"):
         """Every slot's table as the decode and verify programs take
         them: cut to the ``window`` rung where the kind keeps every token
         (:meth:`_decode_window`); a window kind's ring whole, which is one
         width for any stream."""
+        self._m_crossings[program, "up"].inc(len(self._kinds))
+
         def cut(kind):
             return kind.device_table(
                 kind.tables[:, :window] if kind.window is None
@@ -2122,10 +2197,14 @@ class PagedDecodeEngine:
             w = bucket_for(
                 max(d.size for d in drafts.values()) + 1, self.spec_buckets
             )
-            b = self.batch_size
-            tokens = np.full((b, w), self.pad_id, np.int32)
-            n_write = np.zeros((b,), np.int32)
-            draft_len = np.zeros((b,), np.int32)
+            # the call's one operand beside the tables, and views of it
+            # (:func:`_paged_verify_prog`'s ``batch``); pos, done and the
+            # chunk's index are filled in after the rows have grown
+            batch = np.zeros((self.batch_size, w + 5), np.int32)
+            tokens, n_write, draft_len = (
+                batch[:, :w], batch[:, w + 2], batch[:, w + 3]
+            )
+            tokens[:] = self.pad_id
             for slot, d in drafts.items():
                 st = self._slots[slot]
                 req = st["req"]
@@ -2157,16 +2236,13 @@ class PagedDecodeEngine:
             **self._decode_trace_args(residents),
         ):
             with clock.stage("serve/verify/prepare"):
-                rng = jax.random.fold_in(
-                    self._rng, 1 << 20 | self._chunk_idx
-                )
+                batch[:, w], batch[:, w + 1] = self._pos, self._done
+                batch[0, w + 4] = self._chunk_idx
                 self._chunk_idx += 1
                 greedy, top_k, nucleus = self._structure
                 operands = (
-                    self._batch_tables(window),
-                    jnp.asarray(tokens), jnp.asarray(self._pos),
-                    jnp.asarray(self._done), jnp.asarray(n_write),
-                    jnp.asarray(draft_len),
+                    self._batch_tables(window, "verify"),
+                    self._upload("verify", batch),
                 )
             with clock.stage("serve/verify/dispatch"):
                 pools, out, n_acc = self._timed_program(
@@ -2174,7 +2250,7 @@ class PagedDecodeEngine:
                      self._structure),
                     _paged_verify_prog,
                     self.params, self._pools, *operands,
-                    self._temperature, self._top_p, rng,
+                    self._temperature, self._top_p, self._rng,
                     width=w, block_size=self.block_size,
                     n_heads=self.n_heads, greedy=greedy, top_k=top_k,
                     nucleus=nucleus, moe_top_k=self.moe_top_k,
@@ -2182,10 +2258,10 @@ class PagedDecodeEngine:
                 )
                 self._pools = pools
             with clock.stage("serve/verify/wait"):
-                out = np.asarray(out)
+                (out, n_acc), loads = self._read("verify", (out, n_acc))
             self._close_decode_period()
             with clock.stage("serve/verify/fetch"):
-                n_acc = np.asarray(n_acc)
+                self._count_loads("prefill", loads, 1)
         dt = time.perf_counter() - t0
         with clock.stage("serve/verify/emit"):
             self._n_verify_steps += 1
@@ -2282,15 +2358,12 @@ class PagedDecodeEngine:
             **self._decode_trace_args(residents),
         ):
             with clock.stage("serve/decode/prepare"):
-                rng = jax.random.fold_in(
-                    self._rng, 1 << 20 | self._chunk_idx
-                )
+                self._state[_CHUNK, 0] = self._chunk_idx
                 self._chunk_idx += 1
                 greedy, top_k, nucleus = self._structure
                 operands = (
                     self._batch_tables(window),
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._done), jnp.asarray(self._remaining),
+                    self._upload("decode", self._state.copy()),
                 )
             with clock.stage("serve/decode/dispatch"):
                 (pools, tok, pos, done, remaining, out, steps, *load) = (
@@ -2299,7 +2372,7 @@ class PagedDecodeEngine:
                          window, self._structure),
                         _paged_decode_chunk,
                         self.params, self._pools, *operands,
-                        self._temperature, self._top_p, rng,
+                        self._temperature, self._top_p, self._rng,
                         chunk=self.admit_every,
                         block_size=self.block_size, t_max=self.t_max,
                         n_heads=self.n_heads, eos_id=self.eos_id,
@@ -2309,22 +2382,19 @@ class PagedDecodeEngine:
                     )
                 )
                 self._pools = pools
-            # the chunk's first blocking read: it also waits for every
+            # the chunk's one blocking read: it also waits for every
             # prefill chunk dispatched ahead of the chunk
             with clock.stage("serve/decode/wait"):
-                out = np.asarray(out)
+                (out, steps, load, *rows), loads = self._read(
+                    "decode", (out, steps, load, tok, pos, done, remaining)
+                )
             self._close_decode_period()
-            # the chunk has finished: a read below that takes long is a
-            # second sync, not the device
+            # everything is on the host by now: counters and copies
             with clock.stage("serve/decode/fetch"):
                 steps = int(steps)
-                self._drain_load_backlog()
-                load = jax.device_get(load)
+                self._count_loads("prefill", loads, 1)
                 self._count_loads("decode", load, steps)
-                self._tok = np.array(tok)
-                self._pos = np.array(pos)
-                self._done = np.array(done)
-                self._remaining = np.array(remaining)
+                self._state[:_CHUNK] = rows
         dt = time.perf_counter() - t0
         with clock.stage("serve/decode/emit"):
             self._count_gathered(steps, window, load[0] if load else None)
