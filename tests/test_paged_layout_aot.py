@@ -627,3 +627,120 @@ def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
     assert text.count("tpu_custom_call") >= (
         3 * 4 + (3 + 2 + 2 if program == "decode_chunk" else 0)
     )
+
+
+# -- [v, k] rows with the indexer's keys beside them, at keye-vl2-30b-a3b-l6's
+#
+# 6 layers alike at hidden 2,048: 32 query heads over 4 K/V heads of 128,
+# an indexer of 16 heads of 64 that keeps 2,048 keys in EVERY layer, 128
+# experts of width 768 all held, the whole vocabulary, bfloat16; 64 slots,
+# block 128, 2,048 blocks of 1,024 lanes of [v, k] rows and 128 of indexer
+# keys beside them, the one rung (544 blocks = 69,632 positions).  A decode
+# step fetches the kept rows ([64, 2,048, 1,024]: 0.27 GB a layer, one
+# layer's at a time) and gathers nothing at the table's width.
+
+KEYE = dict(d=2048, vocab=151936, experts=128, f=768, slots=64, block=128,
+            rung=544, top_k=2048, n_blocks={"global": 2048})
+KEYE_TEMP_LIMIT_GB = {"decode_chunk": 1.2, "prefill": 0.4}  # read: 0.66, 0.14
+
+
+def _keye_model():
+    from znicz_tpu.workflow.sparse_gqa_lm import SparseGQAMoEModel
+
+    return SparseGQAMoEModel(
+        n_layers=6, n_heads=32, n_kv_heads=4, head_dim=128, index_n_heads=16,
+        index_head_dim=64, index_topk=KEYE["top_k"], top_k=8,
+        max_positions=KEYE["rung"] * KEYE["block"],
+    )
+
+
+def _keye_params(spec):
+    a, bf, f32 = KEYE, jnp.bfloat16, jnp.float32
+    d, f, e = a["d"], a["f"], a["experts"]
+    block = {
+        "attn_norm": ((d,), f32), "wq": ((d, 4096), bf), "wk": ((d, 512), bf),
+        "wv": ((d, 512), bf), "q_norm": ((128,), f32), "k_norm": ((128,), f32),
+        "wo": ((4096, d), bf), "wq_idx": ((d, 1024), bf), "wk_idx": ((d, 64), bf),
+        "k_idx_gain": ((64,), f32), "k_idx_bias": ((64,), f32),
+        "w_idx": ((d, 16), bf), "ffn_norm": ((d,), f32), "router": ((d, e), bf),
+        "experts_gate": ((e, d, f), bf), "experts_up": ((e, d, f), bf),
+        "experts_down": ((e, f, d), bf),
+    }
+    return (
+        [{"embed": spec((a["vocab"], d), bf)}]
+        + [{k: spec(*v) for k, v in block.items()} for _ in range(6)]
+        + [{"final_norm": spec((d,), f32), "head": spec((d, a["vocab"]), bf)}]
+    )
+
+
+@pytest.mark.parametrize("program", list(KEYE_TEMP_LIMIT_GB))
+def test_the_kept_rows_are_fetched_from_pools_stored_as_they_are_computed_on(
+    chip, program, monkeypatch
+):
+    from znicz_tpu.core import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    a, model = KEYE, _keye_model()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = _keye_params(spec)
+    pools = jax.tree.map(
+        lambda p: spec(p.shape, p.dtype),
+        jax.eval_shape(
+            lambda: model.init_pools(params, a["n_blocks"], a["block"])
+        ),
+    )
+    assert [p["kv"].shape for p in pools] == [(2048, 128, 1024)] * 6
+    assert [p["idx"].shape for p in pools] == [(2048, 128, 128)] * 6
+    t_max = a["rung"] * a["block"]
+    scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
+    tower = dict(
+        n_heads=32, block_size=a["block"], moe_top_k=1, moe_dispatch="dense",
+        model=model,
+    )
+    with jax.default_matmul_precision("default"):
+        if program == "decode_chunk":
+            lowered = engine._paged_decode_chunk.lower(
+                params, pools, {"global": spec((a["slots"], a["rung"]), i32)},
+                spec((5, a["slots"]), i32), scalar_f32, scalar_f32, key,
+                chunk=4, t_max=t_max, eos_id=0, **SAMPLING, **tower,
+            )
+        else:
+            lowered = engine._paged_prefill_prog.lower(
+                params, pools, {"global": spec((a["rung"],), i32)},
+                spec((1, t_max), i32), spec((3,), i32), scalar_f32,
+                scalar_f32, key, **SAMPLING, **tower,
+            )
+        compiled = lowered.compile()  # raises where the chip would refuse it
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(
+        f"keye {program}: arguments {mem.argument_size_in_bytes / GB:.2f} GB, "
+        f"temporaries {mem.temp_size_in_bytes / GB:.2f} GB, "
+        f"{text.count('tpu_custom_call')} kernels"
+    )
+    # neither pool is copied or re-tiled, and no [v, k] window of the
+    # table's width is gathered: a decode step fetches the kept rows, a
+    # prefill chunk walks the table a few entries at a time
+    kv_row, idx_row = a["block"] * 1024, a["block"] * 128
+    window = (1 if program == "prefill" else a["slots"]) * a["rung"] * kv_row
+    moved = _relayouts(text, 2048 * kv_row, 2048 * idx_row, window)
+    assert not moved, f"{program} moves a pool or a window: {sorted(set(moved))}"
+    assert not [
+        dims for dims in _SHAPE.findall(text)
+        if int(np.prod([int(d) for d in dims.split(",")])) == window
+    ], f"{program} gathers the table's whole width"
+    assert mem.temp_size_in_bytes < KEYE_TEMP_LIMIT_GB[program] * GB, (
+        f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
+    )
+    # weights 8.75 GB + pools 3.62 GB + temporaries fit 15.75 GiB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    # the three grouped products of every layer's experts are the kernel,
+    # and in a decode step every layer's indexer is one too
+    assert text.count("tpu_custom_call") >= (
+        3 * 6 + (6 if program == "decode_chunk" else 0)
+    )

@@ -267,12 +267,7 @@ def _paged_gqa_attention(
         raise ValueError(
             f"lengths are a decode step's; got {tq} queries a row"
         )
-    # [B, Tq, G, H/G, G', D] -> [B, Tq * H, G' * D], zero where G' != G
-    q_keys = (
-        q.astype(dtype).reshape(b, tq, g, h // g, 1, d)
-        * jnp.eye(g, dtype=dtype)[:, None, :, None]
-    ).reshape(b, tq * h, half)
-    q_row = jnp.concatenate([jnp.zeros_like(q_keys), q_keys], axis=-1)
+    q_row = _gqa_query_rows(q, g, dtype)
     if _reads_pool_in_place(tq):
         table, keys, starts = block_table, lengths, None
         if keys is None:
@@ -309,18 +304,38 @@ def _paged_gqa_attention(
         o = jnp.einsum(
             "brk,bke->bre", p.reshape(b, tq * h, n_keys), rows, **f32
         )
-    # row (t, h) keeps the value columns of its own K/V head, picked by a
-    # product with 0 / 1 (exact at HIGHEST, and small) rather than sliced
-    lane = jnp.arange(o.shape[-1])[None, :, None]
-    own = (jnp.arange(h) // (h // g) * d)[:, None, None] + jnp.arange(d)
-    o = jnp.einsum(
-        "bthe,hed->bthd", o.reshape(b, tq, h, -1),
-        (lane == own).astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    ).reshape(b, tq, h * d)
+    o = _gqa_own_values(o.reshape(b, tq, h, -1), g, d)
     if lengths is not None:  # an idle row's result is zeros in either form
         o = jnp.where(lengths[:, None, None] > 0, o, 0.0)
     return o
+
+
+def _gqa_query_rows(q, g, dtype):
+    """Queries [B, Tq, H, D] laid out like the ``[v, k]`` rows they meet:
+    [B, Tq * H, 2 * G * D], row ``(t, h)`` holding ``q[b, t, h]`` in the key
+    columns of ITS K/V head and zeros elsewhere."""
+    b, tq, h, d = q.shape
+    # [B, Tq, G, H/G, G', D] -> [B, Tq * H, G' * D], zero where G' != G
+    q_keys = (
+        q.astype(dtype).reshape(b, tq, g, h // g, 1, d)
+        * jnp.eye(g, dtype=dtype)[:, None, :, None]
+    ).reshape(b, tq * h, g * d)
+    return jnp.concatenate([jnp.zeros_like(q_keys), q_keys], axis=-1)
+
+
+def _gqa_own_values(o, g, d):
+    """Of a weighted sum over ``[v, k]`` rows as stored, ``o`` [B, Tq, H,
+    >= G * D], row ``(t, h)`` keeps the value columns of its own K/V head:
+    [B, Tq, H * D] float32, picked by a product with 0 / 1 (exact at
+    HIGHEST, and small) rather than sliced."""
+    b, tq, h, _ = o.shape
+    lane = jnp.arange(o.shape[-1])[None, :, None]
+    own = (jnp.arange(h) // (h // g) * d)[:, None, None] + jnp.arange(d)
+    return jnp.einsum(
+        "bthe,hed->bthd", o.astype(jnp.float32),
+        (lane == own).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ).reshape(b, tq, h * d)
 
 
 def paged_latent_attention(
@@ -677,39 +692,235 @@ def kept_latent_attention(
                 d_out=min(-(-d_latent // 128) * 128, width), keep=keep[:, 0],
             )[:, None]
             return _unfolded(o_latent, wv_b, lengths)
-        table, step, n_chunks, needed = _table_chunks(block_table, last, block_size)
-        keys = step * block_size
-        keep = jnp.pad(
-            keep, ((0, 0), (0, 0), (0, n_chunks * keys - keep.shape[-1]))
-        )
-
-        def chunk(i, carry):
-            top, total, acc = carry
-            blks = jax.lax.dynamic_slice_in_dim(table, i * step, step, axis=1)
-            rows = pool[blks].reshape(b, keys, width)
-            kept = jax.lax.dynamic_slice_in_dim(keep, i * keys, keys, axis=2)
-            kept = kept[:, :, None, :]
-            s = jnp.einsum("bthe,bke->bthk", q_row, rows, **f32) * scale
-            s = jnp.where(kept, s, _NEG)
-            new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(kept, jnp.exp(s - new_top), 0.0)
-            turn = jnp.exp(top - new_top)
+        o_latent = _kept_rows_walk(
+            pool, block_table, keep, last, (b, tq, h), block_size=block_size,
+            out_width=width,
+            scores=lambda rows: jnp.einsum(
+                "bthe,bke->bthk", q_row, rows, **f32
+            ) * scale,
             # over the whole row: a slice of the fetched rows is a copy
-            acc = turn * acc + jnp.einsum(
-                "bthk,bke->bthe", p.astype(dtype), rows, **f32
-            )
-            total = turn * total + jnp.sum(p, axis=-1, keepdims=True)
-            return new_top, total, acc
-
-        _, total, acc = jax.lax.fori_loop(
-            0, needed, chunk,
-            (
-                jnp.full((b, tq, h, 1), _NEG, jnp.float32),
-                jnp.zeros((b, tq, h, 1), jnp.float32),
-                jnp.zeros((b, tq, h, width), jnp.float32),
+            weighted=lambda p, rows: jnp.einsum(
+                "bthk,bke->bthe", p, rows, **f32
             ),
         )
-        return _unfolded(acc / jnp.maximum(total, 1e-30), wv_b, lengths)
+        return _unfolded(o_latent, wv_b, lengths)
+
+
+def _kept_rows_walk(pool, block_table, keep, last, queries, *, block_size,
+                    out_width, scores, weighted):
+    """The shared PREFILL form of attention over the keys ``keep`` [B, Tq,
+    M * block_size] names, whatever a cached row holds: the table is
+    walked ``INDEX_CHUNK_BLOCKS`` entries at a time as far as the furthest
+    query (``last`` [B]), each chunk's rows [B, keys, W] fetched once for
+    all ``queries`` = (B, Tq, H), under a running softmax; a key a query
+    does not keep is masked out of it, and a query that keeps none gives
+    zeros.  ``scores(rows)`` -> [B, Tq, H, keys] float32, scaled;
+    ``weighted(p, rows)`` -> [B, Tq, H, out_width] float32 with ``p`` in
+    the pool's dtype: the two products of the row layout.  Returns [B, Tq,
+    H, out_width] float32."""
+    b, tq, h = queries
+    dtype, width = pool.dtype, pool.shape[-1]
+    table, step, n_chunks, needed = _table_chunks(block_table, last, block_size)
+    keys = step * block_size
+    keep = jnp.pad(
+        keep, ((0, 0), (0, 0), (0, n_chunks * keys - keep.shape[-1]))
+    )
+
+    def chunk(i, carry):
+        top, total, acc = carry
+        blks = jax.lax.dynamic_slice_in_dim(table, i * step, step, axis=1)
+        rows = pool[blks].reshape(b, keys, width)
+        kept = jax.lax.dynamic_slice_in_dim(keep, i * keys, keys, axis=2)
+        kept = kept[:, :, None, :]
+        s = jnp.where(kept, scores(rows), _NEG)
+        new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(kept, jnp.exp(s - new_top), 0.0)
+        turn = jnp.exp(top - new_top)
+        acc = turn * acc + weighted(p.astype(dtype), rows)
+        total = turn * total + jnp.sum(p, axis=-1, keepdims=True)
+        return new_top, total, acc
+
+    _, total, acc = jax.lax.fori_loop(
+        0, needed, chunk,
+        (
+            jnp.full((b, tq, h, 1), _NEG, jnp.float32),
+            jnp.zeros((b, tq, h, 1), jnp.float32),
+            jnp.zeros((b, tq, h, out_width), jnp.float32),
+        ),
+    )
+    return acc / jnp.maximum(total, 1e-30)
+
+
+def kept_key_slots(keep: jnp.ndarray, top_k: int, *, block_size: int):
+    """A mask over a row's keys turned into the list of the keys it names:
+    ``keep`` [B, M * block_size] bool with at most ``top_k`` set a row ->
+    ``(entry [B, top_k], offset [B, top_k], named [B, top_k] bool)``: slot
+    ``j`` is the row's ``j``-th kept key, at ``offset`` within the block of
+    table entry ``entry``; a slot past the row's last kept key is not
+    ``named`` (entry and offset 0).
+
+    No sort and no scatter, and nothing scalar is gathered: the kept keys
+    are counted a block (one sum), a slot finds its block by comparing the
+    running count with its own number (``top_k x M`` comparisons a row, a
+    fused reduction), reads that block's mask by a product with 0 / 1 and
+    finds its key inside it from the mask's running sum (a product with a
+    triangle; counts to 128 are exact in bfloat16)."""
+    b, n_keys = keep.shape
+    m = n_keys // block_size
+    by_block = keep.reshape(b, m, block_size)
+    count = jnp.sum(by_block, axis=-1, dtype=jnp.int32)  # [B, M]
+    ends = jnp.cumsum(count, axis=1)
+    slot = jnp.arange(top_k, dtype=jnp.int32)
+    before = ends[:, None, :] <= slot[None, :, None]  # blocks wholly before
+    entry = jnp.sum(before, axis=-1, dtype=jnp.int32)
+    rank = slot[None, :] - jnp.sum(
+        jnp.where(before, count[:, None, :], 0), axis=-1, dtype=jnp.int32
+    )
+    named = slot[None, :] < ends[:, -1:]
+    entry = jnp.where(named, entry, 0)
+    f32 = dict(preferred_element_type=jnp.float32)
+    mask = jnp.einsum(
+        "bjm,bmo->bjo",
+        (entry[..., None] == jnp.arange(m)).astype(jnp.bfloat16),
+        by_block.astype(jnp.bfloat16), **f32,
+    )
+    at = jnp.arange(block_size)
+    running = jnp.einsum(
+        "bjo,op->bjp", mask.astype(jnp.bfloat16),
+        (at[:, None] <= at[None, :]).astype(jnp.bfloat16), **f32,
+    )
+    # the (rank + 1)-th kept key of the block lies after as many
+    # positions as have a running sum of at most rank
+    offset = jnp.sum(
+        running <= rank[..., None].astype(jnp.float32), axis=-1,
+        dtype=jnp.int32,
+    )
+    return entry, jnp.where(named, offset, 0), named
+
+
+def kept_rows_fetched(lengths: jnp.ndarray, top_k: int) -> jnp.ndarray:
+    """Cached rows ONE layer's :func:`kept_gqa_attention` fetches in a
+    decode step (int32 scalar), :func:`paged_gqa_rows_read`'s twin: a live
+    row the keys it kept, ``min(length, top_k)``; every other slot of the
+    fetch names the first row of ``NULL_BLOCK``, which is not counted."""
+    return jnp.sum(jnp.minimum(lengths, top_k), dtype=jnp.int32)
+
+
+def kept_gqa_attention(
+    q: jnp.ndarray,  # [B, Tq, H, D]
+    pool: jnp.ndarray,  # [N_blocks, block_size, 2 * G * D]: [v, k] a token
+    block_table: jnp.ndarray,  # [B, M] int32 pool block ids
+    q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
+    keep: jnp.ndarray,  # [B, Tq, M * block_size] bool: the keys attended
+    *,
+    block_size: int,
+    n_kv_heads: int,
+    top_k: int,  # the most keys ``keep`` names a query
+    scale: float,
+    lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+) -> jnp.ndarray:
+    """Grouped-query attention of every query over the keys ``keep`` names
+    for it, over ``[v, k]`` rows (:func:`gqa_cache_row`); [B, Tq, H * D]
+    float32.  A query that keeps no key gives zeros.
+
+    A DECODE step (``lengths`` given, ``Tq`` 1) FETCHES THE KEPT ROWS AND
+    NOTHING ELSE: the mask becomes each row's list of (table entry,
+    offset) pairs (:func:`kept_key_slots`), the rows they name are
+    gathered from the pool ([B, top_k, W]: 4 MB a row at 2,048 keys of
+    2,048 B, whatever the row's length; a walk of every block under the
+    mask, :func:`kept_latent_attention`'s decode form, reads the whole
+    row, 32 x that at 66k keys) and the queries meet them as stored
+    (:func:`paged_gqa_attention`'s layout: one product for all heads, the
+    zeros add exactly).  A PREFILL chunk walks the table under the mask
+    (:func:`_kept_rows_walk`, shared with the latent rows) with the
+    products GROUPED a K/V head: at ``Tq * H`` = 4,096 query rows the
+    zeros of the as-stored layout would cost ``H / G`` times the FLOPs."""
+    with jax.named_scope("gqa_sparse"):
+        b, tq, h, d = q.shape
+        g, half = n_kv_heads, n_kv_heads * d
+        dtype = pool.dtype
+        f32 = dict(preferred_element_type=jnp.float32)
+        if pool.shape[-1] != 2 * half:
+            raise ValueError(
+                f"a cached row is [v, k] of {n_kv_heads} heads x {d}: want "
+                f"{2 * half} lanes, the pool has {pool.shape[-1]}"
+            )
+        if lengths is not None:
+            if tq != 1:
+                raise ValueError(
+                    f"lengths are a decode step's; got {tq} queries a row"
+                )
+            entry, offset, named = kept_key_slots(
+                keep[:, 0], top_k, block_size=block_size
+            )
+            # the pool's block of each slot's table entry, by a sum under
+            # 0 / 1 (a fused reduction; nothing scalar is gathered)
+            blk = jnp.sum(
+                jnp.where(
+                    entry[..., None] == jnp.arange(block_table.shape[1]),
+                    block_table[:, None, :], 0,
+                ),
+                axis=-1, dtype=jnp.int32,
+            )
+            rows = pool[jnp.where(named, blk, 0), offset]  # [B, top_k, W]
+            s = jnp.einsum(
+                "bre,bke->brk", _gqa_query_rows(q, g, dtype), rows, **f32
+            ) * scale
+            named = named[:, None, :]
+            s = jnp.where(named, s, _NEG)
+            p = jnp.where(
+                named, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0
+            )
+            total = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+            # over the whole row: a slice of the fetched rows is a copy
+            o = jnp.einsum("brk,bke->bre", (p / total).astype(dtype), rows, **f32)
+            o = _gqa_own_values(o[:, None], g, d)
+            return jnp.where(lengths[:, None, None] > 0, o, 0.0)
+        qg = q.astype(dtype).reshape(b, tq, g, h // g, d)
+
+        def split(rows):  # [B, keys, W] -> v, k [B, keys, G, D]
+            rows = rows.reshape(b, -1, 2, g, d)
+            return rows[:, :, 0], rows[:, :, 1]
+
+        o = _kept_rows_walk(
+            pool, block_table, keep, q_pos[:, -1], (b, tq, h),
+            block_size=block_size, out_width=d,
+            scores=lambda rows: jnp.einsum(
+                "btgrd,bkgd->btgrk", qg, split(rows)[1], **f32
+            ).reshape(b, tq, h, -1) * scale,
+            weighted=lambda p, rows: jnp.einsum(
+                "btgrk,bkgd->btgrd", p.reshape(b, tq, g, h // g, -1),
+                split(rows)[0], **f32,
+            ).reshape(b, tq, h, d),
+        )
+        return o.reshape(b, tq, h * d)
+
+
+def paged_selected_gqa_attention(
+    q, q_idx, w_idx, pool, idx_pool, block_table, q_pos, *, block_size: int,
+    n_kv_heads: int, top_k: int, scale: Optional[float] = None,
+    lengths: Optional[jnp.ndarray] = None,
+):
+    """Grouped-query attention that keeps ``top_k`` keys a query, chosen
+    by a learned indexer, over a paged pool of ``[v, k]`` rows and, block
+    for block beside it, a pool of the indexer's keys: :func:`paged_index
+    _scores`, :func:`select_top_keys` (both :func:`paged_selected_latent
+    _attention`'s), :func:`kept_gqa_attention`.  Returns ``(o [B, Tq, H *
+    D] float32, scored, selected)`` with that function's contract."""
+    scores = paged_index_scores(
+        q_idx, w_idx, idx_pool, block_table, q_pos, block_size=block_size,
+        lengths=lengths,
+    )
+    keep = select_top_keys(scores, top_k)
+    o = kept_gqa_attention(
+        q, pool, block_table, q_pos, keep, block_size=block_size,
+        n_kv_heads=n_kv_heads, top_k=top_k, lengths=lengths,
+        scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+    )
+    return (
+        o, jnp.sum(scores > -jnp.inf, dtype=jnp.int32),
+        jnp.sum(keep, dtype=jnp.int32),
+    )
 
 
 def paged_selected_latent_attention(

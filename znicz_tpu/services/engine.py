@@ -673,9 +673,9 @@ class PagedDecodeEngine:
     same physical block can appear in many tables at once.  (A tower
     that declares kinds of cached state, ``model.cache_kinds``, gets
     pools, free list, refcounts and a table a row for EACH kind, through
-    the same allocator; ``n_blocks`` is then ``{kind: blocks}`` and the
-    prefix cache is not served: docs/SERVING.md "Block tables by layer
-    kind".)  Four properties follow:
+    the same allocator; ``n_blocks`` is then ``{kind: blocks}``, and the
+    prefix cache is served only where there is one kind and it has no
+    window: docs/SERVING.md "Block tables by layer kind".)  Four properties follow:
 
     * **memory-proportional concurrency** — a slot consumes blocks for
       the tokens it has actually decoded, not a ``T_max`` reservation;
@@ -773,20 +773,31 @@ class PagedDecodeEngine:
         # unless it declares its own (workflow/generate.CacheKind)
         kinds = getattr(model, "cache_kinds", None)
         # ON by default: sharing is free when nothing matches (a few
-        # sha256 per admission) and the headline win when it does.  A
-        # tower of several kinds is served WITHOUT it: a chain could be
-        # handed to a new request only where every kind still holds it,
-        # and a window kind gives its blocks back while the row lives
-        if prefix_cache and kinds:
+        # sha256 per admission) and the headline win when it does.  It is
+        # served where a row's blocks stay its own for its whole life: the
+        # one-kind tower, and a tower that declares ONE kind with no
+        # window (whatever arrays a block of it holds: a shared block
+        # carries them all, and a copy-on-write split copies them all).
+        # A window kind gives its blocks back while the row lives, and a
+        # chain could be handed to a new request only where every kind
+        # still holds it: such a tower is served WITHOUT the cache
+        gives_back = [k.name for k in kinds or () if k.window is not None]
+        shareable = not gives_back and len(kinds or ()) <= 1
+        if prefix_cache and not shareable:
             self._m_unsupported.labels(feature="prefix_cache").inc()
             raise PrefixCacheUnsupportedError(
                 f"a {type(model).__name__} tower keeps "
                 f"{len(kinds)} kinds of cache blocks "
-                f"({', '.join(k.name for k in kinds)}): the prefix cache "
-                "is served for towers of one kind only"
+                f"({', '.join(k.name for k in kinds)}"
+                + (
+                    f"; {', '.join(gives_back)} give blocks back behind "
+                    "a window" if gives_back else ""
+                )
+                + "): the prefix cache is served for towers of one kind "
+                "that keeps its blocks for the row's life"
             )
         self.prefix_cache = (
-            not kinds if prefix_cache is None else bool(prefix_cache)
+            shareable if prefix_cache is None else bool(prefix_cache)
         )
         # speculative decoding (docs/SERVING.md "Speculative decoding"):
         # spec_k == 0 is OFF (the plain decode chunk runs); > 0 drafts
@@ -1094,6 +1105,12 @@ class PagedDecodeEngine:
         self._m_prefix_tokens = observability.counter(
             "znicz_serve_prefix_cached_tokens_total",
             "prompt tokens whose prefill was skipped via the prefix cache",
+        )
+        self._m_prompt_tokens = observability.counter(
+            "znicz_serve_prompt_tokens_total",
+            "prompt tokens of the requests bound to a slot (a readmission "
+            "after a preemption counts again, as its cached tokens do): "
+            "what znicz_serve_prefix_cached_tokens_total is a share of",
         )
         self._m_prefix_evictions = observability.counter(
             "znicz_serve_prefix_evictions_total",
@@ -1912,6 +1929,7 @@ class PagedDecodeEngine:
             else len(hits)
         )
         req.timings.cached_tokens += skip * self.block_size
+        self._m_prompt_tokens.inc(size)
         if self.prefix_cache:
             n_lookup = size // self.block_size
             self._n_prefix_hits += len(hits)
