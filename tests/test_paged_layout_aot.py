@@ -636,12 +636,16 @@ def test_two_widths_of_latent_rows_are_stored_as_they_are_computed_on(
 # experts of width 768 all held, the whole vocabulary, bfloat16; 64 slots,
 # block 128, 2,048 blocks of 1,024 lanes of [v, k] rows and 128 of indexer
 # keys beside them, the one rung (544 blocks = 69,632 positions).  A decode
-# step fetches the kept rows ([64, 2,048, 1,024]: 0.27 GB a layer, one
-# layer's at a time) and gathers nothing at the table's width.
+# step attends the kept rows inside one kernel a layer that fetches them
+# from the pool where it lies (ops/pallas/kept_rows_attention.py, picked by
+# ``backend.on_tpu()``, which the test patches): the fetched rows ([64,
+# 2,048, 1,024], 0.27 GB a layer as the gathered form wrote them out, PR 41)
+# exist nowhere in the program, and nothing is gathered at the table's width.
 
 KEYE = dict(d=2048, vocab=151936, experts=128, f=768, slots=64, block=128,
             rung=544, top_k=2048, n_blocks={"global": 2048})
-KEYE_TEMP_LIMIT_GB = {"decode_chunk": 1.2, "prefill": 0.4}  # read: 0.66, 0.14
+# read: 0.39, 0.14 (0.66 in the decode chunk while the kept rows were gathered)
+KEYE_TEMP_LIMIT_GB = {"decode_chunk": 0.6, "prefill": 0.4}
 
 
 def _keye_model():
@@ -734,13 +738,21 @@ def test_the_kept_rows_are_fetched_from_pools_stored_as_they_are_computed_on(
         dims for dims in _SHAPE.findall(text)
         if int(np.prod([int(d) for d in dims.split(",")])) == window
     ], f"{program} gathers the table's whole width"
+    fetched = a["slots"] * a["top_k"] * 1024
+    assert program != "decode_chunk" or not [
+        dims for dims in _SHAPE.findall(text)
+        if int(np.prod([int(d) for d in dims.split(",")])) == fetched
+    ], "the decode chunk holds every slot's kept rows as an array"
     assert mem.temp_size_in_bytes < KEYE_TEMP_LIMIT_GB[program] * GB, (
         f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
     )
     # weights 8.75 GB + pools 3.62 GB + temporaries fit 15.75 GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
     # the three grouped products of every layer's experts are the kernel,
-    # and in a decode step every layer's indexer is one too
+    # and in a decode step every layer's indexer and its attention over the
+    # kept rows are one each too
     assert text.count("tpu_custom_call") >= (
-        3 * 6 + (6 if program == "decode_chunk" else 0)
+        3 * 6 + (6 + 6 if program == "decode_chunk" else 0)
     )
+    if program == "decode_chunk":
+        assert "kept_gqa_decode" in text

@@ -16,8 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from znicz_tpu import observability
 from znicz_tpu.core import backend, prng
 from znicz_tpu.ops.filling import fill
+from znicz_tpu.ops.pallas import kept_rows_attention
 from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
 from znicz_tpu.ops.pallas.sparse_index import index_decode_scores
 
@@ -407,6 +409,22 @@ def _reads_pool_in_place(tq: int) -> bool:
     """Whether the absorbed form of ``tq`` queries a row is the kernel
     that reads the pool in place."""
     return tq == 1 and backend.on_tpu()
+
+
+# registered with the module, so that the family is there for a server
+# whose tower never selects
+_KEPT_ROWS_FORM = observability.counter(
+    "znicz_serve_kept_rows_attention_total",
+    "layers of a decode program built to attend the cached rows a selection "
+    "kept, by how the rows reach the two products",
+    ("form",),
+)
+
+
+def _reads_kept_rows_in_place(tq: int, pool: jnp.ndarray) -> bool:
+    """Whether attention of ``tq`` queries a row over the rows a selection
+    kept is the kernel that fetches them from ``pool`` where it lies."""
+    return _reads_pool_in_place(tq) and kept_rows_attention.fetchable(pool)
 
 
 def paged_latent_rows_read(
@@ -825,16 +843,23 @@ def kept_gqa_attention(
 
     A DECODE step (``lengths`` given, ``Tq`` 1) FETCHES THE KEPT ROWS AND
     NOTHING ELSE: the mask becomes each row's list of (table entry,
-    offset) pairs (:func:`kept_key_slots`), the rows they name are
-    gathered from the pool ([B, top_k, W]: 4 MB a row at 2,048 keys of
-    2,048 B, whatever the row's length; a walk of every block under the
-    mask, :func:`kept_latent_attention`'s decode form, reads the whole
-    row, 32 x that at 66k keys) and the queries meet them as stored
-    (:func:`paged_gqa_attention`'s layout: one product for all heads, the
-    zeros add exactly).  A PREFILL chunk walks the table under the mask
-    (:func:`_kept_rows_walk`, shared with the latent rows) with the
-    products GROUPED a K/V head: at ``Tq * H`` = 4,096 query rows the
-    zeros of the as-stored layout would cost ``H / G`` times the FLOPs."""
+    offset) pairs (:func:`kept_key_slots`) and the queries meet the rows
+    they name as stored (:func:`paged_gqa_attention`'s layout: one product
+    for all heads, the zeros add exactly), whatever the row's length (a
+    walk of every block under the mask, :func:`kept_latent_attention`'s
+    decode form, reads the whole row, 32 x the kept keys at 66k).  On the
+    TPU, over a pool it can read (:func:`_reads_kept_rows_in_place`), that
+    is :func:`~znicz_tpu.ops.pallas.kept_rows_attention
+    .kept_rows_decode_attention`: the LIVE rows' kept keys by DMA from the
+    pool where it lies, attended in VMEM.  Elsewhere the rows are gathered
+    ([B, top_k, W], every slot's, written out and read back by the two
+    products), which is also the kernel's twin.  Which of the two a decode
+    program was built with is counted once a layer,
+    ``znicz_serve_kept_rows_attention_total{form}``.  A PREFILL chunk
+    walks the table under the mask (:func:`_kept_rows_walk`, shared with
+    the latent rows) with the products GROUPED a K/V head: at ``Tq * H`` =
+    4,096 query rows the zeros of the as-stored layout would cost ``H /
+    G`` times the FLOPs."""
     with jax.named_scope("gqa_sparse"):
         b, tq, h, d = q.shape
         g, half = n_kv_heads, n_kv_heads * d
@@ -862,10 +887,22 @@ def kept_gqa_attention(
                 ),
                 axis=-1, dtype=jnp.int32,
             )
+            q_row = _gqa_query_rows(q, g, dtype)
+            in_place = _reads_kept_rows_in_place(tq, pool)
+            _KEPT_ROWS_FORM.labels(
+                form="in_place" if in_place else "gathered"
+            ).inc()
+            if in_place:
+                # the slots are named in order: the first so many of a row
+                o = kept_rows_attention.kept_rows_decode_attention(
+                    q_row, pool, blk * block_size + offset,
+                    jnp.sum(named, axis=-1, dtype=jnp.int32), scale=scale,
+                    d_out=min(-(-half // 128) * 128, 2 * half),
+                )
+                o = _gqa_own_values(o[:, None], g, d)
+                return jnp.where(lengths[:, None, None] > 0, o, 0.0)
             rows = pool[jnp.where(named, blk, 0), offset]  # [B, top_k, W]
-            s = jnp.einsum(
-                "bre,bke->brk", _gqa_query_rows(q, g, dtype), rows, **f32
-            ) * scale
+            s = jnp.einsum("bre,bke->brk", q_row, rows, **f32) * scale
             named = named[:, None, :]
             s = jnp.where(named, s, _NEG)
             p = jnp.where(
