@@ -756,3 +756,147 @@ def test_the_kept_rows_are_fetched_from_pools_stored_as_they_are_computed_on(
     )
     if program == "decode_chunk":
         assert "kept_gqa_decode" in text
+
+
+# -- [v, k] rows of 2,048 lanes, query heads a layer kind, at laguna-xs2-stage1's
+#
+# 7 layers (full and dense, sliding x 3, full, sliding x 2) at hidden 2,048;
+# 48 query heads on the full layers and 64 on the sliding ones over 8 K/V
+# heads of 128 (groups of 6 and of 8), a gate a head, 256 experts of width
+# 512 all held beside a shared one, the whole vocabulary of 100,352,
+# bfloat16; 48 slots, block 128, 1,280 global blocks and 384 window blocks of
+# 2,048 lanes, the widest global rung (176 blocks = 22,528 positions) beside
+# the window kind's ring of 6 (512 keys, chunks of 4 steps).  A decode step
+# of either kind reads the pool in place through the kernel the other towers
+# use (one Pallas call a layer); a prefill chunk walks the table with the
+# products grouped a K/V head (no [8,192 x 22,528] score matrix, no
+# block-diagonal zeros).
+
+LG = dict(d=2048, heads={"global": 48, "window": 64}, kv_heads=8, dim=128,
+          f=512, f_dense=8192, experts=256, vocab=100352, slots=48, block=128,
+          rung=176, ring=6, chunk=4, max_seq=22528,
+          windowed=(False, True, True, True, False, True, True),
+          n_blocks={"global": 1280, "window": 384})
+
+
+def _laguna_model():
+    from znicz_tpu.workflow.gated_window_lm import GatedWindowGQAMoEModel
+
+    return GatedWindowGQAMoEModel(
+        n_heads=64, global_heads=48, n_kv_heads=8, head_dim=128, top_k=8,
+        window=512, windowed=LG["windowed"], max_positions=LG["max_seq"],
+        routed_scaling_factor=2.5, global_rope_theta=5e5, global_rotary_dim=64,
+        rope_factor=64.0, rope_original_max=4096, rope_beta_fast=64.0,
+        rope_beta_slow=1.0, attention_factor=1.4158883083359672,
+    )
+
+
+def _laguna_params(spec):
+    a, bf, f32 = LG, jnp.bfloat16, jnp.float32
+    d, kv, e, f = a["d"], a["kv_heads"] * a["dim"], a["experts"], a["f"]
+    blocks = []
+    for layer, windowed in enumerate(a["windowed"]):
+        h = a["heads"]["window" if windowed else "global"]
+        leaves = {
+            "attn_norm": ((d,), f32), "wq": ((d, h * a["dim"]), bf),
+            "wk": ((d, kv), bf), "wv": ((d, kv), bf), "wg": ((d, h), bf),
+            "wo": ((h * a["dim"], d), bf), "ffn_norm": ((d,), f32),
+        }
+        if layer == 0:
+            leaves.update(
+                w_gate=((d, a["f_dense"]), bf), w_up=((d, a["f_dense"]), bf),
+                w_down=((a["f_dense"], d), bf),
+            )
+        else:
+            leaves.update(
+                router=((d, e), bf), router_bias=((e,), f32),
+                experts_gate=((e, d, f), bf), experts_up=((e, d, f), bf),
+                experts_down=((e, f, d), bf), shared_gate=((d, f), bf),
+                shared_up=((d, f), bf), shared_down=((f, d), bf),
+            )
+        blocks.append({k: spec(*v) for k, v in leaves.items()})
+    return (
+        [{"embed": spec((a["vocab"], d), bf)}] + blocks
+        + [{"final_norm": spec((d,), f32), "head": spec((d, a["vocab"]), bf)}]
+    )
+
+
+LAGUNA_TEMP_LIMIT_GB = {"decode_chunk": 0.8, "prefill": 0.8}
+
+
+@pytest.mark.parametrize("program", list(LAGUNA_TEMP_LIMIT_GB))
+def test_rows_of_2048_lanes_under_heads_by_kind_are_stored_as_they_are_computed_on(
+    chip, program, monkeypatch
+):
+    from znicz_tpu.core import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    a, model = LG, _laguna_model()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = _laguna_params(spec)
+    pools = jax.tree.map(
+        lambda p: spec(p.shape, p.dtype),
+        jax.eval_shape(lambda: model.init_pools(params, a["n_blocks"], a["block"])),
+    )
+    assert [p["kv"].shape[0] for p in pools] == [1280, 384, 384, 384, 1280, 384, 384]
+    assert pools[0]["kv"].shape[1:] == (a["block"], 2048)
+    state = spec((5, a["slots"]), i32)
+    scalar_f32, key = spec((), f32), spec((2,), jnp.uint32)
+    tower = dict(
+        n_heads=48, block_size=a["block"], moe_top_k=1, moe_dispatch="dense",
+        model=model,
+    )
+    with jax.default_matmul_precision("default"):
+        if program == "decode_chunk":
+            tables = {
+                "global": spec((a["slots"], a["rung"]), i32),
+                "window": spec((a["slots"], a["ring"]), i32),
+            }
+            lowered = engine._paged_decode_chunk.lower(
+                params, pools, tables, state, scalar_f32, scalar_f32, key,
+                chunk=a["chunk"], t_max=a["max_seq"], eos_id=0, **SAMPLING, **tower,
+            )
+        else:
+            table = {
+                "global": spec((a["rung"],), i32), "window": spec((a["ring"],), i32),
+            }
+            lowered = engine._paged_prefill_prog.lower(
+                params, pools, table, spec((1, a["max_seq"]), i32),
+                spec((3,), i32), scalar_f32, scalar_f32, key, **SAMPLING, **tower,
+            )
+        compiled = lowered.compile()  # raises where the chip would refuse it
+    text = compiled.as_text()
+    row = a["block"] * 2048
+    # no pool is copied or re-tiled, and no table's width of rows is gathered
+    moved = _relayouts(text, 1280 * row, 384 * row)
+    assert not moved, f"{program} moves a pool: {sorted(set(moved))}"
+    gathered = (
+        a["slots"] * a["rung"] * row, a["slots"] * a["ring"] * row, a["rung"] * row,
+    )
+    assert not [
+        dims for dims in _SHAPE.findall(text)
+        if int(np.prod([int(d) for d in dims.split(",")])) in gathered
+    ], f"{program} gathers a table's width of rows"
+    mem = compiled.memory_analysis()
+    print(
+        f"laguna {program}: arguments {mem.argument_size_in_bytes / GB:.2f} GB, "
+        f"temporaries {mem.temp_size_in_bytes / GB:.2f} GB"
+    )
+    assert mem.temp_size_in_bytes < LAGUNA_TEMP_LIMIT_GB[program] * GB, (
+        f"{program} holds {mem.temp_size_in_bytes / GB:.2f} GB of temporaries"
+    )
+    # weights 11.13 GB + pools 2.35 GB + temporaries fit 15.75 GiB with the
+    # 0.8 GB to spare ISSUE 44 asks for
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30 - 0.8 * GB
+    )
+    # the three grouped products of the six routed layers are the kernel,
+    # and in a decode step every layer's attention is one too
+    kernels = text.count("tpu_custom_call")
+    assert kernels >= 3 * 6 + (7 if program == "decode_chunk" else 0)

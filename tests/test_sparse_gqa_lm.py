@@ -26,7 +26,6 @@ from znicz_tpu import observability
 from znicz_tpu.ops import attention as att
 from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import (
-    PrefixCacheUnsupportedError,
     SpeculationUnsupportedError,
 )
 from znicz_tpu.workflow import sparse_gqa_lm as sgl
@@ -408,7 +407,7 @@ def test_a_preempted_row_is_readmitted_and_still_serves_the_reference(toy):
         assert toy.served_gaps(eng.completions[rid]).max() < 1e-4
 
 
-def test_the_prefix_cache_is_refused_by_name_for_a_kind_that_gives_blocks_back(toy):
+def test_the_prefix_cache_is_on_by_default_only_where_no_kind_gives_blocks_back(toy):
     """Decided from the kinds' windows: this tower's one kind keeps its
     blocks, a tower with a window kind does not."""
     before = {
@@ -436,14 +435,13 @@ def test_the_prefix_cache_is_refused_by_name_for_a_kind_that_gives_blocks_back(t
         )
 
     assert not build().prefix_cache
-    with pytest.raises(PrefixCacheUnsupportedError, match="window"):
-        build(prefix_cache=True)
+    assert build(prefix_cache=True).prefix_cache  # served when named
     assert _counter(
         "znicz_serve_unsupported_total", feature="speculation"
     ) == before["speculation"] + 1
     assert _counter(
         "znicz_serve_unsupported_total", feature="prefix_cache"
-    ) == before["prefix_cache"] + 1
+    ) == before["prefix_cache"]
 
 
 def test_from_config_reads_the_published_keys_and_refuses_what_is_not_built():

@@ -32,7 +32,6 @@ from znicz_tpu.ops import attention as att
 from znicz_tpu.ops import moe
 from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import (
-    PrefixCacheUnsupportedError,
     SpeculationUnsupportedError,
 )
 from znicz_tpu.workflow import sparse_latent_lm as slm
@@ -630,9 +629,12 @@ def test_what_the_tower_is_not_served_with_is_refused_by_name(toy):
     }
     with pytest.raises(SpeculationUnsupportedError):
         toy.engine(spec_k=2)
-    with pytest.raises(PrefixCacheUnsupportedError):
-        toy.engine(prefix_cache=True)
+    # the prefix cache is off by default and served when named (the engine
+    # decides from the kinds' windows: tests/test_engine_prefix_kinds.py)
+    assert toy.engine(prefix_cache=True).prefix_cache
     with pytest.raises(ValueError, match="by kind"):
         toy.engine(n_blocks=64)
     for feature, n in before.items():
-        assert _counter("znicz_serve_unsupported_total", feature=feature) == n + 1
+        assert _counter("znicz_serve_unsupported_total", feature=feature) == n + (
+            feature == "speculation"
+        )

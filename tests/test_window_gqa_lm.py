@@ -36,7 +36,6 @@ from znicz_tpu.ops.attention import (
 )
 from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import (
-    PrefixCacheUnsupportedError,
     RequestTooLargeError,
     SpeculationUnsupportedError,
 )
@@ -590,17 +589,17 @@ def test_admission_counts_what_a_request_holds_in_each_kind(toy):
     assert eng.stats()["preemptions"] == 0
 
 
-def test_the_prefix_cache_and_speculation_are_refused_by_name(toy):
-    """The prefix cache is not served for a tower with a window kind: asked
-    for by name it is refused with a typed, counted error; left to its
-    default it is off, so nothing is published and no hit can hand a window
-    layer rows their owner gave back."""
+def test_the_prefix_cache_is_off_unless_named_and_speculation_is_refused(toy):
+    """Left to its default the prefix cache is off for a tower with a window
+    kind, so nothing is published and nothing hashed; asked for by name it
+    is served, a chain held a kind (tests/test_engine_prefix_kinds.py), and
+    nothing is counted as refused.  Speculation stays refused by type."""
     before = _counter("znicz_serve_unsupported_total", feature="prefix_cache")
-    with pytest.raises(PrefixCacheUnsupportedError, match="global, window"):
-        toy.engine(prefix_cache=True)
+    assert toy.engine(prefix_cache=True).prefix_cache
+    assert not toy.engine(prefix_cache=False).prefix_cache
     assert _counter(
         "znicz_serve_unsupported_total", feature="prefix_cache"
-    ) == before + 1
+    ) == before
     before = _counter("znicz_serve_unsupported_total", feature="speculation")
     with pytest.raises(SpeculationUnsupportedError, match="WindowGQAMoEModel"):
         toy.engine(spec_k=2)

@@ -175,6 +175,7 @@ def paged_gqa_attention(
     window: Optional[int] = None,
     lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
     scale: Optional[float] = None,
+    grouped_prefill: bool = False,
 ) -> jnp.ndarray:
     """GROUPED-QUERY attention over a paged pool: ``H`` query heads read
     ``G = n_kv_heads`` cached K/V heads, query head ``h`` the head ``h //
@@ -195,6 +196,13 @@ def paged_gqa_attention(
     leads, :func:`_window_in_table_order`, and the kernel is told where
     in that block the window starts); everything else gathers ``pool
     [block_table]`` at the table's width and computes on the copy.
+    ``grouped_prefill``: a call of SEVERAL queries a row walks the table
+    instead, as far as its last query, under a running softmax with the
+    products grouped a K/V head (:func:`_kept_rows_walk` under the causal
+    mask, :func:`kept_gqa_attention`'s prefill form): for a tower whose
+    ``Tq * H`` query rows (8,192 at 64 heads) against the table's width
+    would make the as-stored layout's zeros ``G`` times the FLOPs and its
+    scores a gigabyte.
 
     Validity is by ABSOLUTE key index.  ``window`` None: the table is
     plain (entry ``j`` covers positions ``j * block_size ..``) and key
@@ -211,6 +219,7 @@ def paged_gqa_attention(
             q, pool, block_table, q_pos, block_size=block_size,
             n_kv_heads=n_kv_heads, window=window, lengths=lengths,
             scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+            grouped_prefill=grouped_prefill,
         )
 
 
@@ -254,7 +263,7 @@ def paged_gqa_rows_read(
 
 def _paged_gqa_attention(
     q, pool, block_table, q_pos, *, block_size, n_kv_heads, window, lengths,
-    scale,
+    scale, grouped_prefill=False,
 ):
     b, tq, h, d = q.shape
     g, half = n_kv_heads, n_kv_heads * d
@@ -269,6 +278,24 @@ def _paged_gqa_attention(
         raise ValueError(
             f"lengths are a decode step's; got {tq} queries a row"
         )
+    if grouped_prefill and tq > 1:
+        n_keys = block_table.shape[1] * block_size
+        if window is None:
+            k_pos, last = jnp.arange(n_keys)[None, :], q_pos[:, -1]
+        else:  # a ring: any entry may hold keys of the window
+            k_pos = ring_key_positions(
+                block_table.shape[1], block_size, q_pos[:, -1]
+            )
+            last = jnp.full((b,), n_keys - 1, jnp.int32)
+        k_pos, at = k_pos[:, None, :], q_pos[:, :, None]
+        keep = (k_pos <= at) & (k_pos >= 0)
+        if window is not None:
+            keep = keep & (k_pos > at - window)
+        scores, weighted = _grouped_products(q, g, dtype, scale)
+        return _kept_rows_walk(
+            pool, block_table, keep, last, (b, tq, h), block_size=block_size,
+            out_width=d, scores=scores, weighted=weighted,
+        ).reshape(b, tq, h * d)
     q_row = _gqa_query_rows(q, g, dtype)
     if _reads_pool_in_place(tq):
         table, keys, starts = block_table, lengths, None
@@ -913,24 +940,40 @@ def kept_gqa_attention(
             o = jnp.einsum("brk,bke->bre", (p / total).astype(dtype), rows, **f32)
             o = _gqa_own_values(o[:, None], g, d)
             return jnp.where(lengths[:, None, None] > 0, o, 0.0)
-        qg = q.astype(dtype).reshape(b, tq, g, h // g, d)
-
-        def split(rows):  # [B, keys, W] -> v, k [B, keys, G, D]
-            rows = rows.reshape(b, -1, 2, g, d)
-            return rows[:, :, 0], rows[:, :, 1]
-
+        scores, weighted = _grouped_products(q, g, dtype, scale)
         o = _kept_rows_walk(
             pool, block_table, keep, q_pos[:, -1], (b, tq, h),
-            block_size=block_size, out_width=d,
-            scores=lambda rows: jnp.einsum(
-                "btgrd,bkgd->btgrk", qg, split(rows)[1], **f32
-            ).reshape(b, tq, h, -1) * scale,
-            weighted=lambda p, rows: jnp.einsum(
-                "btgrk,bkgd->btgrd", p.reshape(b, tq, g, h // g, -1),
-                split(rows)[0], **f32,
-            ).reshape(b, tq, h, d),
+            block_size=block_size, out_width=d, scores=scores,
+            weighted=weighted,
         )
         return o.reshape(b, tq, h * d)
+
+
+def _grouped_products(q, g, dtype, scale):
+    """The two products of :func:`_kept_rows_walk` for queries ``q`` [B,
+    Tq, H, D] over ``[v, k]`` rows of ``g`` K/V heads, GROUPED a K/V head
+    (``H / g`` query heads meet their own head's keys and values and no
+    zeros): ``(scores, weighted)``."""
+    b, tq, h, d = q.shape
+    f32 = dict(preferred_element_type=jnp.float32)
+    qg = q.astype(dtype).reshape(b, tq, g, h // g, d)
+
+    def split(rows):  # [B, keys, W] -> v, k [B, keys, G, D]
+        rows = rows.reshape(b, -1, 2, g, d)
+        return rows[:, :, 0], rows[:, :, 1]
+
+    def scores(rows):
+        return jnp.einsum(
+            "btgrd,bkgd->btgrk", qg, split(rows)[1], **f32
+        ).reshape(b, tq, h, -1) * scale
+
+    def weighted(p, rows):
+        return jnp.einsum(
+            "btgrk,bkgd->btgrd", p.reshape(b, tq, g, h // g, -1),
+            split(rows)[0], **f32,
+        ).reshape(b, tq, h, d)
+
+    return scores, weighted
 
 
 def paged_selected_gqa_attention(

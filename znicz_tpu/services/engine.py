@@ -604,6 +604,17 @@ class _BlockKind:
         content a future lookup trusts)."""
         return self.ref[blk] > 1 or blk in self.block_hash
 
+    def forget(self, h: bytes) -> None:
+        """Drop the cache's entry for chain hash ``h``, if it has one; a
+        block nothing else references returns to the free list."""
+        blk = self.cache.pop(h, None)
+        if blk is None:
+            return
+        del self.block_hash[blk]
+        if blk in self.lru:
+            del self.lru[blk]
+            self.free.append(blk)
+
     def push(self, slot: int, blk: int) -> None:
         """``blk`` (referenced by the caller) covers the row's next
         block index."""
@@ -673,9 +684,11 @@ class PagedDecodeEngine:
     same physical block can appear in many tables at once.  (A tower
     that declares kinds of cached state, ``model.cache_kinds``, gets
     pools, free list, refcounts and a table a row for EACH kind, through
-    the same allocator; ``n_blocks`` is then ``{kind: blocks}``, and the
-    prefix cache is served only where there is one kind and it has no
-    window: docs/SERVING.md "Block tables by layer kind".)  Four properties follow:
+    the same allocator; ``n_blocks`` is then ``{kind: blocks}``, and a
+    cached chain has a holder a kind, a window kind's for the match's last
+    window only; with a window kind the prefix cache is off unless asked
+    for by name: docs/SERVING.md "Block tables by layer kind".)  Four
+    properties follow:
 
     * **memory-proportional concurrency** — a slot consumes blocks for
       the tokens it has actually decoded, not a ``T_max`` reservation;
@@ -772,32 +785,21 @@ class PagedDecodeEngine:
         # the kinds of cached state the tower keeps: one (every layer's)
         # unless it declares its own (workflow/generate.CacheKind)
         kinds = getattr(model, "cache_kinds", None)
-        # ON by default: sharing is free when nothing matches (a few
-        # sha256 per admission) and the headline win when it does.  It is
-        # served where a row's blocks stay its own for its whole life: the
-        # one-kind tower, and a tower that declares ONE kind with no
-        # window (whatever arrays a block of it holds: a shared block
-        # carries them all, and a copy-on-write split copies them all).
-        # A window kind gives its blocks back while the row lives, and a
-        # chain could be handed to a new request only where every kind
-        # still holds it: such a tower is served WITHOUT the cache
-        gives_back = [k.name for k in kinds or () if k.window is not None]
-        shareable = not gives_back and len(kinds or ()) <= 1
-        if prefix_cache and not shareable:
-            self._m_unsupported.labels(feature="prefix_cache").inc()
-            raise PrefixCacheUnsupportedError(
-                f"a {type(model).__name__} tower keeps "
-                f"{len(kinds)} kinds of cache blocks "
-                f"({', '.join(k.name for k in kinds)}"
-                + (
-                    f"; {', '.join(gives_back)} give blocks back behind "
-                    "a window" if gives_back else ""
-                )
-                + "): the prefix cache is served for towers of one kind "
-                "that keeps its blocks for the row's life"
-            )
+        # ON by default where a row's blocks stay its own for its whole
+        # life (the one-kind tower, and a tower that declares ONE kind with
+        # no window, whatever arrays a block of it holds): sharing is free
+        # when nothing matches (a few sha256 per admission) and the
+        # headline win when it does.  A tower with a WINDOW kind gives that
+        # kind's blocks back while the row lives, so a chain has a holder A
+        # KIND (global blocks for the whole match, window blocks for its
+        # last window) and is handed to a new request only where every kind
+        # still holds its part: served when asked for by name, OFF by
+        # default (such a tower then hashes nothing and keeps no cache-only
+        # block)
+        gives_back = any(k.window is not None for k in kinds or ())
         self.prefix_cache = (
-            shareable if prefix_cache is None else bool(prefix_cache)
+            not gives_back and len(kinds or ()) <= 1
+            if prefix_cache is None else bool(prefix_cache)
         )
         # speculative decoding (docs/SERVING.md "Speculative decoding"):
         # spec_k == 0 is OFF (the plain decode chunk runs); > 0 drafts
@@ -1115,6 +1117,23 @@ class PagedDecodeEngine:
         self._m_prefix_evictions = observability.counter(
             "znicz_serve_prefix_evictions_total",
             "cached blocks evicted to satisfy allocation pressure",
+        )
+        self._m_prefix_mapped = observability.counter(
+            "znicz_serve_prefix_blocks_mapped_total",
+            "blocks a request bound to a slot took from the prefix cache, "
+            "by kind of cache block (a window kind's are the match's last "
+            "window)",
+            ("kind",),
+        )
+        self._m_prefix_hit_requests = observability.counter(
+            "znicz_serve_prefix_hit_requests_total",
+            "requests bound to a slot that mapped a cached chain (what "
+            "znicz_serve_prefix_blocks_mapped_total is a mean over)",
+        )
+        self._m_prefix_cut = observability.counter(
+            "znicz_serve_prefix_chain_cut_total",
+            "admissions whose cached chain was shortened or lost",
+            ("reason",),
         )
         # speculative decoding tallies (zero and silent while spec is
         # off; the registry series are process-wide get-or-create)
@@ -1626,7 +1645,14 @@ class PagedDecodeEngine:
             return kind.free.pop()
         if kind.lru:
             blk, _ = kind.lru.popitem(last=False)
-            del kind.cache[kind.block_hash.pop(blk)]
+            h = kind.block_hash.pop(blk)
+            del kind.cache[h]
+            if kind.window is None:
+                # every match a window kind's block of this hash could
+                # serve runs through the block that just went
+                for other in self._kinds:
+                    if other.window is not None:
+                        other.forget(h)
             self._n_evictions += 1
             self._m_prefix_evictions.inc()
             return blk
@@ -1707,16 +1733,32 @@ class PagedDecodeEngine:
         return True
 
     def _cow_split(self, slot: int, j: int, *, copy: bool) -> bool:
-        """Copy-on-write: retarget entry ``j`` of ``slot``'s table (the
-        one kind's: sharing needs the prefix cache) to a fresh private
-        block before a write into a shared/cached block.
+        """Copy-on-write: retarget the entry of block index ``j`` of
+        ``slot``'s table to a fresh private block before a write into a
+        shared/cached block.
         ``copy=False`` when the impending write rewrites the whole
         block (a prefill chunk re-run) — the fresh block needs no
         content.  No-op for private blocks, and where the prefix cache is
-        off (only a cached chain is ever shared; a tower of several kinds
-        is served without one).  False when allocation had to preempt
-        ``slot`` itself."""
+        off (only a cached chain is ever shared).  False when allocation
+        had to preempt ``slot`` itself.  A tower of several kinds never
+        comes to a split: a match is whole blocks and stops short of the
+        prompt's last token (:meth:`_cached_chain`), so a row's first own
+        token opens a fresh block in every kind; a shared block in a
+        write's way there is refused by type."""
         if not self.prefix_cache:
+            return True
+        if len(self._kinds) > 1:
+            for kind in self._kinds:
+                at = j - kind.row_base[slot]
+                if 0 <= at < len(kind.row_blocks[slot]) and kind.shared(
+                    int(kind.row_blocks[slot][at])
+                ):
+                    self._m_unsupported.labels(feature="prefix_cache").inc()
+                    raise PrefixCacheUnsupportedError(
+                        f"a write into a shared block of kind {kind.name!r}"
+                        ": copy-on-write across kinds of cache blocks is "
+                        "not served"
+                    )
             return True
         kind = self._kinds[0]
         blk = int(kind.row_blocks[slot][j])
@@ -1756,15 +1798,9 @@ class PagedDecodeEngine:
         Safe to call from any thread (dict lookups only, no
         iteration)."""
         p = np.asarray(prompt, np.int32).reshape(-1)
-        keys: List[str] = []
-        cached = 0
-        walking, cache = self.prefix_cache, self._kinds[0].cache
-        for h in _chain_digests(p, self.block_size):
-            keys.append(h.hex())
-            if walking and h in cache:
-                cached += 1
-            else:
-                walking = False
+        digests = list(_chain_digests(p, self.block_size))
+        keys = [h.hex() for h in digests]
+        cached = self._cached_chain(digests, p.size)[0]
         return {
             "prefix_cache": self.prefix_cache,
             "block_size": self.block_size,
@@ -1773,35 +1809,68 @@ class PagedDecodeEngine:
             "cached_tokens": cached * self.block_size,
         }
 
-    def _lookup_prefix(self, req: Request) -> List[int]:
-        """Longest cached block-chain prefix of the request's prompt
-        (full blocks only — a divergence mid-block misses from that
-        block on).  Claim-free: the caller bumps refcounts when it
-        binds.  The hash chain is memoized on the request (content-
-        pure); only the hash -> block resolution reads live state."""
-        hits: List[int] = []
+    def _cached_chain(self, digests: List[bytes], size: int):
+        """``(blocks, cut)``: how many leading whole blocks of a prompt of
+        ``size`` tokens with chain hashes ``digests`` a new row can map —
+        the longest chain at which EVERY kind holds its part, the kinds
+        that keep every token all of it, a window kind the blocks a query
+        at the chain's end still reads (:meth:`_BlockKind.first_needed`)
+        — and whether a window kind that no longer held its part made it
+        shorter than the other kinds' chain.  Where there are several
+        kinds the chain stops short of the prompt's last token, so the
+        final chunk runs into fresh blocks of every kind.  Dict lookups
+        only."""
         if not self.prefix_cache:
-            return hits
+            return 0, False
+        several = len(self._kinds) > 1
+        limit = (size - 1) // self.block_size if several else len(digests)
+        longest = 0
+        for h in digests[:limit]:
+            if any(h not in k.cache for k in self._kinds if k.window is None):
+                break
+            longest += 1
+        windows = [k for k in self._kinds if k.window is not None]
+        n = longest
+        while n and not all(
+            h in k.cache
+            for k in windows
+            for h in digests[k.first_needed(n * self.block_size):n]
+        ):
+            n -= 1
+        return n, n < longest
+
+    def _chain_blocks(self, kind: _BlockKind, digests, n: int):
+        """``(first index, blocks)``: ``kind``'s part of a cached chain of
+        ``n`` blocks, as :meth:`_cached_chain` found it."""
+        if not n:
+            return 0, []
+        first = kind.first_needed(n * self.block_size)
+        return first, [kind.cache[h] for h in digests[first:n]]
+
+    def _lookup_prefix(self, req: Request):
+        """:meth:`_cached_chain` of the request's prompt: the blocks a row
+        can map (full blocks only — a divergence mid-block misses from
+        that block on) and whether a window kind cut them.  Claim-free:
+        the caller bumps refcounts when it binds.  The hash chain is
+        memoized on the request (content-pure); only the hash -> block
+        resolution reads live state."""
+        if not self.prefix_cache:
+            return 0, False
         if req.digests is None:
             req.digests = list(self._chain_hashes(req.prompt))
-        cache = self._kinds[0].cache
-        for h in req.digests:
-            blk = cache.get(h)
-            if blk is None:
-                break
-            hits.append(blk)
-        return hits
+        return self._cached_chain(req.digests, req.prompt.size)
 
     def _publish_row(self, slot: int) -> None:
         """Retire/preempt hook: publish this row's COMPLETED full
         blocks (every position holds a real token's K/V) into the
-        prefix cache.  First writer wins when two physical blocks hold
-        the same content — the duplicate stays private and frees
-        normally at release.  (The prefix cache is served for towers of
-        one kind of blocks: where it is on, that kind's.)"""
+        prefix cache, kind by kind: a kind that keeps every token all of
+        them, a window kind the ones the row still holds (those behind
+        were given back: what stays serves a match that ends at a block
+        boundary the row has reached, within the window of it).  First
+        writer wins when two physical blocks hold the same content — the
+        duplicate stays private and frees normally at release."""
         if not self.prefix_cache:
             return
-        kind = self._kinds[0]
         st = self._slots[slot]
         req = st["req"]
         emitted = st.get("emitted") or []
@@ -1814,30 +1883,36 @@ class PagedDecodeEngine:
             # emitted token EXCEPT the last (sampled, never fed back,
             # so its K/V was never written)
             covered = req.prompt.size + max(len(emitted) - 1, 0)
-        row = kind.row_blocks[slot]
-        n_full = min(covered // self.block_size, len(row))
+        n_full = min(
+            [covered // self.block_size]
+            + [k.row_base[slot] + len(k.row_blocks[slot]) for k in self._kinds]
+        )
         if not n_full:
             return
         toks = np.concatenate(
             [req.prompt, np.asarray(emitted, np.int32)]
         )[: n_full * self.block_size]
-        for j, h in enumerate(self._chain_hashes(toks)):
-            blk = int(row[j])
-            if h in kind.cache or blk in kind.block_hash:
-                continue  # already published (a mapped prefix), or dup
-            kind.cache[h] = blk
-            kind.block_hash[blk] = h
+        digests = list(self._chain_hashes(toks))
+        for kind in self._kinds:
+            base = kind.row_base[slot]
+            for j in range(base, n_full):
+                h, blk = digests[j], int(kind.row_blocks[slot][j - base])
+                if h in kind.cache or blk in kind.block_hash:
+                    continue  # already published (a mapped prefix), or dup
+                kind.cache[h] = blk
+                kind.block_hash[blk] = h
 
     def flush_prefix_cache(self) -> int:
         """Drop every cache entry; cache-only blocks return to the
         free list (blocks live requests still reference just lose their
-        hash and free normally at release).  Returns entries dropped."""
-        kind = self._kinds[0]
-        n = len(kind.cache)
-        kind.cache.clear()
-        kind.block_hash.clear()
-        kind.free.extend(kind.lru)
-        kind.lru.clear()
+        hash and free normally at release).  Returns entries dropped (a
+        chain counts once: the first kind's)."""
+        n = len(self._kinds[0].cache)
+        for kind in self._kinds:
+            kind.cache.clear()
+            kind.block_hash.clear()
+            kind.free.extend(kind.lru)
+            kind.lru.clear()
         self._update_pool_gauges()
         return n
 
@@ -1872,32 +1947,36 @@ class PagedDecodeEngine:
         for slot in range(self.batch_size):
             if self._slots[slot] is None and self._queue:
                 req = self._queue[0]
-                hits = self._lookup_prefix(req)
+                n_hit = self._lookup_prefix(req)[0]
+                hits = [
+                    self._chain_blocks(kind, req.digests, n_hit)[1]
+                    for kind in self._kinds
+                ]
                 # a fully-cached prompt still COW-reruns its final
-                # block's chunk for the first-token logits (hits are of
-                # the one kind a tower with a prefix cache has)
-                need = [owed(kind, req, len(hits)) for kind in self._kinds]
+                # block's chunk for the first-token logits
+                need = [
+                    owed(kind, req, len(h)) for kind, h in zip(self._kinds, hits)
+                ]
                 # allocatable = free + evictable cache, NOT counting the
                 # hit blocks themselves (binding pins them)
                 pool = [
-                    kind.allocatable - sum(1 for b in hits if b in kind.lru)
-                    for kind in self._kinds
+                    kind.allocatable - sum(1 for b in h if b in kind.lru)
+                    for kind, h in zip(self._kinds, hits)
                 ]
                 if any(
                     p - r < n for p, r, n in zip(pool, reserved, need)
                 ):
                     break
                 reserved = [r + n for r, n in zip(reserved, need)]
-                self._start_prefill(slot, self._queue.popleft(), hits)
+                self._start_prefill(slot, self._queue.popleft())
         self._m_queue_depth.set(len(self._queue))
         self._m_active.set(self.active)
 
-    def _start_prefill(
-        self, slot: int, req: Request, hits: Optional[List[int]] = None
-    ) -> None:
+    def _start_prefill(self, slot: int, req: Request) -> None:
         """Bind a queued request to a slot: claim the longest cached
         block-chain prefix of its prompt (refcount bumps pin the blocks
-        under the binder) and queue only the UNCACHED tail for chunked
+        under the binder; a window kind's part goes into its ring at the
+        blocks' own indices) and queue only the UNCACHED tail for chunked
         prefill.  Tail blocks are allocated and chunks run lazily by
         :meth:`_prefill_tick`, so binding itself can never stall or
         starve anyone.  Prompts anchor at position 0 and RIGHT-pad the
@@ -1909,35 +1988,37 @@ class PagedDecodeEngine:
         # the prompt goes up ONCE, whole: its chunks are cut on the device
         tokens = np.full((1, self.prompt_width), self.pad_id, np.int32)
         tokens[0, :size] = req.prompt
-        if hits is None:
-            # _admit_pending passes its own lookup through (nothing can
-            # mutate the cache in between); this walk serves direct
-            # white-box callers only
-            hits = self._lookup_prefix(req)
-        kind = self._kinds[0]  # hits exist only where there is one kind
-        for blk in hits:
-            kind.incref(blk)
-            if blk in kind.lru:
-                del kind.lru[blk]
-            kind.push(slot, blk)
+        n_hit, cut = self._lookup_prefix(req)
+        for kind in self._kinds:
+            first, blocks = self._chain_blocks(kind, req.digests, n_hit)
+            kind.row_base[slot] = first
+            for blk in blocks:
+                kind.incref(blk)
+                if blk in kind.lru:
+                    del kind.lru[blk]
+                kind.push(slot, blk)
+            if blocks:
+                self._m_prefix_mapped.labels(kind=kind.name).inc(len(blocks))
         # a fully-cached prompt still needs its first-token LOGITS: the
         # final block's chunk re-runs (the write guard COW-splits it off
         # the shared block), so at least one chunk always executes
         skip = (
-            len(hits) - 1
-            if hits and len(hits) * self.block_size == size
-            else len(hits)
+            n_hit - 1 if n_hit and n_hit * self.block_size == size else n_hit
         )
         req.timings.cached_tokens += skip * self.block_size
         self._m_prompt_tokens.inc(size)
         if self.prefix_cache:
             n_lookup = size // self.block_size
-            self._n_prefix_hits += len(hits)
-            self._n_prefix_misses += n_lookup - len(hits)
+            self._n_prefix_hits += n_hit
+            self._n_prefix_misses += n_lookup - n_hit
             self._n_cached_tokens += skip * self.block_size
-            self._m_prefix_hits.inc(len(hits))
-            self._m_prefix_misses.inc(n_lookup - len(hits))
+            self._m_prefix_hits.inc(n_hit)
+            self._m_prefix_misses.inc(n_lookup - n_hit)
             self._m_prefix_tokens.inc(skip * self.block_size)
+            if n_hit:
+                self._m_prefix_hit_requests.inc()
+            if cut:
+                self._m_prefix_cut.labels(reason="window_not_held").inc()
         self._slots[slot] = {
             "req": req, "emitted": [], "mode": "prefill",
             "seq": self._n_admits,

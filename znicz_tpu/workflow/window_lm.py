@@ -184,7 +184,8 @@ class WindowGQAMoEModel:
                 q_pos, row_mask, block_size=block_size, lengths=lengths,
             )
             new_pools.append(pool)
-            load.append(pairs)
+            if pairs is not None:  # a dense layer (a tower built on this one)
+                load.append(pairs)
         return x, new_pools, _expert_load(load)
 
     def prefill_chunk(
