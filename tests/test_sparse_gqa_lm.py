@@ -199,6 +199,120 @@ def test_the_decode_fetch_and_the_prefill_walk_agree_on_the_same_keep():
     assert int(att.kept_rows_fetched(lengths, TOP_K)) == 16 + 0 + 9 + 16
 
 
+# -- a decode step's selection, over the live rows alone ---------------------
+
+ROWS = att.SELECT_TILE_ROWS
+N_SLOTS = 2 * ROWS + 3  # whole tiles and a part of one
+
+
+def _decode_scores(rng, live, *, n_slots=N_SLOTS, m=12):
+    """Index scores of a decode step with ``live`` of ``n_slots`` rows
+    decoding, scattered among idle ones: ``(scores [B, 1, m * BS], lengths,
+    table)``.  Five distinct values, so the cut falls among equal scores;
+    the first live row sees fewer keys than are kept, the second the whole
+    table."""
+    n_keys = m * BS
+    lengths = np.zeros(n_slots, np.int32)
+    where = rng.permutation(n_slots)[:live]
+    lengths[where] = rng.integers(TOP_K, n_keys + 1, live)
+    lengths[where[:1]] = TOP_K - 3
+    lengths[where[1:2]] = n_keys
+    values = rng.integers(0, 5, (n_slots, 1, n_keys)).astype(np.float32)
+    seen = np.arange(n_keys) < lengths[:, None, None]
+    table = 1 + rng.permutation(n_slots * m).reshape(n_slots, m)
+    return (
+        jnp.asarray(np.where(seen, values, -np.inf)), jnp.asarray(lengths),
+        jnp.asarray(table, jnp.int32),
+    )
+
+
+def _whole_batch_selection(scores, lengths, top_k, *, block_table, block_size):
+    """``select_live_rows`` as it was before PR 45: every row of the batch,
+    live or idle, through ``select_top_keys`` and the listing."""
+    keep, *sums = att._select_every_row(scores, top_k)
+    listed = att.kept_row_addresses(
+        keep[:, 0], block_table, top_k, block_size=block_size
+    )
+    return (listed, *sums)
+
+
+@pytest.mark.parametrize(
+    "live", [0, 1, ROWS - 1, ROWS, ROWS + 1, N_SLOTS],
+    ids=["none", "one", "tile-less-one", "tile", "tile-and-one", "all"],
+)
+def test_the_live_rows_are_listed_as_the_whole_batch_lists_them(live):
+    """EQUAL, not close, on every row: a live row's kept keys in order at
+    the pool rows they lie at, nothing named for an idle one, with rows
+    that see fewer keys than are kept and ties at the cut."""
+    rng = np.random.default_rng(40 + live)
+    scores, lengths, table = _decode_scores(rng, live)
+    (want_address, want_named), want_scored, want_selected, _ = (
+        _whole_batch_selection(
+            scores, lengths, TOP_K, block_table=table, block_size=BS
+        )
+    )
+    (address, named), scored, selected, visited = att.select_live_rows(
+        scores, lengths, TOP_K, block_table=table, block_size=BS
+    )
+    np.testing.assert_array_equal(named, want_named)
+    np.testing.assert_array_equal(address, want_address)
+    idle = np.asarray(lengths) == 0
+    assert not np.asarray(named)[idle].any() and not np.asarray(address)[idle].any()
+    assert np.asarray(named)[~idle].sum(axis=-1).tolist() == [
+        min(int(n), TOP_K) for n in np.asarray(lengths)[~idle]
+    ]
+    assert int(scored) == int(want_scored) == int(np.asarray(lengths).sum())
+    assert int(selected) == int(want_selected)
+    assert int(visited) == -(-live // ROWS) * ROWS
+    if live > 1:  # the cut of the row that sees the whole table is a tie
+        row = np.sort(np.asarray(scores)[np.asarray(lengths).argmax(), 0])[::-1]
+        assert row[TOP_K - 1] == row[TOP_K]
+
+
+def _random_decode_step(toy, rng, live, n_slots):
+    """The operands of one decode step over pools of random rows: ``live``
+    of ``n_slots`` rows decode at random positions, scattered."""
+    m = 16
+    pools = jax.tree.map(
+        lambda p: jnp.asarray(rng.standard_normal(p.shape), p.dtype),
+        toy.model.init_pools(toy.params, {"global": n_slots * m + 1}, BS),
+    )
+    table = 1 + rng.permutation(n_slots * m).reshape(n_slots, m)
+    mask = np.zeros(n_slots, bool)
+    mask[rng.permutation(n_slots)[:live]] = True
+    pos = np.where(mask, rng.integers(0, m * BS, n_slots), 0)
+    return (
+        pools, {"global": jnp.asarray(table, jnp.int32)},
+        jnp.asarray(_tokens(rng, n_slots)), jnp.asarray(pos, jnp.int32),
+    ), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("live", [1, ROWS + 1], ids=["one", "tile-and-one"])
+def test_a_decode_step_is_the_step_that_selected_over_every_slot(
+    toy, live, monkeypatch
+):
+    """The tower's step with the selection tiled over its live rows gives
+    the logits, the pools and the sums of the step that selected and listed
+    over the whole batch, to the bit."""
+    args, mask = _random_decode_step(toy, np.random.default_rng(50 + live), live, ROWS + 3)
+
+    def step():
+        # a new function: a new trace, whatever is patched
+        return jax.jit(
+            lambda *a, **kw: toy.model.decode_step(*a, **kw),
+            static_argnames=("block_size",),
+        )(toy.params, *args, block_size=BS, write_mask=mask)
+
+    pools, logits, load = step()
+    monkeypatch.setattr(att, "select_live_rows", _whole_batch_selection)
+    want_pools, want_logits, want_load = step()
+    np.testing.assert_array_equal(logits, want_logits)
+    jax.tree.map(np.testing.assert_array_equal, pools, want_pools)
+    assert int(load.pop("sparse_rows")) == -(-live // ROWS) * ROWS
+    assert int(want_load.pop("sparse_rows")) == ROWS + 3
+    jax.tree.map(np.testing.assert_array_equal, load, want_load)
+
+
 # -- the tower against the reference -----------------------------------------
 
 
@@ -332,6 +446,72 @@ def test_the_engine_serves_the_reference_greedy_and_fetches_the_kept_rows(toy):
     assert 0 < selected < scored
     # a step a layer fetches the kept rows: at most live rows x top_k
     assert rows == selected and rows <= steps * 2 * TOP_K
+
+
+def _deltas(*names_and_labels):
+    """A function that reads how far the named counters moved since."""
+    def read():
+        return [_counter(name, **labels) for name, labels in names_and_labels]
+    before = read()
+    return lambda: [now - then for now, then in zip(read(), before)]
+
+
+def test_batches_of_one_nine_and_every_slot_share_one_decode_program(toy):
+    """The selection's loop follows the live rows INSIDE the compiled
+    decode chunk: 1, 9 and all 12 slots decoding run the one program, each
+    answer the reference's greedy."""
+    from znicz_tpu.services import engine as engine_module
+
+    rng = np.random.default_rng(60)
+    # a tick prefills every waiting prompt, so a batch decodes together
+    eng = toy.engine(batch_size=12, prefix_cache=False, prefill_budget=512)
+    compiled = []
+    for batch in (1, 9, 12):
+        moved = _deltas(
+            ("znicz_serve_sparse_rows_selected_total", dict(phase="decode")),
+            ("znicz_serve_decode_steps_total", {}),
+        )
+        ids = [
+            # 9 to 16 blocks a row, so every batch decodes at the rung of
+            # 16, and answers long enough for the batch to decode together
+            eng.submit(_tokens(rng, int(rng.integers(33, 37))), int(rng.integers(24, 29)))
+            for _ in range(batch)
+        ]
+        eng.run()
+        for rid in ids:
+            assert toy.served_gaps(eng.completions[rid]).max() < 1e-4
+        rows, steps = moved()
+        # whole tiles of the rows that decoded: one tile while at most 8
+        # did, two while more
+        assert ROWS * steps <= rows <= -(-batch // ROWS) * ROWS * steps
+        assert (rows > ROWS * steps) == (batch > ROWS)
+        compiled.append((
+            _counter("znicz_serve_compiles_total", kind="decode"),
+            engine_module._paged_decode_chunk._cache_size(),
+        ))
+    assert compiled[0] == compiled[1] == compiled[2]
+    programs = eng.compile_stats()["programs"]
+    assert [key[:2] for key in programs if key[0] == "paged_chunk"] == [
+        ("paged_chunk", 4)
+    ]
+
+
+def test_the_rows_the_selection_visits_are_counted_by_phase(toy):
+    """``znicz_serve_sparse_rows_selected_total{phase}``: a prefill chunk
+    counts its one row, a decode step of one request one tile of rows (a
+    layer's, as the two counters of keys are)."""
+    eng = toy.engine(batch_size=12, prefix_cache=False)
+    moved = _deltas(
+        ("znicz_serve_sparse_rows_selected_total", dict(phase="prefill")),
+        ("znicz_serve_prefill_chunks_total", {}),
+        ("znicz_serve_sparse_rows_selected_total", dict(phase="decode")),
+        ("znicz_serve_decode_steps_total", {}),
+    )
+    eng.submit(_tokens(np.random.default_rng(61), 21), 9)
+    eng.run()
+    prefill_rows, chunks, decode_rows, steps = moved()
+    assert prefill_rows == chunks == -(-21 // BS)
+    assert steps > 0 and decode_rows == ROWS * steps
 
 
 def test_a_request_after_a_prefix_hit_gets_the_logits_of_the_cold_request(toy):

@@ -268,6 +268,59 @@ def test_selection_is_exact_and_keeps_every_key_of_a_short_row():
         assert set(np.flatnonzero(keep[1, t])) == set(np.asarray(idx[t]).tolist())
 
 
+ROWS = att.SELECT_TILE_ROWS
+N_SLOTS = 2 * ROWS + 3  # whole tiles and a part of one
+
+
+def _whole_batch_selection(scores, lengths, top_k):
+    """``select_live_rows`` as it was before PR 45: every row of the batch,
+    live or idle, through ``select_top_keys``."""
+    return att._select_every_row(scores, top_k)
+
+
+@pytest.mark.parametrize(
+    "live", [0, 1, ROWS - 1, ROWS, ROWS + 1, N_SLOTS],
+    ids=["none", "one", "tile-less-one", "tile", "tile-and-one", "all"],
+)
+def test_the_live_rows_keep_what_the_whole_batch_keeps(live):
+    """A decode step's mask, chosen a tile of its live rows at a time, is
+    EQUAL to the selection over every slot: live rows scattered among idle
+    ones, a row that sees fewer keys than are kept, ties at the cut;
+    nothing kept for an idle row."""
+    rng = np.random.default_rng(70 + live)
+    n_keys = 40
+    lengths = np.zeros(N_SLOTS, np.int32)
+    where = rng.permutation(N_SLOTS)[:live]
+    lengths[where] = rng.integers(TOP_K, n_keys + 1, live)
+    lengths[where[:1]] = TOP_K - 5
+    lengths[where[1:2]] = n_keys
+    values = rng.integers(0, 4, (N_SLOTS, 1, n_keys)).astype(np.float32)
+    scores = jnp.asarray(np.where(
+        np.arange(n_keys) < lengths[:, None, None], values, -np.inf
+    ))
+    want, want_scored, want_selected, _ = _whole_batch_selection(
+        scores, jnp.asarray(lengths), TOP_K
+    )
+    keep, scored, selected, visited = att.select_live_rows(
+        scores, jnp.asarray(lengths), TOP_K
+    )
+    assert keep.shape == want.shape and keep.dtype == want.dtype
+    np.testing.assert_array_equal(keep, want)
+    assert not np.asarray(keep)[lengths == 0].any()
+    assert np.asarray(keep)[:, 0].sum(axis=-1).tolist() == np.minimum(lengths, TOP_K).tolist()
+    assert int(scored) == int(want_scored) == lengths.sum()
+    assert int(selected) == int(want_selected)
+    assert int(visited) == -(-live // ROWS) * ROWS
+    if live > 1:  # the cut of the row that sees every key is a tie
+        row = np.sort(values[lengths.argmax(), 0])[::-1]
+        assert row[TOP_K - 1] == row[TOP_K]
+
+
+def test_the_selection_of_live_rows_is_a_decode_steps():
+    with pytest.raises(ValueError, match="decode step"):
+        att.select_live_rows(jnp.zeros((2, 3, 8)), jnp.ones(2, jnp.int32), 4)
+
+
 def _latent_case(rng, lengths, sizes, *, width, ring=None, n_blocks=48):
     """Latent rows ``[c, k_r, zeros]`` of ``lengths`` tokens in a pool of
     ``width`` lanes, through plain tables or rings of ``ring`` entries."""
@@ -555,6 +608,51 @@ def test_a_decode_step_by_the_kernel_agrees_with_the_gathered_form(
     assert int(load_k["cached_rows_by_kind"]["window"]) == 3 * BS
     assert int(load_k["cached_rows_by_kind"]["global"]) == 8 * BS
     assert int(load_k["sparse_selected"]) == int(load_g["sparse_selected"]) == TOP_K
+
+
+@pytest.mark.parametrize("live", [1, ROWS + 1], ids=["one", "tile-and-one"])
+def test_a_decode_step_is_the_step_that_selected_over_every_slot(
+    toy, live, monkeypatch
+):
+    """The tower's step with the selection tiled over its live rows gives
+    the logits, the pools and the sums of the step that selected over the
+    whole batch, to the bit (pools of random rows, the live rows scattered
+    at random positions)."""
+    rng = np.random.default_rng(80 + live)
+    n_slots, m, ring = ROWS + 3, 16, 5
+    blocks = n_slots * m + 1
+    pools = jax.tree.map(
+        lambda p: jnp.asarray(rng.standard_normal(p.shape), p.dtype),
+        toy.model.init_pools(toy.params, {"global": blocks, "window": blocks}, BS),
+    )
+    table = 1 + rng.permutation(n_slots * m).reshape(n_slots, m)
+    tables = {
+        "global": jnp.asarray(table, jnp.int32),
+        "window": jnp.asarray(table[:, :ring], jnp.int32),
+    }
+    mask = np.zeros(n_slots, bool)
+    mask[rng.permutation(n_slots)[:live]] = True
+    pos = jnp.asarray(np.where(mask, rng.integers(0, m * BS, n_slots), 0), jnp.int32)
+    tokens = jnp.asarray(_tokens(rng, n_slots))
+
+    def step():
+        # a new function: a new trace, whatever is patched
+        return jax.jit(
+            lambda *a, **kw: toy.model.decode_step(*a, **kw),
+            static_argnames=("block_size",),
+        )(
+            toy.params, pools, tables, tokens, pos, block_size=BS,
+            write_mask=jnp.asarray(mask),
+        )
+
+    got_pools, logits, load = step()
+    monkeypatch.setattr(att, "select_live_rows", _whole_batch_selection)
+    want_pools, want_logits, want_load = step()
+    np.testing.assert_array_equal(logits, want_logits)
+    jax.tree.map(np.testing.assert_array_equal, got_pools, want_pools)
+    assert int(load.pop("sparse_rows")) == -(-live // ROWS) * ROWS
+    assert int(want_load.pop("sparse_rows")) == n_slots
+    jax.tree.map(np.testing.assert_array_equal, load, want_load)
 
 
 # -- through the engine ----------------------------------------------------

@@ -10,6 +10,7 @@ Layouts: ``q/k/v`` are ``[batch, seq, heads, head_dim]`` (BTHD).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -575,6 +576,9 @@ def _unfolded(o_latent, wv_b, lengths):
 
 # table entries whose indexer keys are gathered and scored at a time
 INDEX_CHUNK_BLOCKS = 16
+# rows of a decode step whose keys are chosen and listed at a time (a
+# float32 sublane tile): :func:`select_live_rows`
+SELECT_TILE_ROWS = 8
 
 
 def _table_chunks(block_table, last, block_size):
@@ -663,7 +667,14 @@ def select_top_keys(scores: jnp.ndarray, top_k: int) -> jnp.ndarray:
     of ``score >= candidate`` over the row), which at 128 x 33,792 scores
     costs a twelfth of ``jax.lax.top_k``'s sort on the v5e (PERF.md
     section 6, PR 36), and the result is a mask over the keys as they lie
-    in the pool, which is what the attention over them reads."""
+    in the pool, which is what the attention over them reads.
+
+    EVERY row of ``scores`` is visited, whatever it holds: a prefill chunk
+    (one row, ``Tq`` queries) calls this as it is; a decode step, most of
+    whose rows idle, calls it a tile of its live rows at a time
+    (:func:`select_live_rows`).  A row's mask depends on that row's scores
+    alone and every step is an integer comparison or an integer sum, so
+    the rows a call is given, and how many, cannot move a result."""
     with jax.named_scope("dsa_select"):
         bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
         order = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
@@ -809,7 +820,12 @@ def kept_key_slots(keep: jnp.ndarray, top_k: int, *, block_size: int):
     running count with its own number (``top_k x M`` comparisons a row, a
     fused reduction), reads that block's mask by a product with 0 / 1 and
     finds its key inside it from the mask's running sum (a product with a
-    triangle; counts to 128 are exact in bfloat16)."""
+    triangle; counts to 128 are exact in bfloat16).
+
+    Every row of ``keep`` is visited (``top_k x M`` comparisons and two
+    products each, idle or not), and a row's list depends on that row's
+    mask alone: a decode step hands over a tile of its live rows at a time
+    (:func:`select_live_rows`) and reads what the whole batch would."""
     b, n_keys = keep.shape
     m = n_keys // block_size
     by_block = keep.reshape(b, m, block_size)
@@ -843,6 +859,122 @@ def kept_key_slots(keep: jnp.ndarray, top_k: int, *, block_size: int):
     return entry, jnp.where(named, offset, 0), named
 
 
+def kept_row_addresses(
+    keep: jnp.ndarray, block_table: jnp.ndarray, top_k: int, *,
+    block_size: int,
+):
+    """Where the keys ``keep`` [B, M * block_size] names lie in the pool:
+    ``(address [B, top_k] int32, named [B, top_k] bool)``, slot ``j`` the
+    row's ``j``-th kept key at pool row ``block * block_size + offset``
+    (:func:`kept_key_slots` through ``block_table`` [B, M]); a slot that is
+    not ``named`` holds address 0, the first row of ``NULL_BLOCK``.  The
+    block of a slot's table entry is a sum under 0 / 1 (a fused reduction;
+    nothing scalar is gathered)."""
+    entry, offset, named = kept_key_slots(keep, top_k, block_size=block_size)
+    blk = jnp.sum(
+        jnp.where(
+            entry[..., None] == jnp.arange(block_table.shape[1]),
+            block_table[:, None, :], 0,
+        ),
+        axis=-1, dtype=jnp.int32,
+    )
+    return jnp.where(named, blk * block_size + offset, 0), named
+
+
+def select_live_rows(
+    scores: jnp.ndarray,  # [B, 1, M * block_size] float32, -inf: not seen
+    lengths: jnp.ndarray,  # [B] int32; 0: the row idles
+    top_k: int,
+    *,
+    block_table: Optional[jnp.ndarray] = None,  # [B, M]: list the kept rows
+    block_size: Optional[int] = None,
+):
+    """A DECODE step's selection, over the rows that decode and no other:
+    :func:`select_top_keys` (and, with ``block_table``, :func:`kept_row
+    _addresses`) a TILE of ``SELECT_TILE_ROWS`` live rows at a time, in a
+    loop inside the one compiled program whose trip count follows the live
+    rows (``lengths > 0``, live rows first in slot order): at 22 of 64
+    slots live three tiles run, not the batch's eight.
+
+    Returns ``(kept, scored, selected, visited)``: ``kept`` is the mask
+    ``keep`` [B, 1, keys] (rows never visited all False) or, with
+    ``block_table``, the listing ``(address [B, top_k], named [B, top_k])``
+    (rows never visited name nothing; the ``[B, keys]`` mask then exists a
+    tile at a time and never in HBM); ``scored`` / ``selected``, the keys
+    seen and kept, int32 sums over the rows; ``visited``, the rows the loop
+    went over, ``tiles x SELECT_TILE_ROWS``.
+
+    IDENTICAL to the two functions over the whole batch: a row's result
+    depends on that row's scores alone, by integer compares and sums, so
+    a live row reads what it would in any company; an idle row's scores
+    are all ``-inf`` (:func:`paged_index_scores` masks by ``lengths``), so
+    the whole batch gives it nothing kept and nothing named, which is what
+    an unvisited row starts as, and what the idle rows that fill the last
+    tile come out as.  The counts and ties run under scope ``dsa_select``,
+    the listing under ``gqa_sparse``, as they do over the whole batch."""
+    b, tq, _ = scores.shape
+    if tq != 1:
+        raise ValueError(f"lengths are a decode step's; got {tq} queries a row")
+    return _select_tiles(
+        scores, lengths, block_table, top_k=top_k, block_size=block_size,
+        r=min(SELECT_TILE_ROWS, b),
+    )
+
+
+# jitted for the reason kept_rows_attention._attend is: a tower's layers
+# share one trace and one lowering of the loop (and a step run operation by
+# operation compiles it once, not anew for every call's closures)
+@partial(jax.jit, static_argnames=("top_k", "block_size", "r"))
+def _select_tiles(scores, lengths, block_table, *, top_k, block_size, r):
+    """:func:`select_live_rows` with tiles of ``r`` rows."""
+    b, _, n_keys = scores.shape
+    live = lengths > 0
+    n_tiles = -(-jnp.sum(live, dtype=jnp.int32) // r)
+    # the batch's rows, the live ones first; past the batch: no row
+    order = jnp.pad(
+        jnp.argsort(~live, stable=True).astype(jnp.int32), (0, -b % r),
+        constant_values=b,
+    )
+
+    if block_table is None:
+        start = jnp.zeros((b, n_keys), bool)
+
+        def record(kept, rows, keep):
+            with jax.named_scope("dsa_select"):
+                return kept.at[rows].set(keep, mode="drop"), keep
+    else:
+        start = (jnp.zeros((b, top_k), jnp.int32), jnp.zeros((b, top_k), bool))
+
+        def record(kept, rows, keep):
+            with jax.named_scope("gqa_sparse"):
+                address, named = kept_row_addresses(
+                    keep, block_table[rows], top_k, block_size=block_size
+                )
+                return (
+                    kept[0].at[rows].set(address, mode="drop"),
+                    kept[1].at[rows].set(named, mode="drop"),
+                ), named
+
+    def tile(i, carry):
+        kept, scored, selected = carry
+        with jax.named_scope("dsa_select"):
+            rows = jax.lax.dynamic_slice_in_dim(order, i * r, r)
+            # a row past the batch reads the last one's: nothing seen
+            s = jnp.where((rows < b)[:, None], scores[rows, 0], -jnp.inf)
+        kept, chosen = record(kept, rows, select_top_keys(s[:, None], top_k)[:, 0])
+        return (
+            kept, scored + jnp.sum(s > -jnp.inf, dtype=jnp.int32),
+            selected + jnp.sum(chosen, dtype=jnp.int32),
+        )
+
+    kept, scored, selected = jax.lax.fori_loop(
+        0, n_tiles, tile, (start, jnp.int32(0), jnp.int32(0))
+    )
+    if block_table is None:
+        kept = kept[:, None]
+    return kept, scored, selected, n_tiles * r
+
+
 def kept_rows_fetched(lengths: jnp.ndarray, top_k: int) -> jnp.ndarray:
     """Cached rows ONE layer's :func:`kept_gqa_attention` fetches in a
     decode step (int32 scalar), :func:`paged_gqa_rows_read`'s twin: a live
@@ -856,32 +988,37 @@ def kept_gqa_attention(
     pool: jnp.ndarray,  # [N_blocks, block_size, 2 * G * D]: [v, k] a token
     block_table: jnp.ndarray,  # [B, M] int32 pool block ids
     q_pos: jnp.ndarray,  # [B, Tq] int32 absolute query positions
-    keep: jnp.ndarray,  # [B, Tq, M * block_size] bool: the keys attended
+    keep: Optional[jnp.ndarray],  # [B, Tq, M * block_size] bool: the keys attended
     *,
     block_size: int,
     n_kv_heads: int,
     top_k: int,  # the most keys ``keep`` names a query
     scale: float,
     lengths: Optional[jnp.ndarray] = None,  # [B] int32; 0: the row idles
+    listed=None,  # a decode step's (address, named) [B, top_k], for ``keep``
 ) -> jnp.ndarray:
     """Grouped-query attention of every query over the keys ``keep`` names
     for it, over ``[v, k]`` rows (:func:`gqa_cache_row`); [B, Tq, H * D]
     float32.  A query that keeps no key gives zeros.
 
     A DECODE step (``lengths`` given, ``Tq`` 1) FETCHES THE KEPT ROWS AND
-    NOTHING ELSE: the mask becomes each row's list of (table entry,
-    offset) pairs (:func:`kept_key_slots`) and the queries meet the rows
-    they name as stored (:func:`paged_gqa_attention`'s layout: one product
-    for all heads, the zeros add exactly), whatever the row's length (a
-    walk of every block under the mask, :func:`kept_latent_attention`'s
-    decode form, reads the whole row, 32 x the kept keys at 66k).  On the
-    TPU, over a pool it can read (:func:`_reads_kept_rows_in_place`), that
-    is :func:`~znicz_tpu.ops.pallas.kept_rows_attention
-    .kept_rows_decode_attention`: the LIVE rows' kept keys by DMA from the
-    pool where it lies, attended in VMEM.  Elsewhere the rows are gathered
-    ([B, top_k, W], every slot's, written out and read back by the two
-    products), which is also the kernel's twin.  Which of the two a decode
-    program was built with is counted once a layer,
+    NOTHING ELSE: the mask becomes each row's list of pool rows
+    (:func:`kept_row_addresses`) and the queries meet the rows they name
+    as stored (:func:`paged_gqa_attention`'s layout: one product for all
+    heads, the zeros add exactly), whatever the row's length (a walk of
+    every block under the mask, :func:`kept_latent_attention`'s decode
+    form, reads the whole row, 32 x the kept keys at 66k).  Given ``keep``
+    the list is made here for EVERY row of the batch, idle or not; the
+    serving path hands over ``listed`` instead, which :func:`select_live
+    _rows` made for the live rows alone (the same list: an idle row's mask
+    names nothing either way).  On the TPU, over a pool it can read
+    (:func:`_reads_kept_rows_in_place`), the attention is :func:`~znicz_tpu
+    .ops.pallas.kept_rows_attention.kept_rows_decode_attention`: the LIVE
+    rows' kept keys by DMA from the pool where it lies, attended in VMEM.
+    Elsewhere the rows are gathered ([B, top_k, W], one fetch a slot of
+    the list, named or not, idle rows' too, written out and read back by
+    the two products), which is also the kernel's twin.  Which of the two
+    a decode program was built with is counted once a layer,
     ``znicz_serve_kept_rows_attention_total{form}``.  A PREFILL chunk
     walks the table under the mask (:func:`_kept_rows_walk`, shared with
     the latent rows) with the products GROUPED a K/V head: at ``Tq * H`` =
@@ -902,17 +1039,8 @@ def kept_gqa_attention(
                 raise ValueError(
                     f"lengths are a decode step's; got {tq} queries a row"
                 )
-            entry, offset, named = kept_key_slots(
-                keep[:, 0], top_k, block_size=block_size
-            )
-            # the pool's block of each slot's table entry, by a sum under
-            # 0 / 1 (a fused reduction; nothing scalar is gathered)
-            blk = jnp.sum(
-                jnp.where(
-                    entry[..., None] == jnp.arange(block_table.shape[1]),
-                    block_table[:, None, :], 0,
-                ),
-                axis=-1, dtype=jnp.int32,
+            address, named = listed or kept_row_addresses(
+                keep[:, 0], block_table, top_k, block_size=block_size
             )
             q_row = _gqa_query_rows(q, g, dtype)
             in_place = _reads_kept_rows_in_place(tq, pool)
@@ -922,13 +1050,13 @@ def kept_gqa_attention(
             if in_place:
                 # the slots are named in order: the first so many of a row
                 o = kept_rows_attention.kept_rows_decode_attention(
-                    q_row, pool, blk * block_size + offset,
+                    q_row, pool, address,
                     jnp.sum(named, axis=-1, dtype=jnp.int32), scale=scale,
                     d_out=min(-(-half // 128) * 128, 2 * half),
                 )
                 o = _gqa_own_values(o[:, None], g, d)
                 return jnp.where(lengths[:, None, None] > 0, o, 0.0)
-            rows = pool[jnp.where(named, blk, 0), offset]  # [B, top_k, W]
+            rows = pool.reshape(-1, 2 * half)[address]  # [B, top_k, W]
             s = jnp.einsum("bre,bke->brk", q_row, rows, **f32) * scale
             named = named[:, None, :]
             s = jnp.where(named, s, _NEG)
@@ -984,22 +1112,41 @@ def paged_selected_gqa_attention(
     """Grouped-query attention that keeps ``top_k`` keys a query, chosen
     by a learned indexer, over a paged pool of ``[v, k]`` rows and, block
     for block beside it, a pool of the indexer's keys: :func:`paged_index
-    _scores`, :func:`select_top_keys` (both :func:`paged_selected_latent
-    _attention`'s), :func:`kept_gqa_attention`.  Returns ``(o [B, Tq, H *
-    D] float32, scored, selected)`` with that function's contract."""
+    _scores`, the selection (both :func:`paged_selected_latent
+    _attention`'s), :func:`kept_gqa_attention`.  A prefill chunk selects
+    with :func:`select_top_keys` over its one row; a decode step
+    (``lengths``) chooses AND lists the kept keys of its live rows alone,
+    a tile at a time (:func:`select_live_rows`: the result is the whole
+    batch's).  Returns ``(o [B, Tq, H * D] float32, scored, selected,
+    visited)`` with that function's contract."""
     scores = paged_index_scores(
         q_idx, w_idx, idx_pool, block_table, q_pos, block_size=block_size,
         lengths=lengths,
     )
-    keep = select_top_keys(scores, top_k)
+    keep = listed = None
+    if lengths is None:
+        keep, scored, selected, visited = _select_every_row(scores, top_k)
+    else:
+        listed, scored, selected, visited = select_live_rows(
+            scores, lengths, top_k, block_table=block_table,
+            block_size=block_size,
+        )
     o = kept_gqa_attention(
         q, pool, block_table, q_pos, keep, block_size=block_size,
-        n_kv_heads=n_kv_heads, top_k=top_k, lengths=lengths,
+        n_kv_heads=n_kv_heads, top_k=top_k, lengths=lengths, listed=listed,
         scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
     )
+    return o, scored, selected, visited
+
+
+def _select_every_row(scores, top_k):
+    """:func:`select_top_keys` over all of ``scores`` [B, Tq, keys] with
+    :func:`select_live_rows`'s sums: ``(keep, scored, selected, visited =
+    B)``."""
+    keep = select_top_keys(scores, top_k)
     return (
-        o, jnp.sum(scores > -jnp.inf, dtype=jnp.int32),
-        jnp.sum(keep, dtype=jnp.int32),
+        keep, jnp.sum(scores > -jnp.inf, dtype=jnp.int32),
+        jnp.sum(keep, dtype=jnp.int32), jnp.int32(scores.shape[0]),
     )
 
 
@@ -1011,26 +1158,31 @@ def paged_selected_latent_attention(
     """Latent attention that keeps ``top_k`` keys a query, chosen by a
     learned indexer, over a paged pool of latent rows ``[c, rot(k_r),
     zeros]`` and, block for block beside it, a pool of the indexer's keys:
-    :func:`paged_index_scores` of every cached token,
-    :func:`select_top_keys`, :func:`kept_latent_attention`.  The same
-    three steps serve a prefill chunk (``Tq`` queries of one row) and a
-    decode step (``Tq`` 1, ``lengths``: 0 marks a row that idles, whose
-    result is zeros).  Returns ``(o [B, Tq, H * d_v] float32, scored,
-    selected)``: the keys the indexer scored and the keys attention kept,
-    int32 sums over the call's queries."""
+    :func:`paged_index_scores` of every cached token, the selection,
+    :func:`kept_latent_attention`.  The same three steps serve a prefill
+    chunk (``Tq`` queries of one row: :func:`select_top_keys` over it) and
+    a decode step (``Tq`` 1, ``lengths``: 0 marks a row that idles, whose
+    result is zeros; :func:`select_live_rows` visits the live rows a tile
+    at a time and leaves an idle row's mask False, which is what the
+    selection over the whole batch gives it).  Returns ``(o [B, Tq, H *
+    d_v] float32, scored, selected, visited)``: the keys the indexer
+    scored and the keys attention kept, int32 sums over the call's
+    queries, and the rows the selection went over."""
     scores = paged_index_scores(
         q_idx, w_idx, idx_pool, block_table, q_pos, block_size=block_size,
         lengths=lengths,
     )
-    keep = select_top_keys(scores, top_k)
+    if lengths is None:
+        keep, scored, selected, visited = _select_every_row(scores, top_k)
+    else:
+        keep, scored, selected, visited = select_live_rows(
+            scores, lengths, top_k
+        )
     o = kept_latent_attention(
         q_nope, q_rope, pool, block_table, q_pos, keep, wk_b, wv_b,
         block_size=block_size, scale=scale, lengths=lengths,
     )
-    return (
-        o, jnp.sum(scores > -jnp.inf, dtype=jnp.int32),
-        jnp.sum(keep, dtype=jnp.int32),
-    )
+    return o, scored, selected, visited
 
 
 def paged_window_latent_attention(

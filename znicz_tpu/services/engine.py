@@ -65,6 +65,15 @@ ahead of its program, a prefill span the dispatch alone.
 ``znicz_serve_prefill_chunks_between_decodes`` say what stands between
 a decode step and the gap a client sees.
 
+A tower that selects the keys it attends reports, with each call's
+load sums, what ONE selecting layer did over the call's queries:
+``znicz_serve_sparse_keys_scored_total{phase}`` (keys the indexer
+scored), ``znicz_serve_sparse_keys_selected_total{phase}`` (keys
+attention kept) and ``znicz_serve_sparse_rows_selected_total{phase}``
+(rows the selection went over: a prefill chunk's one row; a decode step's
+live rows in whole tiles, so over decode steps x slots it is the share of
+the batch the selection still visits).
+
 A program call crosses the host-device link once each way: beside a
 block table a kind it sends ONE packed int32 array (built fresh for the
 call) and what it returns is read in ONE ``jax.device_get``; the rng key
@@ -1232,6 +1241,14 @@ class PagedDecodeEngine:
             "selection, summed over the queries of the phase's calls",
             ("phase",),
         )
+        self._m_sparse_rows = observability.counter(
+            "znicz_serve_sparse_rows_selected_total",
+            "rows ONE selecting layer's selection went over, summed over "
+            "the phase's calls: a prefill chunk its one row; a decode step "
+            "its live rows in whole tiles (over decode steps x slots: the "
+            "share of the batch the selection still visits)",
+            ("phase",),
+        )
         self._update_pool_gauges()
 
     def _upload(self, program: str, host: np.ndarray) -> jax.Array:
@@ -1268,6 +1285,9 @@ class PagedDecodeEngine:
                 )
                 self._m_sparse_selected.labels(phase=phase).inc(
                     int(load["sparse_selected"])
+                )
+                self._m_sparse_rows.labels(phase=phase).inc(
+                    int(load["sparse_rows"])
                 )
             if "pairs" not in load:
                 continue

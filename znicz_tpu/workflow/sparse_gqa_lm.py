@@ -63,7 +63,7 @@ from znicz_tpu.workflow.latent_lm import (
     _expert_load,
     _head_logits,
 )
-from znicz_tpu.workflow.sparse_latent_lm import _tiles
+from znicz_tpu.workflow.sparse_latent_lm import _selection_load, _tiles
 
 GLOBAL = "global"
 
@@ -208,7 +208,7 @@ class SparseGQAMoEModel:
         w_idx = _dot(u, block["w_idx"]) * (
             self.index_n_heads ** -0.5 * self.index_head_dim ** -0.5
         )
-        o, scored, selected = paged_selected_gqa_attention(
+        o, *selection = paged_selected_gqa_attention(
             q, padded(q_idx), w_idx, kv, idx, table, q_pos,
             block_size=block_size, n_kv_heads=self.n_kv_heads,
             top_k=self.index_topk, lengths=lengths,
@@ -227,28 +227,26 @@ class SparseGQAMoEModel:
                 row_mask=None if row_mask is None else row_mask.reshape(-1),
             )
         pool = {"kv": kv, "idx": idx}
-        return x + y.reshape(b, tq, d), pool, pairs, scored, selected
+        return x + y.reshape(b, tq, d), pool, pairs, selection
 
     def _tower(self, params, x, pools, write, table, q_pos, row_mask, *,
                block_size, lengths=None):
         """``(x, pools, load)``: ``load`` holds the expert-load sums and
-        ``sparse_scored`` / ``sparse_selected``, the keys ONE layer's
-        indexer scored and its attention read over the call's queries."""
-        new_pools, pairs_by_layer, scored, selected = [], [], [], []
+        ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: the keys
+        ONE layer's indexer scored and its attention read over the call's
+        queries, and the rows its selection went over (a prefill chunk's
+        one; a decode step's live rows in whole tiles, :func:`~znicz_tpu
+        .ops.attention.select_live_rows`)."""
+        new_pools, pairs_by_layer, sums = [], [], []
         for block, pool in zip(params[1:-1], pools):
-            x, pool, pairs, n_scored, n_selected = self._block_step(
+            x, pool, pairs, selection = self._block_step(
                 block, x, pool, write, table, q_pos, row_mask,
                 block_size=block_size, lengths=lengths,
             )
             new_pools.append(pool)
             pairs_by_layer.append(pairs)
-            scored.append(n_scored)
-            selected.append(n_selected)
-        load = dict(
-            _expert_load(pairs_by_layer),
-            sparse_scored=sum(scored) // len(scored),
-            sparse_selected=sum(selected) // len(selected),
-        )
+            sums.append(selection)
+        load = dict(_expert_load(pairs_by_layer), **_selection_load(sums))
         return x, new_pools, load
 
     def prefill_chunk(

@@ -244,7 +244,7 @@ class SparseLatentMoEModel:
             axis=-1,
         )
         new_pool = {"kv": write(pool["kv"], row.astype(pool["kv"].dtype))}
-        scored = selected = None
+        selection = None
         if full:
             turned = sizes.qk_rope_head_dim  # leading values of q_I, k_I
 
@@ -265,7 +265,7 @@ class SparseLatentMoEModel:
             w_idx = _dot(u, block["w_idx"]) * (
                 self.index_n_heads ** -0.5 * self.index_head_dim ** -0.5
             )
-            o, scored, selected = paged_selected_latent_attention(
+            o, *selection = paged_selected_latent_attention(
                 q_nope, q_rope, q_idx, w_idx, new_pool["kv"], new_pool["idx"],
                 table, q_pos, block["wk_b"], block["wv_b"],
                 block_size=block_size, scale=sizes.softmax_scale,
@@ -279,7 +279,7 @@ class SparseLatentMoEModel:
             )
         gate = jax.nn.sigmoid(_dot(u, block["wg"]))  # [B, Tq, H], one a head
         o = (o.reshape(b, tq, sizes.n_heads, -1) * gate[..., None]).reshape(b, tq, -1)
-        return _dot(o, block["wo"]), new_pool, scored, selected
+        return _dot(o, block["wo"]), new_pool, selection
 
     def _feed_forward(self, block, h, row_mask):
         """The feed-forward of one layer on normalised rows ``h`` [T, D]:
@@ -305,22 +305,24 @@ class SparseLatentMoEModel:
     def _tower(self, params, x, pools, writes, tables, q_pos, row_mask, *,
                block_size, lengths=None):
         """``(x, pools, load)``: ``load`` holds the expert-load sums and
-        ``sparse_scored`` / ``sparse_selected``, the keys ONE full layer's
-        indexer scored and its attention read over the call's queries."""
+        ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: the keys
+        ONE full layer's indexer scored and its attention read over the
+        call's queries, and the rows its selection went over (a prefill
+        chunk's one; a decode step's live rows in whole tiles,
+        :func:`~znicz_tpu.ops.attention.select_live_rows`)."""
         b, tq, d = x.shape
-        new_pools, pairs_by_layer, scored, selected = [], [], [], []
+        new_pools, pairs_by_layer, sums = [], [], []
         for block, pool, kind in zip(params[1:-1], pools, self.layer_kinds):
             is_full = kind == GLOBAL
             u = rms_norm(x, block["attn_norm"], eps=self.rms_eps)
-            update, pool, n_scored, n_selected = self._attention(
+            update, pool, selection = self._attention(
                 block, is_full, u, pool, writes[kind], tables[kind], q_pos,
                 block_size=block_size, lengths=lengths,
             )
             x = x + update
             new_pools.append(pool)
             if is_full:
-                scored.append(n_scored)
-                selected.append(n_selected)
+                sums.append(selection)
             y, pairs = self._feed_forward(
                 block,
                 rms_norm(x, block["ffn_norm"], eps=self.rms_eps).reshape(b * tq, d),
@@ -329,10 +331,7 @@ class SparseLatentMoEModel:
             x = x + y.reshape(b, tq, d)
             if pairs is not None:
                 pairs_by_layer.append(pairs)
-        load = dict(_expert_load(pairs_by_layer) or {})
-        if scored:
-            load["sparse_scored"] = sum(scored) // len(scored)
-            load["sparse_selected"] = sum(selected) // len(selected)
+        load = dict(_expert_load(pairs_by_layer) or {}, **_selection_load(sums))
         return x, new_pools, load
 
     def prefill_chunk(
@@ -411,6 +410,20 @@ class SparseLatentMoEModel:
             cached_rows=sum(by_kind[k] for k in layers) // len(layers),
         )
         return pools, _head_logits(params, x[:, 0], self.rms_eps), load
+
+
+def _selection_load(per_layer) -> dict:
+    """The selecting layers' ``(scored, selected, visited)`` sums as the
+    load's ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: ONE
+    layer's, the mean over the layers that select (empty where none
+    does)."""
+    if not per_layer:
+        return {}
+    names = ("sparse_scored", "sparse_selected", "sparse_rows")
+    return {
+        name: sum(sums) // len(per_layer)
+        for name, sums in zip(names, zip(*per_layer))
+    }
 
 
 def init_params(
