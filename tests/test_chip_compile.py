@@ -32,7 +32,10 @@ from znicz_tpu.core import backend
 from znicz_tpu.ops import conv, kohonen as kh, normalization
 from znicz_tpu.ops.pallas import kohonen as pallas_kh, rbm as pallas_rbm
 from znicz_tpu.ops.pallas.attention import flash_attention
-from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
+from znicz_tpu.ops.pallas.latent_attention import (
+    latent_decode_attention,
+    shared_run_decode_attention,
+)
 
 
 @pytest.fixture(scope="module")
@@ -278,6 +281,46 @@ def test_gqa_decode_attention_compiles(chip, table_width, bounded):
         spec((64, table_width), jnp.int32), spec((64,), jnp.int32),
         spec((64,), jnp.int32),
     )
+
+
+@pytest.mark.parametrize(
+    "slots, heads, lanes, table_width, blocks, q_from",
+    [
+        (48, 48, 2048, 176, 1280, 0), (48, 48, 2048, 176, 1280, 1024),
+        (48, 48, 2048, 4, 1280, 0),
+        (64, 32, 1024, 128, 1600, 0), (64, 32, 1024, 128, 1600, 512),
+    ],
+    ids=[
+        "laguna", "laguna-key-lanes", "laguna-rung-4", "smallthinker",
+        "smallthinker-key-lanes",
+    ],
+)
+def test_shared_run_decode_attention_compiles(
+    chip, slots, heads, lanes, table_width, blocks, q_from
+):
+    # a global layer's decode step of the two grouped-query window towers
+    # as served (laguna-xs2-stage1: 48 slots, 48 query rows, [v, k] rows of
+    # 2,048 bfloat16 lanes, a table of 176, a pool of 1,280 blocks;
+    # smallthinker-21b-l8: 64 slots, 28 rows padded to 32, 1,024 lanes):
+    # the plan (scalar loops over the table and the lengths in SMEM), the
+    # shared pass (a tile's 8 x heads stacked query rows, its own VMEM
+    # limit) and the own pass that goes on from the shared pass's
+    # statistics, three kernels
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q_row, pool, tables, lengths):
+        return shared_run_decode_attention(
+            q_row, pool, tables, lengths, scale=0.0884, d_out=lanes // 2,
+            q_from=q_from,
+        )
+
+    text = _compile(
+        fn, spec((slots, heads, lanes), jnp.bfloat16),
+        spec((blocks, 128, lanes), jnp.bfloat16),
+        spec((slots, table_width), jnp.int32), spec((slots,), jnp.int32),
+    )
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
 
 
 @pytest.mark.parametrize("window", [1, 4, 96])

@@ -289,6 +289,90 @@ def test_the_engine_serves_the_reference_greedy_through_both_kinds(toy):
     assert not eng.prefix_cache  # a window kind: off unless asked for by name
 
 
+@pytest.fixture
+def shared_runs(monkeypatch):
+    """The TPU's decode path on the CPU (the kernels interpreted), with
+    chunks of 2 blocks and tiles of 2 rows; returns the tile's rows."""
+    from znicz_tpu.ops import attention as att
+    from znicz_tpu.ops.pallas import latent_attention as la
+
+    monkeypatch.setattr(att, "_reads_pool_in_place", lambda tq: tq == 1)
+    monkeypatch.setattr(la, "CHUNK_BLOCKS", 2)
+    monkeypatch.setattr(la, "TILE_ROWS", 2)
+    return 2
+
+
+def _rows_by_kind(name):
+    return {
+        kind: _counter(f"znicz_serve_decode_{name}_rows_total", kind=kind)
+        for kind in ("global", "window")
+    }
+
+
+def test_a_step_counts_a_shared_run_once_and_what_its_queries_met(toy, shared_runs):
+    """Three rows decode: two open with the same 8 global blocks, the third
+    with its own.  A global layer fetches the run once, the members' own
+    blocks and the third row whole; its queries met every row's length."""
+    model, params = toy.model, toy.params
+    pools = model.init_pools(params, {"global": 64, "window": 32}, BS)
+    pos = np.asarray([37, 45, 41, 0])
+    table = np.zeros((4, 16), np.int32)
+    table[0, :10] = list(range(1, 9)) + [20, 21]
+    table[1, :12] = list(range(1, 9)) + [22, 23, 24, 25]
+    table[2, :11] = range(30, 41)
+    ring = 1 + np.arange(4 * 6, dtype=np.int32).reshape(4, 6)
+    _, _, load = model.decode_step(
+        params, pools, {"global": jnp.asarray(table), "window": jnp.asarray(ring)},
+        jnp.asarray([3, 4, 5, 0]), jnp.asarray(pos), block_size=BS,
+        write_mask=jnp.asarray([True, True, True, False]),
+    )
+    in_blocks = [-(-(p + 1) // BS) * BS for p in pos[:3]]
+    assert int(load["attended_rows_by_kind"]["global"]) == sum(in_blocks)
+    run = 8 * BS  # four chunks of two blocks, all under the shorter length
+    assert int(load["cached_rows_by_kind"]["global"]) == (
+        run + (in_blocks[0] - run) + (in_blocks[1] - run) + in_blocks[2]
+    )
+    # a window layer's ring differs a row: read as it is attended
+    window = int(load["cached_rows_by_kind"]["window"])
+    assert window == int(load["attended_rows_by_kind"]["window"]) > 0
+    layers = model.layer_kinds
+    assert int(load["cached_rows"]) == sum(
+        int(load["cached_rows_by_kind"][k]) for k in layers
+    ) // len(layers)
+
+
+def test_a_served_prefix_is_read_once_a_tile_by_one_decode_program(shared_runs):
+    """1, R + 1 and every slot live behind a primed prefix: the answers are
+    the reference's, the global kind fetches less than its queries meet as
+    soon as two rows share, and the live rows never name a new program."""
+    toy = Toy(max_positions=131)  # a model of its own: its programs are traced here
+    rng = np.random.default_rng(47)
+    prefix = _tokens(rng, 32)  # 8 blocks: every decode chunk at the widest rung
+    eng = toy.engine(prefix_cache=True, batch_size=4, max_seq=64)
+    eng.submit(prefix, max_new_tokens=1)
+    eng.run()
+    for live in (1, shared_runs + 1, 4):
+        fetched, attended = _rows_by_kind("cached"), _rows_by_kind("attended")
+        ids = [
+            eng.submit(np.concatenate([prefix, _tokens(rng, 3 + i)]), max_new_tokens=7)
+            for i in range(live)
+        ]
+        eng.run()
+        for rid in ids:
+            done = eng.completions[rid]
+            assert done.timings["cached_tokens"] == 32
+            assert toy.served_gaps(done).max() < 1e-4
+        fetched = {k: v - fetched[k] for k, v in _rows_by_kind("cached").items()}
+        attended = {k: v - attended[k] for k, v in _rows_by_kind("attended").items()}
+        assert fetched["window"] == attended["window"] > 0
+        if live == 1:
+            assert fetched["global"] == attended["global"] > 0
+        else:
+            assert 0 < fetched["global"] < attended["global"]
+    decode_programs = [k for k in eng._programs if k[0] == "paged_chunk"]
+    assert len(decode_programs) == 1, decode_programs
+
+
 def test_from_config_reads_the_published_keys_and_refuses_what_is_not_built():
     path = os.path.join(REPO, "benchmarks", "configs", "laguna-xs2-stage1.json")
     with open(path) as f:

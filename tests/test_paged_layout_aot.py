@@ -455,8 +455,12 @@ def test_the_two_kinds_of_pool_are_stored_as_they_are_computed_on(
     # grouped products of every layer are the kernel, and in a decode step
     # every layer's attention is one too (no gathered window, no ring)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    # (a global layer's two: the pass over the blocks a tile of rows share
+    # and the pass over each row's own)
     kernels = text.count("tpu_custom_call")
-    assert kernels >= (4 if program == "decode_chunk" else 3) * a["layers"]
+    assert kernels >= (4 if program == "decode_chunk" else 3) * a["layers"] + (
+        2 if program == "decode_chunk" else 0
+    )
     if program == "decode_chunk":
         gathered = (a["slots"] * a["rung"] * row, ring)
         assert not [
@@ -898,5 +902,7 @@ def test_rows_of_2048_lanes_under_heads_by_kind_are_stored_as_they_are_computed_
     )
     # the three grouped products of the six routed layers are the kernel,
     # and in a decode step every layer's attention is one too
+    # (a global layer's two: the pass over the blocks a tile of rows share
+    # and the pass over each row's own)
     kernels = text.count("tpu_custom_call")
-    assert kernels >= 3 * 6 + (7 if program == "decode_chunk" else 0)
+    assert kernels >= 3 * 6 + (7 + 2 if program == "decode_chunk" else 0)

@@ -207,7 +207,9 @@ def test_a_decode_step_reads_the_pool_in_place_as_the_gathered_form(
 ):
     """On the TPU a decode step is the kernel that walks each row's table
     (a ring turned to start at the window's first block, with the window's
-    first key as a lower bound); here it runs interpreted."""
+    first key as a lower bound; a plain table through the form that reads
+    shared leading blocks once: tests/test_shared_run_attention.py); here
+    it runs interpreted."""
     from znicz_tpu.ops import attention as att
 
     lengths = [7, 16, 17, 39, 23]
@@ -227,14 +229,17 @@ def test_a_decode_step_reads_the_pool_in_place_as_the_gathered_form(
         table, live, block_size=BS, window=window
     )) == table.size * BS
     calls = []
-    kernel = att.latent_decode_attention
+    form = (
+        "shared_run_decode_attention" if window is None
+        else "latent_decode_attention"
+    )
+    kernel = getattr(att, form)
     monkeypatch.setattr(
-        att, "latent_decode_attention",
-        lambda *a, **kw: calls.append(kw) or kernel(*a, **kw),
+        att, form, lambda *a, **kw: calls.append(kw) or kernel(*a, **kw),
     )
     monkeypatch.setattr(att, "_reads_pool_in_place", lambda tq: tq == 1)
     in_place = attend()
-    assert len(calls) == 1 and (calls[0]["starts"] is None) == (window is None)
+    assert len(calls) == 1 and ("starts" in calls[0]) == (window is not None)
     np.testing.assert_allclose(in_place, gathered, **TOL)
     assert not in_place[4].any()
     for r, length in enumerate(lengths[:4]):
@@ -243,9 +248,8 @@ def test_a_decode_step_reads_the_pool_in_place_as_the_gathered_form(
         )
     # what it read: each live row's keys from the first block it attends
     want = {None: 8 + 16 + 20 + 40, WINDOW: 8 + 16 + 20 + 20}[window]
-    assert int(att.paged_gqa_rows_read(
-        table, live, block_size=BS, window=window
-    )) == want
+    for count in (att.paged_gqa_rows_read, att.paged_gqa_rows_attended):
+        assert int(count(table, live, block_size=BS, window=window)) == want
 
 
 def test_a_decode_steps_idle_row_reads_nothing_and_gives_zeros():
@@ -480,6 +484,8 @@ def test_decode_load_counts_the_rows_a_layer_of_each_kind_read(toy):
     # off the TPU both kinds gather their tables whole, both rows
     assert int(load["cached_rows_by_kind"]["global"]) == 2 * 32 * BS
     assert int(load["cached_rows_by_kind"]["window"]) == 2 * 6 * BS
+    for kind, rows in load["attended_rows_by_kind"].items():
+        assert int(rows) == int(load["cached_rows_by_kind"][kind])
     assert int(load["cached_rows"]) == (2 * 32 * BS + 3 * 2 * 6 * BS) // 4
 
 

@@ -21,7 +21,11 @@ from znicz_tpu import observability
 from znicz_tpu.core import backend, prng
 from znicz_tpu.ops.filling import fill
 from znicz_tpu.ops.pallas import kept_rows_attention
-from znicz_tpu.ops.pallas.latent_attention import latent_decode_attention
+from znicz_tpu.ops.pallas.latent_attention import (
+    latent_decode_attention,
+    shared_run_decode_attention,
+    shared_run_rows_fetched,
+)
 from znicz_tpu.ops.pallas.sparse_index import index_decode_scores
 
 
@@ -196,7 +200,15 @@ def paged_gqa_attention(
     length (a ring is first turned so that the window's first block
     leads, :func:`_window_in_table_order`, and the kernel is told where
     in that block the window starts); everything else gathers ``pool
-    [block_table]`` at the table's width and computes on the copy.
+    [block_table]`` at the table's width and computes on the copy.  A
+    plain table's in-place step is :func:`~znicz_tpu.ops.pallas.latent
+    _attention.shared_run_decode_attention`: the leading blocks that the
+    live rows of a tile of 8 have IN COMMON (a prefix the cache holds
+    once) are read once for the tile, the rows' queries stacked, and each
+    row then reads only the blocks that are its own, under the same
+    running softmax; rows that share nothing are read as before, bit for
+    bit.  A ring's first block differs a row, so a window layer keeps the
+    one pass.
     ``grouped_prefill``: a call of SEVERAL queries a row walks the table
     instead, as far as its last query, under a running softmax with the
     products grouped a K/V head (:func:`_kept_rows_walk` under the causal
@@ -249,10 +261,36 @@ def paged_gqa_rows_read(
     block_size: int,
     window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Cached rows ONE layer's grouped-query attention reads in a decode
-    step (int32 scalar), by the form that runs here: in place, each row's
-    keys from the first block it attends, rounded up to whole blocks;
-    gathered, every slot's table."""
+    """Cached rows ONE layer's grouped-query attention FETCHES in a decode
+    step (int32 scalar), by the form that runs here: gathered, every
+    slot's table; in place behind a window, each row's keys from the first
+    block it attends, rounded up to whole blocks; in place over a plain
+    table, the blocks a tile of rows have in common ONCE and each row's
+    own (:func:`~znicz_tpu.ops.pallas.latent_attention.shared_run_rows
+    _fetched`).  What the rows ATTEND is :func:`paged_gqa_rows_attended`:
+    the two differ by the rows read once for several."""
+    if window is None and _reads_pool_in_place(1):
+        return shared_run_rows_fetched(
+            block_table, lengths, block_size=block_size
+        )
+    return paged_gqa_rows_attended(
+        block_table, lengths, block_size=block_size, window=window
+    )
+
+
+def paged_gqa_rows_attended(
+    block_table: jnp.ndarray,  # [B, M]
+    lengths: jnp.ndarray,  # [B] int32: position + 1; 0: the row idles
+    *,
+    block_size: int,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """Cached rows the queries of ONE layer's grouped-query attention meet
+    in a decode step (int32 scalar), a row counted once for EACH query
+    that meets it: in place, each row's keys from the first block it
+    attends, rounded up to whole blocks; gathered, every slot's table.
+    :func:`paged_gqa_rows_read` over this is the share of that traffic
+    still fetched."""
     if not _reads_pool_in_place(1):
         return jnp.int32(block_table.size * block_size)
     if window is not None:
@@ -307,11 +345,20 @@ def _paged_gqa_attention(
                 table, keys, block_size=block_size, window=window
             )
         # the kernel wants whole (16, 128) tiles of query rows
-        pad = -h % 16
-        o = latent_decode_attention(
-            jnp.pad(q_row, ((0, 0), (0, pad), (0, 0))), pool, table, keys,
-            scale=scale, d_out=half, starts=starts,
-        )[:, :h].astype(jnp.float32)
+        q_row = jnp.pad(q_row, ((0, 0), (0, -h % 16), (0, 0)))
+        if window is None:  # rows may open with the same blocks: read once
+            o = shared_run_decode_attention(
+                q_row, pool, table, keys, scale=scale, d_out=half,
+                # the value half of a query row is zeros: where rows are
+                # stacked the score product is the MXU's, and skips it
+                q_from=half if half % 128 == 0 else 0,
+            )
+        else:
+            o = latent_decode_attention(
+                q_row, pool, table, keys, scale=scale, d_out=half,
+                starts=starts,
+            )
+        o = o[:, :h].astype(jnp.float32)
     else:
         n_keys = block_table.shape[1] * block_size
         rows = pool[block_table].reshape(b, n_keys, 2 * half)

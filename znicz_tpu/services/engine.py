@@ -74,6 +74,15 @@ attention kept) and ``znicz_serve_sparse_rows_selected_total{phase}``
 live rows in whole tiles, so over decode steps x slots it is the share of
 the batch the selection still visits).
 
+A grouped-query tower with layer kinds reports the cached rows ONE
+layer of each kind fetched in a decode chunk and the rows its queries
+met: ``znicz_serve_decode_cached_rows_total{kind}`` and
+``znicz_serve_decode_attended_rows_total{kind}``.  The first is the
+smaller where the live rows of a global layer open with the same blocks
+(a prefix the cache holds once), which a tile of 8 rows then reads once
+(:func:`~znicz_tpu.ops.pallas.latent_attention.shared_run_decode
+_attention`); their ratio is the share of the per-row traffic still paid.
+
 A program call crosses the host-device link once each way: beside a
 block table a kind it sends ONE packed int32 array (built fresh for the
 call) and what it returns is read in ONE ``jax.device_get``; the rng key
@@ -1190,6 +1199,15 @@ class PagedDecodeEngine:
             "kinds (times the kind's layers: what the tower read)",
             ("kind",),
         )
+        self._m_decode_attended_rows = observability.counter(
+            "znicz_serve_decode_attended_rows_total",
+            "cached rows the queries of ONE layer of a kind met in the "
+            "paged decode program, a row counted for each query that met "
+            "it, summed over its steps; cached_rows_total over this is "
+            "the share of that traffic still fetched (under 1 where "
+            "several rows read the blocks they share once)",
+            ("kind",),
+        )
         self._m_decode_chunks = observability.counter(
             "znicz_serve_decode_chunks_total",
             "paged decode and verify chunks by gather window (blocks)",
@@ -2257,7 +2275,9 @@ class PagedDecodeEngine:
         is the cached rows a layer of it read over the chunk's steps (a
         tower of several kinds: the MEAN over its layers, so that steps x
         layers x this is what the tower read, as for every other tower;
-        ``cached_rows_by_kind`` has a layer of each kind).  The classic
+        ``cached_rows_by_kind`` has a layer of each kind, and ``attended
+        _rows_by_kind`` the rows its queries met, which is more where
+        several rows read shared blocks once).  The classic
         K/V tower reports none: its gather reads every slot's window,
         active or not."""
         load = load or {}
@@ -2269,6 +2289,8 @@ class PagedDecodeEngine:
         )
         for kind, rows in load.get("cached_rows_by_kind", {}).items():
             self._m_decode_cached_rows.labels(kind=kind).inc(int(rows))
+        for kind, rows in load.get("attended_rows_by_kind", {}).items():
+            self._m_decode_attended_rows.labels(kind=kind).inc(int(rows))
         self._m_decode_chunks.labels(window=window).inc()
 
     # -- speculative decoding: draft -> verify -> accept -> rollback ------

@@ -42,6 +42,7 @@ from znicz_tpu.ops import moe as moe_op
 from znicz_tpu.ops.attention import (
     gqa_cache_row,
     paged_gqa_attention,
+    paged_gqa_rows_attended,
     paged_gqa_rows_read,
 )
 from znicz_tpu.ops.normalization import rms_norm
@@ -227,9 +228,13 @@ class WindowGQAMoEModel:
         ``pos`` [B], ``tables`` a ``{kind: [B, width]}`` -> ``(pools,
         logits [B, vocab], load)`` with :meth:`LatentMoEModel
         .decode_step`'s contract.  ``load`` also holds ``cached_rows_by
-        _kind``: the cached rows ONE layer of each kind read in this step
-        (:func:`~znicz_tpu.ops.attention.paged_gqa_rows_read`), and
-        ``cached_rows``, their mean over the tower's layers."""
+        _kind``: the cached rows ONE layer of each kind FETCHED in this
+        step (:func:`~znicz_tpu.ops.attention.paged_gqa_rows_read`: blocks
+        that several live rows of a global layer share count once a tile
+        of rows), ``cached_rows``, their mean over the tower's layers, and
+        ``attended_rows_by_kind``: the rows the layer's queries met, a row
+        counted for each query (:func:`~znicz_tpu.ops.attention.paged_gqa
+        _rows_attended`)."""
         rows = jnp.arange(token.shape[0])
         lengths = pos + 1
         if write_mask is not None:
@@ -249,16 +254,19 @@ class WindowGQAMoEModel:
             None if write_mask is None else write_mask[:, None],
             block_size=block_size, lengths=lengths,
         )
-        by_kind = {
-            kind.name: paged_gqa_rows_read(
-                tables[kind.name], lengths, block_size=block_size,
-                window=kind.window,
-            )
-            for kind in self.cache_kinds
-        }
+        by_kind, attended = (
+            {
+                kind.name: count(
+                    tables[kind.name], lengths, block_size=block_size,
+                    window=kind.window,
+                )
+                for kind in self.cache_kinds
+            }
+            for count in (paged_gqa_rows_read, paged_gqa_rows_attended)
+        )
         layers = self.layer_kinds
         load = dict(
-            load, cached_rows_by_kind=by_kind,
+            load, cached_rows_by_kind=by_kind, attended_rows_by_kind=attended,
             cached_rows=sum(by_kind[k] for k in layers) // len(layers),
         )
         return pools, _head_logits(params, x[:, 0], self.rms_eps), load
