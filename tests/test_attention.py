@@ -243,25 +243,6 @@ class TestPagedAttention:
         np.testing.assert_array_equal(clean[0], pert[0])  # masked out
         assert np.abs(clean[1] - pert[1]).max() > 1e-6  # really attended
 
-    def test_pallas_stub_delegates_to_reference(self):
-        from znicz_tpu.ops.pallas import paged_attention as pp
-
-        bs = 8
-        _, _, k_pool, v_pool, table = self._paged_setup(bs=bs)
-        rng = np.random.default_rng(4)
-        q = rng.normal(size=(2, 1, 2, 8)).astype(np.float32)
-        pos = np.asarray([[9], [17]], np.int32)
-        ref = attention.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-        )
-        out = pp.paged_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(table), jnp.asarray(pos), block_size=bs,
-        )
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
-        assert pp.PALLAS_PAGED_IMPLEMENTED is False
-
     def test_engine_pools_store_heads_merged(self):
         # the stored layout is [n_blocks, block_size, H*hd] (why:
         # tests/test_paged_layout_aot.py); the bytes a block and the

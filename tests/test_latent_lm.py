@@ -22,7 +22,7 @@ from znicz_tpu.ops.attention import paged_latent_attention
 from znicz_tpu.ops.normalization import rms_norm
 from znicz_tpu.services.engine import PagedDecodeEngine
 from znicz_tpu.services.errors import SpeculationUnsupportedError
-from znicz_tpu.workflow import latent_lm
+from znicz_tpu.workflow import latent_lm, paged_tower
 from znicz_tpu.workflow.generate import copy_paged_block
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -299,7 +299,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
 
     want = feed_forward(cfg, block, h, mm, 0)
     chosen, weight = route(h)
-    total = latent_lm._gated(
+    total = paged_tower._gated(
         h, block["shared_gate"], block["shared_up"], block["shared_down"]
     )
     seen, each = 0, 16 // shares
@@ -320,12 +320,12 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(
 def test_a_sliced_head_gives_the_matching_columns_of_the_whole_head(toy):
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.standard_normal((5, 64)), jnp.float32)
-    whole = latent_lm._head_logits(toy.params, x, toy.model.rms_eps)
+    whole = paged_tower._head_logits(toy.params, x, toy.model.rms_eps)
     for lo in (0, 64, 192):
         sliced = list(toy.params)
         sliced[-1] = dict(toy.params[-1], head=toy.params[-1]["head"][:, lo:lo + 64])
         np.testing.assert_allclose(
-            latent_lm._head_logits(sliced, x, toy.model.rms_eps), whole[:, lo:lo + 64], rtol=1e-5, atol=1e-6
+            paged_tower._head_logits(sliced, x, toy.model.rms_eps), whole[:, lo:lo + 64], rtol=1e-5, atol=1e-6
         )
 
 

@@ -117,7 +117,8 @@ def paged_attention(
     softmax, f32 value accumulation.
     """
     if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        # a static shape's root, taken once at trace time on purpose
+        scale = 1.0 / np.sqrt(q.shape[-1])  # znicz-check: disable=ZNC002
     b, tq, h, d = q.shape
     n_keys = block_table.shape[1] * block_size
     # [B, M, bs, H*D] -> [B, M*bs, H*D]: the row-ordered KV window

@@ -6,9 +6,8 @@ every expert held here.
 
 Built on :class:`~znicz_tpu.workflow.window_lm.WindowGQAMoEModel`: the
 cache (``[v, k]`` rows of ``n_kv_heads`` heads a token, a global kind and a
-window kind whose table is a ring), the pools, the tower's walk over the
-layers, the prefill chunk and the decode step are that tower's, unchanged.
-What this one brings is the block:
+window kind whose table is a ring), the pools and what a decode step counts
+are that tower's, unchanged.  What this one brings is the block:
 
 - a WINDOW layer has ``n_heads`` query heads, a GLOBAL layer
   ``global_heads``, over the same K/V heads (``wq``, ``wo`` and the gate
@@ -45,7 +44,7 @@ from znicz_tpu.ops import moe as moe_op
 from znicz_tpu.ops.attention import gqa_cache_row, paged_gqa_attention
 from znicz_tpu.ops.normalization import rms_norm
 from znicz_tpu.ops.rope import apply_rotary, plain_inv_freq, yarn_inv_freq
-from znicz_tpu.workflow.latent_lm import _dot, _gated
+from znicz_tpu.workflow.paged_tower import WINDOW, _dot, _gated
 from znicz_tpu.workflow.window_lm import WindowGQAMoEModel
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -153,13 +152,12 @@ class GatedWindowGQAMoEModel(WindowGQAMoEModel):
             axis=-1,
         )
 
-    def _block_step(self, block, windowed, x, pool, write, table, q_pos,
-                    row_mask, *, block_size, lengths):
-        """One block over ``x`` [B, Tq, D] float32, with :meth:`
-        WindowGQAMoEModel._block_step`'s contract; ``pairs`` is None in a
-        dense layer."""
+    def _block_step(self, block, kind, x, pool, write, table, q_pos,
+                    row_mask, *, block_size, lengths, decode):
+        """One block, a dense or a routed one."""
         b, tq, d = x.shape
         eps, g, hd = self.rms_eps, self.n_kv_heads, self.head_dim
+        windowed = kind == WINDOW
         heads = self.n_heads if windowed else self.global_heads
         u = rms_norm(x, block["attn_norm"], eps=eps)
         q = self._turn(
@@ -183,7 +181,7 @@ class GatedWindowGQAMoEModel(WindowGQAMoEModel):
         if "router" not in block:
             with jax.named_scope("ffn_dense"):
                 y = _gated(h, block["w_gate"], block["w_up"], block["w_down"])
-            return x + y.reshape(b, tq, d), {"kv": kv}, None
+            return x + y.reshape(b, tq, d), {"kv": kv}, None, None
         h = h.astype(block["router"].dtype)
         with jax.named_scope("moe_dispatch"):
             chosen, weight = moe_op.route_sigmoid_topk(
@@ -200,7 +198,7 @@ class GatedWindowGQAMoEModel(WindowGQAMoEModel):
             y = y + _gated(
                 h, block["shared_gate"], block["shared_up"], block["shared_down"]
             )
-        return x + y.reshape(b, tq, d), {"kv": kv}, pairs
+        return x + y.reshape(b, tq, d), {"kv": kv}, pairs, None
 
 
 def init_params(

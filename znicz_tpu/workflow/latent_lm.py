@@ -16,11 +16,10 @@ and the residual stream between products stays float32.
 :class:`LatentMoEModel` is the model KIND the engine is handed
 (``PagedDecodeEngine(params, ..., model=LatentMoEModel(...))``): a frozen,
 hashable description, so it is a static argument of the engine's compiled
-programs, and the three functions the paged engine needs of a tower —
-:meth:`init_pools`, :meth:`prefill_chunk`, :meth:`decode_step` — with the
-classic tower's contracts (:mod:`znicz_tpu.workflow.generate`): one
-``[1, block_size]`` chunk a call, per-row positions, writes of idle rows
-to ``NULL_BLOCK``, validity by absolute key index.
+programs.  The three functions the paged engine needs of a tower are
+:class:`~znicz_tpu.workflow.paged_tower.PagedTower`'s; what is here is the
+block: a prefill chunk attends by the MATERIALISED form, a decode step by
+the ABSORBED one.
 
 Parameter tree: ``[{"embed"}, block_0, ..., block_{L-1}, {"final_norm",
 "head"}]``; a block holds ``attn_norm, wq_a, q_norm, wq_b_nope,
@@ -32,8 +31,6 @@ experts_down, shared_gate, shared_up, shared_down``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,35 +46,11 @@ from znicz_tpu.ops.rope import (
     yarn_attention_factor,
     yarn_inv_freq,
 )
-from znicz_tpu.workflow.generate import NULL_BLOCK
-
-
-def _dot(a, w):
-    """``a @ w`` with ``a`` rounded to the weights' dtype and the sum
-    kept in float32."""
-    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _gated(h, gate, up, down):
-    return _dot(jax.nn.silu(_dot(h, gate)) * _dot(h, up), down)
-
-
-def _head_logits(params, x, eps):
-    """The final norm and the head (whole, or this chip's slice of the
-    vocabulary) over ``x`` [B, D]."""
-    return _dot(rms_norm(x, params[-1]["final_norm"], eps=eps), params[-1]["head"])
-
-
-def _chunk_row(x, last):
-    """The row of a prefill chunk ``x`` [1, C, D] whose logits the call
-    returns: in-chunk index ``last``, the chunk's final one by default."""
-    if last is None:
-        return x[:, -1]
-    return jax.lax.dynamic_index_in_dim(x, last, axis=1, keepdims=False)
+from znicz_tpu.workflow.paged_tower import PagedTower, _dot, _gated, _tiles
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentMoEModel:
+class LatentMoEModel(PagedTower):
     """The sizes the parameters do not carry, and what of the model
     this chip holds."""
 
@@ -146,7 +119,7 @@ class LatentMoEModel:
         copies every pool in and out of the layout it gathers from (6 x
         0.64 GB of temporaries at the axk1-ep16 sizes: the decode program
         did not fit the chip)."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return _tiles(self.kv_lora_rank + self.qk_rope_head_dim)
 
     def _inv_freq(self):
         return yarn_inv_freq(
@@ -161,32 +134,18 @@ class LatentMoEModel:
 
     # -- the cache ----------------------------------------------------------
 
-    def init_pools(self, params, n_blocks: int, block_size: int):
-        """One zeroed ``[n_blocks, block_size, row_width]`` pool of latent
-        rows a layer (``{"kv": ...}``; block ``NULL_BLOCK`` reserved, as
-        in ``init_paged_kv``)."""
-        if n_blocks < 2 or block_size < 1:
-            raise ValueError(
-                f"want n_blocks >= 2 (one is the reserved null block) and "
-                f"block_size >= 1; got {n_blocks}, {block_size}"
-            )
-        shape = (n_blocks, block_size, self.row_width)
-        return [
-            {"kv": jnp.zeros(shape, block["wkv_a"].dtype)}
-            for block in params[1:-1]
-        ]
+    def _pool_rows(self, block, kind):
+        """Latent rows of :attr:`row_width` lanes (``"kv"``)."""
+        return {"kv": self.row_width}, block["wkv_a"].dtype
 
     # -- the tower ----------------------------------------------------------
 
     def _block_step(
-        self, block, x, pool, write, tables, q_pos, row_mask, *, block_size,
-        absorbed, lengths,
+        self, block, kind, x, pool, write, tables, q_pos, row_mask, *,
+        block_size, lengths, decode,
     ):
-        """One block over ``x`` [B, Tq, D] float32: ``write`` scatters the
-        new latent rows into the pool, attention gathers through the
-        tables.  Returns ``(x, pool, pairs)``; ``pairs`` [held] counts the
-        (token, choice) pairs each held expert computed (None in a dense
-        layer)."""
+        """One block: the attention ABSORBED in a decode step, MATERIALISED
+        in a prefill chunk."""
         b, tq, d = x.shape
         eps, dc = self.rms_eps, self.kv_lora_rank
         inv_freq = self._inv_freq()
@@ -210,7 +169,7 @@ class LatentMoEModel:
         o = paged_latent_attention(
             q_nope, q_rope, kv_pool, tables, q_pos, block["wk_b"],
             block["wv_b"], block_size=block_size, scale=self.softmax_scale,
-            absorbed=absorbed, lengths=lengths,
+            absorbed=decode, lengths=lengths,
         )
         x = x + _dot(o, block["wo"])
         h = rms_norm(x, block["ffn_norm"], eps=eps).reshape(b * tq, d)
@@ -233,101 +192,17 @@ class LatentMoEModel:
             y = y + _gated(
                 h, block["shared_gate"], block["shared_up"], block["shared_down"]
             )
-        return x + y.reshape(b, tq, d), {"kv": kv_pool}, pairs
+        return x + y.reshape(b, tq, d), {"kv": kv_pool}, pairs, None
 
-    def _tower(self, params, x, pools, write, tables, q_pos, row_mask, *,
-               block_size, absorbed, lengths=None):
-        new_pools, load = [], []
-        for block, pool in zip(params[1:-1], pools):
-            x, pool, pairs = self._block_step(
-                block, x, pool, write, tables, q_pos, row_mask,
-                block_size=block_size, absorbed=absorbed, lengths=lengths,
-            )
-            new_pools.append(pool)
-            if pairs is not None:
-                load.append(pairs)
-        return x, new_pools, _expert_load(load)
-
-    def prefill_chunk(
-        self, params, pools, table, tokens, offset, *, block_size, last=None,
-    ):
-        """ONE aligned ``[1, block_size]`` chunk of a prompt through the
-        tower by the MATERIALISED attention form; ``(pools, logits [1,
-        vocab], load)`` at in-chunk index ``last`` (the chunk's final
-        position by default).  Positions past ``last`` are right-padding:
-        they write rows no query reaches (see ``paged_prefill_chunk``) and
-        are routed to no expert."""
-        c = tokens.shape[1]
-        if c != block_size:
-            raise ValueError(
-                f"chunk length {c} must equal block_size {block_size} "
-                "(one chunk == one block)"
-            )
-        blk = table[offset // block_size]
-        x = params[0]["embed"][tokens].astype(jnp.float32)
-        q_pos = offset + jnp.arange(c)[None, :]
-        real = None if last is None else (jnp.arange(c) <= last)[None, :]
-
-        def write(pool, new):
-            return pool.at[blk].set(new[0])
-
-        x, pools, load = self._tower(
-            params, x, pools, write, table[None], q_pos, real,
-            block_size=block_size, absorbed=False,
-        )
-        logits = _head_logits(params, _chunk_row(x, last), self.rms_eps)
-        return pools, logits, load
-
-    def decode_step(
-        self, params, pools, tables, token, pos, *, block_size,
-        write_mask=None,
-    ):
-        """One incremental step by the ABSORBED attention form: ``token``
-        [B] at per-row positions ``pos`` [B] -> ``(pools, logits [B,
-        vocab], load)``.  Rows with ``write_mask`` False (done, idle)
-        write to ``NULL_BLOCK``, attend nothing and are routed to no
-        expert.  ``load`` also holds ``cached_rows``: the cached rows a
-        layer's attention read in this step, as the form that ran counts
-        them (:func:`~znicz_tpu.ops.attention.paged_latent_rows_read`)."""
-        rows = jnp.arange(token.shape[0])
-        blk = tables[rows, pos // block_size]
-        lengths = pos + 1
-        if write_mask is not None:
-            blk = jnp.where(write_mask, blk, NULL_BLOCK)
-            lengths = jnp.where(write_mask, lengths, 0)
-        slot = pos % block_size
-        x = params[0]["embed"][token[:, None]].astype(jnp.float32)
-
-        def write(pool, new):
-            return pool.at[blk, slot].set(new[:, 0])
-
-        x, pools, load = self._tower(
-            params, x, pools, write, tables, pos[:, None],
-            None if write_mask is None else write_mask[:, None],
-            block_size=block_size, absorbed=True, lengths=lengths,
-        )
-        load = dict(
-            load or {},
-            cached_rows=paged_latent_rows_read(
+    def _decode_reads(self, tables, lengths, *, block_size):
+        """``cached_rows``: the cached rows a layer's attention read in
+        this step, as the form that ran counts them
+        (:func:`~znicz_tpu.ops.attention.paged_latent_rows_read`)."""
+        return {
+            "cached_rows": paged_latent_rows_read(
                 tables, lengths, block_size=block_size
-            ),
-        )
-        return pools, _head_logits(params, x[:, 0], self.rms_eps), load
-
-
-def _expert_load(per_layer) -> Optional[dict]:
-    """What one call's routed layers did, as small int32 sums that come
-    back with the call's outputs: ``pairs`` [held] (token, choice) pairs
-    by held expert, ``busiest`` the busiest expert's pairs summed over the
-    layers, ``idle`` experts that received no pair, summed likewise."""
-    if not per_layer:
-        return None
-    stacked = jnp.stack(per_layer)  # [layers, held]
-    return {
-        "pairs": jnp.sum(stacked, axis=0),
-        "busiest": jnp.sum(jnp.max(stacked, axis=1)),
-        "idle": jnp.sum(stacked == 0, dtype=jnp.int32),
-    }
+            )
+        }
 
 
 def init_params(

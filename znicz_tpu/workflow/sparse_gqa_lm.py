@@ -6,14 +6,14 @@ norm, all held here (Keye-VL-2.0's language model), served through the
 paged engine as one stage of a pipeline: the layers it is handed, the
 embedding ahead of them and the head behind.
 
-It is built from the towers beside it.  From :mod:`znicz_tpu.workflow
-.window_lm`: the ``[v, k]`` row of ``n_kv_heads`` heads a token, the
+A :class:`~znicz_tpu.workflow.paged_tower.PagedTower` built from the towers
+beside it.  From :mod:`znicz_tpu.workflow.window_lm`: the ``[v, k]`` row of ``n_kv_heads`` heads a token, the
 softmax router over every expert, all of them held.  From
 :mod:`znicz_tpu.workflow.sparse_latent_lm`: the indexer
 (:func:`~znicz_tpu.ops.attention.paged_index_scores`), the exact
 selection (:func:`~znicz_tpu.ops.attention.select_top_keys`), a second
-pool of the indexer's keys block for block beside the cached rows, the
-``sparse_scored`` / ``sparse_selected`` sums.  What is new:
+pool of the indexer's keys block for block beside the cached rows.  What
+is new:
 
 * the selection runs over ``[v, k]`` rows, in every layer: a prefill chunk
   walks the table under the mask with the products grouped a K/V head, a
@@ -42,7 +42,7 @@ experts_gate, experts_up, experts_down``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,20 +56,12 @@ from znicz_tpu.ops.attention import (
 )
 from znicz_tpu.ops.normalization import layer_norm, rms_norm
 from znicz_tpu.ops.rope import apply_rotary, plain_inv_freq
-from znicz_tpu.workflow.generate import NULL_BLOCK, CacheKind
-from znicz_tpu.workflow.latent_lm import (
-    _chunk_row,
-    _dot,
-    _expert_load,
-    _head_logits,
-)
-from znicz_tpu.workflow.sparse_latent_lm import _selection_load, _tiles
-
-GLOBAL = "global"
+from znicz_tpu.workflow.generate import CacheKind
+from znicz_tpu.workflow.paged_tower import GLOBAL, PagedTower, _dot, _tiles
 
 
 @dataclasses.dataclass(frozen=True)
-class SparseGQAMoEModel:
+class SparseGQAMoEModel(PagedTower):
     """The sizes the parameters do not carry."""
 
     n_layers: int
@@ -135,35 +127,21 @@ class SparseGQAMoEModel:
     def routed_layers(params) -> int:
         return len(params) - 2
 
-    def init_pools(self, params, n_blocks: Mapping[str, int], block_size: int):
-        """A layer: one zeroed ``[n_blocks, block_size, 2 * n_kv_heads *
-        head_dim]`` pool of ``[v, k]`` rows (``"kv"``) and one of the
-        indexer's keys beside it (``"idx"``, :attr:`index_row_width`
-        lanes, the same blocks: one table and one allocator state serve
-        both); block ``NULL_BLOCK`` reserved."""
-        n = n_blocks[GLOBAL]
-        if n < 2 or block_size < 1:
-            raise ValueError(
-                f"want n_blocks >= 2 (one is the reserved null block) and "
-                f"block_size >= 1; got {n}, {block_size}"
-            )
-        width = 2 * self.n_kv_heads * self.head_dim
-        return [
-            {
-                "kv": jnp.zeros((n, block_size, width), block["wk"].dtype),
-                "idx": jnp.zeros(
-                    (n, block_size, self.index_row_width), block["wk"].dtype
-                ),
-            }
-            for block in params[1:-1]
-        ]
+    def _pool_rows(self, block, kind):
+        """``[v, k]`` rows of ``n_kv_heads`` heads (``"kv"``) and the
+        indexer's keys beside them (``"idx"``, :attr:`index_row_width`
+        lanes)."""
+        lanes = {
+            "kv": 2 * self.n_kv_heads * self.head_dim,
+            "idx": self.index_row_width,
+        }
+        return lanes, block["wk"].dtype
 
     # -- the tower ----------------------------------------------------------
 
-    def _block_step(self, block, x, pool, write, table, q_pos, row_mask, *,
-                    block_size, lengths):
-        """One block over ``x`` [B, Tq, D] float32: ``(x, pool, pairs,
-        scored, selected)``."""
+    def _block_step(self, block, kind, x, pool, write, table, q_pos,
+                    row_mask, *, block_size, lengths, decode):
+        """One block: every layer selects, every layer routes."""
         b, tq, d = x.shape
         eps, hd = self.rms_eps, self.head_dim
         u = rms_norm(x, block["attn_norm"], eps=eps)
@@ -229,89 +207,13 @@ class SparseGQAMoEModel:
         pool = {"kv": kv, "idx": idx}
         return x + y.reshape(b, tq, d), pool, pairs, selection
 
-    def _tower(self, params, x, pools, write, table, q_pos, row_mask, *,
-               block_size, lengths=None):
-        """``(x, pools, load)``: ``load`` holds the expert-load sums and
-        ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: the keys
-        ONE layer's indexer scored and its attention read over the call's
-        queries, and the rows its selection went over (a prefill chunk's
-        one; a decode step's live rows in whole tiles, :func:`~znicz_tpu
-        .ops.attention.select_live_rows`)."""
-        new_pools, pairs_by_layer, sums = [], [], []
-        for block, pool in zip(params[1:-1], pools):
-            x, pool, pairs, selection = self._block_step(
-                block, x, pool, write, table, q_pos, row_mask,
-                block_size=block_size, lengths=lengths,
-            )
-            new_pools.append(pool)
-            pairs_by_layer.append(pairs)
-            sums.append(selection)
-        load = dict(_expert_load(pairs_by_layer), **_selection_load(sums))
-        return x, new_pools, load
-
-    def prefill_chunk(
-        self, params, pools, table, tokens, offset, *, block_size, last=None,
-    ):
-        """ONE aligned ``[1, block_size]`` chunk of a prompt through the
-        tower, ``table`` a ``{kind: [width]}``; ``(pools, logits [1,
-        vocab], load)`` with :meth:`LatentMoEModel.prefill_chunk`'s
-        contract."""
-        c = tokens.shape[1]
-        if c != block_size:
-            raise ValueError(
-                f"chunk length {c} must equal block_size {block_size} "
-                "(one chunk == one block)"
-            )
-        table = table[GLOBAL]
-        blk = table[offset // block_size]
-        x = params[0]["embed"][tokens].astype(jnp.float32)
-        q_pos = offset + jnp.arange(c)[None, :]
-        real = None if last is None else (jnp.arange(c) <= last)[None, :]
-
-        def write(pool, new):
-            return pool.at[blk].set(new[0])
-
-        x, pools, load = self._tower(
-            params, x, pools, write, table[None], q_pos, real,
-            block_size=block_size,
-        )
-        logits = _head_logits(params, _chunk_row(x, last), self.rms_eps)
-        return pools, logits, load
-
-    def decode_step(
-        self, params, pools, tables, token, pos, *, block_size,
-        write_mask=None,
-    ):
-        """One incremental step: ``token`` [B] at per-row positions
-        ``pos`` [B], ``tables`` a ``{kind: [B, width]}`` -> ``(pools,
-        logits [B, vocab], load)`` with :meth:`WindowGQAMoEModel
-        .decode_step`'s contract.  ``cached_rows`` counts the ``[v, k]``
-        rows ONE layer FETCHED (:func:`~znicz_tpu.ops.attention
-        .kept_rows_fetched`: at most ``index_topk`` a live row, whatever
-        its length); what the indexer scored is ``sparse_scored``."""
-        tables = tables[GLOBAL]
-        rows = jnp.arange(token.shape[0])
-        blk = tables[rows, pos // block_size]
-        lengths = pos + 1
-        if write_mask is not None:
-            blk = jnp.where(write_mask, blk, NULL_BLOCK)
-            lengths = jnp.where(write_mask, lengths, 0)
-        slot = pos % block_size
-        x = params[0]["embed"][token[:, None]].astype(jnp.float32)
-
-        def write(pool, new):
-            return pool.at[blk, slot].set(new[:, 0])
-
-        x, pools, load = self._tower(
-            params, x, pools, write, tables, pos[:, None],
-            None if write_mask is None else write_mask[:, None],
-            block_size=block_size, lengths=lengths,
-        )
+    def _decode_reads(self, tables, lengths, *, block_size):
+        """``cached_rows``: the ``[v, k]`` rows ONE layer FETCHED
+        (:func:`~znicz_tpu.ops.attention.kept_rows_fetched`: at most
+        ``index_topk`` a live row, whatever its length); what the indexer
+        scored is ``sparse_scored``."""
         fetched = kept_rows_fetched(lengths, self.index_topk)
-        load = dict(
-            load, cached_rows=fetched, cached_rows_by_kind={GLOBAL: fetched}
-        )
-        return pools, _head_logits(params, x[:, 0], self.rms_eps), load
+        return dict(cached_rows=fetched, cached_rows_by_kind={GLOBAL: fetched})
 
 
 def init_params(

@@ -5,13 +5,13 @@ both, and many small sigmoid-routed experts beside a shared one, chosen
 with a score bias (dots3-note lineage), served through the paged engine as
 ONE chip's share of an expert-parallel deployment.
 
-It is built from the two towers beside it.  From
-:mod:`znicz_tpu.workflow.latent_lm`: the latent row a token, the low-rank
-queries, the absorbed products, the routed layer that is told which
-experts it holds, the sliced head.  From :mod:`znicz_tpu.workflow
-.window_lm`: two :class:`~znicz_tpu.workflow.generate.CacheKind` s, so the
-engine keeps blocks, free list and tables for each, the window kind's
-table a ring.  What is new:
+A :class:`~znicz_tpu.workflow.paged_tower.PagedTower` built from the two
+towers beside it.  From :mod:`znicz_tpu.workflow.latent_lm`: the latent row
+a token, the low-rank queries, the absorbed products, the routed layer that
+is told which experts it holds, the sliced head.  From :mod:`znicz_tpu
+.workflow.window_lm`: two :class:`~znicz_tpu.workflow.generate.CacheKind` s,
+so the engine keeps blocks, free list and tables for each, the window
+kind's table a ring.  What is new:
 
 * the two kinds of layer have their OWN sizes (heads, latent rank, key
   width, rotary base), so the two kinds of cached row differ in width: a
@@ -59,20 +59,16 @@ from znicz_tpu.ops.attention import (
 )
 from znicz_tpu.ops.normalization import layer_norm, rms_norm
 from znicz_tpu.ops.rope import apply_rotary, plain_inv_freq
-from znicz_tpu.workflow.generate import NULL_BLOCK, CacheKind
-from znicz_tpu.workflow.latent_lm import (
-    _chunk_row,
+from znicz_tpu.workflow.generate import CacheKind
+from znicz_tpu.workflow.paged_tower import (
+    GLOBAL,
+    WINDOW,
+    PagedTower,
     _dot,
-    _expert_load,
     _gated,
-    _head_logits,
+    _rows_a_layer,
+    _tiles,
 )
-
-GLOBAL, WINDOW = "global", "window"
-
-
-def _tiles(lanes: int) -> int:
-    return -(-lanes // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,7 +91,7 @@ class LatentSizes:
 
 
 @dataclasses.dataclass(frozen=True)
-class SparseLatentMoEModel:
+class SparseLatentMoEModel(PagedTower):
     """The sizes the parameters do not carry, which layers are of which
     kind, and what of the model this chip holds."""
 
@@ -186,32 +182,20 @@ class SparseLatentMoEModel:
     def routed_layers(params) -> int:
         return sum(1 for block in params[1:-1] if "router" in block)
 
-    def init_pools(self, params, n_blocks: Mapping[str, int], block_size: int):
-        """One zeroed ``[n_blocks[kind], block_size, row_widths[kind]]``
-        pool of latent rows a layer (``"kv"``) and, in a full layer, one of
-        the indexer's keys beside it (``"idx"``, ``index_head_dim`` lanes,
-        the same blocks: one table and one allocator state serve both);
-        block ``NULL_BLOCK`` reserved in each kind.
+    def _pool_rows(self, block, kind):
+        """Latent rows of ``row_widths[kind]`` lanes (``"kv"``) and, in a
+        full layer, the indexer's keys beside them (``"idx"``,
+        ``index_head_dim`` lanes).
 
         The indexer's keys do not ride in the latent row's tail lanes:
         the indexer reads 128 lanes of EVERY cached token and attention
         640 lanes of 2,048, and for a gather of a lane-slice the TPU's
         compiler re-lays the whole pool (a 1.6 GB copy a layer in every
         call at dots3-ep16-l5's sizes: AOT compile, PR 36)."""
-        if min(n_blocks.values()) < 2 or block_size < 1:
-            raise ValueError(
-                f"want n_blocks >= 2 a kind (one is the reserved null block) "
-                f"and block_size >= 1; got {dict(n_blocks)}, {block_size}"
-            )
-        widths = self.row_widths
-        pools = []
-        for kind, block in zip(self.layer_kinds, params[1:-1]):
-            dtype, n = block["wkv_a"].dtype, n_blocks[kind]
-            pool = {"kv": jnp.zeros((n, block_size, widths[kind]), dtype)}
-            if kind == GLOBAL:
-                pool["idx"] = jnp.zeros((n, block_size, self.index_head_dim), dtype)
-            pools.append(pool)
-        return pools
+        lanes = {"kv": self.row_widths[kind]}
+        if kind == GLOBAL:
+            lanes["idx"] = self.index_head_dim
+        return lanes, block["wkv_a"].dtype
 
     # -- the tower ----------------------------------------------------------
 
@@ -302,99 +286,28 @@ class SparseLatentMoEModel:
             h, block["shared_gate"], block["shared_up"], block["shared_down"]
         ), pairs
 
-    def _tower(self, params, x, pools, writes, tables, q_pos, row_mask, *,
-               block_size, lengths=None):
-        """``(x, pools, load)``: ``load`` holds the expert-load sums and
-        ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: the keys
-        ONE full layer's indexer scored and its attention read over the
-        call's queries, and the rows its selection went over (a prefill
-        chunk's one; a decode step's live rows in whole tiles,
-        :func:`~znicz_tpu.ops.attention.select_live_rows`)."""
+    def _block_step(self, block, kind, x, pool, write, table, q_pos,
+                    row_mask, *, block_size, lengths, decode):
         b, tq, d = x.shape
-        new_pools, pairs_by_layer, sums = [], [], []
-        for block, pool, kind in zip(params[1:-1], pools, self.layer_kinds):
-            is_full = kind == GLOBAL
-            u = rms_norm(x, block["attn_norm"], eps=self.rms_eps)
-            update, pool, selection = self._attention(
-                block, is_full, u, pool, writes[kind], tables[kind], q_pos,
-                block_size=block_size, lengths=lengths,
-            )
-            x = x + update
-            new_pools.append(pool)
-            if is_full:
-                sums.append(selection)
-            y, pairs = self._feed_forward(
-                block,
-                rms_norm(x, block["ffn_norm"], eps=self.rms_eps).reshape(b * tq, d),
-                None if row_mask is None else row_mask.reshape(-1),
-            )
-            x = x + y.reshape(b, tq, d)
-            if pairs is not None:
-                pairs_by_layer.append(pairs)
-        load = dict(_expert_load(pairs_by_layer) or {}, **_selection_load(sums))
-        return x, new_pools, load
-
-    def prefill_chunk(
-        self, params, pools, table, tokens, offset, *, block_size, last=None,
-    ):
-        """ONE aligned ``[1, block_size]`` chunk of a prompt through the
-        tower, ``table`` a ``{kind: [width]}``; ``(pools, logits [1,
-        vocab], load)`` with :meth:`LatentMoEModel.prefill_chunk`'s
-        contract."""
-        c = tokens.shape[1]
-        if c != block_size:
-            raise ValueError(
-                f"chunk length {c} must equal block_size {block_size} "
-                "(one chunk == one block)"
-            )
-        x = params[0]["embed"][tokens].astype(jnp.float32)
-        q_pos = offset + jnp.arange(c)[None, :]
-        real = None if last is None else (jnp.arange(c) <= last)[None, :]
-
-        def write_into(blk):
-            return lambda pool, new: pool.at[blk].set(new[0])
-
-        writes = {
-            kind: write_into(t[(offset // block_size) % t.shape[0]])
-            for kind, t in table.items()
-        }
-        x, pools, load = self._tower(
-            params, x, pools, writes, {k: t[None] for k, t in table.items()},
-            q_pos, real, block_size=block_size,
-        )
-        logits = _head_logits(params, _chunk_row(x, last), self.rms_eps)
-        return pools, logits, load
-
-    def decode_step(
-        self, params, pools, tables, token, pos, *, block_size,
-        write_mask=None,
-    ):
-        """One incremental step: ``token`` [B] at per-row positions
-        ``pos`` [B], ``tables`` a ``{kind: [B, width]}`` -> ``(pools,
-        logits [B, vocab], load)`` with :meth:`WindowGQAMoEModel
-        .decode_step`'s contract.  ``cached_rows_by_kind`` holds the rows
-        ONE layer of each kind read, as the form that runs reads them: a
-        full layer each live row's length (of which it attends the keys
-        selected: ``sparse_selected``), a window layer the window's."""
-        rows = jnp.arange(token.shape[0])
-        lengths = pos + 1
-        if write_mask is not None:
-            lengths = jnp.where(write_mask, lengths, 0)
-        slot = pos % block_size
-        x = params[0]["embed"][token[:, None]].astype(jnp.float32)
-
-        def write_into(t):
-            blk = t[rows, (pos // block_size) % t.shape[1]]
-            if write_mask is not None:
-                blk = jnp.where(write_mask, blk, NULL_BLOCK)
-            return lambda pool, new: pool.at[blk, slot].set(new[:, 0])
-
-        x, pools, load = self._tower(
-            params, x, pools, {k: write_into(t) for k, t in tables.items()},
-            tables, pos[:, None],
-            None if write_mask is None else write_mask[:, None],
+        u = rms_norm(x, block["attn_norm"], eps=self.rms_eps)
+        update, pool, selection = self._attention(
+            block, kind == GLOBAL, u, pool, write, table, q_pos,
             block_size=block_size, lengths=lengths,
         )
+        x = x + update
+        y, pairs = self._feed_forward(
+            block,
+            rms_norm(x, block["ffn_norm"], eps=self.rms_eps).reshape(b * tq, d),
+            None if row_mask is None else row_mask.reshape(-1),
+        )
+        return x + y.reshape(b, tq, d), pool, pairs, selection
+
+    def _decode_reads(self, tables, lengths, *, block_size):
+        """``cached_rows_by_kind``: the rows ONE layer of each kind read,
+        as the form that runs reads them: a full layer each live row's
+        length (of which it attends the keys selected:
+        ``sparse_selected``), a window layer the window's; ``cached_rows``,
+        their mean over the tower's layers."""
         by_kind = {
             GLOBAL: paged_latent_rows_read(
                 tables[GLOBAL], lengths, block_size=block_size
@@ -404,26 +317,10 @@ class SparseLatentMoEModel:
                 window=self.window,
             ),
         }
-        layers = self.layer_kinds
-        load = dict(
-            load, cached_rows_by_kind=by_kind,
-            cached_rows=sum(by_kind[k] for k in layers) // len(layers),
+        return dict(
+            cached_rows_by_kind=by_kind,
+            cached_rows=_rows_a_layer(by_kind, self.layer_kinds),
         )
-        return pools, _head_logits(params, x[:, 0], self.rms_eps), load
-
-
-def _selection_load(per_layer) -> dict:
-    """The selecting layers' ``(scored, selected, visited)`` sums as the
-    load's ``sparse_scored`` / ``sparse_selected`` / ``sparse_rows``: ONE
-    layer's, the mean over the layers that select (empty where none
-    does)."""
-    if not per_layer:
-        return {}
-    names = ("sparse_scored", "sparse_selected", "sparse_rows")
-    return {
-        name: sum(sums) // len(per_layer)
-        for name, sums in zip(names, zip(*per_layer))
-    }
 
 
 def init_params(

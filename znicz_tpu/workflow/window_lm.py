@@ -11,18 +11,16 @@ turns queries and keys by plain rotary frequencies over the whole head and
 attends the last ``window`` keys only, a global layer turns nothing and
 attends every key; the router scores the NORMALISED layer input, before
 attention, with a softmax over the chosen logits; an expert is ``(relu(h
-gate) * h up) down``; there is no shared expert and no dense layer.  What
-is the same is imported from there (the products' precision, the expert
-load's sums, the head), and numerics are the same: weights and cache in
-one dtype (bfloat16 in serving), float32 sums, a float32 residual stream.
+gate) * h up) down``; there is no shared expert and no dense layer.  The
+numerics are the same: weights and cache in one dtype (bfloat16 in
+serving), float32 sums, a float32 residual stream.
 
-:class:`WindowGQAMoEModel` is the model KIND the engine is handed.  It
-declares two :class:`~znicz_tpu.workflow.generate.CacheKind` s, so the
-engine keeps blocks, free list and tables for each and the functions here
-take ``{kind: table}``: the global kind's table is plain, the window
-kind's a ring (entry ``(position // block_size) % width``), which is what
-lets the engine give back the blocks behind the window while the row
-lives.
+:class:`WindowGQAMoEModel` is the model KIND the engine is handed, a
+:class:`~znicz_tpu.workflow.paged_tower.PagedTower`.  It declares two
+:class:`~znicz_tpu.workflow.generate.CacheKind` s, so the engine keeps
+blocks, free list and tables for each: the global kind's table is plain,
+the window kind's a ring, which is what lets the engine give back the
+blocks behind the window while the row lives.
 
 Parameter tree: ``[{"embed"}, block_0, ..., block_{L-1}, {"final_norm",
 "head"}]``; a block holds ``attn_norm, wq, wk, wv, wo, ffn_norm, router,
@@ -32,7 +30,7 @@ experts_gate, experts_up, experts_down``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,19 +45,18 @@ from znicz_tpu.ops.attention import (
 )
 from znicz_tpu.ops.normalization import rms_norm
 from znicz_tpu.ops.rope import apply_rotary, plain_inv_freq
-from znicz_tpu.workflow.generate import NULL_BLOCK, CacheKind
-from znicz_tpu.workflow.latent_lm import (
-    _chunk_row,
+from znicz_tpu.workflow.generate import CacheKind
+from znicz_tpu.workflow.paged_tower import (
+    GLOBAL,
+    WINDOW,
+    PagedTower,
     _dot,
-    _expert_load,
-    _head_logits,
+    _rows_a_layer,
 )
-
-GLOBAL, WINDOW = "global", "window"
 
 
 @dataclasses.dataclass(frozen=True)
-class WindowGQAMoEModel:
+class WindowGQAMoEModel(PagedTower):
     """The sizes the parameters do not carry, and which layers are of
     which kind."""
 
@@ -120,31 +117,17 @@ class WindowGQAMoEModel:
     def routed_layers(params) -> int:
         return len(params) - 2
 
-    def init_pools(self, params, n_blocks: Mapping[str, int], block_size: int):
-        """One zeroed ``[n_blocks[kind], block_size, 2 * n_kv_heads *
-        head_dim]`` pool of ``[v, k]`` rows a layer (``{"kv": ...}``;
-        block ``NULL_BLOCK`` reserved in each kind)."""
-        if min(n_blocks.values()) < 2 or block_size < 1:
-            raise ValueError(
-                f"want n_blocks >= 2 a kind (one is the reserved null block) "
-                f"and block_size >= 1; got {dict(n_blocks)}, {block_size}"
-            )
-        width = 2 * self.n_kv_heads * self.head_dim
-        return [
-            {"kv": jnp.zeros((n_blocks[kind], block_size, width), block["wk"].dtype)}
-            for kind, block in zip(self.layer_kinds, params[1:-1])
-        ]
+    def _pool_rows(self, block, kind):
+        """``[v, k]`` rows of ``n_kv_heads`` heads (``"kv"``)."""
+        return {"kv": 2 * self.n_kv_heads * self.head_dim}, block["wk"].dtype
 
     # -- the tower ----------------------------------------------------------
 
-    def _block_step(self, block, windowed, x, pool, write, table, q_pos,
-                    row_mask, *, block_size, lengths):
-        """One block over ``x`` [B, Tq, D] float32: ``write`` scatters the
-        new ``[v, k]`` rows into the pool, attention reads through the
-        table.  Returns ``(x, pool, pairs)``; ``pairs`` [experts] counts
-        the (token, choice) pairs each expert computed."""
+    def _block_step(self, block, kind, x, pool, write, table, q_pos,
+                    row_mask, *, block_size, lengths, decode):
+        """One block: rotary and the window in a WINDOW layer only."""
         b, tq, d = x.shape
-        eps = self.rms_eps
+        eps, windowed = self.rms_eps, kind == WINDOW
         a = rms_norm(x, block["attn_norm"], eps=eps)
         # routed from the layer's input, before attention
         chosen, weight = moe_op.route_softmax_topk(
@@ -174,86 +157,16 @@ class WindowGQAMoEModel:
                 row_mask=None if row_mask is None else row_mask.reshape(-1),
                 activation=jax.nn.relu,
             )
-        return x + y.reshape(b, tq, d), {"kv": kv}, pairs
+        return x + y.reshape(b, tq, d), {"kv": kv}, pairs, None
 
-    def _tower(self, params, x, pools, writes, tables, q_pos, row_mask, *,
-               block_size, lengths=None):
-        new_pools, load = [], []
-        for block, pool, kind in zip(params[1:-1], pools, self.layer_kinds):
-            x, pool, pairs = self._block_step(
-                block, kind == WINDOW, x, pool, writes[kind], tables[kind],
-                q_pos, row_mask, block_size=block_size, lengths=lengths,
-            )
-            new_pools.append(pool)
-            if pairs is not None:  # a dense layer (a tower built on this one)
-                load.append(pairs)
-        return x, new_pools, _expert_load(load)
-
-    def prefill_chunk(
-        self, params, pools, table, tokens, offset, *, block_size, last=None,
-    ):
-        """ONE aligned ``[1, block_size]`` chunk of a prompt through the
-        tower, ``table`` a ``{kind: [width]}``; ``(pools, logits [1,
-        vocab], load)`` with :meth:`LatentMoEModel.prefill_chunk`'s
-        contract."""
-        c = tokens.shape[1]
-        if c != block_size:
-            raise ValueError(
-                f"chunk length {c} must equal block_size {block_size} "
-                "(one chunk == one block)"
-            )
-        x = params[0]["embed"][tokens].astype(jnp.float32)
-        q_pos = offset + jnp.arange(c)[None, :]
-        real = None if last is None else (jnp.arange(c) <= last)[None, :]
-
-        def write_into(blk):
-            return lambda pool, new: pool.at[blk].set(new[0])
-
-        writes = {
-            kind: write_into(t[(offset // block_size) % t.shape[0]])
-            for kind, t in table.items()
-        }
-        x, pools, load = self._tower(
-            params, x, pools, writes, {k: t[None] for k, t in table.items()},
-            q_pos, real, block_size=block_size,
-        )
-        logits = _head_logits(params, _chunk_row(x, last), self.rms_eps)
-        return pools, logits, load
-
-    def decode_step(
-        self, params, pools, tables, token, pos, *, block_size,
-        write_mask=None,
-    ):
-        """One incremental step: ``token`` [B] at per-row positions
-        ``pos`` [B], ``tables`` a ``{kind: [B, width]}`` -> ``(pools,
-        logits [B, vocab], load)`` with :meth:`LatentMoEModel
-        .decode_step`'s contract.  ``load`` also holds ``cached_rows_by
-        _kind``: the cached rows ONE layer of each kind FETCHED in this
-        step (:func:`~znicz_tpu.ops.attention.paged_gqa_rows_read`: blocks
-        that several live rows of a global layer share count once a tile
-        of rows), ``cached_rows``, their mean over the tower's layers, and
-        ``attended_rows_by_kind``: the rows the layer's queries met, a row
-        counted for each query (:func:`~znicz_tpu.ops.attention.paged_gqa
-        _rows_attended`)."""
-        rows = jnp.arange(token.shape[0])
-        lengths = pos + 1
-        if write_mask is not None:
-            lengths = jnp.where(write_mask, lengths, 0)
-        slot = pos % block_size
-        x = params[0]["embed"][token[:, None]].astype(jnp.float32)
-
-        def write_into(t):
-            blk = t[rows, (pos // block_size) % t.shape[1]]
-            if write_mask is not None:
-                blk = jnp.where(write_mask, blk, NULL_BLOCK)
-            return lambda pool, new: pool.at[blk, slot].set(new[:, 0])
-
-        x, pools, load = self._tower(
-            params, x, pools, {k: write_into(t) for k, t in tables.items()},
-            tables, pos[:, None],
-            None if write_mask is None else write_mask[:, None],
-            block_size=block_size, lengths=lengths,
-        )
+    def _decode_reads(self, tables, lengths, *, block_size):
+        """``cached_rows_by_kind``: the cached rows ONE layer of each kind
+        FETCHED in this step (:func:`~znicz_tpu.ops.attention.paged_gqa_rows
+        _read`: blocks that several live rows of a global layer share count
+        once a tile of rows), ``cached_rows``, their mean over the tower's
+        layers, and ``attended_rows_by_kind``: the rows the layer's queries
+        met, a row counted for each query (:func:`~znicz_tpu.ops.attention
+        .paged_gqa_rows_attended`)."""
         by_kind, attended = (
             {
                 kind.name: count(
@@ -264,12 +177,10 @@ class WindowGQAMoEModel:
             }
             for count in (paged_gqa_rows_read, paged_gqa_rows_attended)
         )
-        layers = self.layer_kinds
-        load = dict(
-            load, cached_rows_by_kind=by_kind, attended_rows_by_kind=attended,
-            cached_rows=sum(by_kind[k] for k in layers) // len(layers),
+        return dict(
+            cached_rows_by_kind=by_kind, attended_rows_by_kind=attended,
+            cached_rows=_rows_a_layer(by_kind, self.layer_kinds),
         )
-        return pools, _head_logits(params, x[:, 0], self.rms_eps), load
 
 
 def init_params(
